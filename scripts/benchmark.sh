@@ -3,6 +3,9 @@
 # a branch, run the example matrix, record metrics). Air-gapped subset:
 # the randomwalks examples train from scratch; bench.py measures PPO
 # throughput on a GPT2-small-class workload.
+# One process per chip: each step below is its own process and they run
+# one after another. Compiles are cached under $JAX_COMPILATION_CACHE_DIR,
+# else <checkout>/.jax_cache (trlx_tpu/utils/compile_cache.py).
 set -e
 cd "$(dirname "$0")/.."
 

@@ -285,3 +285,32 @@ def test_bias_kernel_matches_reference(causal):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), atol=5e-5, err_msg=name
         )
+
+
+def test_flash_attention_on_mesh_matches_single_device():
+    """GSPMD cannot partition a Mosaic call, so on a multi-device mesh
+    the kernel runs under shard_map (batch over dp x fsdp, heads over
+    tp): values and gradients equal the unmeshed call, and shapes the
+    mesh does not divide raise instead of replicating."""
+    from trlx_tpu.ops.flash_attention import flash_attention_on_mesh
+    from trlx_tpu.parallel import make_mesh
+
+    mesh = make_mesh({"dp": 2, "fsdp": 2, "tp": 2})
+    B, H, Hkv, T, D = 8, 4, 2, 16, 8
+    rng = np.random.default_rng(3)
+    q = jnp.asarray(rng.normal(size=(B, H, T, D)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(B, Hkv, T, D)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(B, Hkv, T, D)), jnp.float32)
+    mask = jnp.ones((B, T), jnp.int32).at[0, :5].set(0)
+
+    def loss(on_mesh):
+        def f(q_, k_, v_):
+            return (flash_attention_on_mesh(on_mesh, q_, k_, v_, mask) ** 2).sum()
+        return jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2)))
+
+    (lm, gm), (l1, g1) = loss(mesh)(q, k, v), loss(None)(q, k, v)
+    np.testing.assert_allclose(float(lm), float(l1), rtol=1e-5)
+    for a, b in zip(gm, g1):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5, rtol=2e-4)
+    with pytest.raises(ValueError, match="must divide"):
+        flash_attention_on_mesh(mesh, q[:6], k[:6], v[:6], mask[:6])

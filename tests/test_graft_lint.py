@@ -625,7 +625,7 @@ def test_baseline_then_diff_reports_only_new(tmp_path):
 # ---------------------------------------------------------------------------
 
 def test_whole_repo_lint_is_clean():
-    """check_bench_sync-style loud failure: the tree must lint clean,
+    """Loud failure: the tree must lint clean,
     with every suppression carrying a reasoned pragma (bad-pragma
     findings fail here too)."""
     findings = runner.run_repo(REPO)

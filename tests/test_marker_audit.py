@@ -226,25 +226,6 @@ def test_total_budget_fits_tier1_timeout():
     )
 
 
-def test_bench_docs_and_artifacts_in_sync():
-    """The r06-gap closer (ISSUE 8 satellite): a trajectory row that
-    claims a number without its ``BENCH_rNN.json`` artifact — or an
-    artifact with no row — fails tier-1. ``bench.py --record`` writes
-    both in one step so they cannot drift."""
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "check_bench_sync",
-        os.path.join(
-            os.path.dirname(TESTS_DIR), "scripts", "check_bench_sync.py"
-        ),
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    problems = mod.check()
-    assert not problems, "\n".join(problems)
-
-
 def test_learn_loops_outside_allowlist_are_slow_marked():
     offenders = []
     for fname in _test_files():

@@ -132,10 +132,6 @@ def test_ppo_learn_two_processes_pp_stages(tmp_path):
     assert sums[0] == sums[-1], sums
 
 
-from tests.jax_compat import requires_multiprocess_cpu
-
-
-@requires_multiprocess_cpu
 def test_ppo_ragged_two_processes(tmp_path):
     """Ragged per-group shapes on multi-host: 3 local rows over 4 local
     data ways on every rollout chunk and eval batch. Both processes must

@@ -24,8 +24,6 @@ from trlx_tpu.models.wrappers import CausalLMWithValueHead
 from trlx_tpu.parallel import make_mesh, shard_params
 from trlx_tpu.parallel.mesh import data_sharding
 
-from tests.jax_compat import requires_shard_map
-
 
 def tiny_cfg(**kw):
     base = dict(
@@ -65,7 +63,6 @@ def test_pp_forward_matches_sequential(axes):
 
 
 @pytest.mark.parametrize("n_microbatch", [2, 4, 8])
-@requires_shard_map
 def test_pp_microbatch_counts(n_microbatch):
     cfg = tiny_cfg(pp_microbatches=n_microbatch)
     lm = TransformerLM(cfg)
@@ -82,7 +79,6 @@ def test_pp_microbatch_counts(n_microbatch):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5, rtol=1e-5)
 
 
-@requires_shard_map
 def test_pp_multi_capture_parity():
     """Hydra + value-branch fork hiddens out of the pipelined pass equal
     the segmented sequential scan's captures."""
@@ -137,7 +133,6 @@ def test_pp_grad_parity(remat):
         np.testing.assert_allclose(np.asarray(b), np.asarray(a), atol=1e-5, rtol=1e-4)
 
 
-@requires_shard_map
 def test_pp_forward_train_hydra_parity():
     """The PPO teacher-forced pass (policy logits + values + frozen
     reference logits) is invariant to pipelining."""
@@ -165,7 +160,6 @@ def test_pp_forward_train_hydra_parity():
         )
 
 
-@requires_shard_map
 def test_pp_alibi_local_window_flags():
     """Per-layer global/local attention flags (gpt-neo) ride the stacked
     xs into the pipeline stages; alibi biases are per-microbatch ctx."""
@@ -212,7 +206,6 @@ def test_pp_sp_mutually_exclusive():
         lm(params, ids, mask)
 
 
-@requires_shard_map
 def test_pp_out_of_range_capture_points_omitted():
     """points >= n_layer are omitted under pp, matching the sequential
     path (which never captures them), not returned as zeros."""
@@ -317,7 +310,6 @@ def test_ppo_learn_on_pp_mesh(tmp_path):
     assert trainer.iter_count == 2
 
 
-@requires_shard_map
 def test_pp_ilql_forward_parity():
     """ILQL's head group reads the final hidden out of the pipelined
     trunk; Q/V head outputs must be pipelining-invariant."""
@@ -496,7 +488,6 @@ def test_pp_t5_bf16_grad_compiles():
     )
 
 
-@requires_shard_map
 def test_pp_prompt_tuning_parity():
     """Teacher-forced prompt tuning (soft tokens as leading positions)
     rides through the pipelined forward unchanged."""

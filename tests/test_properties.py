@@ -223,6 +223,38 @@ def test_adam8bit_registry_and_trainer(tmp_path):
     assert trainer.iter_count == 2
 
 
+def test_adam8bit_step_stays_bounded_when_v_underflows_its_code(monkeypatch):
+    """An entry whose gradient sits far below its block's largest loses
+    its second moment to the zero code while its first moment survives;
+    when its gradient then passes near zero, the step must not divide a
+    healthy m by ~eps (the 1.3B recipe's value head blew up this way on
+    the chip). Both int8 paths stay within exact Adam's own step bound."""
+    from trlx_tpu.ops import adam8bit
+    from trlx_tpu.ops.adam8bit import (
+        fused_adamw_8bit_update,
+        scale_by_adam_8bit,
+    )
+
+    monkeypatch.setattr(adam8bit, "_FUSED_CHUNK_ELEMS", 512)  # one small chunk
+    lr = 1e-3
+    params = {"w": jnp.zeros((256,), jnp.float32)}
+    g1 = {"w": jnp.full((256,), 1e-3).at[0].set(1.0)}  # 1000:1 in one block
+    g2 = {"w": jnp.full((256,), 1e-9).at[0].set(1.0)}  # ... then ~zero
+
+    tx = scale_by_adam_8bit()
+    state = tx.init(params)
+    p_fused, s_fused = params, state
+    worst = 0.0
+    for g in (g1, g2):
+        u, state = tx.update(g, state, params)
+        worst = max(worst, float(jnp.abs(u["w"]).max()))
+        p_fused, s_fused = fused_adamw_8bit_update(p_fused, g, s_fused, lr)
+    # exact Adam's step is bounded by (1-b1)/sqrt(1-b2) ~ 3.2; the
+    # unfloored code gave ~2e4 here
+    assert worst < 5.0, worst
+    assert float(jnp.abs(p_fused["w"]).max()) < 2 * 5.0 * lr
+
+
 def test_fused_adamw_8bit_matches_optax_path():
     """The fused blockwise apply (dequantize -> update -> requantize ->
     param apply streamed per chunk, no fp32 moment/updates tree) computes

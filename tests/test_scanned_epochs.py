@@ -207,12 +207,14 @@ def test_prefetch_cursor_excluded_until_trained(tmp_path):
 def test_async_metrics_off_restores_immediate_flush(tmp_path):
     """train.async_metrics=false: every fused block flushes its stats
     synchronously (no deferral), and the run still matches the step
-    budget — the escape hatch for exact per-block observability."""
+    budget — the escape hatch for exact per-block observability. Rides
+    along: checkpoint_interval=0 with save_best off commits nothing, not
+    even the final checkpoint."""
     ckpt_dir = str(tmp_path / "ckpts")
     config = ppo_tiny_config(
         ckpt_dir,
         train=dict(total_steps=2, epochs=2, eval_interval=100,
-                   checkpoint_interval=100, save_best=False,
+                   checkpoint_interval=0, save_best=False,
                    async_metrics=False),
     )
     trainer = trlx_tpu.train(
@@ -220,6 +222,7 @@ def test_async_metrics_off_restores_immediate_flush(tmp_path):
     )
     assert trainer.iter_count == 2
     assert not trainer._deferred_train
+    assert not [d for d in os.listdir(ckpt_dir) if "checkpoint" in d]
     losses = [
         r["losses/total_loss"] for r in read_metrics(ckpt_dir)
         if "losses/total_loss" in r

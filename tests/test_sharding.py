@@ -117,10 +117,6 @@ def test_local_batch_size():
         local_batch_size(mesh, 12)
 
 
-from tests.jax_compat import requires_shard_map
-
-
-@requires_shard_map
 def test_loss_invariant_across_meshes():
     # the same SFT loss must come out (to fp tolerance) under pure-dp,
     # fsdp, and tp meshes — the vocab-parallel logits/xent and megatron

@@ -42,6 +42,7 @@ TRACED_ARG_POSITIONS = {
     "jax.lax.fori_loop": (2,),
     "jax.lax.cond": (1, 2),
     "jax.lax.switch": (1,),  # list of branches
+    "jax.shard_map": (0,),
     "jax.experimental.shard_map.shard_map": (0,),
     "jax.vmap": (0,),
     "jax.grad": (0,),

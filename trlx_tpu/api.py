@@ -25,6 +25,7 @@ from trlx_tpu.data.default_configs import (
     default_sft_config,
 )
 from trlx_tpu.utils import logging, set_seed
+from trlx_tpu.utils.compile_cache import enable_compile_cache
 from trlx_tpu.utils.loading import get_pipeline, get_trainer
 
 logger = logging.get_logger(__name__)
@@ -62,6 +63,8 @@ def train(
             config = default_sft_config()
 
     set_seed(config.train.seed)
+    # before the trainer exists: its constructor already compiles
+    enable_compile_cache()
 
     if dataset is not None:
         warnings.warn("the `dataset` argument is being depreciated, split it into `samples` and `rewards` instead")

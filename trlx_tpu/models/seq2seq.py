@@ -474,9 +474,17 @@ class T5LM:
         """Static gate for the fused-attention path: teacher-forced
         shapes with 128-divisible sequence dims (Mosaic lane/DMA
         alignment); decode steps (cache) never come through here."""
-        return self.cfg.attention_impl == "pallas" and all(
-            d % 128 == 0 for d in seq_dims
-        )
+        if self.cfg.attention_impl != "pallas":
+            return False
+        ok = all(d % 128 == 0 for d in seq_dims)
+        if not ok:
+            from trlx_tpu.ops.common import warn_pallas_fallback
+
+            warn_pallas_fallback(
+                "seq2seq teacher-forced forward",
+                f"sequence dims {seq_dims} are not all multiples of 128",
+            )
+        return ok
 
     def _self_attn_args(self, params, stack: str, T: int, key_mask, causal,
                         use_pallas: bool):

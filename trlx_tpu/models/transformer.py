@@ -36,6 +36,9 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from trlx_tpu.ops.common import interpret_mode as _interpret_mode
+from trlx_tpu.ops.common import warn_pallas_fallback as _warn_pallas_fallback
+
 Array = jnp.ndarray
 NEG_INF = -1e9  # additive mask value (finite: avoids NaN rows for all-masked)
 
@@ -215,6 +218,9 @@ class Norm(nn.Module):
 
 class Attention(nn.Module):
     cfg: TransformerConfig
+    # device mesh the fused pallas kernels are shard_mapped over
+    # (TransformerLM.mesh); None = one device
+    mesh: Any = None
 
     @nn.compact
     def __call__(
@@ -454,14 +460,40 @@ class Attention(nn.Module):
             and T % 8 == 0
         ):
             prefill_offset = cache["static_index"]
-        use_pallas = (
+        # a teacher-forced forward is differentiated, and Mosaic lowers
+        # the dk/dv kernel's key-mask lane slice only at a 128-aligned
+        # key length (the forward alone takes any shape); interpret mode
+        # has no such floor, so the CPU parity tests keep their sizes
+        teacher_aligned = _interpret_mode() or (
+            T % 8 == 0 and k.shape[1] % 128 == 0
+        )
+        wants_pallas = (
             cfg.attention_impl == "pallas"
             and ring_mesh is None
-            and key_mask is not None
-            and plain_bias
-            and (cache is None or prefill_offset is not None)
             and kernel_out is None
         )
+        use_pallas = (
+            wants_pallas
+            and key_mask is not None
+            and plain_bias
+            and (teacher_aligned if cache is None else prefill_offset is not None)
+        )
+        if (
+            wants_pallas
+            and not use_pallas
+            and T > 1
+            and (cache is None or isinstance(cache.get("static_index"), int))
+        ):
+            # a teacher-forced forward or a prefill asked for the fused
+            # kernel and takes XLA instead: say so, once per shape
+            # (decode steps staying on XLA is the design, not a fallback)
+            _warn_pallas_fallback(
+                "teacher-forced forward" if cache is None else "prefill",
+                f"T={T} S={k.shape[1]} plain_bias={plain_bias} "
+                f"key_mask={key_mask is not None}: the kernels need "
+                "T % 8 == 0, S % 128 == 0, 1/sqrt(D) scaling and a "
+                "causal+padding mask",
+            )
         if Hkv != H and not use_pallas and kernel_out is None:
             # grouped-query on the XLA/ring paths: repeat kv heads (the
             # pallas kernel handles GQA natively and must NOT see
@@ -483,9 +515,10 @@ class Attention(nn.Module):
                 q, k, v, ring_mesh, segment_mask=key_mask, causal=True
             )
         elif use_pallas:
-            from trlx_tpu.ops.flash_attention import flash_attention
+            from trlx_tpu.ops.flash_attention import flash_attention_on_mesh
 
-            out = flash_attention(
+            out = flash_attention_on_mesh(
+                self.mesh,
                 q.transpose(0, 2, 1, 3),
                 k.transpose(0, 2, 1, 3),
                 v.transpose(0, 2, 1, 3),
@@ -703,6 +736,7 @@ class Block(nn.Module):
     (gptj/neox) residual layout."""
 
     cfg: TransformerConfig
+    mesh: Any = None  # forwarded to Attention
 
     @nn.compact
     def __call__(
@@ -716,7 +750,7 @@ class Block(nn.Module):
     ) -> Tuple[Array, Optional[Dict[str, Array]]]:
         cfg = self.cfg
         h = Norm(cfg, name="ln_1")(x)
-        attn_out, new_kv = Attention(cfg, name="attn")(
+        attn_out, new_kv = Attention(cfg, self.mesh, name="attn")(
             h, attn_bias, positions, cache, key_mask, ring_mesh
         )
         if cfg.parallel_residual:
@@ -847,9 +881,21 @@ class TransformerLM:
         self.block = Block(cfg)
         self.ln_f = Norm(cfg)  # stateless: also applied with ln_embed params
         self.lm_head = None if cfg.tie_word_embeddings else LMHead(cfg)
-        # set by the trainer when cfg.attention_impl == "ring": the device
-        # mesh whose `sp` axis carries the sequence shards
-        self.mesh = None
+        self._mesh = None
+
+    @property
+    def mesh(self):
+        """The device mesh, set by the trainer when the model itself must
+        know it: ring attention (`sp` carries the sequence shards),
+        pipelining (`pp` carries the layer stages), and the pallas
+        kernels on more than one device (GSPMD cannot partition a Mosaic
+        call, so Attention shard_maps it over this mesh)."""
+        return self._mesh
+
+    @mesh.setter
+    def mesh(self, mesh) -> None:
+        self._mesh = mesh
+        self.block = Block(self.cfg, mesh)
 
     def _ring_mesh(self, batch: int, seq: int, cache) -> Optional[Any]:
         """The mesh to run ring attention over, or None for the XLA/pallas

@@ -4,8 +4,8 @@ self-documenting perf artifacts (``train.obs.*``).
 The repo grew five telemetry islands — watchdog phase beats, guardrail
 trip history, memdoctor watermarks/OOM events, fleet membership and
 broadcast records, and the supervisor's JSONL ledger — with no shared
-timeline; and the bench trajectory went blind whenever nobody ran
-``bench.py --record`` on a TPU. This subsystem closes both gaps:
+timeline; and a run's speed was known only if someone benchmarked it
+by hand. This subsystem closes both gaps:
 
   SpanTracer (obs/spans.py)
       a sibling consumer of the hang doctor's existing beat sites
@@ -30,8 +30,8 @@ timeline; and the bench trajectory went blind whenever nobody ran
       samples/s, phase breakdown, engine occupancy/refills/reclaimed
       pages, an analytic-FLOPs MFU estimate reusing the memory
       doctor's param accounting) and commits a ``telemetry.json``
-      snapshot alongside every checkpoint — so every run records an
-      r05-comparable trajectory point even when nobody runs bench.
+      snapshot alongside every checkpoint — so every run records its
+      own numbers, stamped with the device they were taken on.
   ProfilerArm (obs/profiler.py)
       on-demand ``jax.profiler`` window capture for cycles N..M
       (``train.obs.profile.*``), or one-shot on a guardrail

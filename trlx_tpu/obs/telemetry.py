@@ -3,8 +3,7 @@ numbers, derived continuously from the trainer's OWN flushed stats.
 
 The aggregator consumes exactly two inputs, both already produced by
 the training loop (so the telemetry accounting CANNOT drift from the
-trainer's accounting — the r06..r10 bench blindness was five rounds of
-numbers living only in someone's terminal):
+trainer's accounting):
 
 - per-cycle span snapshots (wall + phase partition) and sample/token
   counts from the rollout loop's honest mask-weighted ledger
@@ -14,10 +13,8 @@ numbers living only in someone's terminal):
 
 ``telemetry.json`` is committed alongside every checkpoint and
 refreshed at the flight-dir root, provenance-stamped (run id, device
-kind+count, backend, model geometry, timestamp) so
-``scripts/check_bench_sync.py`` accepts it as a legal trajectory
-artifact for docs/benchmarks.md rows — every TPU run records an
-r05-comparable point even when nobody runs ``bench.py --record``.
+kind+count, backend, model geometry, timestamp), so every run carries
+its own record of where its numbers came from.
 
 The MFU estimate is analytic (2P FLOPs/token forward, 6P train
 fwd+bwd, ref/experience forwards counted once each), reusing the
@@ -31,9 +28,9 @@ from __future__ import annotations
 import time
 from typing import Any, Dict, List, Optional
 
-# bf16 dense-matmul peak per chip, by device kind (same table bench.py
-# carries; duplicated rather than imported — bench.py is a script, not
-# a package module)
+# bf16 dense-matmul peak per chip, by device kind — the one table
+# (bench.py reads it through chip_peak_tflops). A device that is not
+# listed has no peak: no default stands in for it.
 PEAK_TFLOPS = {
     "TPU v4": 275.0, "TPU v5 lite": 197.0, "TPU v5": 459.0,
     "TPU v6 lite": 918.0,
@@ -56,16 +53,18 @@ def tree_param_count(tree) -> int:
     return total
 
 
-def chip_peak_tflops(device_kind: str) -> float:
+def chip_peak_tflops(device_kind: str) -> Optional[float]:
+    """Peak for ``device_kind`` (longest matching prefix of the table),
+    or None for a device the table does not know."""
     for key, peak in sorted(PEAK_TFLOPS.items(), key=lambda kv: -len(kv[0])):
         if device_kind.startswith(key):
             return peak
-    return 197.0  # conservative default for unknown chips
+    return None
 
 
 def device_provenance() -> Dict[str, Any]:
-    """Best-effort device stamp (CPU containers stamp honestly as
-    cpu — the r09/r10 lesson: a non-TPU artifact must SAY so)."""
+    """Best-effort device stamp (a CPU run stamps honestly as cpu: a
+    non-TPU artifact must SAY so)."""
     try:
         import jax
 
@@ -245,7 +244,8 @@ class TelemetryAggregator:
         teacher-forced forwards (4P per sample-token), train steps pay
         fwd+bwd (6P per trained token). P from the memory doctor's
         param accounting; peak from the device kind. None when any
-        input is unknown (CPU runs report no MFU rather than a fake)."""
+        input is unknown (a CPU run or a chip missing from PEAK_TFLOPS
+        reports no MFU rather than a fake)."""
         if not self._param_count or not rows:
             return None
         prov = self.static.get("device") or {}
@@ -263,10 +263,10 @@ class TelemetryAggregator:
         exp_tokens = sum(r.get("samples", 0) for r in rows) * seq
         train_tokens = sum(r.get("train_steps", 0) for r in rows) * batch * seq
         flops = 2.0 * p * gen_tokens + 4.0 * p * exp_tokens + 6.0 * p * train_tokens
-        peak = (
-            chip_peak_tflops(prov.get("device_kind", "")) * 1e12
-            * max(int(prov.get("device_count", 1)), 1)
-        )
+        chip_peak = chip_peak_tflops(prov.get("device_kind", ""))
+        if chip_peak is None:
+            return None
+        peak = chip_peak * 1e12 * max(int(prov.get("device_count", 1)), 1)
         return round(flops / wall / peak, 4)
 
     # -- snapshot / persistence ------------------------------------------

@@ -15,6 +15,7 @@ reductions are per-shard.
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, MutableMapping, Optional, Tuple, Union
 
 import flax.struct
@@ -231,6 +232,18 @@ def interpret_mode() -> bool:
     kernel through this path, which is what makes kernel==reference
     goldens runnable without device time."""
     return jax.default_backend() == "cpu"
+
+
+@functools.lru_cache(maxsize=None)
+def warn_pallas_fallback(where: str, why: str) -> None:
+    """`attention_impl="pallas"` was asked for and `where` takes the XLA
+    path instead: one warning per distinct (where, why) — the cache is
+    the log-once — so a run can always tell which implementation ran."""
+    from trlx_tpu.utils import logging
+
+    logging.get_logger(__name__).warning(
+        "attention_impl=pallas: %s runs on the XLA path (%s)", where, why
+    )
 
 
 def pick_block(n: int, block: int) -> int:

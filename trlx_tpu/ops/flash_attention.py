@@ -432,6 +432,40 @@ def _bwd(causal, sm_scale, block_q, q_offset, res, g):
 flash_attention.defvjp(_fwd, _bwd)
 
 
+def flash_attention_on_mesh(mesh, q, k, v, key_mask, q_offset=None):
+    """:func:`flash_attention` under a device mesh (None = one device).
+
+    GSPMD cannot partition a Mosaic custom call — on a multi-device mesh
+    JAX refuses to lower one outside a shard_map — so the call is a
+    shard_map over the axes attention is embarrassingly parallel in:
+    batch rows over (dp, fsdp), heads over tp. Each device runs the
+    kernel on its own rows and heads; no collective is involved, and the
+    specs are the layout the activations already have. Shapes the mesh
+    does not divide raise: a replicated fallback would run every chip
+    over the whole batch without a word. Under pp > 1 the block already
+    runs inside the pipeline's shard_map and the call is left as it is.
+    """
+    if mesh is None or mesh.size == 1 or mesh.shape.get("pp", 1) > 1:
+        return flash_attention(q, k, v, key_mask, q_offset=q_offset)
+    from jax.sharding import PartitionSpec as P
+
+    data, tp = mesh.shape["dp"] * mesh.shape["fsdp"], mesh.shape["tp"]
+    if q.shape[0] % data or q.shape[1] % tp or k.shape[1] % tp:
+        raise ValueError(
+            f"attention_impl=pallas on mesh {dict(mesh.shape)}: batch "
+            f"{q.shape[0]} must divide over dp*fsdp={data} and heads "
+            f"{q.shape[1]}/{k.shape[1]} (q/kv) over tp={tp}"
+        )
+    heads = P(("dp", "fsdp"), "tp", None, None)
+    return jax.shard_map(
+        lambda q_, k_, v_, m_: flash_attention(q_, k_, v_, m_, q_offset=q_offset),
+        mesh=mesh,
+        in_specs=(heads, heads, heads, P(("dp", "fsdp"), None)),
+        out_specs=heads,
+        check_vma=False,
+    )(q, k, v, key_mask)
+
+
 # ---------------------------------------------------------------------------
 # Bias-carrying variant (T5 relative position bias).
 #

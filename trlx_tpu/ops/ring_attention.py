@@ -120,19 +120,17 @@ def ring_attention_sharded(
 ) -> jnp.ndarray:
     """shard_map wrapper: sequence dim sharded over 'sp', batch over
     (dp, fsdp), heads over 'tp'."""
-    from jax.experimental.shard_map import shard_map
-
     spec_qkv = P(("dp", "fsdp"), "sp", "tp", None)
     spec_mask = P(("dp", "fsdp"), "sp")
 
     fn = partial(ring_attention, axis_name="sp", causal=causal)
     if segment_mask is None:
-        sharded = shard_map(
+        sharded = jax.shard_map(
             lambda q_, k_, v_: fn(q_, k_, v_),
             mesh=mesh, in_specs=(spec_qkv,) * 3, out_specs=spec_qkv,
         )
         return sharded(q, k, v)
-    sharded = shard_map(
+    sharded = jax.shard_map(
         lambda q_, k_, v_, m_: fn(q_, k_, v_, segment_mask=m_),
         mesh=mesh, in_specs=(spec_qkv,) * 3 + (spec_mask,), out_specs=spec_qkv,
     )

@@ -82,7 +82,7 @@ class OptimizerName(str, Enum):
     ADAMW_8BIT_BNB = "adamw_8bit_bnb"  # first-party int8-state adamw (ops/adam8bit.py)
     # fused apply variant: dequantize->update->requantize->param write
     # streamed per block chunk, no fp32 moment/updates tree — the
-    # memory-tight large-model recipe (docs/benchmarks.md)
+    # memory-tight large-model recipe (configs/mesh/single_chip_1p3b.yml)
     ADAMW_8BIT_FUSED = "adamw_8bit_fused"
     SGD = "sgd"
     LION = "lion"

@@ -17,12 +17,21 @@ class ByteTokenizer:
     """UTF-8 byte tokenizer with bos/eos/pad specials.
 
     ids 0..255 = bytes; 256 = bos, 257 = eos; pad = eos (the gpt2
-    convention the reference relies on).
+    convention the reference relies on). A wider `vocab_size` (a
+    random-init model at a real vocabulary width, e.g. 50257) changes
+    nothing on the encode side; decode folds ids >= 258 back into byte
+    space so a host reward function sees text at full vocab width.
     """
 
-    vocab_size = 258
-
-    def __init__(self, padding_side: str = "left", truncation_side: str = "right"):
+    def __init__(
+        self,
+        padding_side: str = "left",
+        truncation_side: str = "right",
+        vocab_size: int = 258,
+    ):
+        if vocab_size < 258:
+            raise ValueError(f"byte tokenizer needs vocab_size >= 258, got {vocab_size}")
+        self.vocab_size = vocab_size
         self.padding_side = padding_side
         self.truncation_side = truncation_side
         self.bos_token_id = 256
@@ -116,8 +125,8 @@ class ByteTokenizer:
         buf = bytearray()
         for i in ids:
             i = int(i)
-            if i < 256:
-                buf.append(i)
+            if i < 256 or i >= 258:
+                buf.append(i % 256)
                 continue
             out += buf.decode("utf-8", errors="replace")
             buf.clear()
@@ -140,7 +149,8 @@ class ByteTokenizer:
 def load_tokenizer(tokenizer_cfg) -> Any:
     """Resolve TokenizerConfig -> tokenizer instance.
 
-    `tokenizer_path` of "byte"/"char" gives the built-in ByteTokenizer;
+    `tokenizer_path` of "byte"/"char" gives the built-in ByteTokenizer
+    (`tokenizer_extra_configs: {vocab_size: N}` widens it);
     anything else goes through transformers.AutoTokenizer (local path or
     hub cache). pad defaults to eos, matching reference trainer setup.
     """
@@ -149,6 +159,7 @@ def load_tokenizer(tokenizer_cfg) -> Any:
         return ByteTokenizer(
             padding_side=tokenizer_cfg.padding_side,
             truncation_side=tokenizer_cfg.truncation_side,
+            **tokenizer_cfg.tokenizer_extra_configs,
         )
     import transformers
 
