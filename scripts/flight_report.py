@@ -3,10 +3,11 @@
 
 Input: a checkpoint dir (reads ``<dir>/flight/``), a flight dir, or a
 single ``flight-*.jsonl`` file's directory. Output: per-run summary —
-a per-cycle table (wall, samples/s, phase breakdown), the event
-overlay (guardrail trips/actions, chaos injections, OOM-ladder rungs,
-watermark crossings, checkpoints/restores, supervisor records) keyed
-into the cycles they happened in, and slowest-phase attribution.
+a per-cycle table (wall, samples/s, phase breakdown, and the cycle's
+work-site spans as self time), the event overlay (guardrail
+trips/actions, chaos injections, OOM-ladder rungs, watermark crossings,
+checkpoints/restores, supervisor records) keyed into the cycles they
+happened in, and slowest-phase attribution.
 
 Pure stdlib + the jax-free ``trlx_tpu.obs.recorder`` reader, so it
 runs on any login node against a live run's directory.
@@ -28,6 +29,7 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from trlx_tpu.obs.recorder import flight_files, iter_rows  # noqa: E402
+from trlx_tpu.obs.spans import span_self_times  # noqa: E402
 
 # event kinds rendered in the overlay (cycle rows are the table)
 _EVENT_ORDER = (
@@ -123,6 +125,16 @@ def render(directory: str, last: int = 0, run: str = "") -> str:
                 f"{c.get('wall_s', 0.0):>8.3f} {str(c.get('samples', '-')):>5} "
                 f"{str(c.get('samples_per_sec', '-')):>7} {cells}  {slowest}"
             )
+            if c.get("spans"):
+                # work-site spans of the cycle, as self time (a span's
+                # duration less what its children cover)
+                own = span_self_times(c["spans"])
+                lines.append(
+                    "        spans (self s): " + ", ".join(
+                        f"{k} {v:.3f}"
+                        for k, v in sorted(own.items(), key=lambda kv: -kv[1])
+                    )
+                )
         if pending:  # events after the last cycle row (run_end, ...)
             lines.append("  events after the last cycle:")
             for e in pending:

@@ -64,6 +64,16 @@ def test_unknown_section_key_raises():
         TRLConfig.from_dict(d)
 
 
+def test_removed_profile_keys_fail_as_unknown_keys():
+    """train.profile_dir/_start/_stop are gone (the one profiler
+    trigger is train.obs.profile.*): an old config naming them fails
+    like any other unknown key, not silently."""
+    d = default_ppo_config().to_dict()
+    d["train"]["profile_dir"] = "/tmp/trace"
+    with pytest.raises(ValueError, match="unknown keys"):
+        TRLConfig.from_dict(d)
+
+
 def test_method_registry():
     assert get_method("ppoconfig") is PPOConfig
     assert get_method("ILQLConfig") is ILQLConfig

@@ -287,6 +287,56 @@ def test_bias_kernel_matches_reference(causal):
         )
 
 
+def _pallas_names(fn, *args):
+    """Names of the pallas_calls in fn's jaxpr, in order."""
+    return [
+        e.params["name"]
+        for e in jax.make_jaxpr(fn)(*args).eqns
+        if e.primitive.name == "pallas_call"
+    ]
+
+
+def test_kernels_carry_fixed_names():
+    """A kernel is a functools.partial, so only `name=` on its
+    pallas_call names it: on the chip that name is the instruction's
+    (`%flash_fwd.1`), which a trace reduction finds after any refactor;
+    without it the instruction is named after the flax scope and a
+    counter (`%attn.89`)."""
+    from trlx_tpu.ops.flash_attention import flash_attention_bias
+
+    q = jnp.ones((1, 2, 128, 16), jnp.float32)
+    mask = jnp.ones((1, 128), jnp.int32)
+    bias = jnp.zeros((2, 128, 128), jnp.float32)
+    assert _pallas_names(
+        jax.grad(lambda x: flash_attention(x, x, x, mask).sum()), q
+    ) == ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]
+    assert _pallas_names(
+        jax.grad(lambda x: flash_attention_bias(x, x, x, mask, bias).sum()), q
+    ) == ["flash_bias_fwd", "flash_bias_bwd_dq", "flash_bias_bwd_dkv"]
+
+
+def test_every_pallas_call_in_ops_has_a_name_of_its_own():
+    """All eight, the decode kernels included, without running them."""
+    import ast
+    import glob
+    import os
+
+    import trlx_tpu.ops
+
+    names = []
+    for path in glob.glob(os.path.join(os.path.dirname(trlx_tpu.ops.__file__), "*.py")):
+        with open(path) as f:
+            tree = ast.parse(f.read())
+        for node in ast.walk(tree):
+            if isinstance(node, ast.Call) and getattr(node.func, "attr", "") == "pallas_call":
+                kw = {k.arg: k.value for k in node.keywords}
+                assert "name" in kw, f"{path}:{node.lineno} pallas_call without name="
+                names.append(kw["name"].value)
+    assert sorted(names) == sorted(set(names)) and {
+        "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "decode_attn", "paged_decode_attn",
+    } <= set(names)
+
+
 def test_flash_attention_on_mesh_matches_single_device():
     """GSPMD cannot partition a Mosaic call, so on a multi-device mesh
     the kernel runs under shard_map (batch over dp x fsdp, heads over

@@ -203,6 +203,26 @@ def test_int8_decode_kernel_matches_fallback():
     assert agree >= 0.95, f"only {agree:.2%} of greedy tokens agree"
 
 
+def test_generate_program_and_its_stages_are_named(tiny_lm):
+    """The sampler's XLA module is `jit_generate`, and the stages a
+    trace must tell apart carry their scope: prefill, decode_step (with
+    decode_attn over the int8 cache inside it) and sample."""
+    import dataclasses
+
+    from trlx_tpu.models.generation import make_generate_fn
+
+    lm, params = tiny_lm
+    qlm = TransformerLM(dataclasses.replace(lm.cfg, kv_cache_quant="int8"))
+    fn = make_generate_fn(qlm, SamplerSettings(max_new_tokens=4))
+    ids = jnp.ones((2, 6), jnp.int32)
+    text = fn.lower(params, ids, jnp.ones_like(ids), jax.random.PRNGKey(0)).as_text(
+        debug_info=True
+    )
+    assert "module @jit_generate" in text
+    for scope in ("prefill/", "decode_step/", "/decode_attn/", "sample/"):
+        assert scope in text, scope
+
+
 def test_int8_decode_weights_track_full_precision(tiny_lm):
     """decode_weights_quant="int8": the whole rollout (prefill +
     decode) runs the quantized policy; greedy tokens must track the

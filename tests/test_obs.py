@@ -73,6 +73,139 @@ def test_span_mismatched_end_is_harmless():
     assert sum(phases.values()) == pytest.approx(wall)
 
 
+class _FakeClock:
+    def __init__(self, t=0.0):
+        self.t = t
+
+    def __call__(self):
+        return self.t
+
+
+def _beat_cycle(tracer, clock, with_spans):
+    """One cycle's beats, optionally with work-site spans inside it."""
+    import contextlib
+
+    def span(name, **counts):
+        return tracer.span(name, **counts) if with_spans else contextlib.nullcontext({})
+
+    def at(t):
+        clock.t = t
+        return t
+
+    tracer.start_cycle(at(10.0))
+    tracer.on_beat(at(11.0), "rollout", "start")
+    with span("generate", rows=8):
+        at(11.5)
+    with span("tokens_wait", rows=8) as counts:
+        at(13.0)
+        counts["tokens"] = 64
+    with span("score_dispatch"):
+        at(13.25)
+        with span("inner"):
+            at(13.75)
+        at(14.0)
+    tracer.on_beat(at(14.0), "reward", "start")
+    tracer.on_beat(at(14.5), "reward", "end")
+    tracer.on_beat(at(15.0), "rollout", "end")
+    tracer.on_beat(at(15.0), "fused_block", "start")
+    with span("block_wait"):
+        at(15.5)
+    tracer.on_beat(at(16.0), "fused_block", "end")
+    return tracer.snapshot_cycle(at(17.0))
+
+
+def test_work_site_spans_parent_counts_and_self_time():
+    from trlx_tpu.obs.spans import span_self_times
+
+    clock = _FakeClock()
+    t = SpanTracer(clock=clock, annotate=None)
+    _beat_cycle(t, clock, with_spans=True)
+    rows = {r[0]: r for r in t.cycle_spans}
+    # seconds from the cycle's start, on the beats' clock
+    assert rows["generate"][1:3] == [1.0, 1.5]
+    assert rows["tokens_wait"][1:3] == [1.5, 3.0]
+    # parent: the enclosing span, else the innermost open phase
+    assert rows["generate"][3] == "rollout"
+    assert rows["inner"][3] == "score_dispatch"
+    assert rows["block_wait"][3] == "fused_block"
+    # a count known only at the end of the block still lands in the row
+    assert rows["tokens_wait"][4] == {"rows": 8, "tokens": 64}
+    assert rows["score_dispatch"][4] == {}
+    own = span_self_times(t.cycle_spans)
+    assert own["score_dispatch"] == pytest.approx(0.5)  # 1.0 less inner's 0.5
+    assert own["inner"] == pytest.approx(0.5)
+    assert own["tokens_wait"] == pytest.approx(1.5)
+    # handed over once: the next cycle starts with none
+    t.snapshot_cycle(18.0)
+    assert t.cycle_spans == []
+
+
+def test_spans_leave_the_phase_partition_as_it_is():
+    """The trap a phase named `tokens_wait` would spring: spans are a
+    second level and never take wall away from the phase around them."""
+    results = []
+    for with_spans in (False, True):
+        clock = _FakeClock()
+        t = SpanTracer(clock=clock, annotate=None)
+        results.append(_beat_cycle(t, clock, with_spans))
+    (wall0, phases0), (wall1, phases1) = results
+    assert wall0 == wall1 == pytest.approx(7.0)
+    assert phases0 == phases1  # bit for bit
+    assert phases1["rollout"] == pytest.approx(3.5)
+    assert "tokens_wait" not in phases1
+    assert sum(phases1.values()) == pytest.approx(wall1, abs=1e-9)
+
+
+def test_phases_and_spans_are_mirrored_as_trlx_annotations():
+    """What a profiler capture sees: `trlx:<name>` entered and left in
+    order, phases (from the beats) and spans alike."""
+    seen = []
+
+    class Ann:
+        def __init__(self, name):
+            self.name = name
+            seen.append(("enter", name))
+
+        def __exit__(self, *exc):
+            seen.append(("exit", self.name))
+
+    clock = _FakeClock()
+    t = SpanTracer(clock=clock, annotate=Ann)
+    _beat_cycle(t, clock, with_spans=True)
+    assert seen[:4] == [("enter", "rollout"), ("enter", "generate"),
+                        ("exit", "generate"), ("enter", "tokens_wait")]
+    assert ("exit", "rollout") in seen and ("exit", "block_wait") in seen
+    assert sum(k == "enter" for k, _ in seen) == sum(k == "exit" for k, _ in seen)
+    # the default mirror is a real jax.profiler.TraceAnnotation
+    from trlx_tpu.obs.spans import ANNOTATION_PREFIX, _annotation
+
+    ann = _annotation("probe")
+    ann.__exit__(None, None, None)
+    assert type(ann).__name__ == "TraceAnnotation" and ANNOTATION_PREFIX == "trlx:"
+
+
+def test_observer_span_is_null_when_off_and_never_raises(tmp_path):
+    off = RunObserver(ObsConfig.from_dict({"enabled": False}), str(tmp_path / "a"))
+    with off.span("generate", rows=8) as counts:
+        counts["tokens"] = 1  # a throwaway dict
+    assert off.tracer.cycle_spans == [] and off.tracer._spans == []
+    assert not os.path.exists(tmp_path / "a")
+
+    on = RunObserver(ObsConfig.from_dict({}), str(tmp_path / "b"))
+    # the body's exception passes through, and the span still closes
+    with pytest.raises(KeyError):
+        with on.span("generate"):
+            raise KeyError("from the body")
+    assert [r["name"] for r in on.tracer._spans] == ["generate"]
+    # the tracer's own failure disarms the observer, the body runs on
+    on.tracer.open_span = None
+    ran = []
+    with on.span("tokens_wait"):
+        ran.append(1)
+    assert ran == [1] and not on.active
+    on.finish()
+
+
 # ---------------------------------------------------------------------------
 # flight recorder: rotation + atomic append + torn-tail tolerance
 # ---------------------------------------------------------------------------
@@ -395,19 +528,28 @@ def _tiny_ppo_config(ckpt_dir: str):
     )
 
 
-def test_faultfree_learn_emits_flight_stream_and_telemetry(tmp_path):
+@pytest.fixture(scope="module")
+def faultfree_run(tmp_path_factory):
+    """ONE tiny fault-free learn(), shared by the tests below."""
     import trlx_tpu
 
-    ckpt_dir = str(tmp_path / "ckpts")
+    ckpt_dir = str(tmp_path_factory.mktemp("faultfree") / "ckpts")
     prompts = ["hello world", "the cat", "a b", "xyz",
                "what is", "I am", "go", "ok"]
 
     def reward(samples, prompts, outputs, **kw):
         return [float(len(o)) for o in outputs]
 
-    trainer = trlx_tpu.train(
-        reward_fn=reward, prompts=prompts, config=_tiny_ppo_config(ckpt_dir)
-    )
+    # no EOS: every row generates its whole budget, so token counts
+    # are exact (as the benchmark's mixes do)
+    config = _tiny_ppo_config(ckpt_dir)
+    config.method.gen_kwargs["eos_token_id"] = -1
+    trainer = trlx_tpu.train(reward_fn=reward, prompts=prompts, config=config)
+    return trainer, ckpt_dir
+
+
+def test_faultfree_learn_emits_flight_stream_and_telemetry(faultfree_run):
+    trainer, ckpt_dir = faultfree_run
     flight_dir = os.path.join(ckpt_dir, "flight")
     rows = list(iter_rows(flight_dir))
     assert rows, "default-on obs produced no flight stream"
@@ -472,6 +614,66 @@ def test_faultfree_learn_emits_flight_stream_and_telemetry(tmp_path):
     rendered = fr.render(flight_dir)
     assert "slowest-phase attribution" in rendered
     assert trainer.obs.run_id in rendered
+
+
+def test_learn_writes_work_site_spans_into_every_cycle_row(faultfree_run):
+    trainer, ckpt_dir = faultfree_run
+    rows = list(iter_rows(os.path.join(ckpt_dir, "flight")))
+    cycles = [r for r in rows if r["kind"] == "cycle" and r["samples"]]
+    assert len(cycles) >= 2
+    for c in cycles:
+        by_name = {}
+        for name, t0, t1, parent, counts in c["spans"]:
+            assert t1 >= t0, c
+            by_name.setdefault(name, []).append((t0, t1, parent, counts))
+        assert {"generate", "tokens_wait", "score_dispatch", "block_wait"} <= set(by_name), c
+        (t0, t1, parent, counts), = by_name["tokens_wait"]
+        # 8 rollouts x 8 new tokens, counted where the rows land
+        assert counts == {"rows": 8, "tokens": 64} and parent == "rollout"
+        # the pull follows the sampler's dispatch; scoring is dispatched
+        # only after the pull has returned
+        assert by_name["generate"][0][1] <= t0
+        assert t1 <= by_name["score_dispatch"][0][0]
+        assert all(p == "fused_block" for *_, p, _ in by_name["block_wait"])
+    # time/rollout_generate: the dispatch plus the wait for the tokens
+    with open(os.path.join(ckpt_dir, "logs", "metrics.jsonl")) as f:
+        logged = [json.loads(line) for line in f]
+    gen_times = [r["time/rollout_generate"] for r in logged if "time/rollout_generate" in r]
+    assert gen_times and all(t > 0 for t in gen_times)
+
+
+def test_cycle_programs_carry_their_own_names(faultfree_run):
+    """`XLA Modules` in a trace and the compile log name a program by
+    its function: generation, scoring and the train step each have one."""
+    import jax
+    import jax.numpy as jnp
+
+    trainer, _ = faultfree_run
+    assert [f.__name__ for f in trainer._generate_fns.values()] == ["generate"]
+    names = {getattr(f, "__name__", "") for f in trainer._experience_fns.values()}
+    assert {"ppo_experience_fwd", "ppo_score_inject"} <= names
+
+    def abstract(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding), tree)
+
+    full, n = trainer._fused_epoch_batch()
+    perms = trainer._epoch_perms(n)
+    with trainer.mesh:
+        text = trainer._fused_train_step.lower(
+            abstract(trainer.params), abstract(trainer.opt_state),
+            abstract(trainer.place_batch(full)),
+            jax.ShapeDtypeStruct(perms.shape, jnp.int32),
+        ).as_text(debug_info=True)
+    assert "module @jit_fused_train_step" in text
+    # the stages inside it that flax does not name (the loss and the
+    # value head are differentiated: their scope rides inside jvp())
+    for scope in ("jvp(loss)/", "jvp(value_head)/", "optimizer_update/"):
+        assert scope in text, scope
+    assert "jit_train_step" in trainer.make_train_step().lower(
+        abstract(trainer.params), abstract(trainer.opt_state),
+        abstract(jax.tree_util.tree_map(lambda x: x[:8], trainer.place_batch(full))),
+    ).as_text()
 
 
 def test_obs_disabled_restores_pre_obs_behavior(tmp_path):

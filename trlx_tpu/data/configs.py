@@ -190,13 +190,6 @@ class TrainConfig(_Section):
     # Params and optimizer masters stay `param_dtype`; with
     # minibatch accumulation the running sum stays fp32.
     grads_dtype: Optional[str] = None
-    # When set, a jax.profiler trace of train steps [profile_start,
-    # profile_stop) is written here (the reference exposes Nsight knobs in
-    # its NeMo configs — megatron_20b.yaml:126-131; this is the XLA
-    # equivalent, viewable in TensorBoard / Perfetto).
-    profile_dir: Optional[str] = None
-    profile_start: int = 2
-    profile_stop: int = 5
     # The train step fuses forward+backward+update under one jit, so only
     # `time/step` can be reported per-step. Enabling this measures a
     # forward-only pass once (shapes are static, so its cost is constant)

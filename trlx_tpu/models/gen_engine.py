@@ -50,7 +50,6 @@ from __future__ import annotations
 
 import dataclasses
 from dataclasses import dataclass
-from functools import partial
 from typing import Any, Dict, Optional
 
 import jax
@@ -1241,20 +1240,22 @@ def make_engine_fn(
     )
     if spec.spec_decode:
 
-        @partial(jax.jit, static_argnums=())
-        def fn(params, draft_params, q_ids, q_mask, rng, row_budget=None):
+        @jax.jit
+        def engine_generate_spec(
+            params, draft_params, q_ids, q_mask, rng, row_budget=None
+        ):
             return run(
                 model, params, q_ids, q_mask, rng, settings, spec,
                 draft_params=draft_params, row_budget=row_budget,
             )
 
-        return fn
+        return engine_generate_spec
 
-    @partial(jax.jit, static_argnums=())
     def fn(params, q_ids, q_mask, rng, row_budget=None):
         return run(
             model, params, q_ids, q_mask, rng, settings, spec,
             row_budget=row_budget,
         )
 
-    return fn
+    fn.__name__ = "engine_generate"  # the XLA module is jit_engine_generate
+    return jax.jit(fn)

@@ -24,7 +24,6 @@ from __future__ import annotations
 
 import dataclasses
 from dataclasses import dataclass
-from functools import partial
 from typing import Callable, Dict, Optional, Tuple
 
 import jax
@@ -333,18 +332,20 @@ def generate(
     # [B, P, V] prefill logits (3.3 GB fp32 at b8/seq2048/vocab50257 —
     # and ~7% of prefill FLOPs) are never materialized; the one needed
     # row is projected from the final hidden below
-    out = model(
-        params, input_ids, attention_mask, positions=positions, cache=cache,
-        compute_logits=False,
-    )
+    with jax.named_scope("prefill"):
+        out = model(
+            params, input_ids, attention_mask, positions=positions,
+            cache=cache, compute_logits=False,
+        )
     prompt_len = n_virt + attention_mask.sum(axis=1)  # [B] next real position
 
     def pick_next(rng, hidden_last, logits_last, finished):
-        if logits_processor is not None:
-            logits_last = logits_processor(hidden_last, logits_last)
-        tok = sample_token(rng, logits_last, settings)
-        tok = jnp.where(finished, jnp.int32(settings.pad_token_id), tok)
-        now_finished = finished | (tok == settings.eos_token_id)
+        with jax.named_scope("sample"):
+            if logits_processor is not None:
+                logits_last = logits_processor(hidden_last, logits_last)
+            tok = sample_token(rng, logits_last, settings)
+            tok = jnp.where(finished, jnp.int32(settings.pad_token_id), tok)
+            now_finished = finished | (tok == settings.eos_token_id)
         return tok, now_finished
 
     rng, sub = jax.random.split(rng)
@@ -388,9 +389,10 @@ def generate(
 
         def body(state):
             cache, tok, pos, finished, t, rng, ids_buf, mask_buf = state
-            step_out = model(
-                params, tok[:, None], positions=pos[:, None], cache=cache
-            )
+            with jax.named_scope("decode_step"):
+                step_out = model(
+                    params, tok[:, None], positions=pos[:, None], cache=cache
+                )
             rng, sub = jax.random.split(rng)
             next_tok, now_finished = pick_next(
                 sub, step_out["hidden_states"][:, -1], step_out["logits"][:, -1],
@@ -437,11 +439,11 @@ def make_generate_fn(
     distinct prompt padding length (trainers pad prompts to a fixed
     max_prompt_length so there is exactly one)."""
 
-    @partial(jax.jit, donate_argnums=())
     def fn(params, input_ids, attention_mask, rng):
         return generate(
             model, params, input_ids, attention_mask, rng, settings,
             logits_processor=logits_processor,
         )
 
-    return fn
+    fn.__name__ = "generate"  # the XLA module is jit_generate
+    return jax.jit(fn)

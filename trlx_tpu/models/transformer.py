@@ -379,32 +379,33 @@ class Attention(nn.Module):
                     # the softmax chain), the per-channel V scale rides
                     # the [B,T,H,D] output; nothing S-sized is ever
                     # dequantized to HBM
-                    k_i8 = jax.lax.dynamic_index_in_dim(
-                        ck, ix, 0, keepdims=False
-                    )  # [B, Hkv, S, D]
-                    v_i8 = jax.lax.dynamic_index_in_dim(
-                        cv, ix, 0, keepdims=False
-                    )  # [B, Hkv, S, D]
-                    ks_l = jax.lax.dynamic_index_in_dim(
-                        cks, ix, 0, keepdims=False
-                    )  # [B, Hkv, 1, S]
-                    if Hkv != H:
-                        rep = H // Hkv
-                        k_i8 = jnp.repeat(k_i8, rep, axis=1)
-                        v_i8 = jnp.repeat(v_i8, rep, axis=1)
-                        ks_l = jnp.repeat(ks_l, rep, axis=1)
-                        layer_vs = jnp.repeat(layer_vs, rep, axis=1)
-                    scores = jnp.einsum(
-                        "bthd,bhsd->bhts",
-                        q,
-                        k_i8.astype(cfg.dtype),
-                        preferred_element_type=jnp.float32,
-                    ) * (1.0 / math.sqrt(D))
-                    scores = scores * ks_l + attn_bias
-                    probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
-                    kernel_out = jnp.einsum(
-                        "bhts,bhsd->bthd", probs, v_i8.astype(cfg.dtype)
-                    ) * layer_vs.transpose(0, 2, 1, 3).astype(cfg.dtype)
+                    with jax.named_scope("decode_attn"):
+                        k_i8 = jax.lax.dynamic_index_in_dim(
+                            ck, ix, 0, keepdims=False
+                        )  # [B, Hkv, S, D]
+                        v_i8 = jax.lax.dynamic_index_in_dim(
+                            cv, ix, 0, keepdims=False
+                        )  # [B, Hkv, S, D]
+                        ks_l = jax.lax.dynamic_index_in_dim(
+                            cks, ix, 0, keepdims=False
+                        )  # [B, Hkv, 1, S]
+                        if Hkv != H:
+                            rep = H // Hkv
+                            k_i8 = jnp.repeat(k_i8, rep, axis=1)
+                            v_i8 = jnp.repeat(v_i8, rep, axis=1)
+                            ks_l = jnp.repeat(ks_l, rep, axis=1)
+                            layer_vs = jnp.repeat(layer_vs, rep, axis=1)
+                        scores = jnp.einsum(
+                            "bthd,bhsd->bhts",
+                            q,
+                            k_i8.astype(cfg.dtype),
+                            preferred_element_type=jnp.float32,
+                        ) * (1.0 / math.sqrt(D))
+                        scores = scores * ks_l + attn_bias
+                        probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
+                        kernel_out = jnp.einsum(
+                            "bhts,bhsd->bthd", probs, v_i8.astype(cfg.dtype)
+                        ) * layer_vs.transpose(0, 2, 1, 3).astype(cfg.dtype)
                 else:
                     # non-plain-bias fallback: full dequant back to the
                     # [B, S, Hkv, D] orientation the generic XLA path

@@ -144,6 +144,7 @@ class CausalLMWithValueHead:
             )
         return params
 
+    @jax.named_scope("value_head")
     def _values(self, params: Dict, out: Dict) -> Array:
         """Value head input: final hidden, or the value branch re-run from
         its captured fork point."""
@@ -250,25 +251,27 @@ class CausalLMWithValueHead:
                 params, input_ids, attention_mask, remat=remat,
                 compute_logits=compute_logits,
             )
-            ref_out = self.lm(
-                ref_params, input_ids, attention_mask, remat=remat,
-                compute_logits=compute_logits,
-            )
+            with jax.named_scope("ref_branch"):
+                ref_out = self.lm(
+                    ref_params, input_ids, attention_mask, remat=remat,
+                    compute_logits=compute_logits,
+                )
         else:
             out = self._multi_forward(
                 params, input_ids, attention_mask, remat, compute_logits
             )
             out["values"] = self._values(params, out)
-            ref_out = self.lm.forward_from_layer(
-                ref_params,
-                jax.lax.stop_gradient(out["branch_hidden"]),
-                out["attn_bias"],
-                out["positions"],
-                remat=remat,
-                local_bias=out.get("local_bias"),
-                key_mask=out.get("key_mask"),
-                compute_logits=compute_logits,
-            )
+            with jax.named_scope("ref_branch"):
+                ref_out = self.lm.forward_from_layer(
+                    ref_params,
+                    jax.lax.stop_gradient(out["branch_hidden"]),
+                    out["attn_bias"],
+                    out["positions"],
+                    remat=remat,
+                    local_bias=out.get("local_bias"),
+                    key_mask=out.get("key_mask"),
+                    compute_logits=compute_logits,
+                )
         return dict(
             out,
             ref_logits=(
@@ -353,30 +356,33 @@ class Seq2SeqLMWithValueHead:
                 decoder_attention_mask, remat=remat,
                 compute_logits=compute_logits,
             )
-            ref_out = self.lm(
-                ref_params, input_ids, attention_mask, decoder_input_ids,
-                decoder_attention_mask, remat=remat,
-                compute_logits=compute_logits,
-            )
+            with jax.named_scope("ref_branch"):
+                ref_out = self.lm(
+                    ref_params, input_ids, attention_mask, decoder_input_ids,
+                    decoder_attention_mask, remat=remat,
+                    compute_logits=compute_logits,
+                )
         else:
             out = self.lm.forward_with_branch_capture(
                 params["base"], input_ids, attention_mask, decoder_input_ids,
                 decoder_attention_mask, self.branch_at, remat=remat,
                 compute_logits=compute_logits,
             )
-            out["values"] = apply_head(params["v_head"], out["hidden_states"])[..., 0]
-            ref_out = self.lm.forward_from_layer(
-                ref_params,
-                jax.lax.stop_gradient(out["branch_hidden"]),
-                out["self_bias"],
-                jax.lax.stop_gradient(out["encoder_hidden"]),
-                out["cross_bias"],
-                remat=remat,
-                compute_logits=compute_logits,
-                pos_bias=out.get("pos_bias"),
-                skey_mask=out.get("skey_mask"),
-                ckey_mask=out.get("ckey_mask"),
-            )
+            with jax.named_scope("value_head"):
+                out["values"] = apply_head(params["v_head"], out["hidden_states"])[..., 0]
+            with jax.named_scope("ref_branch"):
+                ref_out = self.lm.forward_from_layer(
+                    ref_params,
+                    jax.lax.stop_gradient(out["branch_hidden"]),
+                    out["self_bias"],
+                    jax.lax.stop_gradient(out["encoder_hidden"]),
+                    out["cross_bias"],
+                    remat=remat,
+                    compute_logits=compute_logits,
+                    pos_bias=out.get("pos_bias"),
+                    skey_mask=out.get("skey_mask"),
+                    ckey_mask=out.get("ckey_mask"),
+                )
         return dict(
             out,
             ref_logits=(

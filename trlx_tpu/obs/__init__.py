@@ -14,6 +14,11 @@ by hand. This subsystem closes both gaps:
       (rollout, reward, fused_block, train_step, checkpoint, eval,
       experience, exp_wait), innermost-phase attribution, per cycle.
       By construction the phase walls sum to the cycle wall exactly.
+      Below the phases, work-site spans (``obs.span(name, **counts)``
+      around code the trainer already has: generate, tokens_wait,
+      score_dispatch, block_wait, ...) ride each ``cycle`` row without
+      touching the partition; phases and spans are mirrored into a
+      profiler capture as ``trlx:<name>`` annotations.
   FlightRecorder (obs/recorder.py)
       ONE size-rotated JSONL event stream under
       ``<checkpoint_dir>/flight/``: per-cycle phase breakdowns plus

@@ -23,6 +23,7 @@ cheap no-op — observability must never be the thing that kills a run.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import os
 import time
@@ -51,11 +52,7 @@ def _no_raise(method):
         try:
             return method(self, *args, **kwargs)
         except Exception as e:
-            self.active = False
-            logger.error(
-                "obs: %s failed (%s) — flight recorder disarmed for the "
-                "rest of the run; training continues", method.__name__, e,
-            )
+            self._disarm(method.__name__, e)
             return None
 
     return wrapped
@@ -79,7 +76,7 @@ class RunObserver:
         self.active = bool(cfg.enabled and is_writer)
         self._clock = clock
         self.run_id = run_id or uuid.uuid4().hex[:12]
-        self.tracer = SpanTracer()
+        self.tracer = SpanTracer(clock=clock)
         self.telemetry = TelemetryAggregator(window=cfg.telemetry_window)
         self.recorder = FlightRecorder(
             flight_dir, self.run_id,
@@ -110,7 +107,7 @@ class RunObserver:
             chaos.on_fire = self._on_chaos
         # keep beat timestamps and cycle boundaries on one timebase
         if watchdog is not None:
-            self._clock = watchdog.clock
+            self._clock = self.tracer.clock = watchdog.clock
 
     # -- listeners -------------------------------------------------------
 
@@ -122,12 +119,37 @@ class RunObserver:
         except Exception as e:
             # same contract as _no_raise: log ONCE, then go quiet — a
             # silently frozen stream is undebuggable
-            self.active = False
-            logger.error(
-                "obs: span tracer failed on a beat (%s) — flight "
-                "recorder disarmed for the rest of the run; training "
-                "continues", e,
-            )
+            self._disarm("span tracer on a beat", e)
+
+    @contextlib.contextmanager
+    def span(self, name: str, **counts: Any):
+        """Work-site span around code the trainer already has:
+        ``with self.obs.span("tokens_wait", rows=8) as counts: ...``
+        (see :meth:`SpanTracer.span`). A null context when the
+        observer is off. Same contract as ``_no_raise``: the tracer's
+        own failures disarm the observer and never reach the body;
+        the body's exceptions pass through untouched."""
+        rec = None
+        if self.active:
+            try:
+                rec = self.tracer.open_span(name, dict(counts))
+            except Exception as e:
+                self._disarm("span", e)
+        try:
+            yield rec["counts"] if rec is not None else {}
+        finally:
+            if rec is not None:
+                try:
+                    self.tracer.close_span(rec)
+                except Exception as e:
+                    self._disarm("span", e)
+
+    def _disarm(self, what: str, e: Exception) -> None:
+        self.active = False
+        logger.error(
+            "obs: %s failed (%s) — flight recorder disarmed for the "
+            "rest of the run; training continues", what, e,
+        )
 
     @_no_raise
     def _on_guardrail_trip(self, signal: str, detail: str) -> None:
@@ -222,7 +244,13 @@ class RunObserver:
             wall, breakdown, step=step, policy_version=policy_version,
             n_steps=n_steps,
         )
-        self.recorder.append("cycle", **row)
+        # the cycle's work-site spans ride the same row, seconds from
+        # the cycle's start: [name, t0, t1, parent, counts]
+        spans = [
+            [name, round(t0, 6), round(t1, 6), parent, counts]
+            for name, t0, t1, parent, counts in self.tracer.cycle_spans
+        ]
+        self.recorder.append("cycle", **row, spans=spans)
         self.profiler.end_cycle(closing)
         if not final:
             self.profiler.begin_cycle(self.cycle)

@@ -81,6 +81,7 @@ def logprobs_of_labels(logits: jnp.ndarray, labels: jnp.ndarray) -> jnp.ndarray:
     )
 
 
+@jax.named_scope("logprobs")
 def chunked_logprobs(
     project_fn,
     hidden: jnp.ndarray,

@@ -356,6 +356,7 @@ def paged_attention_pallas(
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, rep * T, D), q.dtype),
         interpret=_interpret(),
+        name="paged_decode_attn",
     )(
         jnp.reshape(layer_ix, (1,)).astype(jnp.int32),
         page_table.reshape(-1).astype(jnp.int32),
@@ -480,6 +481,7 @@ def decode_attention_int8(
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, rep, D), q.dtype),
         interpret=_interpret(),
+        name="decode_attn",
     )(
         jnp.reshape(layer_ix, (1,)).astype(jnp.int32),
         qr,

@@ -182,6 +182,7 @@ def _flash_forward(q, k, v, key_mask, causal, sm_scale, block_q,
             jax.ShapeDtypeStruct((B * H, T, 1), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_fwd",
     )(qr, kr, vr, key_mask.astype(jnp.int32)[:, None, :])
     out = out.reshape(B, H, T, D)
     if with_stats:
@@ -330,6 +331,7 @@ def _flash_backward(q, k, v, key_mask, o, m, l, g, causal, sm_scale, block_q,
         out_specs=pl.BlockSpec((1, bq, D), lambda bh, qi: (bh, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, T, D), q.dtype),
         interpret=_interpret(),
+        name="flash_bwd_dq",
     )(qr, kr, vr, mask3, dor, m, l, delta)
 
     bk = _pick_block(S, block_q)
@@ -369,6 +371,7 @@ def _flash_backward(q, k, v, key_mask, o, m, l, g, causal, sm_scale, block_q,
             jax.ShapeDtypeStruct((B * Hkv, S, D), v.dtype),
         ],
         interpret=_interpret(),
+        name="flash_bwd_dkv",
     )(qg, kr, vr, mask3, dog, m_t, l_t, delta_t)
 
     return (
@@ -571,6 +574,7 @@ def _flash_bias_forward(q, k, v, key_mask, bias, causal, sm_scale, block_q,
             jax.ShapeDtypeStruct((B * H, T, 1), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_bias_fwd",
     )(
         q.reshape(B * H, T, D), k.reshape(B * H, S, D), v.reshape(B * H, S, D),
         key_mask.astype(jnp.int32)[:, None, :], bias.astype(jnp.float32),
@@ -722,6 +726,7 @@ def _flash_bias_backward(q, k, v, key_mask, bias, o, m, l, g, causal,
             jax.ShapeDtypeStruct((H, T, S), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_bias_bwd_dq",
     )(qr, kr, vr, mask3, bias32, dor, m, l, delta)
 
     # key blocks stay at 128: the kernel's mask slice pl.ds(ki*bk, bk)
@@ -760,6 +765,7 @@ def _flash_bias_backward(q, k, v, key_mask, bias, o, m, l, g, causal,
             jax.ShapeDtypeStruct((B * H, S, D), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_bias_bwd_dkv",
     )(qr, kr, vr, mask3, biasT, dor, m_t, l_t, delta_t)
 
     return (

@@ -61,6 +61,7 @@ def group_relative_advantages(
     return adv.reshape(rewards.shape)
 
 
+@jax.named_scope("loss")
 def grpo_loss(
     logprobs: jnp.ndarray,
     old_logprobs: jnp.ndarray,

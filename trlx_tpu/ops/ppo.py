@@ -50,6 +50,7 @@ def gae_advantages_and_returns(
     return jax.lax.stop_gradient(advantages), returns
 
 
+@jax.named_scope("loss")
 def ppo_loss(
     logprobs: jnp.ndarray,
     values: jnp.ndarray,

@@ -711,6 +711,7 @@ class TPUBaseTrainer(BaseRLTrainer):
                     kv_prefix=params.get("prefix"),
                 )
 
+            fn.__name__ = "generate"  # the XLA module is jit_generate
             self._generate_fns[key] = jax.jit(fn)
         return self._generate_fns[key]
 
@@ -991,6 +992,7 @@ class TPUBaseTrainer(BaseRLTrainer):
                         group_sharding=gshard,
                     )
 
+            fn.__name__ = "engine_generate"
             self._engine_fns[key] = jax.jit(fn)
         return self._engine_fns[key], spec
 
@@ -1132,6 +1134,7 @@ class TPUBaseTrainer(BaseRLTrainer):
                     q_pin=q_pin, q_ready=q_ready, q_rng_row=q_rng_row,
                 )
 
+        fn.__name__ = "serve_engine_generate"
         jfn = jax.jit(fn)
         gshard = (
             self._engine_group_sharding(groups) if groups > 1 else None
@@ -1442,39 +1445,40 @@ class TPUBaseTrainer(BaseRLTrainer):
                 grads,
                 jnp.asarray(True),
             )
-        if hasattr(tx, "fused_apply"):
-            # the freeze mask streams through the fused apply itself
-            # (O(chunk) extra memory); blending frozen values back after
-            # the apply would hold THREE fp32 param trees at peak —
-            # measured as the 0.5 GB that OOMed the 1.3B recipe. The
-            # NaN guard must respect the same budget, so here it zeroes
-            # the gradients BEFORE the apply instead of selecting whole
-            # trees after it: a poisoned step degrades to a weight-decay
-            # -only update (no NaN ever reaches params/moments), and the
-            # host-side abort counter still trips on persistent NaN.
-            if guard:
-                # where, not multiply: NaN grads * 0 is still NaN
-                grads = jax.tree_util.tree_map(
-                    lambda g: jnp.where(good, g, jnp.zeros_like(g)), grads
+        with jax.named_scope("optimizer_update"):
+            if hasattr(tx, "fused_apply"):
+                # the freeze mask streams through the fused apply itself
+                # (O(chunk) extra memory); blending frozen values back after
+                # the apply would hold THREE fp32 param trees at peak —
+                # measured as the 0.5 GB that OOMed the 1.3B recipe. The
+                # NaN guard must respect the same budget, so here it zeroes
+                # the gradients BEFORE the apply instead of selecting whole
+                # trees after it: a poisoned step degrades to a weight-decay
+                # -only update (no NaN ever reaches params/moments), and the
+                # host-side abort counter still trips on persistent NaN.
+                if guard:
+                    # where, not multiply: NaN grads * 0 is still NaN
+                    grads = jax.tree_util.tree_map(
+                        lambda g: jnp.where(good, g, jnp.zeros_like(g)), grads
+                    )
+                new_params, new_opt_state = tx.fused_apply(
+                    params, grads, opt_state, mask=self._update_mask
                 )
-            new_params, new_opt_state = tx.fused_apply(
-                params, grads, opt_state, mask=self._update_mask
-            )
-        else:
-            updates, new_opt_state = tx.update(grads, opt_state, params)
-            new_params = optax.apply_updates(params, updates)
-            if guard:
-                # NaN/inf guard must live INSIDE the trace: params and
-                # opt_state are donated, so by the time the host could
-                # inspect the loss the pre-update buffers are gone. The
-                # traced select commits the old state when the update is
-                # poisoned; the abort counter lives in the learn loop.
-                new_params = jax.tree_util.tree_map(
-                    lambda n, o: jnp.where(good, n, o), new_params, params
-                )
-                new_opt_state = jax.tree_util.tree_map(
-                    lambda n, o: jnp.where(good, n, o), new_opt_state, opt_state
-                )
+            else:
+                updates, new_opt_state = tx.update(grads, opt_state, params)
+                new_params = optax.apply_updates(params, updates)
+                if guard:
+                    # NaN/inf guard must live INSIDE the trace: params and
+                    # opt_state are donated, so by the time the host could
+                    # inspect the loss the pre-update buffers are gone. The
+                    # traced select commits the old state when the update is
+                    # poisoned; the abort counter lives in the learn loop.
+                    new_params = jax.tree_util.tree_map(
+                        lambda n, o: jnp.where(good, n, o), new_params, params
+                    )
+                    new_opt_state = jax.tree_util.tree_map(
+                        lambda n, o: jnp.where(good, n, o), new_opt_state, opt_state
+                    )
         if guard:
             # fold the skip signal into the returned loss: the host's
             # isfinite check then catches finite-loss/bad-grad skips too,
@@ -1503,8 +1507,12 @@ class TPUBaseTrainer(BaseRLTrainer):
     def make_train_step(self):
         """One jitted function per optimizer step. Donates params/opt_state."""
         params_sh, opt_sh = self._pinned_state_shardings()
+
+        def train_step(params, opt_state, batch):
+            return self._step_update(params, opt_state, batch)
+
         return jax.jit(
-            self._step_update,
+            train_step,
             donate_argnums=(0, 1),
             out_shardings=(params_sh, opt_sh, None, None),
         )
@@ -1521,7 +1529,7 @@ class TPUBaseTrainer(BaseRLTrainer):
         Signature: (params, opt_state, full_batch, perms[n_steps, bs])
         -> (params, opt_state, mean_loss, mean_stats)."""
 
-        def fused(params, opt_state, full_batch, perms):
+        def fused_train_step(params, opt_state, full_batch, perms):
             def body(carry, perm):
                 p, o = carry
                 mb = jax.tree_util.tree_map(lambda x: x[perm], full_batch)
@@ -1538,7 +1546,7 @@ class TPUBaseTrainer(BaseRLTrainer):
 
         params_sh, opt_sh = self._pinned_state_shardings()
         return jax.jit(
-            fused,
+            fused_train_step,
             donate_argnums=(0, 1),
             out_shardings=(params_sh, opt_sh, None, None),
         )
@@ -1600,7 +1608,7 @@ class TPUBaseTrainer(BaseRLTrainer):
         # the flush is the fused block's device sync point: a wedged
         # collective manifests as this read never returning, so it
         # heartbeats as part of the fused_block phase
-        with self.watchdog.phase("fused_block"):
+        with self.watchdog.phase("fused_block"), self.obs.span("block_wait"):
             entries = self._deferred_train.flush()
         out = None
         for i, (stats, step, meta) in enumerate(entries):
@@ -2614,7 +2622,10 @@ class TPUBaseTrainer(BaseRLTrainer):
             try:
                 if self.chaos is not None and self.memdoctor.enabled:
                     self.chaos.oom("oom_prefill")
-                return self.generate(input_ids, attention_mask)
+                # the sampler's dispatch; its device time ends at the
+                # `tokens_wait` span (the host pull of the tokens)
+                with self.obs.span("generate", rows=len(input_ids)):
+                    return self.generate(input_ids, attention_mask)
             except Exception as e:
                 if not (self.memdoctor.enabled and is_oom(e)):
                     raise
@@ -3090,11 +3101,6 @@ class TPUBaseTrainer(BaseRLTrainer):
                     if self._should_stop():
                         self._preemption_exit()
                         return results
-                    if self.config.train.profile_dir is not None:
-                        if self.iter_count == self.config.train.profile_start:
-                            jax.profiler.start_trace(self.config.train.profile_dir)
-                        elif self.iter_count == self.config.train.profile_stop:
-                            jax.profiler.stop_trace()
                     if self._train_step is None:
                         # a guardrail lr_cut dropped the jitted step
                         # mid-epoch (the new schedule must trace in)
@@ -4019,7 +4025,8 @@ class TPUOnlineTrainer(TPUBaseTrainer):
             )
             accumulated_stats.append(stats)
 
-            self.push_to_store(rollout_batch)
+            with self.obs.span("store_push"):
+                self.push_to_store(rollout_batch)
             n_collected += rows_local * mh.data_group_count(self.mesh)
             if hasattr(pbar, "update"):
                 pbar.update(rows_local * mh.data_group_count(self.mesh))
@@ -4051,6 +4058,39 @@ class TPUOnlineTrainer(TPUBaseTrainer):
         )
 
     # -- shared score/assemble helpers -----------------------------------
+
+    def _pull_sampled_tokens(self, gen_out, rows: int, stats) -> np.ndarray:
+        """ONE packed device->host transfer for the three generation
+        outputs (one sync instead of three): ``[B, seq + 2N]`` =
+        sequences | response_ids | response_mask, this host's rows.
+        ``local_rows`` returns a numpy array, so the pull BLOCKS until
+        the sampler has finished: it is the sync at which the rollout's
+        device time ends (the ``tokens_wait`` span), and it adds its
+        wait to ``time/rollout_generate`` — the caller put the
+        sampler's dispatch wall there, so the key reads the time its
+        name says. ``rows`` = the real (non-pad) local rows."""
+        from time import time
+
+        t0 = time()
+        with self.obs.span("tokens_wait", rows=rows) as counts:
+            packed = mh.local_rows(
+                jnp.concatenate(
+                    [
+                        gen_out["sequences"],
+                        gen_out["response_ids"],
+                        gen_out["response_mask"].astype(
+                            gen_out["sequences"].dtype
+                        ),
+                    ],
+                    axis=1,
+                )
+            )
+            n_new = gen_out["response_ids"].shape[1]
+            counts["tokens"] = int(packed[:rows, -n_new:].sum())
+        stats["time/rollout_generate"] = (
+            stats.get("time/rollout_generate", 0.0) + time() - t0
+        )
+        return packed
 
     def _update_reward_moments(self, scores, scores_mask, stats):
         """Fold one chunk's host-computed scores into the running reward

@@ -209,7 +209,7 @@ class TPUGRPOTrainer(TPUOnlineTrainer):
         model = self.model
         chunks = self.config.train.logit_chunks
 
-        def fn(params, ref_params, tokens, attention_mask, response_mask, row_valid):
+        def grpo_experience_fwd(params, ref_params, tokens, attention_mask, response_mask, row_valid):
             out = model.forward(
                 params, tokens, attention_mask, compute_logits=chunks == 0
             )
@@ -251,15 +251,17 @@ class TPUGRPOTrainer(TPUOnlineTrainer):
                 "mean_kl": mean_kl, "mean_kl_per_token": mean_kl_per_token,
             }
 
-        self._experience_fns[key] = jax.jit(fn)
+        self._experience_fns[key] = jax.jit(grpo_experience_fwd)
         return self._experience_fns[key]
 
     def _get_adv_inject_fn(self):
         key = "adv_inject"
         if key not in self._experience_fns:
-            self._experience_fns[key] = jax.jit(
-                lambda batch, adv: batch.replace(advantages=adv)
-            )
+
+            def grpo_adv_inject(batch, adv):
+                return batch.replace(advantages=adv)
+
+            self._experience_fns[key] = jax.jit(grpo_adv_inject)
         return self._experience_fns[key]
 
     def _group_advantages(self, scores: np.ndarray, stats: Dict[str, Any]):
@@ -302,23 +304,9 @@ class TPUGRPOTrainer(TPUOnlineTrainer):
             else gen_out["sequences"].shape[0] // mh.data_group_count(self.mesh)
         )
 
-        # ONE packed device->host transfer for the generation outputs
-        # (same choreography as PPO's seam — the DMA streams while the
-        # experience forward below computes)
-        packed_dev = mh.local_rows(
-            jnp.concatenate(
-                [
-                    gen_out["sequences"],
-                    gen_out["response_ids"],
-                    gen_out["response_mask"].astype(gen_out["sequences"].dtype),
-                ],
-                axis=1,
-            )
-        )
-        try:
-            packed_dev.copy_to_host_async()
-        except Exception:
-            pass
+        # the sampler's device time ends here (same seam as PPO's): the
+        # pull blocks, the experience forward is dispatched after it
+        packed_dev = self._pull_sampled_tokens(gen_out, B_local, stats)
 
         # fast path: the score-independent policy+ref logprob forward is
         # dispatched NOW on the sampler's device tensors; it executes
@@ -331,7 +319,7 @@ class TPUGRPOTrainer(TPUOnlineTrainer):
         )
         pre_batch = pre_kl_stats = None
         if device_gen:
-            with self.mesh:
+            with self.mesh, self.obs.span("score_dispatch"):
                 fwd_fn = self._get_experience_fwd_fn(P_width, N)
                 pre_batch, pre_kl_stats = self._dispatch_experience(
                     fwd_fn,
@@ -356,9 +344,10 @@ class TPUGRPOTrainer(TPUOnlineTrainer):
         P = prompt_tensors.shape[1]
 
         prompt_sizes = [P] * len(sequences)
-        str_samples, str_prompts, str_outputs = self.decode(
-            prompt_tensors, sequences, prompt_sizes, append_eos_token=True
-        )
+        with self.obs.span("detokenize"):
+            str_samples, str_prompts, str_outputs = self.decode(
+                prompt_tensors, sequences, prompt_sizes, append_eos_token=True
+            )
 
         rollout_score_time = time()
         all_scores = self._call_reward_fn(
@@ -415,7 +404,7 @@ class TPUGRPOTrainer(TPUOnlineTrainer):
             # generation; complete it with the host-computed advantages
             # (device_gen implies B % local_ways == 0, so the advantage
             # vector shards cleanly)
-            with self.mesh:
+            with self.mesh, self.obs.span("score_inject"):
                 inject_fn = self._get_adv_inject_fn()
                 rollout_batch = inject_fn(
                     pre_batch,
@@ -434,7 +423,7 @@ class TPUGRPOTrainer(TPUOnlineTrainer):
             adv_padded = np.concatenate(
                 [advantages, np.zeros(target - B, np.float32)]
             )
-            with self.mesh:
+            with self.mesh, self.obs.span("score_dispatch"):
                 fwd_fn = self._get_experience_fwd_fn(P, N)
                 pre_batch, kl_stats = self._dispatch_experience(
                     fwd_fn,

@@ -301,7 +301,7 @@ class TPUPPOTrainer(TPUOnlineTrainer):
 
         chunks = self.config.train.logit_chunks
 
-        def seq2seq_fn(params, ref_params, enc_ids, enc_mask, dec_ids, response_mask, scores, scores_mask, kl_coef, row_valid, scale_div):
+        def ppo_experience_seq2seq(params, ref_params, enc_ids, enc_mask, dec_ids, response_mask, scores, scores_mask, kl_coef, row_valid, scale_div):
             scores = scores / jnp.maximum(scale_div, 1e-8)
             mask = response_mask.astype(jnp.float32)
             dec_mask = jnp.concatenate(
@@ -351,7 +351,7 @@ class TPUPPOTrainer(TPUOnlineTrainer):
             return batch_out, {"mean_kl": mean_kl, "mean_kl_per_token": mean_kl_per_token}
 
         if self.seq2seq:
-            self._experience_fns[key] = jax.jit(seq2seq_fn)
+            self._experience_fns[key] = jax.jit(ppo_experience_seq2seq)
             return self._experience_fns[key]
 
         # causal path: composed from the SAME two jitted halves the
@@ -360,7 +360,7 @@ class TPUPPOTrainer(TPUOnlineTrainer):
         fwd_fn = self._get_experience_fwd_fn(P, N)
         inject_fn = self._get_score_inject_fn(N, S)
 
-        def fn(params, ref_params, tokens, attention_mask, response_mask, scores, scores_mask, kl_coef, row_valid, scale_div):
+        def ppo_experience(params, ref_params, tokens, attention_mask, response_mask, scores, scores_mask, kl_coef, row_valid, scale_div):
             # no envelope here: this composed fn is itself dispatched
             # through _dispatch_experience at its call site — wrapping
             # both layers would classify one OOM twice
@@ -370,7 +370,7 @@ class TPUPPOTrainer(TPUOnlineTrainer):
             )
             return inject_fn(pre_batch, scores, scores_mask, scale_div), kl_stats
 
-        self._experience_fns[key] = fn
+        self._experience_fns[key] = ppo_experience
         return self._experience_fns[key]
 
     def _get_experience_fwd_fn(self, P: int, N: int):
@@ -387,7 +387,7 @@ class TPUPPOTrainer(TPUOnlineTrainer):
 
         chunks = self.config.train.logit_chunks
 
-        def fn(params, ref_params, tokens, attention_mask, response_mask, kl_coef, row_valid):
+        def ppo_experience_fwd(params, ref_params, tokens, attention_mask, response_mask, kl_coef, row_valid):
             out = model.forward_train(
                 params, ref_params, tokens, attention_mask,
                 compute_logits=chunks == 0,
@@ -428,7 +428,7 @@ class TPUPPOTrainer(TPUOnlineTrainer):
             )
             return batch_out, {"mean_kl": mean_kl, "mean_kl_per_token": mean_kl_per_token}
 
-        self._experience_fns[key] = jax.jit(fn)
+        self._experience_fns[key] = jax.jit(ppo_experience_fwd)
         return self._experience_fns[key]
 
     def _get_score_inject_fn(self, N: int, S: int):
@@ -438,7 +438,7 @@ class TPUPPOTrainer(TPUOnlineTrainer):
         if key in self._experience_fns:
             return self._experience_fns[key]
 
-        def fn(batch_out, scores, scores_mask, scale_div):
+        def ppo_score_inject(batch_out, scores, scores_mask, scale_div):
             scores = scores / jnp.maximum(scale_div, 1e-8)
             mask = batch_out.response_mask
             rewards = batch_out.rewards
@@ -453,7 +453,7 @@ class TPUPPOTrainer(TPUOnlineTrainer):
                 rewards = rewards + padded
             return batch_out.replace(rewards=rewards * mask)
 
-        self._experience_fns[key] = jax.jit(fn)
+        self._experience_fns[key] = jax.jit(ppo_score_inject)
         return self._experience_fns[key]
 
     def _score_and_assemble(
@@ -482,25 +482,9 @@ class TPUPPOTrainer(TPUOnlineTrainer):
             else gen_out["sequences"].shape[0] // mh.data_group_count(self.mesh)
         )
 
-        # ONE packed device->host transfer for the three generation
-        # outputs (one sync instead of three). The concatenate is
-        # enqueued FIRST — devices run FIFO, so the DMA starts as soon
-        # as generation finishes and streams while the experience
-        # forward below computes
-        packed_dev = mh.local_rows(
-            jnp.concatenate(
-                [
-                    gen_out["sequences"],
-                    gen_out["response_ids"],
-                    gen_out["response_mask"].astype(gen_out["sequences"].dtype),
-                ],
-                axis=1,
-            )
-        )
-        try:
-            packed_dev.copy_to_host_async()
-        except Exception:
-            pass
+        # the sampler's device time ends here: the pull blocks, so the
+        # experience forward below is dispatched only after it
+        packed_dev = self._pull_sampled_tokens(gen_out, B_local, stats)
 
         # fast path: the score-INDEPENDENT half of the experience step
         # (policy/ref/value forward + KL penalty — the heaviest rollout
@@ -524,7 +508,7 @@ class TPUPPOTrainer(TPUOnlineTrainer):
         )
         pre_batch = pre_kl_stats = None
         if device_gen:
-            with self.mesh:
+            with self.mesh, self.obs.span("score_dispatch"):
                 fwd_fn = self._get_experience_fwd_fn(P_width, N)
                 pre_batch, pre_kl_stats = self._dispatch_experience(
                     fwd_fn,
@@ -552,9 +536,10 @@ class TPUPPOTrainer(TPUOnlineTrainer):
         P = prompt_tensors.shape[1]
 
         prompt_sizes = [P] * len(sequences)
-        str_samples, str_prompts, str_outputs = self.decode(
-            prompt_tensors, sequences, prompt_sizes, append_eos_token=True
-        )
+        with self.obs.span("detokenize"):
+            str_samples, str_prompts, str_outputs = self.decode(
+                prompt_tensors, sequences, prompt_sizes, append_eos_token=True
+            )
 
         rollout_score_time = time()
         all_scores = self._call_reward_fn(
@@ -627,7 +612,7 @@ class TPUPPOTrainer(TPUOnlineTrainer):
         if device_gen:
             # the forward half has been executing since right after
             # generation; complete it with the host-computed scores
-            with self.mesh:
+            with self.mesh, self.obs.span("score_inject"):
                 inject_fn = self._get_score_inject_fn(N, S)
                 rollout_batch = inject_fn(
                     pre_batch,
@@ -653,7 +638,7 @@ class TPUPPOTrainer(TPUOnlineTrainer):
                     rpad(sequences.astype(np.int32)),
                     rpad(attention_mask),
                 )
-            with self.mesh:
+            with self.mesh, self.obs.span("score_dispatch"):
                 rollout_batch, kl_stats = self._dispatch_experience(
                     exp_fn,
                     self.params,
