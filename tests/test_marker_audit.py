@@ -26,7 +26,7 @@ import os
 # file -> budgeted seconds for its tier-1 (not-slow) portion
 TIER1_BUDGETS = {
     "test_chunked_loss.py": 10,
-    "test_configs.py": 5,
+    "test_configs.py": 4,
     # r14: serving-tier suite (ledger fuzz + engine warm-pool goldens +
     # frontend units + ONE two-learn e2e) — measured ~45s serial on the
     # r13 1-core container (2026-08-04; the 8-way box runs the learns
@@ -37,7 +37,7 @@ TIER1_BUDGETS = {
     # multihost 0.05, properties 0.06, pipeline_parallel 4.9 measured
     # 2026-08-03).
     "test_curves.py": 2,
-    "test_deferred_stats.py": 5,
+    "test_deferred_stats.py": 2,
     "test_dpo.py": 15,
     # r09 re-baseline: every touched-or-large budget re-measured
     # SERIALLY on the idle 8-way CPU mesh (2026-08-03) to pay for the
@@ -47,11 +47,24 @@ TIER1_BUDGETS = {
     # generation 11.5s, seq2seq 16.6s, remat 0.3s, models 16.2s
     # (raised 15->20), peft 13.9s, trainers 7.9s
     "test_elastic.py": 34,
-    "test_examples.py": 2,
+    "test_examples.py": 1,
     "test_exp_queue.py": 29,
     "test_fault_tolerance.py": 63,
     "test_flash_attention.py": 14,
     "test_fleet.py": 35,
+    # PR 26: the stop of the backward pass at the hydra branch point —
+    # six tiny PPO trainers (hydra, NeoX shape, both value-branch
+    # orders, T5) differentiated through the new path and the parent's,
+    # five bypass traces and one FLOP count: 101 s alone on the 8-way
+    # CPU mesh of this 8-core container, 113 s inside the driver's
+    # 6-worker run (2026-09-30), where test_seq2seq (budget 13) took
+    # 113 s and test_memdoctor (budget 35) 80 s: budgeted 33 on the
+    # table's scale. Paid under the unchanged 780 ceiling with times of
+    # the same run: grpo 55->40 (36.0 s), resilient 5->1 (0.0),
+    # summarize_eval 5->1 (0.0), pipelines 4->1 (0.0), deferred_stats
+    # 5->2 (0.9), remat 2->1 (0.0), configs 5->4 (3.1), examples 2->1
+    # (0.1), graft_lint 8->7 (6.2).
+    "test_frozen_trunk.py": 33,
     "test_gen_engine.py": 34,
     "test_generation.py": 14,
     "test_golden.py": 3,
@@ -61,8 +74,8 @@ TIER1_BUDGETS = {
     # ceiling by trimming r09/r10-measured slack: guardrails 105->103
     # (99.9 measured), fault_tolerance 65->63 (62.4), gen_engine 36->34
     # (32.6), memdoctor 37->35 (32).
-    "test_graft_lint.py": 8,
-    "test_grpo.py": 55,
+    "test_graft_lint.py": 7,
+    "test_grpo.py": 40,
     # r09: +4 preference-RL chaos learn() tests (GRPO nan/sigterm, DPO
     # nan/sigterm); whole file re-measured 99.9s serial
     "test_guardrails.py": 103,
@@ -118,17 +131,17 @@ TIER1_BUDGETS = {
     "test_ops.py": 5,
     "test_peft.py": 14,
     "test_pipeline_parallel.py": 7,
-    "test_pipelines.py": 4,
+    "test_pipelines.py": 1,
     "test_properties.py": 2,
     "test_reference_harness.py": 4,
-    "test_remat.py": 2,
-    "test_resilient.py": 5,
+    "test_remat.py": 1,
+    "test_resilient.py": 1,
     "test_ring_attention.py": 8,
     "test_scanned_epochs.py": 46,
     "test_seq2seq.py": 13,
     "test_serve.py": 46,
     "test_sharding.py": 7,
-    "test_summarize_eval.py": 5,
+    "test_summarize_eval.py": 1,
     "test_supervisor.py": 11,
     "test_sweep.py": 14,
     "test_trainers.py": 9,

@@ -642,6 +642,37 @@ def test_learn_writes_work_site_spans_into_every_cycle_row(faultfree_run):
     assert gen_times and all(t > 0 for t in gen_times)
 
 
+def test_backward_depth_gauges_once_per_built_step(faultfree_run, tmp_path):
+    """`model/layers` and `model/backward_layers` land once per built
+    train step, in the flight stream and in the tracker. All layers
+    trainable: the backward runs through both. Hydra top-1 of 2: through
+    one (the stop at the branch point is engaged)."""
+    from trlx_tpu.utils.loading import get_trainer
+
+    trainer, ckpt_dir = faultfree_run
+    rows = list(iter_rows(os.path.join(ckpt_dir, "flight")))
+    end = [r["kind"] for r in rows].index("run_end")
+    built = [r for r in rows[:end] if r["kind"] == "gauge"]
+    # learn() builds the per-step program and the fused block, once each
+    assert [(r["model/layers"], r["model/backward_layers"]) for r in built] == [(2, 2)] * 2
+    with open(os.path.join(ckpt_dir, "logs", "metrics.jsonl")) as f:
+        logged = [json.loads(line) for line in f]
+    gauges = [r for r in logged if "model/backward_layers" in r]
+    assert len(gauges) == 2 and gauges[0]["model/layers"] == 2.0
+    # a fact of the program, not an event of the run: a monitor that
+    # counts the events tail (the benchmark's `correct`) must not see it
+    assert "gauge" not in trainer.obs.events_tail()
+
+    hydra_dir = str(tmp_path / "ckpts")
+    config = _tiny_ppo_config(hydra_dir).evolve(model=dict(num_layers_unfrozen=1))
+    trainer = get_trainer(config.train.trainer)(config=config)
+    trainer.make_fused_train_steps()
+    trainer.make_train_step()
+    built = [r for r in iter_rows(os.path.join(hydra_dir, "flight"))
+             if r["kind"] == "gauge"]
+    assert [(r["model/layers"], r["model/backward_layers"]) for r in built] == [(2, 1)] * 2
+
+
 def test_cycle_programs_carry_their_own_names(faultfree_run):
     """`XLA Modules` in a trace and the compile log name a program by
     its function: generation, scoring and the train step each have one."""

@@ -608,23 +608,36 @@ class T5LM:
         branch_at: int,
         remat=False,
         compute_logits: bool = True,
+        frozen_below: int = 0,
     ) -> Dict[str, Array]:
         """Teacher-forced forward that also returns the decoder hidden
         state entering layer `branch_at` plus the biases needed to re-run
         the top branch (parity: the reference's frozen `T5Branch`,
-        modeling_ppo.py:1483-1592, which re-runs top decoder blocks)."""
+        modeling_ppo.py:1483-1592, which re-runs top decoder blocks).
+
+        `frozen_below` (static) is the index of the first trainable
+        decoder layer. With `branch_at <= frozen_below` everything
+        `make_seq2seq_freeze_mask` freezes is a constant of a
+        differentiated forward: the encoder, the shared embedding, the
+        relative biases and the bottom decoder layers run on
+        gradient-stopped params without remat, so the backward ends at
+        `branch_hidden` (same contract as
+        `TransformerLM.forward_with_multi_capture`). The
+        pipeline-parallel path (`pp > 1`) keeps the full backward."""
         cfg = self.cfg
-        encoder_hidden = self.encode(params, input_ids, attention_mask,
-                                     remat=remat)
         B, T = decoder_input_ids.shape
+        n_mb = self._pp_microbatches(cfg.n_decoder_layer, B)
+        const = 0 < branch_at <= frozen_below and not n_mb
+        frozen = jax.lax.stop_gradient(params) if const else params
+        encoder_hidden = self.encode(frozen, input_ids, attention_mask,
+                                     remat=False if const else remat)
         args = self._decoder_args(
-            params, B, T, encoder_hidden.shape[1], decoder_attention_mask,
+            frozen, B, T, encoder_hidden.shape[1], decoder_attention_mask,
             attention_mask, encoder_hidden,
         )
         (self_bias, _, cross_bias, pos_bias, skey_mask, ckey_mask) = args
 
-        h = self._embed(params, decoder_input_ids)
-        n_mb = self._pp_microbatches(cfg.n_decoder_layer, B)
+        h = self._embed(frozen, decoder_input_ids)
         if n_mb:
             h_top, (h_branch,) = self._pp_scan(
                 self.dec_block, params["decoder"]["blocks"], h,
@@ -632,20 +645,24 @@ class T5LM:
             )
         else:
             bottom = jax.tree_util.tree_map(
-                lambda x: x[:branch_at], params["decoder"]["blocks"]
+                lambda x: x[:branch_at], frozen["decoder"]["blocks"]
             )
             top = jax.tree_util.tree_map(
                 lambda x: x[branch_at:], params["decoder"]["blocks"]
             )
             h_branch, _ = self._scan(
-                self.dec_block, bottom, h, *args, remat=remat,
+                self.dec_block, bottom, h, *args, remat=False if const else remat,
             )
+            if const:
+                h_branch = jax.lax.stop_gradient(h_branch)
             h_top, _ = self._scan(
                 self.dec_block, top, h_branch, *args, remat=remat,
             )
         hidden = self.norm.apply({"params": params["decoder"]["ln_f"]}, h_top)
+        # a tied head reads the (frozen) shared embedding
+        head = dict(params, shared=frozen["shared"])
         return {
-            "logits": self._logits(params, hidden) if compute_logits else None,
+            "logits": self._logits(head, hidden) if compute_logits else None,
             "hidden_states": hidden,
             "branch_hidden": h_branch,
             "self_bias": self_bias,

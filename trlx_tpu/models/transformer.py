@@ -1455,12 +1455,23 @@ class TransformerLM:
         points: Tuple[int, ...],
         remat: bool = False,
         compute_logits: bool = True,
+        frozen_below: int = 0,
     ) -> Dict[str, Array]:
         """Forward capturing the hidden state entering each layer index in
         `points` (sorted ascending). Generalizes branch capture so the
         hydra reference branch and the trainable value branch
         (reference make_value_branch, modeling_ppo.py:255-263) can fork at
-        different depths in ONE trunk pass."""
+        different depths in ONE trunk pass.
+
+        `frozen_below` (static) is the index of the first trainable
+        layer. Everything under it is a constant of a differentiated
+        forward: the embedding and the segments that end at or below it
+        run on gradient-stopped params without remat (nothing is
+        transposed, so nothing is saved or recomputed), and their output
+        is gradient-stopped too, so the backward ends where the freeze
+        mask (`make_freeze_mask`) says training does. A segment that
+        straddles it, and the pipeline-parallel path (`pp > 1`), keep
+        the full backward."""
         B, T = input_ids.shape
         if attention_mask is None:
             attention_mask = jnp.ones((B, T), jnp.int32)
@@ -1472,9 +1483,12 @@ class TransformerLM:
             bias, local_bias = self._build_bias(
                 attention_mask, jnp.arange(T), jnp.arange(T)
             )
-        h = self._embed_h(params, input_ids, positions)
-
         n_mb = 0 if ring is not None else self._pp_microbatches(B, None)
+        if n_mb:
+            frozen_below = 0
+        frozen = jax.lax.stop_gradient(params) if frozen_below else params
+        h = self._embed_h(frozen, input_ids, positions)
+
         if n_mb:
             # match the sequential path: points >= n_layer are omitted
             # (never captured), not returned as zeros
@@ -1490,19 +1504,25 @@ class TransformerLM:
             prev = 0
             for point in tuple(points) + (self.cfg.n_layer,):
                 if point > prev:
+                    const = point <= frozen_below
                     seg = jax.tree_util.tree_map(
-                        lambda x: x[prev:point], params["blocks"]
+                        lambda x: x[prev:point],
+                        (frozen if const else params)["blocks"],
                     )
                     h, _ = self._scan_blocks(
-                        seg, h, bias, positions, remat=remat,
+                        seg, h, bias, positions, remat=False if const else remat,
                         key_mask=attention_mask,
                         local_bias=local_bias, layer_offset=prev, ring_mesh=ring,
                     )
+                    if const:
+                        h = jax.lax.stop_gradient(h)
                 if point < self.cfg.n_layer:
                     captures.append(h)
                 prev = point
         hidden = self.ln_f.apply({"params": params["ln_f"]}, h)
-        logits = self._logits(params, hidden) if compute_logits else None
+        # a tied head reads the (frozen) embedding
+        head = dict(params, embed=frozen["embed"])
+        logits = self._logits(head, hidden) if compute_logits else None
         return {
             "logits": logits,
             "hidden_states": hidden,

@@ -184,6 +184,18 @@ class CausalLMWithValueHead:
             points.add(self.value_branch_at)
         return tuple(sorted(points))
 
+    def frozen_below(self) -> int:
+        """Index of the first trunk layer that trains, as
+        `make_freeze_mask` has it: the trunk under `branch_at` is frozen
+        wherever the value branch forks (it trains its own copy,
+        `params["v_branch"]`). 0 = the backward runs the whole trunk:
+        all layers train, a peft adapter (`setup_model` leaves
+        `branch_at` None), or an embedding LayerNorm, which trains
+        under every layer."""
+        if self.branch_at is None or self.cfg.embed_layernorm:
+            return 0
+        return self.branch_at
+
     def _multi_forward(self, params, input_ids, attention_mask, remat,
                        compute_logits=True):
         """Trunk pass capturing hydra and/or value-branch fork hiddens."""
@@ -191,7 +203,7 @@ class CausalLMWithValueHead:
         points = self._capture_points()
         out = self.lm.forward_with_multi_capture(
             base, input_ids, attention_mask, points, remat=remat,
-            compute_logits=compute_logits,
+            compute_logits=compute_logits, frozen_below=self.frozen_below(),
         )
         named = dict(zip(points, out["captures"]))
         if self.branch_at is not None:
@@ -224,7 +236,11 @@ class CausalLMWithValueHead:
         """hidden -> logits closure for chunked-from-hidden losses
         (`ops.common.chunked_logprobs`); resolves any LoRA overlay so the
         projection matches the forward's effective weights."""
-        return logit_projection(_effective_base(self, params))
+        base = _effective_base(self, params)
+        if self.frozen_below():
+            # a tied head reads the (frozen) embedding
+            base = dict(base, embed=jax.lax.stop_gradient(base["embed"]))
+        return logit_projection(base)
 
     def forward_train(
         self,
@@ -306,6 +322,12 @@ class Seq2SeqLMWithValueHead:
             "v_head": init_head(r_head, self.cfg.d_model, 1),
         }
 
+    def frozen_below(self) -> int:
+        """Index of the first decoder layer that trains, as
+        `make_seq2seq_freeze_mask` has it (0 = the backward runs every
+        layer, the encoder's too)."""
+        return self.branch_at or 0
+
     def make_ref_params(self, params: Dict) -> Dict:
         from trlx_tpu.models.seq2seq import extract_t5_branch_params
 
@@ -337,7 +359,11 @@ class Seq2SeqLMWithValueHead:
         """hidden -> logits closure for chunked-from-hidden losses."""
         from trlx_tpu.models.seq2seq import t5_logit_projection
 
-        return t5_logit_projection(_effective_base(self, params), self.cfg)
+        base = _effective_base(self, params)
+        if self.frozen_below():
+            # a tied head reads the (frozen) shared embedding
+            base = dict(base, shared=jax.lax.stop_gradient(base["shared"]))
+        return t5_logit_projection(base, self.cfg)
 
     def forward_train(
         self,
@@ -367,6 +393,7 @@ class Seq2SeqLMWithValueHead:
                 params["base"], input_ids, attention_mask, decoder_input_ids,
                 decoder_attention_mask, self.branch_at, remat=remat,
                 compute_logits=compute_logits,
+                frozen_below=self.frozen_below(),
             )
             with jax.named_scope("value_head"):
                 out["values"] = apply_head(params["v_head"], out["hidden_states"])[..., 0]

@@ -174,15 +174,27 @@ class RunObserver:
     def events_tail(self) -> Dict[str, list]:
         return {k: list(v) for k, v in self._events.items()}
 
+    def _correlated(self, fields: Dict[str, Any]) -> Dict[str, Any]:
+        row = {"cycle": self.cycle, "step": self._step,
+               "pv": self._policy_version}
+        row.update(fields)  # caller's fields win (e.g. run_start's step)
+        return row
+
     @_no_raise
     def record(self, kind: str, **fields: Any) -> None:
         """One correlated event row (run_id / cycle / step / policy
         version stamped here)."""
-        row = {"cycle": self.cycle, "step": self._step,
-               "pv": self._policy_version}
-        row.update(fields)  # caller's fields win (e.g. run_start's step)
+        row = self._correlated(fields)
         self.recorder.append(kind, **row)
         self._remember(kind, {"t": round(time.time(), 3), **row})
+
+    @_no_raise
+    def gauge(self, **values: Any) -> None:
+        """One correlated `gauge` row: static facts of the program as
+        built (`model/backward_layers`). Nothing happened to the run, so
+        unlike `record` it stays out of the events tail, which a
+        monitor may count as incidents."""
+        self.recorder.append("gauge", **self._correlated(values))
 
     # -- run / cycle lifecycle -------------------------------------------
 

@@ -1495,6 +1495,25 @@ class TPUBaseTrainer(BaseRLTrainer):
         doctor's split_microbatch rung is active). Default: identity."""
         return batch
 
+    def _note_backward_depth(self) -> None:
+        """Gauge pair, once per built train step, in the flight stream
+        and the tracker: `model/layers`, and `model/backward_layers`,
+        the layers the step's backward pass runs through (the wrapper's
+        `frozen_below()`: hydra PPO stops it at the branch point)."""
+        cfg = self.model.cfg
+        below = getattr(self.model, "frozen_below", lambda: 0)()
+        if self.mesh.shape["pp"] > 1:
+            below = 0  # the pipelined forward keeps the full backward
+        decoder = getattr(cfg, "n_decoder_layer", None)
+        if decoder is None:
+            layers, backward = cfg.n_layer, cfg.n_layer - below
+        else:  # seq2seq: a frozen trunk takes the whole encoder with it
+            layers = cfg.n_layer + decoder
+            backward = decoder - below if below else layers
+        gauges = {"model/layers": layers, "model/backward_layers": backward}
+        self.obs.gauge(**gauges)
+        self._tracker_log(gauges, step=self.iter_count)
+
     def _pinned_state_shardings(self):
         # Pin output shardings to the current (input) shardings: without
         # this, GSPMD may choose different layouts for the step-1 outputs,
@@ -1506,6 +1525,7 @@ class TPUBaseTrainer(BaseRLTrainer):
 
     def make_train_step(self):
         """One jitted function per optimizer step. Donates params/opt_state."""
+        self._note_backward_depth()
         params_sh, opt_sh = self._pinned_state_shardings()
 
         def train_step(params, opt_state, batch):
@@ -1544,6 +1564,7 @@ class TPUBaseTrainer(BaseRLTrainer):
             )
             return params, opt_state, jnp.mean(losses), mean_stats
 
+        self._note_backward_depth()
         params_sh, opt_sh = self._pinned_state_shardings()
         return jax.jit(
             fused_train_step,
