@@ -5,6 +5,15 @@
     `reference_rows` first rollouts, at the cell's full width, depth and
     sequence length, while the parameters are still the seeded ones.
     Tolerances and their reason are in the configuration's file (`correct`).
+    Where the reference makes discrete choices (an expert picked, a block
+    selected), its `hidden_states` may return, with the hidden states, a
+    boolean per position: true where every choice made on the way to that
+    position had a margin above the threshold the file states (the module
+    reads it from `hf["correct"]`). The tolerances above then hold on those
+    DECISIVE positions; the rest, where bf16 activations may fairly choose
+    otherwise, are held to the looser `tie_logprob_rms_tol` and
+    `tie_logprob_max_tol`, and the share of decisive positions to
+    `decisive_share_min`, all stated in the file. Nothing is skipped.
 (b) The sampler against the same reference: tokens drawn from the policy
     have an expected log-probability of minus the entropy, so the mean of
     log p(token) + H over the sampled positions is zero up to sampling
@@ -34,6 +43,35 @@ SAMPLER_Z = 6.0
 SAMPLER_SLACK = 0.02
 
 
+def scorer_check(system, reference, decisive, tol: Dict) -> Dict[str, float]:
+    """Check (a) on flat arrays of log-probabilities, the system's and the
+    reference's, one entry a sampled position. `decisive` is None (every
+    position held to the tolerances) or a boolean array (module doc-string)."""
+    import numpy as np
+
+    def errors(diff):
+        if diff.size == 0:
+            return 0.0, 0.0
+        return float(np.sqrt(np.mean(diff**2))), float(np.max(np.abs(diff)))
+
+    diff = np.asarray(system) - np.asarray(reference)
+    ok = bool(np.all(np.isfinite(system)))
+    if decisive is None:
+        rms, worst = errors(diff)
+        out = {"logprob_rms_err": rms, "logprob_max_err": worst}
+    else:
+        decisive = np.asarray(decisive, bool)
+        rms, worst = errors(diff[decisive])
+        tie_rms, tie_worst = errors(diff[~decisive])
+        out = {"logprob_rms_err": rms, "logprob_max_err": worst,
+               "tie_logprob_rms_err": tie_rms, "tie_logprob_max_err": tie_worst,
+               "decisive_share": float(decisive.mean()) if decisive.size else 0.0}
+        ok = (ok and tie_rms <= tol["tie_logprob_rms_tol"] and tie_worst <= tol["tie_logprob_max_tol"]
+              and out["decisive_share"] >= tol["decisive_share_min"])
+    out["scorer_ok"] = bool(ok and rms <= tol["logprob_rms_tol"] and worst <= tol["logprob_max_tol"])
+    return out
+
+
 def reference_check(trainer, cell, hf: Dict, rows: int) -> Dict[str, float]:
     """Checks (a) and (b) on the rollouts now in the trainer's store."""
     import jax
@@ -54,33 +92,29 @@ def reference_check(trainer, cell, hf: Dict, rows: int) -> Dict[str, float]:
     @jax.jit
     def reference(base, tokens, mask):
         p = ref.params_from_system(base)
-        hidden = ref.hidden_states(p, hf, tokens, mask)[:, P - 1 : P + N - 1]
+        hidden = ref.hidden_states(p, hf, tokens, mask)
+        hidden, decisive = hidden if isinstance(hidden, tuple) else (hidden, None)
+        hidden = hidden[:, P - 1 : P + N - 1]
         logp = jax.nn.log_softmax(ref.logits(p, hidden), axis=-1)
         taken = jnp.take_along_axis(logp, tokens[:, P:, None], axis=-1)[..., 0]
         entropy = -(jnp.exp(logp) * logp).sum(-1)
-        return taken, entropy
+        return taken, entropy, None if decisive is None else decisive[:, P - 1 : P + N - 1]
 
     with trainer.mesh:
-        taken, entropy = reference(trainer.params["base"], tokens, mask)
+        taken, entropy, decisive = reference(trainer.params["base"], tokens, mask)
     taken, entropy = np.asarray(taken)[resp_mask], np.asarray(entropy)[resp_mask]
+    if decisive is not None:
+        decisive = np.asarray(decisive)[resp_mask]
     system = np.asarray(hist.logprobs[:rows])[resp_mask]
-    diff = system - taken
     surprise = taken + entropy
     n = surprise.size
     bound = SAMPLER_Z * float(surprise.std()) / math.sqrt(n) + SAMPLER_SLACK
     out = {
         "positions": n,
-        "logprob_rms_err": float(np.sqrt(np.mean(diff**2))),
-        "logprob_max_err": float(np.max(np.abs(diff))),
+        **scorer_check(system, taken, decisive, cell.config["correct"]),
         "sampler_mean_surprise": float(surprise.mean()),
         "sampler_bound": bound,
     }
-    tol = cell.config["correct"]
-    out["scorer_ok"] = bool(
-        np.all(np.isfinite(system))
-        and out["logprob_rms_err"] <= tol["logprob_rms_tol"]
-        and out["logprob_max_err"] <= tol["logprob_max_tol"]
-    )
     out["sampler_ok"] = bool(abs(out["sampler_mean_surprise"]) <= bound)
     return out
 
@@ -120,3 +154,18 @@ def window_check(stream, cycles: List[Dict], traffic: Dict, experiences: int,
 def compile_check(in_window: Dict[str, float]) -> bool:
     """Check (d)."""
     return in_window["compiles"] == 0 and in_window["cache_misses"] == 0
+
+
+def compared(ref: Dict, win: Dict, in_window: Dict, tol: Dict) -> Dict[str, list]:
+    """Every number the checks compared, beside its limit: [number, limit]."""
+    out = {"logprob_rms": [ref["logprob_rms_err"], tol["logprob_rms_tol"]],
+           "logprob_max": [ref["logprob_max_err"], tol["logprob_max_tol"]]}
+    if "decisive_share" in ref:
+        out["tie_logprob_rms"] = [ref["tie_logprob_rms_err"], tol["tie_logprob_rms_tol"]]
+        out["tie_logprob_max"] = [ref["tie_logprob_max_err"], tol["tie_logprob_max_tol"]]
+        out["decisive_share_at_least"] = [ref["decisive_share"], tol["decisive_share_min"]]
+    out["sampler_surprise"] = [abs(ref["sampler_mean_surprise"]), ref["sampler_bound"]]
+    out["generated_tokens_exactly"] = [win["generated_tokens"], win["expected_tokens"]]
+    out["failed_cycles"] = [win["failed"], 0]
+    out["built_in_window"] = [in_window["compiles"] + in_window["cache_misses"], 0]
+    return out

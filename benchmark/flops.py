@@ -10,57 +10,116 @@ runs: nothing for recomputation under remat, nothing for gradients of
 frozen layers, logits only where a log-probability is used, causal
 attention as the lower triangle.
 
-`dims` is the dict a reference module's `dims()` returns.
+**The family states the work, this file adds it up.** Every function below
+takes the description of a model as run on this chip, `work(reference, hf)`:
+
+    {"layers": [layer, ...],   bottom to top, one entry for each layer that is run
+     "head": {"flops": ..., "weight_elems": ...},   the output projection, one position
+     "leading": 0}             bottom layers a hydra branch never trains (leading dense ones)
+
+    layer = {"linear_flops": forward matmul FLOPs one token requires, attention scores aside
+             "pair_flops":   FLOPs of one (query, key) pair of its attention, all heads:
+                             score and weighted value, 2 x heads x (qk width + v width)
+             "weight_elems": weight elements one decode step must read, at the item size
+                             the recipe decodes with
+             "cache_elems":  elements one cached position costs a row, at the cache's item size
+             "routed":       optional, experts chosen per token, not counted above:
+                 {"expert_flops", "expert_elems": one expert, one token
+                  "published": experts the router scores, "held": experts on this chip,
+                  "per_token": experts a token is sent to}}
+
+A reference module gives it as `work(hf)`; one that gives only `dims(hf)` (one
+kind of layer: `n_head`/`n_kv_head` heads of `head_dim`, `mlp_matrices` x `hidden`
+x `intermediate`) has it derived here by `describe`, so the functions also take
+such a `dims` dict. For routed experts the count from shapes is an EXPECTATION
+under uniform routing: a token meets `per_token x held / published` of the experts
+held here, and a decode step over `batch` rows reads `held x (1 - (1 - per_token /
+published)^batch)` of them. The program's counter of tokens routed here is what a
+later reader holds against it.
 """
 
 from __future__ import annotations
 
-from typing import Dict
+from typing import Dict, List, Optional
 
 
-def layer_linear_flops(d: Dict) -> float:
-    """Forward matmul FLOPs of one block for one token, attention scores
-    aside: q, k, v, o and the MLP matrices."""
+def work(reference, hf: Dict) -> Dict:
+    """The description of `hf` by its reference module: its own `work`,
+    or the one derived from its `dims`."""
+    return reference.work(hf) if hasattr(reference, "work") else describe(reference.dims(hf))
+
+
+def describe(d: Dict) -> Dict:
+    """`d` if it is a description already; else the description of
+    `d["n_layer"]` equal blocks with q, k, v, o and the MLP matrices."""
+    if "layers" in d:
+        return d
     e, hd = d["hidden"], d["head_dim"]
-    qkv = 2 * e * hd * (d["n_head"] + 2 * d["n_kv_head"])
-    out = 2 * d["n_head"] * hd * e
-    mlp = 2 * d["mlp_matrices"] * e * d["intermediate"]
-    return float(qkv + out + mlp)
+    qkv = e * hd * (d["n_head"] + 2 * d["n_kv_head"])
+    out = d["n_head"] * hd * e
+    mlp = d["mlp_matrices"] * e * d["intermediate"]
+    layer = {
+        "linear_flops": float(2 * (qkv + out + mlp)),
+        "pair_flops": 4.0 * d["n_head"] * hd,  # two D-wide dot products a head
+        "weight_elems": qkv + out + mlp,
+        "cache_elems": 2 * d["n_kv_head"] * hd,
+    }
+    return {"layers": [layer] * d["n_layer"], "leading": 0,
+            "head": {"flops": 2.0 * e * d["vocab"], "weight_elems": e * d["vocab"]}}
 
 
-def attention_flops(d: Dict, queries: float, keys_each: float) -> float:
-    """Scores and weighted values of one layer: each query does two
-    D-wide dot products per head with each key it sees."""
-    return 4.0 * d["n_head"] * d["head_dim"] * queries * keys_each
+def top_layers(d: Dict, top: Optional[int] = None) -> List[Dict]:
+    layers = describe(d)["layers"]
+    return layers if top is None else layers[len(layers) - top:]
 
 
-def causal_forward_flops(d: Dict, tokens: int, n_layers: int) -> float:
-    """One sequence of `tokens` through `n_layers` blocks, teacher-forced:
-    query t sees t keys, (tokens + 1) / 2 on average."""
-    per_layer = tokens * layer_linear_flops(d) + attention_flops(
-        d, tokens, (tokens + 1) / 2.0
+def layer_linear_flops(layer: Dict) -> float:
+    """Forward matmul FLOPs of one layer for one token, attention scores
+    aside; with routed experts, the expectation (module doc-string)."""
+    routed = layer.get("routed")
+    if not routed:
+        return layer["linear_flops"]
+    met = routed["per_token"] * routed["held"] / routed["published"]
+    return layer["linear_flops"] + met * routed["expert_flops"]
+
+
+def attention_flops(layer: Dict, queries: float, keys_each: float) -> float:
+    """Scores and weighted values of one layer: each query does its
+    `pair_flops` with each key it sees."""
+    return layer["pair_flops"] * queries * keys_each
+
+
+def causal_forward_flops(d: Dict, tokens: int, top: Optional[int] = None) -> float:
+    """One sequence of `tokens` through every layer, or the `top` ones,
+    teacher-forced: query t sees t keys, (tokens + 1) / 2 on average."""
+    return sum(
+        tokens * layer_linear_flops(layer) + attention_flops(layer, tokens, (tokens + 1) / 2.0)
+        for layer in top_layers(d, top)
     )
-    return n_layers * per_layer
 
 
 def logits_flops(d: Dict, positions: float) -> float:
-    return 2.0 * d["hidden"] * d["vocab"] * positions
+    return describe(d)["head"]["flops"] * positions
 
 
 def generation_flops(d: Dict, prompt: int, new: int) -> float:
     """One row: prefill of `prompt` tokens (which yields the first new
     token) and new - 1 single-token steps against a growing cache."""
-    total = causal_forward_flops(d, prompt, d["n_layer"]) + logits_flops(d, 1)
+    total = causal_forward_flops(d, prompt) + logits_flops(d, 1)
     steps = new - 1
     keys = prompt + (steps + 1) / 2.0  # step i sees prompt + i keys
-    total += steps * d["n_layer"] * layer_linear_flops(d)
-    total += d["n_layer"] * attention_flops(d, steps, keys)
+    for layer in top_layers(d):
+        total += steps * layer_linear_flops(layer) + attention_flops(layer, steps, keys)
     total += logits_flops(d, steps)
     return total
 
 
 def trainable_layers(d: Dict, unfrozen: int) -> int:
-    return d["n_layer"] if unfrozen is None or unfrozen < 0 else min(unfrozen, d["n_layer"])
+    """How many layers, from the top, train: all of them, or the hydra
+    branch's `unfrozen`, which never reaches into the leading layers."""
+    w = describe(d)
+    n = len(w["layers"])
+    return n if unfrozen is None or unfrozen < 0 else min(unfrozen, n - w["leading"])
 
 
 def ppo_scoring_flops(d: Dict, prompt: int, new: int, unfrozen: int) -> float:
@@ -69,10 +128,9 @@ def ppo_scoring_flops(d: Dict, prompt: int, new: int, unfrozen: int) -> float:
     whole second model when every layer trains) and the log-probabilities
     of the `new` response tokens under both."""
     seq = prompt + new
-    ref_layers = trainable_layers(d, unfrozen)
     return (
-        causal_forward_flops(d, seq, d["n_layer"])
-        + causal_forward_flops(d, seq, ref_layers)
+        causal_forward_flops(d, seq)
+        + causal_forward_flops(d, seq, trainable_layers(d, unfrozen))
         + 2 * logits_flops(d, new)
     )
 
@@ -83,10 +141,9 @@ def ppo_train_flops(d: Dict, prompt: int, new: int, unfrozen: int) -> float:
     trainable top layers and the output projection at the `new` response
     positions. Frozen layers need no gradient."""
     seq = prompt + new
-    k = trainable_layers(d, unfrozen)
     return (
-        causal_forward_flops(d, seq, d["n_layer"])
-        + 2 * causal_forward_flops(d, seq, k)
+        causal_forward_flops(d, seq)
+        + 2 * causal_forward_flops(d, seq, trainable_layers(d, unfrozen))
         + 3 * logits_flops(d, new)
     )
 
@@ -134,14 +191,20 @@ def adam8bit_bytes(n_params: float, grad_itemsize: int = 2) -> float:
 
 def decode_step_bytes(d: Dict, batch: int, keys: float, weight_itemsize: int = 1,
                       kv_itemsize: int = 1, head_itemsize: int = 2) -> float:
-    """One decode step must read every block weight once, the output
-    projection once and the keys and values of every row."""
-    e, hd = d["hidden"], d["head_dim"]
-    block = e * hd * (d["n_head"] + 2 * d["n_kv_head"]) + d["n_head"] * hd * e
-    block += d["mlp_matrices"] * e * d["intermediate"]
-    weights = d["n_layer"] * block * weight_itemsize + e * d["vocab"] * head_itemsize
-    kv = 2.0 * d["n_layer"] * batch * d["n_kv_head"] * hd * keys * kv_itemsize
-    return float(weights + kv)
+    """One decode step must read every layer's weights once (of routed
+    experts, those the batch's rows are expected to reach), the output
+    projection once and what the cache holds of `keys` positions of every row."""
+    w = describe(d)
+    elems, cached = 0.0, 0.0
+    for layer in w["layers"]:
+        elems += layer["weight_elems"]
+        routed = layer.get("routed")
+        if routed:
+            reached = 1.0 - (1.0 - routed["per_token"] / routed["published"]) ** batch
+            elems += routed["held"] * reached * routed["expert_elems"]
+        cached += layer["cache_elems"]
+    weights = elems * weight_itemsize + w["head"]["weight_elems"] * head_itemsize
+    return float(weights + cached * batch * keys * kv_itemsize)
 
 
 def roofline_seconds(work: Dict[str, float], peak: Dict[str, float]) -> Dict:

@@ -24,6 +24,7 @@ import time
 T0 = time.monotonic()  # process start, for setup_s
 
 import argparse
+import glob
 import importlib
 import json
 import os
@@ -36,12 +37,19 @@ from typing import Any, Dict, Optional
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-# sizes of the rehearsal: every code path of a run, none of its cost
+# sizes of the rehearsal: every code path of a run, none of its cost. A
+# family whose widths go by other keys states its own: `toy_sizes(hf)` of
+# the configuration's reference module takes the place of "config".
 REHEARSAL = {
     "config": {"hidden_size": 128, "num_attention_heads": 2, "intermediate_size": 256,
                "num_hidden_layers": 4, "vocab_size": 512},
     "traffic": {"prompt_tokens": 120, "new_tokens": 8, "reference_rows": 2},
 }
+
+
+def rehearsal_scale(cell) -> Dict[str, Dict[str, Any]]:
+    toy = getattr(cell.reference, "toy_sizes", None)
+    return dict(REHEARSAL, config=toy(cell.config) if toy else REHEARSAL["config"])
 
 
 def log(msg: str) -> None:
@@ -53,6 +61,10 @@ class Reading:
     """What a metric reader may look at."""
 
     cell: Any  # cells.Cell
+    run_dir: str  # the run's files: the program's logs/, flight/, trace_summary.json
+    flight: list  # every row of the program's flight stream, as plain JSON: `cycle`
+    # rows (step, phases, spans with their counts, the cycle's counters), `gauge`
+    # rows, run_start and run_end; layer_metrics/_program_spans.py picks the window's
     hf: Dict[str, Any]  # the configuration as run
     traffic: Dict[str, Any]  # the traffic mix as run
     chips: int
@@ -72,6 +84,21 @@ def device_line(devices) -> Dict[str, Any]:
     peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0) for d in devices]
     return {"platform": devices[0].platform, "kind": devices[0].device_kind,
             "count": len(devices), "memory_peak_bytes": int(max(peaks))}
+
+
+def flight_rows(run_dir: str) -> list:
+    """The rows of `<run_dir>/flight/*.jsonl`, in the order written."""
+    rows = []
+    for path in sorted(glob.glob(os.path.join(run_dir, "flight", "*.jsonl"))):
+        with open(path) as f:
+            for line in f:
+                try:
+                    row = json.loads(line)
+                except ValueError:
+                    continue  # a torn last line
+                if isinstance(row, dict):
+                    rows.append(row)
+    return rows
 
 
 def compiled_memory(trainer) -> Optional[Dict[str, float]]:
@@ -133,7 +160,7 @@ def main() -> int:
     shutil.rmtree(run_dir, ignore_errors=True)
     os.makedirs(run_dir)
     cfg, prompts, traffic, hf = cells.build_config(
-        cell, args.seed, run_dir, REHEARSAL if args.rehearse else None)
+        cell, args.seed, run_dir, rehearsal_scale(cell) if args.rehearse else None)
     meter = CompileMeter()
     state: Dict[str, Any] = {}
 
@@ -187,7 +214,8 @@ def main() -> int:
         device["busy_s"], device["window_s"] = trace["busy_s"], trace["window_s"]
     peaks_kind = "TPU v5 lite" if args.rehearse else device["kind"]
     reading = Reading(
-        cell=cell, hf=hf, traffic=traffic, chips=cell.chips,
+        cell=cell, run_dir=run_dir, flight=flight_rows(run_dir),
+        hf=hf, traffic=traffic, chips=cell.chips,
         peaks=cells.peaks_for(peaks_kind),
         unfrozen=trainer.config.model.num_layers_unfrozen,
         setup_s=window.opened_at - T0, cycles=window.cycles,
@@ -211,6 +239,10 @@ def main() -> int:
             "metrics": metrics, "device": device}
     if trace:
         line["breakdown"] = trace["breakdown"]
+    # every number compared beside its limit: last in the line, last on stderr
+    line["compared"] = correct.compared(ref, win, window.compile_in_window, cell.config["correct"])
+    for name, (number, limit) in line["compared"].items():
+        print(f"[benchmark] compared {name}: {number} limit {limit}", file=sys.stderr, flush=True)
     if args.rehearse:
         log("REHEARSAL only, nothing below is a measurement: " + json.dumps(line))
         return 3

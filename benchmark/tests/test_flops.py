@@ -11,8 +11,12 @@ D = dict(n_layer=2, hidden=8, n_head=2, n_kv_head=2, head_dim=4, intermediate=16
 LINEAR = 2 * (4 * 8 * 8 + 2 * 8 * 16)
 
 
-def test_layer_linear():
-    assert flops.layer_linear_flops(D) == LINEAR == 1024
+def test_layer_linear_of_the_description_derived_from_dims():
+    w = flops.describe(D)
+    assert len(w["layers"]) == 2 and w["leading"] == 0 and flops.describe(w) is w
+    assert flops.layer_linear_flops(w["layers"][0]) == LINEAR == 1024
+    assert w["layers"][0]["pair_flops"] == 4 * 2 * 4 and w["layers"][0]["cache_elems"] == 2 * 2 * 4
+    assert w["head"] == {"flops": 2.0 * 8 * 32, "weight_elems": 8 * 32}
 
 
 def test_causal_forward_counts_the_lower_triangle():

@@ -1,47 +1,28 @@
 """The readers of the flash-attention, generation and sampler metrics on a
 hand-made `Reading`: what they count, that a share stays under 100, that they
-return None where there is nothing to read, and the run-directory formula."""
+return None where there is nothing to read."""
 
-import json
-import os
 from types import SimpleNamespace
 
 import pytest
 
 from benchmark import cells, flops
 from benchmark.layer_metrics import (
-    _program_spans, flash_bwd_roofline, flash_fwd_roofline, generate_roofline,
-    sample_wall_share)
+    _program_spans, decode_attn_share, flash_bwd_roofline, flash_fwd_roofline,
+    generate_roofline, sample_wall_share, score_device_share)
 
 
-def reading(trace=None, cycles=None, chips=1):
+def reading(trace=None, cycles=None, chips=1, flight=()):
     cell = cells.load_cell("pythia-1.4b.ppo-longprompt")
     return SimpleNamespace(
         cell=cell, hf=cell.config, traffic=cell.traffic, chips=chips,
-        peaks=cells.peaks_for("TPU v5 lite"), unfrozen=2, trace=trace,
+        peaks=cells.peaks_for("TPU v5 lite"), unfrozen=2, trace=trace, flight=list(flight),
         cycles=cycles or [{"step": 12, "wall_s": 7.0}, {"step": 16, "wall_s": 7.0}],
         wall_s=14.0)
 
 
-def flight(tmp_path, rows):
-    os.makedirs(tmp_path / "flight")
-    with open(tmp_path / "flight" / "flight-00001.jsonl", "w") as f:
-        for row in rows:
-            f.write(json.dumps(row) + "\n")
-        f.write('{"kind": "cycle", "step": 16, "spa')  # a torn last line
-    return str(tmp_path)
-
-
-def test_run_dir_formula_against_an_explicit_argv():
-    got = _program_spans.run_dir(
-        "a.cell", ["--workload", "a.cell", "--seed", "2400000011", "--seconds", "45", "--trace", "1"])
-    assert got == os.path.join(_program_spans.ROOT, ".benchmark_runs", "a.cell.seed2400000011.trace1")
-    assert _program_spans.run_dir("a.cell", ["--seed", "3"]).endswith("a.cell.seed3.trace0")
-    assert _program_spans.run_dir("a.cell", ["-q"]) is None  # not run.py's command line
-
-
-def test_span_seconds_keeps_the_windows_cycles_and_the_named_spans(tmp_path):
-    d = flight(tmp_path, [
+def test_span_seconds_keeps_the_windows_cycles_and_the_named_spans():
+    r = reading(flight=[
         {"kind": "run_start", "step": 0},
         {"kind": "cycle", "step": 8, "spans": [["generate", 0.0, 9.0, "rollout", {}]]},  # warm-up
         {"kind": "cycle", "step": 12, "spans": [
@@ -50,21 +31,18 @@ def test_span_seconds_keeps_the_windows_cycles_and_the_named_spans(tmp_path):
             ["tokens_wait", 5.5, 6.5, "rollout", {"rows": 8, "tokens": 1024}]]},
         {"kind": "cycle", "step": 16, "spans": [["tokens_wait", 1.0, 2.5, "rollout", {}]]},
     ])
-    r = reading()
-    assert _program_spans.span_seconds(r, ("generate", "tokens_wait"), d) == pytest.approx(3.0)
-    assert _program_spans.span_seconds(r, ("no_such_span",), d) is None
+    assert _program_spans.span_seconds(r, ("generate", "tokens_wait")) == pytest.approx(3.0)
+    assert _program_spans.span_seconds(r, ("no_such_span",)) is None
     # a program that writes no spans (the parent of the PR that added them)
     r.cycles = [{"step": 99, "wall_s": 1.0}]
-    assert _program_spans.span_seconds(r, ("generate",), d) is None
+    assert _program_spans.span_seconds(r, ("generate",)) is None
 
 
-def test_sampler_readers_on_hand_made_spans(tmp_path, monkeypatch):
-    r = reading()
-    d = flight(tmp_path, [
+def test_sampler_readers_on_hand_made_spans():
+    r = reading(flight=[
         {"kind": "cycle", "step": s, "spans": [
             ["generate", 0.0, 0.5, "rollout", {}], ["tokens_wait", 0.5, 1.5, "rollout", {}]]}
         for s in (12, 16)])
-    monkeypatch.setattr(_program_spans, "run_dir", lambda name, argv=None: d)
     assert sample_wall_share.read(r) == pytest.approx(100.0 * 3.0 / 14.0)
     # 8 rows of 1920 + 128 on the 22-layer 1.4B: 0.186 s of prefill FLOPs and
     # 0.425 s of decode bytes (int8 weights and cache) a cycle, by hand
@@ -73,8 +51,31 @@ def test_sampler_readers_on_hand_made_spans(tmp_path, monkeypatch):
     assert share == pytest.approx(100.0 * 2 * 0.6116 / 3.0, rel=1e-3) and share < 100
     r4 = reading(chips=4)
     assert generate_roofline.least_seconds(r4) == pytest.approx(0.6116 / 4, rel=1e-3)
-    monkeypatch.setattr(_program_spans, "run_dir", lambda name, argv=None: None)
-    assert sample_wall_share.read(r) is None and generate_roofline.read(r) is None
+    assert sample_wall_share.read(r4) is None and generate_roofline.read(r4) is None  # no spans
+
+
+def test_device_seconds_by_scope_and_by_program():
+    trace = {
+        "busy_s": 4.0,
+        "scopes_by_self_time": [
+            ["jit(generate)/while/body/decode_step/blocks/attn/decode_attn", 1.0],
+            ["jit(generate)/while/body/decode_step/blocks/attn/decode_attn/inner", 0.5],
+            ["jit(generate)/while/body/decode_step/blocks/attn", 0.7],  # not under the scope
+            ["jit(step)/transpose(jvp(decode_attn))/x", 0.1],  # wrapped by a transform
+            ["jit(generate)/decode_attn_v2", 9.0],  # another name
+            ["", 0.2]],
+        "programs_s": {"jit_generate": 2.5, "jit_ppo_experience_fwd": 0.375,
+                       "jit_ppo_score_inject": 0.025, "jit_fused_train_step": 1.0},
+    }
+    r = reading(trace=trace)
+    assert decode_attn_share.read(r) == pytest.approx(100 * 1.6 / 4.0)
+    assert score_device_share.read(r) == pytest.approx(100 * 0.4 / 4.0)
+    # nothing to read is nothing, never a share of 0
+    other = dict(trace, scopes_by_self_time=[["jit(generate)/paged_decode_attn", 1.0]],
+                 programs_s={"jit_grpo_experience_fwd": 1.0})
+    assert decode_attn_share.read(reading(trace=other)) is None
+    assert score_device_share.read(reading(trace=other)) is None
+    assert decode_attn_share.read(reading()) is None and score_device_share.read(reading()) is None
 
 
 def test_flash_readers_count_what_the_algorithm_requires():
