@@ -36,8 +36,8 @@ TIER1_BUDGETS = {
     # (supervisor 8s) and the version-gated skip files (remat 0.3,
     # multihost 0.05, properties 0.06, pipeline_parallel 4.9 measured
     # 2026-08-03).
-    "test_curves.py": 2,
-    "test_deferred_stats.py": 2,
+    "test_curves.py": 1,
+    "test_deferred_stats.py": 1,
     "test_dpo.py": 15,
     # r09 re-baseline: every touched-or-large budget re-measured
     # SERIALLY on the idle 8-way CPU mesh (2026-08-03) to pay for the
@@ -79,7 +79,21 @@ TIER1_BUDGETS = {
     # r09: +4 preference-RL chaos learn() tests (GRPO nan/sigterm, DPO
     # nan/sigterm); whole file re-measured 99.9s serial
     "test_guardrails.py": 103,
-    "test_marker_audit.py": 2,
+    # PR 28: the routed / latent-attention / four-stream family against its
+    # float32 reference (logits, trainable gradients, cache decode, shares,
+    # hydra cuts, int8 rollout weights, one Mosaic compile at keys 192 /
+    # values 128, 1 s): one toy stack built, run and differentiated once
+    # under jit and shared by the parity tests. 45 s alone on this 8-core
+    # container, 85 s inside a 6-worker run of the driver's command that
+    # shared the cores with another job (2026-10-01). That run's other
+    # files took 1,496 s against the 738 budgeted, so the table's scale is
+    # in-run seconds / 2.0: budgeted 42. Paid under the unchanged 780
+    # ceiling with times of the same run on that scale: serve 46->26
+    # (34.7 s in the run = 17), scanned_epochs 46->31 (30.1 s = 15),
+    # reference_harness 4->1 (0.4 s), curves 2->1 (0.1 s), deferred_stats
+    # 2->1 (0.5 s), net 3->2 (2.8 s = 1.4), marker_audit 2->1 (0.2 s).
+    "test_latent_moe.py": 42,
+    "test_marker_audit.py": 1,
     "test_mcts_value_branch.py": 5,
     # r10: memory-doctor suite (ladder units are fake-clock-fast; the
     # cost is the split-grads golden + three tiny trainer builds) —
@@ -103,7 +117,7 @@ TIER1_BUDGETS = {
     # network leg is its acceptance gate). Paid under the unchanged
     # 780 ceiling by trimming curves 3->2 (0.14s measured here) and
     # examples 4->2 (0.35s measured here), both re-measured same day.
-    "test_net.py": 3,
+    "test_net.py": 2,
     # r11: flight-recorder suite (fake-clock units + ONE tiny learn()
     # integration) — measured ~20s serial on the 8-way CPU mesh
     # (2026-08-04). Paid for under the unchanged ceiling by trimming
@@ -133,13 +147,13 @@ TIER1_BUDGETS = {
     "test_pipeline_parallel.py": 7,
     "test_pipelines.py": 1,
     "test_properties.py": 2,
-    "test_reference_harness.py": 4,
+    "test_reference_harness.py": 1,
     "test_remat.py": 1,
     "test_resilient.py": 1,
     "test_ring_attention.py": 8,
-    "test_scanned_epochs.py": 46,
+    "test_scanned_epochs.py": 31,
     "test_seq2seq.py": 13,
-    "test_serve.py": 46,
+    "test_serve.py": 26,
     "test_sharding.py": 7,
     "test_summarize_eval.py": 1,
     "test_supervisor.py": 11,

@@ -29,7 +29,12 @@ from typing import Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from trlx_tpu.models.transformer import TransformerLM, logit_projection
+from trlx_tpu.models.transformer import (
+    TransformerLM,
+    _add_stats,
+    logit_projection,
+    moe_counters,
+)
 from trlx_tpu.ops.common import topk_mask
 
 Array = jnp.ndarray
@@ -360,6 +365,10 @@ def generate(
         budget = jnp.asarray(row_budget, jnp.int32)
         finished0 = finished0 | (budget <= 1)
 
+    # a routed model's counters (each held expert's rows by layer, the
+    # assignments made): the prefill's, then every decode step's, carried
+    # out of the loop with the tokens (transformer.moe_counters)
+    moe_stats = out.get("moe_stats") or {}
     decode_cache = out["cache"]
     if model.cfg.kv_cache_quant in ("int8", "int8_kernel"):
         # quantize ONCE after prefill (prefill numerics/pallas path stay
@@ -384,11 +393,11 @@ def generate(
         # reference needed synced_gpus/no-early-break workarounds —
         # SURVEY §7 hard parts)
         def cond(state):
-            _, _, _, finished, t, _, _, _ = state
+            _, _, _, finished, t, _, _, _, _ = state
             return (t < N) & ~jnp.all(finished)
 
         def body(state):
-            cache, tok, pos, finished, t, rng, ids_buf, mask_buf = state
+            cache, tok, pos, finished, t, rng, ids_buf, mask_buf, moe_stats = state
             with jax.named_scope("decode_step"):
                 step_out = model(
                     params, tok[:, None], positions=pos[:, None], cache=cache
@@ -410,11 +419,12 @@ def generate(
             return (
                 step_out["cache"], next_tok, pos + 1, now_finished, t + 1,
                 rng, ids_buf, mask_buf,
+                _add_stats(moe_stats, step_out.get("moe_stats")) or {},
             )
 
         state = (decode_cache, tok0, pos0, finished0, jnp.int32(1), rng,
-                 ids_buf, mask_buf)
-        (_, _, _, _, _, _, response_ids, response_mask) = jax.lax.while_loop(
+                 ids_buf, mask_buf, moe_stats)
+        (_, _, _, _, _, _, response_ids, response_mask, moe_stats) = jax.lax.while_loop(
             cond, body, state
         )
     else:
@@ -422,11 +432,14 @@ def generate(
         response_mask = jnp.ones((B, 1), bool)
 
     sequences = jnp.concatenate([input_ids, response_ids], axis=1)
-    return {
+    result = {
         "sequences": sequences,
         "response_ids": response_ids,
         "response_mask": response_mask.astype(jnp.int32),
     }
+    if moe_stats:
+        result["moe_stats"] = moe_counters(moe_stats, "sampler")
+    return result
 
 
 def make_generate_fn(

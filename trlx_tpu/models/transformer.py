@@ -27,6 +27,7 @@ Design notes (TPU-first):
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from dataclasses import dataclass
 from functools import partial
@@ -120,6 +121,87 @@ class TransformerConfig:
     # one extra forward — parallel/pipeline.py:_run_1f1b)
     pp_schedule: str = "gpipe"
 
+    # latent attention (set when `kv_lora_rank` is): queries come through
+    # a `q_lora_rank` latent, keys and values from one `kv_lora_rank`
+    # latent per position plus `qk_rope_head_dim` rotary channels shared
+    # by every head. Keys are qk_nope_head_dim + qk_rope_head_dim wide,
+    # values v_head_dim. The cache holds the latent and the rotated
+    # shared key, kv_lora_rank + qk_rope_head_dim numbers a position.
+    q_lora_rank: Optional[int] = None
+    kv_lora_rank: Optional[int] = None
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN (set when `yarn_factor` is): per-channel blend of the rotary
+    # frequencies and the frequencies over `yarn_factor`, and the score
+    # scale m^2, m = 0.1 * yarn_mscale_all_dim * ln(factor) + 1
+    yarn_factor: Optional[float] = None
+    yarn_original_positions: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
+    # routed feed-forward (set when `n_routed_experts` is): the router is
+    # `n_routed_experts` wide as published; this chip holds experts
+    # [first_expert_held, first_expert_held + n_experts_held) and computes
+    # their part of the result. The bottom `first_k_dense` layers keep the
+    # dense gated MLP of width `intermediate_size`.
+    n_routed_experts: int = 0
+    n_experts_held: Optional[int] = None  # default: all of them
+    first_expert_held: int = 0
+    n_experts_per_token: int = 0
+    moe_intermediate_size: Optional[int] = None
+    n_shared_experts: int = 0
+    routed_scaling_factor: float = 1.0
+    first_k_dense: int = 0
+    # a model initialised at random has a selection bias of zero, which no
+    # pretraining has balanced: an online trainer then runs this many
+    # steps of the balancing rule on its first prompts before the first
+    # rollout (`balance_router_bias`). A loaded checkpoint keeps its own.
+    router_balance_steps: int = 0
+    # residual streams (manifold-constrained hyper-connections when > 1):
+    # the state is [B, T, n, E], mixed around every sub-layer by matrices
+    # computed from it, the stream-to-stream one made doubly stochastic by
+    # `sinkhorn_iters` Sinkhorn steps
+    residual_streams: int = 1
+    sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_clamp: float = 30.0
+
+    @property
+    def latent(self) -> bool:
+        return self.kv_lora_rank is not None
+
+    @property
+    def routed(self) -> bool:
+        return self.n_routed_experts > 0
+
+    @property
+    def beyond_dense(self) -> bool:
+        """Latent attention, routed experts or several residual streams: the
+        models that the paths below the dense decoder (pipeline, ring, paged
+        engine, adapters, loaders) do not reach and raise for."""
+        return self.latent or self.routed or self.residual_streams > 1
+
+    @property
+    def cache_elems_per_position(self) -> int:
+        """Numbers one cached position costs a row, in one layer."""
+        if self.latent:
+            return self.kv_lora_rank + self.qk_rope_head_dim
+        return 2 * self.n_kv_head * self.head_dim
+
+    @property
+    def attn_softmax_scale(self) -> float:
+        """What the scores are multiplied by before the softmax."""
+        if self.attn_scale is not None:
+            return self.attn_scale
+        if not self.latent:
+            return 1.0 / math.sqrt(self.head_dim)
+        m = 1.0
+        if self.yarn_factor is not None and self.yarn_factor > 1:
+            m = 0.1 * self.yarn_mscale_all_dim * math.log(self.yarn_factor) + 1.0
+        return m * m / math.sqrt(self.qk_nope_head_dim + self.qk_rope_head_dim)
+
     def __post_init__(self):
         if self.intermediate_size is None:
             object.__setattr__(self, "intermediate_size", 4 * self.hidden_size)
@@ -127,8 +209,46 @@ class TransformerConfig:
             object.__setattr__(self, "head_dim", self.hidden_size // self.n_head)
         if self.n_kv_head is None:
             object.__setattr__(self, "n_kv_head", self.n_head)
+        if self.latent:
+            object.__setattr__(self, "rotary_dim", self.qk_rope_head_dim)
         if self.rotary_dim is None and self.pos_embed == "rotary":
             object.__setattr__(self, "rotary_dim", self.head_dim)
+        if self.routed and self.n_experts_held is None:
+            object.__setattr__(self, "n_experts_held", self.n_routed_experts)
+        self._check_family()
+
+    def _check_family(self) -> None:
+        """What a latent, routed or multi-stream model does not reach
+        raises here, at configuration time, not as a wrong answer later."""
+        if not self.beyond_dense:
+            return
+        def no(what):
+            raise NotImplementedError(
+                f"{what} is not implemented for a model with latent attention, "
+                "routed experts or several residual streams"
+            )
+        if self.latent and self.kv_cache_quant is not None:
+            no(f"kv_cache_quant={self.kv_cache_quant!r} (an int8 latent cache)")
+        if self.attention_impl == "ring":
+            no("attention_impl='ring'")
+        if self.latent and (self.pos_embed != "rotary" or self.local_window is not None
+                            or self.n_kv_head != self.n_head or self.attn_scale is not None):
+            no("latent attention without rotary positions, with windows, grouped "
+               "heads or a set attn_scale")
+        if self.parallel_residual or self.embed_layernorm:
+            no("parallel_residual / embed_layernorm")
+        if self.routed:
+            held_end = self.first_expert_held + self.n_experts_held
+            if not (0 < self.n_experts_per_token <= self.n_routed_experts
+                    and 0 <= self.first_expert_held and held_end <= self.n_routed_experts
+                    and self.moe_intermediate_size and 0 <= self.first_k_dense < self.n_layer):
+                raise ValueError(
+                    "routed experts need 0 < n_experts_per_token <= n_routed_experts, the held "
+                    "experts inside the router's range, moe_intermediate_size and "
+                    "first_k_dense < n_layer"
+                )
+        elif self.first_k_dense:
+            raise ValueError("first_k_dense without routed experts")
 
     def replace(self, **kw) -> "TransformerConfig":
         return dataclasses.replace(self, **kw)
@@ -150,10 +270,44 @@ def _activation(name: str) -> Callable[[Array], Array]:
 
 def rope_frequencies(cfg: TransformerConfig, positions: Array) -> Tuple[Array, Array]:
     """cos/sin tables [batch, seq, rotary_dim//2] for given positions."""
-    dim = cfg.rotary_dim
-    inv_freq = 1.0 / (cfg.rope_theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    inv_freq = rope_inv_frequencies(cfg)
     angles = positions[..., None].astype(jnp.float32) * inv_freq  # [B, T, dim/2]
+    if cfg.yarn_factor is not None and cfg.yarn_factor > 1:
+        # YaRN scales cos/sin by mscale(factor, mscale) / mscale(factor,
+        # mscale_all_dim); equal settings give 1 and the scale lives in
+        # the softmax alone (TransformerConfig.attn_softmax_scale)
+        ratio = _yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale) / _yarn_mscale(
+            cfg.yarn_factor, cfg.yarn_mscale_all_dim)
+        return jnp.cos(angles) * ratio, jnp.sin(angles) * ratio
     return jnp.cos(angles), jnp.sin(angles)
+
+
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rope_inv_frequencies(cfg: TransformerConfig) -> Array:
+    """Rotary frequencies [rotary_dim // 2]. Under YaRN each channel blends
+    1/theta_i (kept where the channel turns more than `beta_fast` times
+    over the original context) and 1/(factor theta_i) (where it turns less
+    than `beta_slow` times) along a linear ramp between the two correction
+    dimensions."""
+    dim = cfg.rotary_dim
+    exponent = jnp.arange(0, dim, 2, dtype=jnp.float32) / dim
+    inv_freq = 1.0 / (cfg.rope_theta ** exponent)
+    if cfg.yarn_factor is None or cfg.yarn_factor <= 1:
+        return inv_freq
+
+    def correction_dim(rotations: float) -> float:
+        return (dim * math.log(cfg.yarn_original_positions / (rotations * 2 * math.pi))
+                / (2 * math.log(cfg.rope_theta)))
+
+    low = max(math.floor(correction_dim(cfg.yarn_beta_fast)), 0)
+    high = min(math.ceil(correction_dim(cfg.yarn_beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001  # the published code's guard against a zero-width ramp
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low) / (high - low), 0.0, 1.0)
+    return inv_freq / cfg.yarn_factor * ramp + inv_freq * (1.0 - ramp)
 
 
 def apply_rope(x: Array, cos: Array, sin: Array, style: str) -> Array:
@@ -620,13 +774,21 @@ def quantize_decode_weights(params: Dict) -> Dict:
     quantize_kv_cache above). Embeddings and the logit projection stay
     in compute dtype (the tied wte must serve lookups).
 
-    Only kernels under `blocks` dense modules are rewritten; scan
-    xs-slicing delivers per-layer int8 kernels + scales to QDense
-    automatically.
+    Only kernels under `blocks` (and the leading `dense_blocks`) dense
+    modules and the stacked expert kernels are rewritten; scan
+    xs-slicing delivers per-layer int8 kernels + scales to QDense and to
+    `RoutedMLP` automatically.
     """
-    # feature rank by dense-module name (kernel = (L, inputs..., feats...))
+    # feature rank by dense-module name (kernel = (L, inputs..., feats...));
+    # of a latent attention the four projections that are used as written
+    # (the up-projection of the cached latent, `kv_b`, is used transposed
+    # in the decode form and stays as it is, as does the float32 router)
     n_feats = {"q": 2, "k": 2, "v": 2, "o": 1,
-               "fc_in": 1, "fc_gate": 1, "fc_out": 1}
+               "fc_in": 1, "fc_gate": 1, "fc_out": 1,
+               "q_a": 1, "q_b": 2, "kv_a": 1}
+    # stacked expert kernels [L, held, in, out]: one scale per expert and
+    # output channel
+    experts = ("experts_fc_in", "experts_fc_gate", "experts_fc_out")
 
     def walk(tree, name=None):
         out = {}
@@ -635,17 +797,18 @@ def quantize_decode_weights(params: Dict) -> Dict:
                 out[child_name] = walk(leaf, child_name)
             else:
                 out[child_name] = leaf
-        if name in n_feats and "kernel" in tree:
+        if (name in n_feats or name in experts) and "kernel" in tree:
             w = tree["kernel"].astype(jnp.float32)
-            red = tuple(range(1, w.ndim - n_feats[name]))  # input dims
-            s = jnp.max(jnp.abs(w), axis=red) / 127.0  # [L, feats...]
+            red = (2,) if name in experts else tuple(range(1, w.ndim - n_feats[name]))  # input dims
+            s = jnp.max(jnp.abs(w), axis=red) / 127.0  # [L, feats...] or [L, held, out]
             out["kernel"] = jnp.round(
                 w / jnp.maximum(jnp.expand_dims(s, red), 1e-12)
             ).astype(jnp.int8)
             out["kernel_scale"] = s.astype(jnp.float32)
         return out
 
-    return dict(params, blocks=walk(params["blocks"]))
+    stacks = {k: walk(params[k]) for k in ("blocks", "dense_blocks") if k in params}
+    return dict(params, **stacks)
 
 
 def _quantize_kv(x: Array) -> Tuple[Array, Array]:
@@ -705,39 +868,399 @@ def quantize_kv_cache(cache: Dict) -> Dict:
 
 class MLP(nn.Module):
     cfg: TransformerConfig
+    # a shared expert is this MLP at its own width, gated and without bias
+    width: Optional[int] = None  # None: cfg.intermediate_size
+    gated: Optional[bool] = None  # None: cfg.mlp_gated
+    bias: Optional[bool] = None  # None: cfg.use_mlp_bias
 
     @nn.compact
     def __call__(self, x: Array) -> Array:
         cfg = self.cfg
         act = _activation(cfg.activation)
+        use_bias = cfg.use_mlp_bias if self.bias is None else self.bias
         up = partial(
             QDense,
-            features=cfg.intermediate_size,
+            features=cfg.intermediate_size if self.width is None else self.width,
             dtype=cfg.dtype,
             param_dtype=cfg.param_dtype,
             kernel_init=nn.initializers.normal(0.02),
-            use_bias=cfg.use_mlp_bias,
+            use_bias=use_bias,
         )
         h = act(up(name="fc_in")(x))
-        if cfg.mlp_gated:
+        if cfg.mlp_gated if self.gated is None else self.gated:
             h = h * up(name="fc_gate")(x)
         down = QDense(
             features=cfg.hidden_size,
             dtype=cfg.dtype,
             param_dtype=cfg.param_dtype,
             kernel_init=nn.initializers.normal(0.02 / math.sqrt(2 * cfg.n_layer)),
-            use_bias=cfg.use_mlp_bias,
+            use_bias=use_bias,
             name="fc_out",
         )
         return down(h)
 
 
+class _Kernel(nn.Module):
+    """A bare `kernel` param under its own name, for a weight that is used
+    in two orientations (the latent up-projection: expanded in the
+    teacher-forced form, absorbed into query and output in the decode
+    form). Named `kernel` so that decode casts it to the compute dtype
+    with the rest; `quantize_decode_weights` leaves it as it is."""
+
+    shape: Tuple[int, ...]
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self) -> Array:
+        return self.param("kernel", nn.initializers.normal(0.02), self.shape, self.param_dtype)
+
+
+def _rms_norm(x: Array, scale: Optional[Array], eps: float) -> Array:
+    """RMSNorm over the last axis in float32; `scale` None = no learned weight."""
+    x32 = x.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return y if scale is None else y * scale
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention with a latent cache.
+
+        c_q = RMSNorm(x W_dq);  [q_n | q_r] = c_q W_uq  per head;  q_r rotated
+        [c_kv | k_r] = x W_dkv;  c_kv = RMSNorm(c_kv);  k_r rotated, one for all heads
+        [k_n | v] = c_kv W_ukv  per head
+        score = (q_n . k_n + q_r . k_r) * scale, causal;  y = (softmax(score) v) W_o
+
+    Two forms, chosen by the shape of the call and by nothing else:
+    - teacher-forced and prefill (T > 1), scope `latent_attn`: the latent
+      is EXPANDED to per-head keys (nope + shared rotary part) and values,
+      and attention runs over them (the flash kernels under `pallas`).
+      A prefill writes (c_kv, k_r) of its tokens into the cache and, the
+      cache being empty before it, attends among them alone.
+    - a decode step (T == 1 with a cache), scope `latent_decode_attn`: the
+      ABSORBED form. q_n W_uk^T (kv_lora_rank wide) scores against the
+      cached c_kv directly, q_r against the cached k_r; W_uv is applied
+      after the weighted sum of c_kv. Per-head keys and values of the
+      whole cache are never rebuilt.
+    """
+
+    cfg: TransformerConfig
+    mesh: Any = None
+
+    @nn.compact
+    def __call__(self, x, attn_bias, positions, cache=None, key_mask=None, ring_mesh=None):
+        cfg = self.cfg
+        B, T, E = x.shape
+        H, dn, dr, dv, rank = (cfg.n_head, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                               cfg.v_head_dim, cfg.kv_lora_rank)
+        dense = partial(QDense, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                        kernel_init=nn.initializers.normal(0.02), use_bias=False)
+        if cfg.q_lora_rank:
+            q_scale = self.param("q_a_norm", nn.initializers.ones, (cfg.q_lora_rank,), cfg.param_dtype)
+            c_q = _rms_norm(dense(features=cfg.q_lora_rank, name="q_a")(x), q_scale,
+                            cfg.layer_norm_epsilon).astype(cfg.dtype)
+        else:
+            c_q = x
+        q = dense(features=(H, dn + dr), name="q_b")(c_q)  # [B, T, H, dn + dr]
+        kv = dense(features=rank + dr, name="kv_a")(x)  # [B, T, rank + dr]
+        kv_scale = self.param("kv_a_norm", nn.initializers.ones, (rank,), cfg.param_dtype)
+        c_kv = _rms_norm(kv[..., :rank], kv_scale, cfg.layer_norm_epsilon).astype(cfg.dtype)
+        w_ukv = _Kernel((rank, H, dn + dv), cfg.param_dtype, name="kv_b")().astype(cfg.dtype)
+
+        cos, sin = rope_frequencies(cfg, positions)
+        q_n = q[..., :dn]
+        q_r = apply_rope(q[..., dn:], cos, sin, cfg.rotary_style)
+        k_r = apply_rope(kv[..., None, rank:], cos, sin, cfg.rotary_style)[:, :, 0]  # [B, T, dr]
+        scale = cfg.attn_softmax_scale
+
+        new_kv = None
+        if cache is not None:
+            entry = jnp.concatenate([c_kv, k_r], axis=-1)[None].astype(cache["c"].dtype)
+            c_all = jax.lax.dynamic_update_slice(cache["c"], entry, (cache["ix"], 0, cache["index"], 0))
+            new_kv = {"c": c_all}
+
+        if cache is not None and T == 1:
+            with jax.named_scope("latent_decode_attn"):
+                row = jax.lax.dynamic_index_in_dim(c_all, cache["ix"], 0, keepdims=False)  # [B, S, rank + dr]
+                c_s, kr_s = row[..., :rank].astype(cfg.dtype), row[..., rank:].astype(cfg.dtype)
+                q_lat = jnp.einsum("bthd,chd->bthc", q_n, w_ukv[..., :dn])  # [B, 1, H, rank]
+                scores = (
+                    jnp.einsum("bthc,bsc->bhts", q_lat, c_s, preferred_element_type=jnp.float32)
+                    + jnp.einsum("bthr,bsr->bhts", q_r, kr_s, preferred_element_type=jnp.float32)
+                ) * scale + attn_bias
+                probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
+                o_lat = jnp.einsum("bhts,bsc->bthc", probs, c_s)  # [B, 1, H, rank]
+                out = jnp.einsum("bthc,chd->bthd", o_lat, w_ukv[..., dn:])
+        else:
+            if cache is not None:
+                if cache.get("static_index") != 0:
+                    raise NotImplementedError(
+                        "latent attention prefills an empty cache in one call (cache index 0, "
+                        "known at trace time); a prefill in pieces, or after a soft prompt or "
+                        "a key/value prefix, is not implemented"
+                    )
+                # slots [0, T) are the only ones written: attend among them
+                if key_mask is not None:
+                    key_mask = key_mask[:, :T]
+                attn_bias = attn_bias[..., :T]
+            with jax.named_scope("latent_attn"):
+                kv_up = jnp.einsum("btc,chd->bthd", c_kv, w_ukv)  # [B, T, H, dn + dv]
+                k = jnp.concatenate(
+                    [kv_up[..., :dn], jnp.broadcast_to(k_r[:, :, None, :], (B, T, H, dr))], axis=-1)
+                v = kv_up[..., dn:]
+                q_full = jnp.concatenate([q_n, q_r], axis=-1)
+                aligned = _interpret_mode() or (T % 8 == 0 and T % 128 == 0)
+                wants_pallas = cfg.attention_impl == "pallas"
+                if wants_pallas and key_mask is not None and aligned:
+                    from trlx_tpu.ops.flash_attention import flash_attention_on_mesh
+
+                    out = flash_attention_on_mesh(
+                        self.mesh, q_full.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+                        v.transpose(0, 2, 1, 3), key_mask, sm_scale=scale,
+                    ).transpose(0, 2, 1, 3)
+                else:
+                    if wants_pallas:
+                        _warn_pallas_fallback(
+                            "latent attention", f"T={T} key_mask={key_mask is not None}: the "
+                            "kernels need whole 128-slot tiles and a causal+padding mask")
+                    scores = jnp.einsum(
+                        "bthd,bshd->bhts", q_full, k, preferred_element_type=jnp.float32
+                    ) * scale + attn_bias
+                    probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
+                    out = jnp.einsum("bhts,bshd->bthd", probs, v)
+
+        proj = QDense(
+            features=E, axis=(-2, -1), dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+            kernel_init=nn.initializers.normal(0.02 / math.sqrt(2 * cfg.n_layer)),
+            use_bias=False, name="o",
+        )
+        return proj(out), new_kv
+
+
+@jax.custom_vjp
+def _rows_by_assignment(x, order, inverse):
+    """x [N, E] -> [N * k, E]: row r is the token of assignment `order[r]`
+    (assignment a = token * k + choice). Its transpose, written as the
+    gather it is: token n's gradient is the sum of its k rows, found
+    through `inverse` (assignment -> row). XLA would transpose the gather
+    into a scatter-add of N * k rows: with this and `_permute_rows` left to
+    XLA the train step is 5.5% slower on the chip (PERF.md section 6, PR 28)."""
+    k = order.shape[0] // x.shape[0]
+    return jnp.take(x, order // k, axis=0)
+
+
+def _rows_fwd(x, order, inverse):
+    return _rows_by_assignment(x, order, inverse), (inverse, x.shape[0])
+
+
+def _rows_bwd(res, g):
+    inverse, n = res
+    return jnp.take(g, inverse, axis=0).reshape(n, -1, g.shape[-1]).sum(axis=1).astype(g.dtype), None, None
+
+
+_rows_by_assignment.defvjp(_rows_fwd, _rows_bwd)
+
+
+@jax.custom_vjp
+def _permute_rows(x, index, inverse):
+    """x[index] for a permutation `index` whose inverse is `inverse`: the
+    transpose is the gather by the inverse, not a scatter."""
+    return jnp.take(x, index, axis=0)
+
+
+def _permute_fwd(x, index, inverse):
+    return jnp.take(x, index, axis=0), inverse
+
+
+def _permute_bwd(inverse, g):
+    return jnp.take(g, inverse, axis=0), None, None
+
+
+_permute_rows.defvjp(_permute_fwd, _permute_bwd)
+
+
+class _ExpertKernel(nn.Module):
+    """One stacked expert weight [held, in, out] under `<name>/kernel`, with
+    the per-expert, per-output-channel scale `quantize_decode_weights` puts
+    beside an int8 kernel for the rollout."""
+
+    shape: Tuple[int, ...]
+    std: float
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self) -> Tuple[Array, Optional[Array]]:
+        kernel = self.param("kernel", nn.initializers.normal(self.std), self.shape, self.param_dtype)
+        scale = (self.get_variable("params", "kernel_scale")
+                 if self.has_variable("params", "kernel_scale") else None)
+        return kernel, scale
+
+
+class RoutedMLP(nn.Module):
+    """Routed experts as THIS chip's share of them, plus the shared expert.
+
+        s = sigmoid(x W_g) (float32);  chosen = top-k of (s + b);  w = c * s[chosen] / sum(s[chosen])
+        y = Shared(x) + sum over chosen experts HELD HERE of w_e Expert_e(x)
+
+    The router is as wide as published (`n_routed_experts`); the chip holds
+    experts [first_expert_held, first_expert_held + n_experts_held) and
+    computes their part for the tokens routed to them. No capacity, no
+    dropped token; an assignment to an expert held elsewhere costs nothing
+    here and adds nothing (the chips that hold it would add it). Nothing
+    stands in for those chips or their exchange.
+
+    Two forms by the shape of the call: a decode step (T == 1, a handful of
+    rows) runs every held expert over every row as one batched product and
+    weights by the routing (weights are read once, which is all a step at
+    this size can do); everything else sorts assignments by expert and runs
+    grouped products (`jax.lax.ragged_dot`) over the rows routed here. Both
+    were read on the chip at 32 rows a step (PERF.md section 6, PR 28): the
+    sorted form there makes the sampler 24% slower (a sort, three gathers
+    and three grouped products of 16 rows a layer a step, launches all).
+    Returns (y, stats): `load`, the rows each held expert computed,
+    `assignments`, the token-expert pairs the router made (`moe_counters`),
+    and `choices`, how often each of the router's experts was chosen, held
+    here or not (`balance_router_bias`).
+    """
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x: Array, decode: bool = False) -> Tuple[Array, Dict[str, Array]]:
+        cfg = self.cfg
+        B, T, E = x.shape
+        K, held, first, F = (cfg.n_experts_per_token, cfg.n_experts_held, cfg.first_expert_held,
+                             cfg.moe_intermediate_size)
+        act = _activation(cfg.activation)
+        xf = x.reshape(B * T, E)
+        N = B * T
+
+        with jax.named_scope("moe_router"):
+            # float32 here and in every program, so that the sampler and
+            # the scorer choose from the same function of their inputs
+            gate = self.param("router_gate", nn.initializers.normal(0.02),
+                              (E, cfg.n_routed_experts), jnp.float32)
+            bias = self.param("router_bias", nn.initializers.zeros, (cfg.n_routed_experts,), jnp.float32)
+            s = jax.nn.sigmoid(jnp.dot(xf.astype(jnp.float32), gate.astype(jnp.float32),
+                                       precision=jax.lax.Precision.HIGHEST))
+            _, chosen = jax.lax.top_k(s + jax.lax.stop_gradient(bias), K)  # [N, K]
+            picked = jnp.take_along_axis(s, chosen, axis=-1)
+            weight = cfg.routed_scaling_factor * picked / jnp.sum(picked, axis=-1, keepdims=True)
+            local = chosen - first
+            here = (local >= 0) & (local < held)
+            weight = jnp.where(here, weight, 0.0)
+            choices = jnp.sum(chosen[..., None] == jnp.arange(cfg.n_routed_experts), axis=(0, 1))
+
+        std_out = 0.02 / math.sqrt(2 * cfg.n_layer)
+        w_in, s_in = _ExpertKernel((held, E, F), 0.02, cfg.param_dtype, name="experts_fc_in")()
+        w_gate, s_gate = _ExpertKernel((held, E, F), 0.02, cfg.param_dtype, name="experts_fc_gate")()
+        w_out, s_out = _ExpertKernel((held, F, E), std_out, cfg.param_dtype, name="experts_fc_out")()
+
+        with jax.named_scope("moe_experts"):
+            load = jnp.sum(
+                (local[..., None] == jnp.arange(held)) & here[..., None], axis=(0, 1)
+            ).astype(jnp.int32)  # rows of each held expert
+            if decode:
+                xb = jnp.broadcast_to(xf.astype(cfg.dtype)[None], (held, N, E))
+
+                def product(a, w, scale):
+                    y = jnp.einsum("enk,ekf->enf", a, w.astype(cfg.dtype))
+                    return y if scale is None else y * scale[:, None, :].astype(cfg.dtype)
+
+                h = act(product(xb, w_in, s_in)) * product(xb, w_gate, s_gate)
+                out = product(h, w_out, s_out)  # [held, N, E]
+                per_expert = jnp.sum(
+                    jnp.where(local[..., None] == jnp.arange(held), weight[..., None], 0.0), axis=1
+                )  # [N, held]
+                routed = jnp.einsum("ne,end->nd", per_expert.astype(cfg.dtype), out)
+            else:
+                key = jnp.where(here, local, held).reshape(N * K)  # held = elsewhere, sorts last
+                order = jnp.argsort(key, stable=True).astype(jnp.int32)  # row -> assignment
+                inverse = jnp.argsort(order).astype(jnp.int32)  # assignment -> row
+                valid = (jnp.arange(N * K) < jnp.sum(load))[:, None]
+                rows = jnp.where(valid, _rows_by_assignment(xf.astype(cfg.dtype), order, inverse), 0)
+                row_expert = jnp.take(key, order)
+
+                def product(a, w, scale):
+                    y = jax.lax.ragged_dot(a, w.astype(cfg.dtype), load)
+                    if scale is not None:
+                        y = y * jnp.take(scale, jnp.minimum(row_expert, held - 1), axis=0).astype(cfg.dtype)
+                    return y
+
+                h = act(product(rows, w_in, s_in)) * product(rows, w_gate, s_gate)
+                out = jnp.where(valid, product(h, w_out, s_out), 0)  # [N * K, E]
+                back = _permute_rows(out, inverse, order).reshape(N, K, E)
+                routed = jnp.einsum("nk,nkd->nd", weight.astype(cfg.dtype), back)
+
+        with jax.named_scope("moe_shared"):
+            y = routed.reshape(B, T, E)
+            if cfg.n_shared_experts:
+                y = y + MLP(cfg, width=F * cfg.n_shared_experts, gated=True, bias=False,
+                            name="shared")(x)
+        return y, {"load": load.astype(jnp.float32), "assignments": jnp.float32(N * K),
+                   "choices": choices.astype(jnp.float32)}
+
+
+def sinkhorn(m: Array, iters: int, eps: float) -> Array:
+    """`iters` times: rows over their sums, then columns over theirs. m [..., n, n] > 0.
+    Unrolled: a step is a handful of elementwise operations on n x n numbers a
+    token, which the compiler fuses; as a loop each would be a launch of its own,
+    fourteen loops a decode step."""
+    def step(m, _):
+        m = m / (jnp.sum(m, axis=-1, keepdims=True) + eps)
+        return m / (jnp.sum(m, axis=-2, keepdims=True) + eps), None
+
+    return jax.lax.scan(step, m, None, length=iters, unroll=True)[0]
+
+
+class StreamMix(nn.Module):
+    """The mixing matrices of one sub-layer of a multi-stream residual path,
+    computed from the state X [B, T, n, E]:
+
+        x~     = RMSNorm(vec(X))                                (n E wide, no learned weight)
+        H_pre  = sigmoid(a_pre * (x~ Phi_pre) + b_pre)          [B, T, n]
+        H_post = 2 sigmoid(a_post * (x~ Phi_post) + b_post)     [B, T, n]
+        H_res  = Sinkhorn(exp(clamp(a_res * mat(x~ Phi_res) + b_res)))   [B, T, n, n]
+
+    in float32 (the projection itself takes compute-dtype operands: it is
+    n E deep and n^2 + 2n wide). The sub-layer then reads H_pre X and the
+    state becomes H_res X + H_post^T F(H_pre X)."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, X: Array) -> Tuple[Array, Array, Array]:
+        cfg = self.cfg
+        n, E = cfg.residual_streams, cfg.hidden_size
+        B, T = X.shape[:2]
+        phi = self.param("phi", nn.initializers.normal(0.02), (n * E, n * n + 2 * n), cfg.param_dtype)
+        alpha = self.param("alpha", nn.initializers.constant(0.01), (3,), jnp.float32)
+        b_pre = self.param("b_pre", nn.initializers.zeros, (n,), jnp.float32)
+        b_post = self.param("b_post", nn.initializers.zeros, (n,), jnp.float32)
+        # 2 I: H_res starts at 0.71 on the diagonal, which the Sinkhorn steps
+        # balance to 1e-8; nearer the identity a step shrinks the error by
+        # the square of the second singular value only (4 I: 4e-3 left of 20)
+        b_res = self.param("b_res", lambda *_: 2.0 * jnp.eye(n, dtype=jnp.float32))
+        flat = _rms_norm(X.reshape(B, T, n * E), None, cfg.layer_norm_epsilon)
+        z = jnp.dot(flat.astype(cfg.dtype), phi.astype(cfg.dtype), preferred_element_type=jnp.float32)
+        h_pre = jax.nn.sigmoid(alpha[0] * z[..., :n] + b_pre)
+        h_post = 2.0 * jax.nn.sigmoid(alpha[1] * z[..., n : 2 * n] + b_post)
+        logits = alpha[2] * z[..., 2 * n :].reshape(B, T, n, n) + b_res
+        h_res = sinkhorn(jnp.exp(jnp.clip(logits, -cfg.hc_clamp, cfg.hc_clamp)),
+                         cfg.sinkhorn_iters, cfg.hc_eps)
+        return h_pre, h_post, h_res
+
+
 class Block(nn.Module):
     """Pre-norm decoder block; sequential (gpt2/llama) or parallel
-    (gptj/neox) residual layout."""
+    (gptj/neox) residual layout. `kind` says what follows attention: the
+    dense MLP or routed experts (`RoutedMLP`); with several residual
+    streams each sub-layer reads and writes the state through its own
+    mixing matrices (`StreamMix`). Returns (x, new_kv, stats), stats the
+    routed layer's counters or None."""
 
     cfg: TransformerConfig
     mesh: Any = None  # forwarded to Attention
+    kind: str = "dense"  # "dense" | "routed"
 
     @nn.compact
     def __call__(
@@ -748,18 +1271,43 @@ class Block(nn.Module):
         cache: Optional[Dict[str, Array]] = None,
         key_mask: Optional[Array] = None,
         ring_mesh=None,
-    ) -> Tuple[Array, Optional[Dict[str, Array]]]:
+    ) -> Tuple[Array, Optional[Dict[str, Array]], Optional[Dict[str, Array]]]:
         cfg = self.cfg
+        attention = (LatentAttention if cfg.latent else Attention)(cfg, self.mesh, name="attn")
+
+        def feed_forward(h):
+            if self.kind == "routed":
+                return RoutedMLP(cfg, name="moe")(h, decode=cache is not None and h.shape[1] == 1)
+            return MLP(cfg, name="mlp")(h), None
+
+        if cfg.residual_streams > 1:
+            def sub_layer(name, X, fn):
+                with jax.named_scope("hc_mix"):
+                    h_pre, h_post, h_res = StreamMix(cfg, name=name)(X)
+                    u = jnp.einsum("btn,btne->bte", h_pre.astype(X.dtype), X)
+                out = fn(u)
+                y, rest = out[0], out[1:]
+                with jax.named_scope("hc_mix"):
+                    X = (jnp.einsum("btij,btje->btie", h_res.astype(X.dtype), X)
+                         + h_post.astype(X.dtype)[..., None] * y[:, :, None, :])
+                return X, rest
+
+            x, (new_kv,) = sub_layer("hc_attn", x, lambda u: attention(
+                Norm(cfg, name="ln_1")(u), attn_bias, positions, cache, key_mask, ring_mesh))
+            x, (stats,) = sub_layer("hc_mlp", x, lambda u: feed_forward(Norm(cfg, name="ln_2")(u)))
+            return x, new_kv, stats
+
         h = Norm(cfg, name="ln_1")(x)
-        attn_out, new_kv = Attention(cfg, self.mesh, name="attn")(
-            h, attn_bias, positions, cache, key_mask, ring_mesh
-        )
+        attn_out, new_kv = attention(h, attn_bias, positions, cache, key_mask, ring_mesh)
         if cfg.parallel_residual:
-            x = x + attn_out + MLP(cfg, name="mlp")(h)
+            x = x + attn_out
+            mlp_out, stats = feed_forward(h)
+            x = x + mlp_out
         else:
             x = x + attn_out
-            x = x + MLP(cfg, name="mlp")(Norm(cfg, name="ln_2")(x))
-        return x, new_kv
+            mlp_out, stats = feed_forward(Norm(cfg, name="ln_2")(x))
+            x = x + mlp_out
+        return x, new_kv, stats
 
 
 class Embedding(nn.Module):
@@ -861,6 +1409,93 @@ def make_attention_bias(
     return jnp.where(visible, 0.0, NEG_INF)[:, None, :, :].astype(jnp.float32)
 
 
+def _fold_layer_stats(stats: Optional[Dict[str, Array]]) -> Optional[Dict[str, Array]]:
+    """A scan's stacked per-layer counters: `load` [layers, held] and
+    `choices` [layers, published] stay by layer, the assignments made are summed."""
+    if not stats:
+        return None
+    return {"load": stats["load"], "assignments": jnp.sum(stats["assignments"]),
+            "choices": stats["choices"]}
+
+
+def _join_stats(a: Optional[Dict[str, Array]], b: Optional[Dict[str, Array]]):
+    """Counters of two stretches of layers of one pass: loads side by side."""
+    if not a or not b:
+        return a or b
+    return {"load": jnp.concatenate([a["load"], b["load"]], axis=0),
+            "assignments": a["assignments"] + b["assignments"],
+            "choices": jnp.concatenate([a["choices"], b["choices"]], axis=0)}
+
+
+def _add_stats(a: Optional[Dict[str, Array]], b: Optional[Dict[str, Array]]):
+    """Counters of the same layers over two calls (a further decode step)."""
+    if not a or not b:
+        return a or b
+    return jax.tree_util.tree_map(jnp.add, a, b)
+
+
+def moe_counters(stats: Optional[Dict[str, Array]], program: str) -> Dict[str, Array]:
+    """What a jitted program hands out for the flight stream, by program
+    (`sampler`, `scorer`, `train`): `moe/assignments_here` (token-expert
+    pairs computed on this chip), `moe/assignments` (pairs the routers made:
+    tokens x experts per token x routed layers run) and
+    `moe/load_max_over_mean` (the fullest held expert's rows over the mean
+    of the held experts, in the worst layer). Empty for a model without experts."""
+    if not stats:
+        return {}
+    load = jax.lax.stop_gradient(stats["load"])
+    ratio = jnp.max(load, axis=-1) / jnp.maximum(jnp.mean(load, axis=-1), 1e-9)
+    return {
+        f"moe/assignments_here.{program}": jnp.sum(load),
+        f"moe/assignments.{program}": jax.lax.stop_gradient(stats["assignments"]),
+        f"moe/load_max_over_mean.{program}": jnp.max(ratio),
+    }
+
+
+def _with_router_bias(tree: Dict, bias: Array) -> Dict:
+    return dict(tree, blocks=dict(tree["blocks"], moe=dict(tree["blocks"]["moe"], router_bias=bias)))
+
+
+def _balance_step(lm: "TransformerLM", tree: Dict, bias: Array, input_ids: Array,
+                  attention_mask: Array, rate: Array) -> Tuple[Array, Array]:
+    """One step of `balance_router_bias`: (the new bias, the fullest expert's
+    choices over the mean under the old one, by layer)."""
+    out = lm(_with_router_bias(tree, bias), input_ids, attention_mask, compute_logits=False)
+    choices = out["moe_stats"]["choices"]  # [routed layers, published experts]
+    mean = jnp.mean(choices, axis=-1, keepdims=True)
+    return bias + rate * jnp.sign(mean - choices), jnp.max(choices, axis=-1) / mean[:, 0]
+
+
+def balance_router_bias(lm: "TransformerLM", params: Dict, input_ids: Array,
+                        attention_mask: Array, steps: int,
+                        rates: Tuple[float, float] = (0.03, 0.003)) -> Tuple[Dict, Array]:
+    """`steps` steps of auxiliary-loss-free load balancing on one batch, the
+    weights held: after each forward of `input_ids`, in every routed layer
+
+        b_e += rate * sign(mean over experts of choices - choices_e)
+
+    the rule a selection bias that is "added for the choice only" is
+    trained by (`choices_e`: how often the layer's router chose expert e),
+    with the rate falling geometrically from rates[0] to rates[1]. A
+    published checkpoint's bias has been through it for its whole
+    pretraining; under random weights a bias of zero sends a third of all
+    assignments to four experts, the same four at most positions, and how
+    many of them this chip holds is the draw of the seed. Returns the
+    language model's tree with the new `router_bias` and, by layer, the
+    fullest expert's choices over the mean before the first and after the
+    last step, [2, routed layers]."""
+    step = jax.jit(functools.partial(_balance_step, lm))
+    bias = params["blocks"]["moe"]["router_bias"]
+    ratios = []
+    for i in range(steps):
+        rate = rates[0] * (rates[1] / rates[0]) ** (i / max(steps - 1, 1))
+        bias, ratio = step(params, bias, input_ids, attention_mask, jnp.float32(rate))
+        ratios.append(ratio)
+    # one more forward, at rate 0: what the last step left
+    ratios.append(step(params, bias, input_ids, attention_mask, jnp.float32(0.0))[1])
+    return _with_router_bias(params, bias), jnp.stack([ratios[0], ratios[-1]])
+
+
 class TransformerLM:
     """Functional causal LM: explicit params, scan-over-layers forward.
 
@@ -879,10 +1514,19 @@ class TransformerLM:
     def __init__(self, cfg: TransformerConfig):
         self.cfg = cfg
         self.embed = Embedding(cfg)
-        self.block = Block(cfg)
+        self._mesh = None
+        self._build_blocks()
         self.ln_f = Norm(cfg)  # stateless: also applied with ln_embed params
         self.lm_head = None if cfg.tie_word_embeddings else LMHead(cfg)
-        self._mesh = None
+
+    def _build_blocks(self) -> None:
+        """The stack is segments of a kind: `first_k_dense` leading dense
+        layers (`params["dense_blocks"]`, module `lead_block`), then the
+        rest (`params["blocks"]`, module `block`): routed where the model
+        has experts, dense otherwise. Each segment is one scan."""
+        cfg = self.cfg
+        self.block = Block(cfg, self._mesh, kind="routed" if cfg.routed else "dense")
+        self.lead_block = Block(cfg, self._mesh, kind="dense") if cfg.first_k_dense else None
 
     @property
     def mesh(self):
@@ -896,7 +1540,7 @@ class TransformerLM:
     @mesh.setter
     def mesh(self, mesh) -> None:
         self._mesh = mesh
-        self.block = Block(self.cfg, mesh)
+        self._build_blocks()
 
     def _ring_mesh(self, batch: int, seq: int, cache) -> Optional[Any]:
         """The mesh to run ring attention over, or None for the XLA/pallas
@@ -944,6 +1588,14 @@ class TransformerLM:
         shared with the seq2seq stacks."""
         from trlx_tpu.parallel.pipeline import pp_microbatch_count
 
+        cfg = self.cfg
+        if cfg.beyond_dense and (
+            self.mesh is not None and self.mesh.shape.get("pp", 1) > 1
+        ):
+            raise NotImplementedError(
+                "pipeline parallelism (pp > 1) is not implemented for a model with latent "
+                "attention, routed experts or several residual streams"
+            )
         if cache is not None:
             return 0
         return pp_microbatch_count(
@@ -985,7 +1637,7 @@ class TransformerLM:
             bias = ctx_mb["bias"]
             if "flag" in layer:
                 bias = bias + layer["flag"] * ctx_mb["lb"]
-            out, _ = self.block.apply(
+            out, _, _ = self.block.apply(
                 {"params": layer["p"]}, h, bias, ctx_mb["pos"], None,
                 ctx_mb["km"], None,
             )
@@ -1035,6 +1687,18 @@ class TransformerLM:
             h = self.ln_f.apply({"params": params["ln_embed"]}, h)
         return h
 
+    def _to_streams(self, h: Array) -> Array:
+        """[B, T, E] -> the residual state: the embedding copied to each
+        of the streams, [B, T, n, E] (as it is for one stream)."""
+        n = self.cfg.residual_streams
+        return h if n == 1 else jnp.broadcast_to(h[:, :, None, :], h.shape[:2] + (n, h.shape[-1]))
+
+    def _final_hidden(self, ln_f_params: Dict, h: Array) -> Array:
+        """The streams summed, then the final norm."""
+        if self.cfg.residual_streams > 1:
+            h = h.sum(axis=2)
+        return self.ln_f.apply({"params": ln_f_params}, h)
+
     def _layer_flags(self, n: int, layer_offset: int) -> Optional[Array]:
         """1.0 for layers using the local sliding window, else 0.0 — for
         the n layers starting at layer_offset in the full stack."""
@@ -1058,15 +1722,23 @@ class TransformerLM:
         r_embed, r_block, r_head, r_lm = jax.random.split(rng, 4)
         embed_params = self.embed.init(r_embed, ids, pos)["params"]
         h = jnp.zeros((B, T, cfg.hidden_size), cfg.dtype)
+        state = self._to_streams(h)
 
-        block_params = jax.vmap(
-            lambda key: self.block.init(key, h, bias, pos)["params"]
-        )(jax.random.split(r_block, cfg.n_layer))
+        def stacked(block, keys):
+            init = lambda key: block.init(key, state, bias, pos)["params"]
+            # a routed block's grouped products (`jax.lax.ragged_dot`) take
+            # no batch dimension on the chip: its layers are initialised
+            # one after another and stacked
+            return jax.lax.map(init, keys) if block.kind == "routed" else jax.vmap(init)(keys)
+
+        layer_keys = jax.random.split(r_block, cfg.n_layer)
         params = {
             "embed": embed_params,
-            "blocks": block_params,
+            "blocks": stacked(self.block, layer_keys[cfg.first_k_dense:]),
             "ln_f": self.ln_f.init(r_head, h)["params"],
         }
+        if cfg.first_k_dense:
+            params["dense_blocks"] = stacked(self.lead_block, layer_keys[: cfg.first_k_dense])
         if cfg.embed_layernorm:
             params["ln_embed"] = self.ln_f.init(r_head, h)["params"]
         if self.lm_head is not None:
@@ -1088,9 +1760,32 @@ class TransformerLM:
         layer_offset: int = 0,
         ring_mesh=None,
     ) -> Tuple[Array, Optional[Dict[str, Array]]]:
-        """lax.scan over the stacked layer params (and cache layers).
-        `layer_offset` locates this slice within the full stack so
-        per-layer attention kinds (gpt-neo global/local) line up.
+        """`_scan_segment` over layers of the main kind, without the counters."""
+        return self._scan_segment(
+            block_params, h, attn_bias, positions, cache, remat, key_mask,
+            local_bias, layer_offset, ring_mesh,
+        )[:2]
+
+    def _scan_segment(
+        self,
+        block_params: Dict,
+        h: Array,
+        attn_bias: Array,
+        positions: Array,
+        cache: Optional[Dict[str, Array]] = None,
+        remat: bool = False,
+        key_mask: Optional[Array] = None,
+        local_bias: Optional[Array] = None,
+        layer_offset: int = 0,
+        ring_mesh=None,
+        block: Optional[Block] = None,
+    ) -> Tuple[Array, Optional[Dict[str, Array]], Optional[Dict[str, Array]]]:
+        """lax.scan over the stacked layer params (and cache layers) of
+        ONE segment: layers of a kind, run by `block` (default: the main
+        kind). `layer_offset` locates this slice within the full stack so
+        per-layer attention kinds (gpt-neo global/local) and the layers'
+        rows of a latent cache line up. Returns (h, new_cache, stats):
+        stats the routed layers' counters folded over the segment, or None.
 
         Cache path: the [L, B, S, Hkv, D] buffers are CARRIED through
         the scan; each layer's attention writes only its new
@@ -1099,8 +1794,45 @@ class TransformerLM:
         history and measured costs are in Attention.__call__)."""
         n = jax.tree_util.tree_leaves(block_params)[0].shape[0]
         flags = self._layer_flags(n, layer_offset)
+        blk = self.block if block is None else block
+        from trlx_tpu.ops.remat import wrap_remat
+
+        if cache is not None and "c" in cache:
+            # latent cache, this segment's rows [layers, B, S, rank + rope]
+            # (the leading dense layers keep theirs apart, `c_lead`: one
+            # array for both segments made the compiler lay it out anew
+            # between them, twice a decode step): carried like the dense
+            # one; each layer writes its positions' row in place and reads
+            # its own [B, S, rank + rope] slice
+            lead = blk is self.lead_block
+            rows = "c_lead" if lead else "c"
+            row0 = layer_offset - (0 if lead else self.cfg.first_k_dense)
+
+            def latent_body(carry, layer):
+                hidden, c = carry
+                layer_cache = {"c": c, "ix": layer["ix"], "index": cache["index"]}
+                if "static_index" in cache:
+                    layer_cache["static_index"] = cache["static_index"]
+                out, new_kv, stats = blk.apply(
+                    {"params": layer["p"]}, hidden, attn_bias, positions, layer_cache,
+                    key_mask, ring_mesh,
+                )
+                return (out, new_kv["c"]), stats
+
+            (h, c), stats = jax.lax.scan(
+                wrap_remat(latent_body, remat), (h, cache[rows]),
+                {"p": block_params, "ix": row0 + jnp.arange(n)},
+            )
+            new_cache = {k: v for k, v in cache.items() if k != "static_index"}
+            new_cache.update({rows: c, "index": cache["index"] + positions.shape[1]})
+            return h, new_cache, _fold_layer_stats(stats)
 
         if cache is not None and "pk" in cache:
+            if self.cfg.beyond_dense:
+                raise NotImplementedError(
+                    "the paged decode engine (models/gen_engine.py) has no latent page pool "
+                    "and runs no routed or multi-stream layer"
+                )
             # paged cache: the scan carries the page POOLS; the page
             # table / slot positions / validity masks are per-forward
             # constants (the engine advances them between forwards), so
@@ -1125,15 +1857,13 @@ class TransformerLM:
                 bias = attn_bias
                 if flags is not None:
                     bias = bias + layer["flag"] * local_bias
-                out, new_kv = self.block.apply(
+                out, new_kv, _ = blk.apply(
                     {"params": lp}, hidden, bias, positions, layer_cache,
                     key_mask, ring_mesh,
                 )
                 return (out,) + tuple(new_kv[k] for k in pool_keys), None
 
-            from trlx_tpu.ops.remat import wrap_remat as _wrap
-
-            paged_body = _wrap(paged_body, remat)
+            paged_body = wrap_remat(paged_body, remat)
             # "layer_ixs" remaps this forward's layers onto pool layer
             # slots (gen_engine's spec-decode trunk sharing: the hydra
             # DRAFT's trunk layers index the policy pool's trunk — their
@@ -1149,7 +1879,7 @@ class TransformerLM:
                 paged_body, (h,) + tuple(cache[k] for k in pool_keys), xs
             )
             new_cache = dict(cache, **dict(zip(pool_keys, carry[1:])))
-            return carry[0], new_cache
+            return carry[0], new_cache, None
 
         quant = cache is not None and "k_scale" in cache
 
@@ -1187,17 +1917,15 @@ class TransformerLM:
             bias = attn_bias
             if flags is not None:
                 bias = bias + layer["flag"] * local_bias
-            out, new_kv = self.block.apply(
+            out, new_kv, stats = blk.apply(
                 {"params": lp}, hidden, bias, positions, layer_cache, key_mask,
                 ring_mesh,
             )
             if quant:
-                return (out, new_kv["ck"], new_kv["cv"], new_kv["ck_scale"]), None
+                return (out, new_kv["ck"], new_kv["cv"], new_kv["ck_scale"]), stats
             if cache is not None:
-                return (out, new_kv["ck"], new_kv["cv"]), None
-            return out, None
-
-        from trlx_tpu.ops.remat import wrap_remat
+                return (out, new_kv["ck"], new_kv["cv"]), stats
+            return out, stats
 
         body = wrap_remat(body, remat)
 
@@ -1208,7 +1936,7 @@ class TransformerLM:
             xs["flag"] = flags
         if quant:
             xs["vs"] = cache["v_scale"]
-            (h, ck, cv, cks), _ = jax.lax.scan(
+            (h, ck, cv, cks), stats = jax.lax.scan(
                 body,
                 (h, cache["k"], cache["v"], cache["k_scale"]),
                 xs,
@@ -1219,15 +1947,52 @@ class TransformerLM:
                 key_mask=cache["key_mask"],
             )
         elif cache is not None:
-            (h, ck, cv), _ = jax.lax.scan(body, (h, cache["k"], cache["v"]), xs)
+            (h, ck, cv), stats = jax.lax.scan(body, (h, cache["k"], cache["v"]), xs)
             new_cache = dict(
                 k=ck, v=cv, index=cache["index"] + positions.shape[1],
                 key_mask=cache["key_mask"],
             )
         else:
-            h, _ = jax.lax.scan(body, h, xs)
+            h, stats = jax.lax.scan(body, h, xs)
             new_cache = None
-        return h, new_cache
+        return h, new_cache, _fold_layer_stats(stats)
+
+    def _run_layers(
+        self,
+        params: Dict,
+        h: Array,
+        lo: int,
+        hi: int,
+        attn_bias: Array,
+        positions: Array,
+        cache: Optional[Dict[str, Array]] = None,
+        **kw,
+    ) -> Tuple[Array, Optional[Dict[str, Array]], Optional[Dict[str, Array]]]:
+        """Layers [lo, hi) of a WHOLE tree (`dense_blocks` then `blocks`),
+        segment by segment; a cache advances its index once."""
+        k = self.cfg.first_k_dense
+        stats = None
+        index = None if cache is None else (cache.get("index"), cache.get("static_index"))
+        for name, block, start, end in (
+            ("dense_blocks", self.lead_block, lo, min(hi, k)),
+            ("blocks", self.block, max(lo, k), hi),
+        ):
+            if end <= start:
+                continue
+            base = 0 if name == "dense_blocks" else k
+            stack = params[name]
+            if (start - base, end - base) != (0, jax.tree_util.tree_leaves(stack)[0].shape[0]):
+                stack = jax.tree_util.tree_map(lambda x: x[start - base : end - base], stack)
+            h, new_cache, seg_stats = self._scan_segment(
+                stack, h, attn_bias, positions, cache, layer_offset=start, block=block, **kw)
+            stats = _join_stats(stats, seg_stats)
+            if cache is not None:
+                cache = new_cache
+                if end < hi and index[0] is not None:  # a further segment writes the same positions
+                    cache = dict(cache, index=index[0])
+                    if index[1] is not None:
+                        cache["static_index"] = index[1]
+        return h, cache, stats
 
     def __call__(
         self,
@@ -1319,7 +2084,7 @@ class TransformerLM:
         elif cache is not None:
             # bf16 cache: [L, B, S, Hkv, D]; int8 (quantized) cache:
             # [L, B, Hkv, S, D] (layout rationale: quantize_kv_cache)
-            S = cache["k"].shape[3 if "k_scale" in cache else 2]
+            S = cache["c"].shape[2] if "c" in cache else cache["k"].shape[3 if "k_scale" in cache else 2]
             q_slots = cache["index"] + jnp.arange(T)
             if positions is None:
                 positions = q_slots[None, :] * jnp.ones((B, 1), jnp.int32)
@@ -1341,6 +2106,11 @@ class TransformerLM:
                 )
             layer_cache = None
 
+        if self.cfg.beyond_dense and (prefix_embeds is not None or kv_prefix is not None):
+            raise NotImplementedError(
+                "prompt and prefix adapters are not implemented for a model with latent "
+                "attention, routed experts or several residual streams"
+            )
         h = self._embed_h(params, input_ids, positions)
         if prefix_embeds is not None:
             # the virtual slots were embedded as token 0 (+wpe): swap the
@@ -1357,15 +2127,16 @@ class TransformerLM:
                 params["blocks"], h, bias, positions, n_microbatch=n_mb,
                 remat=remat, key_mask=attention_mask, local_bias=local_bias,
             )
-            new_cache = None
+            new_cache, stats = None, None
         else:
-            h, new_cache = self._scan_blocks(
-                params["blocks"], h, bias, positions, layer_cache, remat=remat,
+            h, new_cache, stats = self._run_layers(
+                params, self._to_streams(h), 0, self.cfg.n_layer, bias, positions,
+                layer_cache, remat=remat,
                 key_mask=key_mask if cache is not None else attention_mask,
                 local_bias=local_bias,
                 ring_mesh=None if cache is not None else ring,
             )
-        hidden = self.ln_f.apply({"params": params["ln_f"]}, h)
+        hidden = self._final_hidden(params["ln_f"], h)
         # compute_logits=False: callers using chunked-from-hidden losses
         # (train.logit_chunks) skip the full [B, T, V] projection here
         logits = self._logits(params, hidden) if compute_logits else None
@@ -1373,12 +2144,15 @@ class TransformerLM:
             hidden = hidden[:, n_virtual:]
             logits = logits[:, n_virtual:] if logits is not None else None
             positions = positions[:, n_virtual:]
-        return {
+        out = {
             "logits": logits,
             "hidden_states": hidden,
             "cache": new_cache,
             "positions": positions,
         }
+        if stats:
+            out["moe_stats"] = stats
+        return out
 
     def _logits(self, params: Dict, hidden: Array) -> Array:
         if self.lm_head is not None:
@@ -1423,19 +2197,17 @@ class TransformerLM:
                 capture_points=(branch_at,),
             )
         else:
-            bottom = jax.tree_util.tree_map(
-                lambda x: x[:branch_at], params["blocks"]
-            )
-            top = jax.tree_util.tree_map(lambda x: x[branch_at:], params["blocks"])
-            h_branch, _ = self._scan_blocks(
-                bottom, h, bias, positions, remat=remat, key_mask=attention_mask,
+            h_branch, _, _ = self._run_layers(
+                params, self._to_streams(h), 0, branch_at, bias, positions,
+                remat=remat, key_mask=attention_mask,
                 local_bias=local_bias, ring_mesh=ring,
             )
-            h_top, _ = self._scan_blocks(
-                top, h_branch, bias, positions, remat=remat, key_mask=attention_mask,
-                local_bias=local_bias, layer_offset=branch_at, ring_mesh=ring,
+            h_top, _, _ = self._run_layers(
+                params, h_branch, branch_at, self.cfg.n_layer, bias, positions,
+                remat=remat, key_mask=attention_mask,
+                local_bias=local_bias, ring_mesh=ring,
             )
-        hidden = self.ln_f.apply({"params": params["ln_f"]}, h_top)
+        hidden = self._final_hidden(params["ln_f"], h_top)
         logits = self._logits(params, hidden) if compute_logits else None
         return {
             "logits": logits,
@@ -1488,6 +2260,7 @@ class TransformerLM:
             frozen_below = 0
         frozen = jax.lax.stop_gradient(params) if frozen_below else params
         h = self._embed_h(frozen, input_ids, positions)
+        stats = None
 
         if n_mb:
             # match the sequential path: points >= n_layer are omitted
@@ -1502,28 +2275,27 @@ class TransformerLM:
         else:
             captures = []
             prev = 0
+            h = self._to_streams(h)
             for point in tuple(points) + (self.cfg.n_layer,):
                 if point > prev:
                     const = point <= frozen_below
-                    seg = jax.tree_util.tree_map(
-                        lambda x: x[prev:point],
-                        (frozen if const else params)["blocks"],
-                    )
-                    h, _ = self._scan_blocks(
-                        seg, h, bias, positions, remat=False if const else remat,
+                    h, _, seg_stats = self._run_layers(
+                        frozen if const else params, h, prev, point, bias, positions,
+                        remat=False if const else remat,
                         key_mask=attention_mask,
-                        local_bias=local_bias, layer_offset=prev, ring_mesh=ring,
+                        local_bias=local_bias, ring_mesh=ring,
                     )
+                    stats = _join_stats(stats, seg_stats)
                     if const:
                         h = jax.lax.stop_gradient(h)
                 if point < self.cfg.n_layer:
                     captures.append(h)
                 prev = point
-        hidden = self.ln_f.apply({"params": params["ln_f"]}, h)
+        hidden = self._final_hidden(params["ln_f"], h)
         # a tied head reads the (frozen) embedding
         head = dict(params, embed=frozen["embed"])
         logits = self._logits(head, hidden) if compute_logits else None
-        return {
+        out = {
             "logits": logits,
             "hidden_states": hidden,
             "captures": captures,
@@ -1532,6 +2304,9 @@ class TransformerLM:
             "local_bias": local_bias,
             "key_mask": attention_mask,
         }
+        if stats:
+            out["moe_stats"] = jax.lax.stop_gradient(stats)
+        return out
 
     def forward_from_layer(
         self,
@@ -1558,15 +2333,18 @@ class TransformerLM:
         if attn_bias is None and key_mask is not None:
             B, T = branch_hidden.shape[:2]
             ring = self._ring_mesh(B, T, None)
-        h, _ = self._scan_blocks(
+        h, _, stats = self._scan_segment(
             branch_params["blocks"], branch_hidden, attn_bias, positions,
             remat=remat, local_bias=local_bias,
             layer_offset=self.cfg.n_layer - k,
             key_mask=key_mask, ring_mesh=ring,
         )
-        hidden = self.ln_f.apply({"params": branch_params["ln_f"]}, h)
+        hidden = self._final_hidden(branch_params["ln_f"], h)
         logits = self._logits(branch_params, hidden) if compute_logits else None
-        return {"logits": logits, "hidden_states": hidden}
+        out = {"logits": logits, "hidden_states": hidden}
+        if stats:
+            out["moe_stats"] = jax.lax.stop_gradient(stats)
+        return out
 
     # -- cache -----------------------------------------------------------
 
@@ -1580,6 +2358,20 @@ class TransformerLM:
         a cache that crosses a jit boundary loses its int-ness — both
         cases just fall back to the XLA path."""
         cfg = self.cfg
+        mask = key_mask if key_mask is not None else jnp.ones((batch, max_len), jnp.int32)
+        if cfg.latent:
+            # [layers, B, S, rank + rope]: the normed latent and the rotated
+            # shared key of each position, not per-head keys and values; a
+            # segment of layers of a kind keeps its rows in its own array
+            # (`c_lead`: the leading dense layers, `c`: the rest)
+            def rows(layers):
+                return jnp.zeros((layers, batch, max_len, cfg.cache_elems_per_position), cfg.dtype)
+
+            cache = {"c": rows(cfg.n_layer - cfg.first_k_dense), "index": jnp.int32(0),
+                     "static_index": 0, "key_mask": mask}
+            if cfg.first_k_dense:
+                cache["c_lead"] = rows(cfg.first_k_dense)
+            return cache
         shape = (cfg.n_layer, batch, max_len, cfg.n_kv_head, cfg.head_dim)
         return {
             "k": jnp.zeros(shape, cfg.dtype),
@@ -1594,9 +2386,16 @@ class TransformerLM:
 def extract_branch_params(params: Dict, branch_at: int) -> Dict:
     """Copy the top-(L-branch_at) layers + final norm + logit head as a
     frozen reference branch. Parity: the hydra 'frozen_head' build
-    (reference modeling_ppo.py:475-499) without per-arch classes."""
+    (reference modeling_ppo.py:475-499) without per-arch classes. A branch
+    is layers of the main kind: it forks at or above the leading dense
+    layers (`params["dense_blocks"]`), which it leaves behind."""
+    lead = jax.tree_util.tree_leaves(params["dense_blocks"])[0].shape[0] if "dense_blocks" in params else 0
+    if branch_at < lead:
+        raise NotImplementedError(
+            f"a branch at layer {branch_at} would reach into the {lead} leading dense layers"
+        )
     branch = {
-        "blocks": jax.tree_util.tree_map(lambda x: x[branch_at:], params["blocks"]),
+        "blocks": jax.tree_util.tree_map(lambda x: x[branch_at - lead:], params["blocks"]),
         "ln_f": params["ln_f"],
         "embed": params["embed"],
     }

@@ -133,14 +133,11 @@ class CausalLMWithValueHead:
             "v_head": init_head(r_head, self.cfg.hidden_size, 1),
         }
         if self.value_branch_at is not None:
+            # the same slice a reference branch takes (layers of the main
+            # kind, above any leading dense ones), trainable
+            branch = extract_branch_params(base_params, self.value_branch_at)
             params["v_branch"] = jax.tree_util.tree_map(
-                jnp.copy,
-                {
-                    "blocks": jax.tree_util.tree_map(
-                        lambda x: x[self.value_branch_at :], base_params["blocks"]
-                    ),
-                    "ln_f": base_params["ln_f"],
-                },
+                jnp.copy, {"blocks": branch["blocks"], "ln_f": branch["ln_f"]}
             )
         return params
 
@@ -160,7 +157,7 @@ class CausalLMWithValueHead:
             layer_offset=self.value_branch_at,
             key_mask=out.get("key_mask"), ring_mesh=ring,
         )
-        hidden = self.lm.ln_f.apply({"params": params["v_branch"]["ln_f"]}, h)
+        hidden = self.lm._final_hidden(params["v_branch"]["ln_f"], h)
         return apply_head(params["v_head"], hidden)[..., 0]
 
     def make_ref_params(self, params: Dict) -> Dict:
@@ -288,6 +285,10 @@ class CausalLMWithValueHead:
                     key_mask=out.get("key_mask"),
                     compute_logits=compute_logits,
                 )
+        # a routed reference branch's counters stay apart from the policy's:
+        # the scorer adds them, the train step reads neither them nor any
+        # other output of the branch, which is then dead code there
+        ref_stats = ref_out.get("moe_stats")
         return dict(
             out,
             ref_logits=(
@@ -295,6 +296,7 @@ class CausalLMWithValueHead:
                 if compute_logits else None
             ),
             ref_hidden=jax.lax.stop_gradient(ref_out["hidden_states"]),
+            **({"ref_moe_stats": ref_stats} if ref_stats else {}),
         )
 
 

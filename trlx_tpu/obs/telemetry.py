@@ -82,6 +82,7 @@ def device_provenance() -> Dict[str, Any]:
 
 # tracker-stat keys mirrored into the per-cycle rows / headline (means
 # over the cycle's chunks, flush-cadence attribution)
+_COUNTER_PREFIXES = ("moe/",)
 _ENGINE_KEYS = (
     "rollout/engine_occupancy",
     "rollout/engine_refills",
@@ -110,6 +111,7 @@ class TelemetryAggregator:
         self._pending_samples = 0
         self._pending_tokens = 0.0
         self._last_stats: Dict[str, float] = {}
+        self._pending_counters: Dict[str, float] = {}
         # model/static facts, set once by the trainer
         self.static: Dict[str, Any] = {}
         self._param_count: Optional[int] = None
@@ -133,6 +135,15 @@ class TelemetryAggregator:
             v = stats.get(k)
             if isinstance(v, (int, float)):
                 self._last_stats[k.split("/", 1)[1]] = float(v)
+        # counters the jitted programs carry out with their deferred stats
+        # (a routed model's moe/*): they land in the row of the cycle in
+        # which they were flushed, the one after the work they count
+        for k, v in stats.items():
+            if k.startswith(_COUNTER_PREFIXES):
+                try:
+                    self._pending_counters[k] = float(v)
+                except (TypeError, ValueError):
+                    pass
 
     def close_cycle(
         self, wall_s: float, breakdown: Dict[str, float],
@@ -160,6 +171,8 @@ class TelemetryAggregator:
         }
         if samples and wall_s > 0:
             row["samples_per_sec"] = round(samples / wall_s, 3)
+        if self._pending_counters:
+            row["counters"], self._pending_counters = self._pending_counters, {}
         if self._last_stats:
             row["engine"] = {
                 k: round(v, 4) for k, v in sorted(self._last_stats.items())

@@ -86,8 +86,8 @@ def _flash_kernel(
     *, sm_scale, causal, q_offset, n_chunks, ck,
 ):
     bq = q_ref.shape[1]
-    D = q_ref.shape[2]
-    q = q_ref[0].astype(jnp.float32)  # [Bq, D]
+    D = v_ref.shape[2]  # the output is as wide as the values (keys may differ)
+    q = q_ref[0].astype(jnp.float32)  # [Bq, Dk]
     row0 = pl.program_id(1) * bq + q_offset
 
     def body(j, carry):
@@ -134,7 +134,7 @@ def _kv_head_index(H: int, Hkv: int):
 def _flash_forward(q, k, v, key_mask, causal, sm_scale, block_q,
                    with_stats=False, q_offset=None):
     B, H, T, D = q.shape
-    Hkv, S = k.shape[1], k.shape[2]
+    Hkv, S, Dv = k.shape[1], k.shape[2], v.shape[3]
     if H % Hkv:
         raise ValueError(f"n_head={H} not a multiple of n_kv_head={Hkv}")
     if q_offset is None:
@@ -151,7 +151,7 @@ def _flash_forward(q, k, v, key_mask, causal, sm_scale, block_q,
     # kv rows, so HBM reads per kv head happen once per GROUP, which is
     # the bandwidth saving GQA exists for
     kr = k.reshape(B * Hkv, S, D)
-    vr = v.reshape(B * Hkv, S, D)
+    vr = v.reshape(B * Hkv, S, Dv)
     kv_ix = _kv_head_index(H, Hkv)
 
     kernel = functools.partial(
@@ -164,7 +164,7 @@ def _flash_forward(q, k, v, key_mask, causal, sm_scale, block_q,
         in_specs=[
             pl.BlockSpec((1, bq, D), lambda bh, qi: (bh, qi, 0)),
             pl.BlockSpec((1, S, D), kv_ix),
-            pl.BlockSpec((1, S, D), kv_ix),
+            pl.BlockSpec((1, S, Dv), kv_ix),
             # [B, 1, S] so the block's trailing two dims (1, S) equal the
             # array dims — Mosaic requires trailing block dims divisible
             # by (8, 128) OR equal to the array's (a bare (1, S) block
@@ -172,19 +172,19 @@ def _flash_forward(q, k, v, key_mask, causal, sm_scale, block_q,
             pl.BlockSpec((1, 1, S), lambda bh, qi: (bh // H, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, bq, D), lambda bh, qi: (bh, qi, 0)),
+            pl.BlockSpec((1, bq, Dv), lambda bh, qi: (bh, qi, 0)),
             pl.BlockSpec((1, bq, 1), lambda bh, qi: (bh, qi, 0)),
             pl.BlockSpec((1, bq, 1), lambda bh, qi: (bh, qi, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, T, D), q.dtype),
+            jax.ShapeDtypeStruct((B * H, T, Dv), q.dtype),
             jax.ShapeDtypeStruct((B * H, T, 1), jnp.float32),
             jax.ShapeDtypeStruct((B * H, T, 1), jnp.float32),
         ],
         interpret=_interpret(),
         name="flash_fwd",
     )(qr, kr, vr, key_mask.astype(jnp.int32)[:, None, :])
-    out = out.reshape(B, H, T, D)
+    out = out.reshape(B, H, T, Dv)
     if with_stats:
         return out, m, l
     return out
@@ -282,8 +282,10 @@ def _dkv_kernel(
         )  # [Bk, D]
         return dk_new, dv_new
 
-    z = jnp.zeros((bk, D), jnp.float32)
-    dk, dv = jax.lax.fori_loop(0, n_chunks, body, (z, z))
+    dk, dv = jax.lax.fori_loop(
+        0, n_chunks, body,
+        (jnp.zeros((bk, D), jnp.float32), jnp.zeros((bk, v_ref.shape[2]), jnp.float32)),
+    )
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
@@ -291,7 +293,7 @@ def _dkv_kernel(
 def _flash_backward(q, k, v, key_mask, o, m, l, g, causal, sm_scale, block_q,
                     q_offset=None):
     B, H, T, D = q.shape
-    Hkv, S = k.shape[1], k.shape[2]
+    Hkv, S, Dv = k.shape[1], k.shape[2], v.shape[3]
     rep = H // Hkv
     if q_offset is None:
         q_offset = S - T
@@ -301,12 +303,12 @@ def _flash_backward(q, k, v, key_mask, o, m, l, g, causal, sm_scale, block_q,
 
     qr = q.reshape(B * H, T, D)
     kr = k.reshape(B * Hkv, S, D)
-    vr = v.reshape(B * Hkv, S, D)
+    vr = v.reshape(B * Hkv, S, Dv)
     kv_ix = _kv_head_index(H, Hkv)
-    dor = g.reshape(B * H, T, D)
+    dor = g.reshape(B * H, T, Dv)
     # delta_i = rowsum(dO_i * O_i): tiny elementwise pass, fine in XLA
     delta = jnp.sum(
-        dor.astype(jnp.float32) * o.reshape(B * H, T, D).astype(jnp.float32),
+        dor.astype(jnp.float32) * o.reshape(B * H, T, Dv).astype(jnp.float32),
         axis=-1, keepdims=True,
     )  # [BH, T, 1]
 
@@ -321,9 +323,9 @@ def _flash_backward(q, k, v, key_mask, o, m, l, g, causal, sm_scale, block_q,
         in_specs=[
             pl.BlockSpec((1, bq, D), lambda bh, qi: (bh, qi, 0)),
             pl.BlockSpec((1, S, D), kv_ix),
-            pl.BlockSpec((1, S, D), kv_ix),
+            pl.BlockSpec((1, S, Dv), kv_ix),
             pl.BlockSpec((1, 1, S), lambda bh, qi: (bh // H, 0, 0)),
-            pl.BlockSpec((1, bq, D), lambda bh, qi: (bh, qi, 0)),
+            pl.BlockSpec((1, bq, Dv), lambda bh, qi: (bh, qi, 0)),
             pl.BlockSpec((1, bq, 1), lambda bh, qi: (bh, qi, 0)),
             pl.BlockSpec((1, bq, 1), lambda bh, qi: (bh, qi, 0)),
             pl.BlockSpec((1, bq, 1), lambda bh, qi: (bh, qi, 0)),
@@ -341,7 +343,7 @@ def _flash_backward(q, k, v, key_mask, o, m, l, g, causal, sm_scale, block_q,
     # so the kernel's chunk loop accumulates the whole group into its kv
     # head's (dk, dv) — no repeated kv materialization, no XLA reduce
     qg = q.reshape(B * Hkv, rep * T, D)
-    dog = g.reshape(B * Hkv, rep * T, D)
+    dog = g.reshape(B * Hkv, rep * T, Dv)
     # lane-major stat views for the dkv kernel (see its docstring)
     m_t = m.reshape(B * Hkv, 1, rep * T)
     l_t = l.reshape(B * Hkv, 1, rep * T)
@@ -355,20 +357,20 @@ def _flash_backward(q, k, v, key_mask, o, m, l, g, causal, sm_scale, block_q,
         in_specs=[
             pl.BlockSpec((1, rep * T, D), lambda bh, ki: (bh, 0, 0)),
             pl.BlockSpec((1, bk, D), lambda bh, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, bk, D), lambda bh, ki: (bh, ki, 0)),
+            pl.BlockSpec((1, bk, Dv), lambda bh, ki: (bh, ki, 0)),
             pl.BlockSpec((1, 1, S), lambda bh, ki: (bh // Hkv, 0, 0)),
-            pl.BlockSpec((1, rep * T, D), lambda bh, ki: (bh, 0, 0)),
+            pl.BlockSpec((1, rep * T, Dv), lambda bh, ki: (bh, 0, 0)),
             pl.BlockSpec((1, 1, rep * T), lambda bh, ki: (bh, 0, 0)),
             pl.BlockSpec((1, 1, rep * T), lambda bh, ki: (bh, 0, 0)),
             pl.BlockSpec((1, 1, rep * T), lambda bh, ki: (bh, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, bk, D), lambda bh, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, bk, D), lambda bh, ki: (bh, ki, 0)),
+            pl.BlockSpec((1, bk, Dv), lambda bh, ki: (bh, ki, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * Hkv, S, D), k.dtype),
-            jax.ShapeDtypeStruct((B * Hkv, S, D), v.dtype),
+            jax.ShapeDtypeStruct((B * Hkv, S, Dv), v.dtype),
         ],
         interpret=_interpret(),
         name="flash_bwd_dkv",
@@ -377,14 +379,16 @@ def _flash_backward(q, k, v, key_mask, o, m, l, g, causal, sm_scale, block_q,
     return (
         dq.reshape(B, H, T, D),
         dk.reshape(B, Hkv, S, D),
-        dv.reshape(B, Hkv, S, D),
+        dv.reshape(B, Hkv, S, Dv),
     )
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
 def flash_attention(q, k, v, key_mask, causal=True, sm_scale=None, block_q=256,
                     q_offset=None):
-    """Fused attention. q: [B, H, T, D]; k/v: [B, Hkv, S, D] with
+    """Fused attention. q: [B, H, T, D]; k: [B, Hkv, S, D]; v: [B, Hkv, S, Dv]
+    (the values, and so the output, may be narrower or wider than the keys:
+    latent attention has 192-wide keys and 128-wide values) with
     Hkv | H (grouped-query attention — pass kv heads UNREPEATED, the
     kernels route each q head to its group's kv rows and accumulate the
     group's dk/dv natively); key_mask: [B, S] (1=real).
@@ -435,7 +439,7 @@ def _bwd(causal, sm_scale, block_q, q_offset, res, g):
 flash_attention.defvjp(_fwd, _bwd)
 
 
-def flash_attention_on_mesh(mesh, q, k, v, key_mask, q_offset=None):
+def flash_attention_on_mesh(mesh, q, k, v, key_mask, q_offset=None, sm_scale=None):
     """:func:`flash_attention` under a device mesh (None = one device).
 
     GSPMD cannot partition a Mosaic custom call — on a multi-device mesh
@@ -449,7 +453,7 @@ def flash_attention_on_mesh(mesh, q, k, v, key_mask, q_offset=None):
     runs inside the pipeline's shard_map and the call is left as it is.
     """
     if mesh is None or mesh.size == 1 or mesh.shape.get("pp", 1) > 1:
-        return flash_attention(q, k, v, key_mask, q_offset=q_offset)
+        return flash_attention(q, k, v, key_mask, sm_scale=sm_scale, q_offset=q_offset)
     from jax.sharding import PartitionSpec as P
 
     data, tp = mesh.shape["dp"] * mesh.shape["fsdp"], mesh.shape["tp"]
@@ -461,7 +465,8 @@ def flash_attention_on_mesh(mesh, q, k, v, key_mask, q_offset=None):
         )
     heads = P(("dp", "fsdp"), "tp", None, None)
     return jax.shard_map(
-        lambda q_, k_, v_, m_: flash_attention(q_, k_, v_, m_, q_offset=q_offset),
+        lambda q_, k_, v_, m_: flash_attention(
+            q_, k_, v_, m_, sm_scale=sm_scale, q_offset=q_offset),
         mesh=mesh,
         in_specs=(heads, heads, heads, P(("dp", "fsdp"), None)),
         out_specs=heads,

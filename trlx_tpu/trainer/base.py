@@ -48,7 +48,7 @@ from trlx_tpu.models.generation import (
     generate,
 )
 from trlx_tpu.models.hf import load_pretrained, save_pretrained_hf
-from trlx_tpu.models.transformer import TransformerConfig, TransformerLM
+from trlx_tpu.models.transformer import TransformerConfig, TransformerLM, balance_router_bias
 from trlx_tpu.parallel import (
     data_sharding,
     init_sharded_opt_state,
@@ -418,6 +418,7 @@ class TPUBaseTrainer(BaseRLTrainer):
             )
             self.rng, key = jax.random.split(self.rng)
             params = TransformerLM(tcfg).init(key)
+            self._random_init = True
             return finalize(tcfg), params, extra.get("model_type")
         lm, params, model_type = load_pretrained(
             mc.model_path, dtype=self.compute_dtype, param_dtype=self.param_dtype
@@ -496,19 +497,34 @@ class TPUBaseTrainer(BaseRLTrainer):
         """Standard causal-LM freeze mask: embeddings + bottom layers
         frozen, top-k layers + final norm + lm_head + aux heads train."""
         at = self.branch_at()
-        if at is None or at == 0:
+        cfg = self.model.cfg
+        routed = getattr(cfg, "routed", False)
+        if (at is None or at == 0) and not routed:
             return None
-        n_layer = self.model.cfg.n_layer
-        layer_mask = (jnp.arange(n_layer) >= at).astype(jnp.float32)
+        n_layer = cfg.n_layer
+        lead = getattr(cfg, "first_k_dense", 0)
+        layer_mask = (jnp.arange(n_layer) >= (at or 0)).astype(jnp.float32)
+        # a chip that holds a share of the experts sees the router's gradient
+        # through its own experts alone, an eighth of the sum the chips of a
+        # deployment would add up, and all of it pulling toward those experts:
+        # the two trainable layers' held share went 1.1 -> 1.4 of uniform in 64
+        # steps (chip run, PERF.md section 6). A share trains no router.
+        a_share = routed and cfg.n_experts_held < cfg.n_routed_experts
 
         def mask_leaf(path, leaf):
             keys = [getattr(p, "key", getattr(p, "idx", None)) for p in path]
+            if keys[-1] == "router_bias":
+                return np.float32(0.0)  # the router's selection bias is a buffer
+            if a_share and keys[-1] == "router_gate":
+                return np.float32(0.0)
             if "v_branch" in keys or "lora" in keys:
                 return np.float32(1.0)  # branches/adapters always train
+            if "dense_blocks" in keys:  # the leading dense layers, [0, lead)
+                return layer_mask[:lead].reshape((lead,) + (1,) * (np.ndim(leaf) - 1))
             if "blocks" in keys:
-                return layer_mask.reshape((n_layer,) + (1,) * (np.ndim(leaf) - 1))
-            if "embed" in keys:
-                return np.float32(0.0)
+                return layer_mask[lead:].reshape((n_layer - lead,) + (1,) * (np.ndim(leaf) - 1))
+            if "embed" in keys:  # frozen with the bottom layers, if any are
+                return np.float32(0.0 if at else 1.0)
             return np.float32(1.0)
 
         return jax.tree_util.tree_map_with_path(mask_leaf, params)
@@ -533,6 +549,13 @@ class TPUBaseTrainer(BaseRLTrainer):
             load_peft_adapter,
             normalize_peft_config,
         )
+
+        cfg = getattr(self.model, "cfg", None)
+        if self.config.model.peft_config is not None and getattr(cfg, "beyond_dense", False):
+            raise NotImplementedError(
+                "peft adapters are not implemented for a model with latent attention, "
+                "routed experts or several residual streams"
+            )
 
         if isinstance(self.config.model.peft_config, str) and not (
             is_peft_checkpoint(self.config.model.peft_config)
@@ -839,6 +862,8 @@ class TPUBaseTrainer(BaseRLTrainer):
             # experience forward consumes it (+ sequences/response_mask)
             # straight from here, skipping a host round-trip per chunk
             out = dict(out, prompt_mask=device_mask)
+        # a routed model's counters are scalars of the whole call, not rows
+        moe_stats = out.pop("moe_stats", None)
         if target != B:
             if mh.is_multihost():
                 # each data group's pad rows sit at the END of its own
@@ -851,6 +876,8 @@ class TPUBaseTrainer(BaseRLTrainer):
                 out = dict(out, real_rows=B)
             else:
                 out = jax.tree_util.tree_map(lambda x: x[:B], out)
+        if moe_stats:
+            out["moe_stats"] = moe_stats
         return out
 
     def generate_eval(self, input_ids, attention_mask=None, **kwargs):
@@ -870,6 +897,12 @@ class TPUBaseTrainer(BaseRLTrainer):
         sampler does."""
         if self.config.model.model_arch_type == "seq2seq":
             return False
+        cfg = self.model.cfg
+        if cfg.beyond_dense:
+            raise NotImplementedError(
+                "ppo.gen_engine: the paged decode engine has no latent page pool and runs "
+                "no routed or multi-stream layer; use the static sampler for this model"
+            )
         if mh.is_multihost() or mh.data_group_count(self.mesh) != 1:
             return False
         if self.generation_logits_processor(self.params) is not None:
@@ -1511,6 +1544,10 @@ class TPUBaseTrainer(BaseRLTrainer):
             layers = cfg.n_layer + decoder
             backward = decoder - below if below else layers
         gauges = {"model/layers": layers, "model/backward_layers": backward}
+        if getattr(cfg, "beyond_dense", False):
+            gauges["model/experts_held"] = cfg.n_experts_held or 0
+            gauges["model/cache_elems_per_position"] = cfg.cache_elems_per_position
+            gauges["model/residual_streams"] = cfg.residual_streams
         self.obs.gauge(**gauges)
         self._tracker_log(gauges, step=self.iter_count)
 
@@ -4108,6 +4145,10 @@ class TPUOnlineTrainer(TPUBaseTrainer):
             )
             n_new = gen_out["response_ids"].shape[1]
             counts["tokens"] = int(packed[:rows, -n_new:].sum())
+            # the sampler has finished: its routed layers' counters are a
+            # free read here, and ride this span's counts into the cycle row
+            for name, value in (gen_out.get("moe_stats") or {}).items():
+                counts[name] = float(value)
         stats["time/rollout_generate"] = (
             stats.get("time/rollout_generate", 0.0) + time() - t0
         )
@@ -4838,8 +4879,43 @@ class TPUOnlineTrainer(TPUBaseTrainer):
         # the pipeline is retained so guardrail interventions (requeue /
         # rollback) can rebuild the stream and replay untrained prompts
         self._prompt_pipeline = pipeline
+        self._balance_router_bias(pipeline)
         self._build_prompt_iterator()
         self._fast_forward_prompts()
+
+    def _balance_router_bias(self, pipeline) -> None:
+        """A routed model initialised at random, whose configuration asks
+        for it (`router_balance_steps`), has its selection bias balanced on
+        the first chunk of prompts before anything is sampled
+        (`balance_router_bias`). Every copy of the routed layers made at
+        set-up (the frozen reference, a value branch: the top layers of the
+        main segment) takes the same bias; the weights stay as they are."""
+        cfg = getattr(self.model, "cfg", None)
+        steps = getattr(cfg, "router_balance_steps", 0)
+        if not (steps and getattr(cfg, "routed", False) and getattr(self, "_random_init", False)):
+            return
+        rows = min(len(pipeline), self._prompt_chunk_rows())
+        batch = pipeline.collate([pipeline[i] for i in range(rows)])
+        with self.mesh:
+            base, ratios = balance_router_bias(
+                self.model.lm, self.params["base"], jnp.asarray(batch.input_ids),
+                jnp.asarray(batch.attention_mask), steps)
+        bias = base["blocks"]["moe"]["router_bias"]
+
+        def top_layers(tree):
+            old = tree["blocks"]["moe"]["router_bias"]
+            new = jax.device_put(jnp.array(bias[-old.shape[0]:]), old.sharding)
+            return dict(tree, blocks=dict(tree["blocks"], moe=dict(tree["blocks"]["moe"], router_bias=new)))
+
+        self.params = dict(self.params, base=top_layers(base))
+        if "v_branch" in self.params:
+            self.params["v_branch"] = top_layers(self.params["v_branch"])
+        if getattr(self, "ref_params", None) is not None:
+            self.ref_params = top_layers(self.ref_params)
+        before, after = ([round(float(r), 2) for r in row] for row in np.asarray(ratios))
+        logger.info(
+            f"router bias balanced on {rows} prompts in {steps} steps: fullest expert over the "
+            f"mean, by layer, {before} -> {after}")
 
     def _prompt_chunk_rows(self) -> int:
         """Prompts pulled from the stream per chunk (GRPO pulls

@@ -39,6 +39,7 @@ import numpy as np
 
 from trlx_tpu.data import PPORolloutBatch, PromptBatch
 from trlx_tpu.data.method_configs import PPOConfig
+from trlx_tpu.models.transformer import _join_stats, moe_counters
 from trlx_tpu.models.wrappers import CausalLMWithValueHead, Seq2SeqLMWithValueHead
 from trlx_tpu.ops.common import chunked_logprobs, logprobs_of_labels
 from trlx_tpu.ops.ppo import gae_advantages_and_returns, ppo_loss
@@ -262,7 +263,7 @@ class TPUPPOTrainer(TPUOnlineTrainer):
         else:
             logprobs = logprobs_of_labels(out["logits"][:, P - 1 : P + N - 1], tokens[:, P : P + N])
         values_pred = out["values"][:, P - 1 : P + N - 1]
-        return ppo_loss(
+        loss, stats = ppo_loss(
             logprobs=logprobs,
             values=values_pred,
             old_logprobs=batch.logprobs,
@@ -279,6 +280,9 @@ class TPUPPOTrainer(TPUOnlineTrainer):
             # split-microbatch normalizer compensation (_pre_accum_batch)
             norm_n=None if batch.norm_n is None else batch.norm_n[0],
         )
+        # a routed model's counters ride the step's stats (per optimizer
+        # step; the fused block's flush carries their mean over its steps)
+        return loss, dict(stats, **moe_counters(out.get("moe_stats"), "train"))
 
     # -- the method-specific score/assemble seam -------------------------
 
@@ -426,7 +430,10 @@ class TPUPPOTrainer(TPUOnlineTrainer):
                 rewards=-kl_coef * log_ratio,  # scores injected later
                 response_mask=mask,
             )
-            return batch_out, {"mean_kl": mean_kl, "mean_kl_per_token": mean_kl_per_token}
+            # a routed model's counters: the policy's layers and the reference branch's
+            moe_stats = _join_stats(out.get("moe_stats"), out.get("ref_moe_stats"))
+            return batch_out, {"mean_kl": mean_kl, "mean_kl_per_token": mean_kl_per_token,
+                               **moe_counters(moe_stats, "scorer")}
 
         self._experience_fns[key] = jax.jit(ppo_experience_fwd)
         return self._experience_fns[key]
@@ -696,6 +703,9 @@ class TPUPPOTrainer(TPUOnlineTrainer):
         stats["policy/kl_per_token"] = jnp.sqrt(
             jnp.maximum(kl_stats["mean_kl_per_token"], 0.0)
         )
+        # the scorer's routed-layer counters: device scalars, flushed with
+        # the rest of the chunk's deferred stats
+        stats.update({k: v for k, v in kl_stats.items() if k.startswith("moe/")})
         return rollout_batch, len(sequences)
 
     def _apply_staleness_clip(self, rollout_batch: PPORolloutBatch):

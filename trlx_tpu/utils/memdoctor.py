@@ -586,6 +586,10 @@ def estimate_plan(trainer) -> HBMPlan:
         f"sharded over fsdp={shard}" if shard > 1 else "replicated per device"
     )
     params_b = tree_bytes(trainer.params)
+    mc0 = _model_cfg(trainer)
+    if getattr(mc0, "routed", False):
+        shard_note += (f"; {mc0.n_experts_held} of {mc0.n_routed_experts} routed experts a "
+                       "layer are held here, the shared expert whole")
     plan.add("steady", "params", params_b // shard, shard_note)
     opt_b = tree_bytes(trainer.opt_state)
     plan.add("steady", "opt_state", opt_b // shard, shard_note)
@@ -609,11 +613,13 @@ def estimate_plan(trainer) -> HBMPlan:
     E = _hidden(trainer)
     L = _layers(trainer)
     act_size = _dtype_size(train.compute_dtype)
+    streams = int(getattr(_model_cfg(trainer), "residual_streams", 1))
     plan.add(
         "train", "activations",
-        activation_bytes(rows_dev, S, E, L, train.remat_policy, act_size),
+        activation_bytes(rows_dev, S, E * streams, L, train.remat_policy, act_size),
         f"{trainer.num_mb}x accumulation, mb_size {trainer.mb_size}, "
-        f"remat {train.remat_policy!r} (coeff {_act_coeff(train.remat_policy):g})",
+        f"remat {train.remat_policy!r} (coeff {_act_coeff(train.remat_policy):g})"
+        + (f", {streams} residual streams" if streams > 1 else ""),
     )
     V = _vocab(trainer)
     chunks = max(int(train.logit_chunks or 0), 0)
@@ -681,13 +687,17 @@ def estimate_plan(trainer) -> HBMPlan:
             mc = _model_cfg(trainer)
             kv_quant = getattr(mc, "kv_cache_quant", None)
             kv_size = 1 if kv_quant in ("int8", "int8_kernel") else decode_size
-            kv_b = int(
-                2 * L * chunk * S * getattr(mc, "n_kv_head", _heads(trainer))
+            # numbers a cached position costs a row in one layer: 2 x heads
+            # x head size, or a latent cache's rank + rotary channels
+            per_position = getattr(mc, "cache_elems_per_position", None) or (
+                2 * getattr(mc, "n_kv_head", _heads(trainer))
                 * getattr(mc, "head_dim", E // max(_heads(trainer), 1))
-                * kv_size
             )
+            kv_b = int(L * chunk * S * per_position * kv_size)
             plan.add("rollout", "static_kv_cache", kv_b,
-                     f"whole-chunk cache, quant {kv_quant or 'none'}")
+                     f"whole-chunk cache, {per_position} numbers a position a layer"
+                     + (" (latent)" if getattr(mc, "latent", False) else "")
+                     + f", quant {kv_quant or 'none'}")
     except Exception as exc:
         plan.add("rollout", "kv_cache", 0,
                  f"unestimated for this model family ({type(exc).__name__})")
@@ -1124,7 +1134,40 @@ def analytic_param_count(tcfg: Dict[str, Any]) -> int:
     attn = E * (H * D) + E * (2 * Hkv * D) + (H * D) * E + (H * D + 2 * Hkv * D + E)
     mlp = E * I + I * E + I + E
     norms = 4 * E
-    return V * E + P * E + L * (attn + mlp + norms) + 2 * E
+    if not (tcfg.get("kv_lora_rank") or tcfg.get("n_routed_experts")
+            or int(tcfg.get("residual_streams", 1)) > 1):
+        return V * E + P * E + L * (attn + mlp + norms) + 2 * E
+    return _family_param_count(tcfg)
+
+
+def _family_param_count(tcfg: Dict[str, Any]) -> int:
+    """Parameters HELD HERE of a model with latent attention, routed experts
+    (this chip's share: `n_experts_held` of the `n_routed_experts` the router
+    scores) and several residual streams, from the config's numbers alone."""
+    V, E, L = int(tcfg["vocab_size"]), int(tcfg["hidden_size"]), int(tcfg["n_layer"])
+    H, I = int(tcfg["n_head"]), int(tcfg.get("intermediate_size", 4 * E))
+    gated = 3 if tcfg.get("mlp_gated") else 2
+    rank = tcfg.get("kv_lora_rank")
+    if rank:
+        dn, dr, dv = (int(tcfg[k]) for k in ("qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim"))
+        qr = int(tcfg.get("q_lora_rank") or 0)
+        q = E * qr + qr + qr * H * (dn + dr) if qr else E * H * (dn + dr)
+        attn = q + E * (rank + dr) + rank + rank * H * (dn + dv) + H * dv * E
+    else:
+        D = int(tcfg.get("head_dim", E // max(H, 1)))
+        attn = 4 * E * H * D
+    n = int(tcfg.get("residual_streams", 1))
+    mix = 2 * (n * E * (n * n + 2 * n) + 3 + 2 * n + n * n) if n > 1 else 0
+    dense = attn + gated * E * I + 2 * E + mix
+    published = int(tcfg.get("n_routed_experts", 0))
+    if not published:
+        return 2 * V * E + L * dense + E
+    F = int(tcfg["moe_intermediate_size"])
+    held = int(tcfg.get("n_experts_held") or published)
+    routed = (attn + 2 * E + mix + E * published + published  # router and its bias
+              + 3 * E * F * (held + int(tcfg.get("n_shared_experts", 0))))
+    lead = int(tcfg.get("first_k_dense", 0))
+    return 2 * V * E + lead * dense + (L - lead) * routed + E
 
 
 def analytic_plan(
@@ -1211,10 +1254,12 @@ def analytic_plan(
     rows_dev = max(mb // ways, 1)
     S = train.seq_length
     csize = _dtype_size(train.compute_dtype)
+    streams = int(tdict.get("residual_streams", 1))
     plan.add("train", "activations",
-             activation_bytes(rows_dev, S, E, L, train.remat_policy, csize),
+             activation_bytes(rows_dev, S, E * streams, L, train.remat_policy, csize),
              f"mb_size {mb}, remat {train.remat_policy!r} "
-             f"(coeff {_act_coeff(train.remat_policy):g})")
+             f"(coeff {_act_coeff(train.remat_policy):g})"
+             + (f", {streams} residual streams" if streams > 1 else ""))
     gsize = _dtype_size(train.grads_dtype or train.param_dtype)
     plan.add("train", "grads", n_params * gsize // shard,
              f"dtype {train.grads_dtype or train.param_dtype}")
@@ -1262,9 +1307,12 @@ def analytic_plan(
     else:
         kv_quant = tdict.get("kv_cache_quant")
         kv_size = 1 if kv_quant in ("int8", "int8_kernel") else 2
+        latent = tdict.get("kv_lora_rank")
+        per_position = (int(latent) + int(tdict.get("qk_rope_head_dim", 0))) if latent else 2 * Hkv * D
         plan.add("rollout", "static_kv_cache",
-                 int(2 * L * chunk * S * Hkv * D * kv_size),
-                 f"whole-chunk cache, quant {kv_quant or 'none'}")
+                 int(L * chunk * S * per_position * kv_size),
+                 f"whole-chunk cache, {per_position} numbers a position a layer"
+                 + (" (latent)" if latent else "") + f", quant {kv_quant or 'none'}")
 
     exp = dict(getattr(config.method, "exp", None) or {})
     if exp.get("enabled"):
