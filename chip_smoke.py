@@ -296,7 +296,7 @@ def leg_c(ctx) -> None:
         ck = jnp.round(kf / ks[..., None]).astype(jnp.int8)
         vs = jnp.max(jnp.abs(vf), 3) / 127.0  # [L, B, Hkv, D]
         cv = jnp.round(vf / vs[:, :, :, None]).astype(jnp.int8)
-        k_scale = ks[:, :, :, None, :]  # [L, B, Hkv, 1, S]
+        k_scale = ks  # [L, B, Hkv, S]
         v_scale = vs[:, :, :, None, :]  # [L, B, Hkv, 1, D]
         q = rand(12, (Bd, H, D))
         key_mask = (
@@ -307,7 +307,8 @@ def leg_c(ctx) -> None:
 
         def kern(q, ck, cv, k_scale, v_scale):
             return decode_attention_int8(
-                q, ck, cv, k_scale, v_scale[L - 1], key_mask, lx, sm
+                q, ck, cv, k_scale, v_scale[L - 1], key_mask, lx,
+                jnp.int32(S - 1), sm,
             )
 
         def ref(q, ck, cv, k_scale, v_scale):
@@ -316,7 +317,7 @@ def leg_c(ctx) -> None:
             rep = H // Hkv
             qg = q.astype(jnp.float32).reshape(Bd, Hkv, rep, D)
             s = jnp.einsum("bgrd,bgsd->bgrs", qg, ck[L - 1].astype(jnp.float32)) * sm
-            s = s * k_scale[L - 1]
+            s = s * k_scale[L - 1][:, :, None]
             s = jnp.where(key_mask[:, None, None, :] > 0, s, NEG_INF)
             o = jnp.einsum("bgrs,bgsd->bgrd", jax.nn.softmax(s, -1),
                            cv[L - 1].astype(jnp.float32))

@@ -160,10 +160,10 @@ def test_int8_kv_cache_decode_matches_bf16(tiny_lm):
     warm = lm(params, ids, mask, cache=cache, compute_logits=False)
     qcache = quantize_kv_cache(warm["cache"])
     assert qcache["k"].dtype == jnp.int8 and qcache["v"].dtype == jnp.int8
-    # int8 layout is [L, B, Hkv, S, D] with k_scale [L, B, Hkv, 1, S]
+    # int8 layout is [L, B, Hkv, S, D] with k_scale [L, B, Hkv, S]
     deq = np.asarray(qcache["k"], np.float32) * np.asarray(
         qcache["k_scale"], np.float32
-    ).transpose(0, 1, 2, 4, 3)
+    )[..., None]
     ref = np.asarray(warm["cache"]["k"], np.float32).transpose(0, 1, 3, 2, 4)
     # written slots within 1% of full precision; unwritten slots exact 0
     assert np.abs(deq[:, :, :, :P] - ref[:, :, :, :P]).max() <= 0.01 * (
@@ -185,7 +185,7 @@ def test_int8_decode_kernel_matches_fallback():
     )
     lm = TransformerLM(cfg)
     params = lm.init(jax.random.PRNGKey(1))
-    qlm = TransformerLM(dataclasses.replace(cfg, kv_cache_quant="int8_kernel"))
+    qlm = TransformerLM(dataclasses.replace(cfg, kv_cache_quant="int8"))
     B, P, N = 2, 64, 64  # P + N = 128: kernel path engages
     ids = jnp.asarray(np.tile(np.arange(3, 3 + P), (B, 1)), jnp.int32)
     mask = np.ones((B, P), np.int32)
@@ -201,6 +201,123 @@ def test_int8_decode_kernel_matches_fallback():
     # int8 noise may flip a near-tie on a long greedy rollout; demand
     # near-total agreement rather than bitwise equality
     assert agree >= 0.95, f"only {agree:.2%} of greedy tokens agree"
+
+
+def _int8_decode_step(rep, S, write_ix, fused, mesh=None, garbage=False, dtype=jnp.float32):
+    """One decode step of `Attention` over a seeded int8 cache written
+    up to `write_ix - 1`, rows 0 and 1 left-padded by 0 and 3 slots:
+    the fused kernel where `fused`, else the folded-scale XLA branch
+    (the predicate patched to refuse). `garbage` fills every slot past
+    the write index with 127s and NaN scales first."""
+    from unittest import mock
+
+    from trlx_tpu.models import transformer as tr
+
+    B, Hkv, D, L = 4, 2, 16, 2
+    cfg = TransformerConfig(
+        vocab_size=32, hidden_size=Hkv * rep * D, n_layer=L, n_head=Hkv * rep,
+        n_kv_head=Hkv, head_dim=D, n_positions=S, pos_embed="rotary",
+        dtype=dtype, kv_cache_quant="int8",
+    )
+    rng = np.random.default_rng(S + rep)
+    kf = rng.standard_normal((L, B, Hkv, S, D)).astype(np.float32)
+    vf = rng.standard_normal((L, B, Hkv, S, D)).astype(np.float32)
+    ks = np.abs(kf).max(-1) / 127.0  # [L, B, Hkv, S]
+    vs = np.abs(vf).max(3) * 1.25 / 127.0  # [L, B, Hkv, D]
+    ck = np.round(kf / ks[..., None]).astype(np.int8)
+    cv = np.round(vf / vs[:, :, :, None]).astype(np.int8)
+    if garbage:
+        ck[:, :, :, write_ix + 1:] = 127
+        cv[:, :, :, write_ix + 1:] = -127
+        ks[:, :, :, write_ix + 1:] = np.nan
+    slots = np.arange(S)
+    key_mask = ((slots[None] <= write_ix) & (slots[None] >= 3 * (np.arange(B) == 1)[:, None])).astype(np.int32)
+    x = jnp.asarray(rng.standard_normal((B, 1, cfg.hidden_size)), dtype)
+    attn = tr.Attention(cfg, mesh)
+    positions = jnp.full((B, 1), write_ix, jnp.int32)
+    bias = tr.make_attention_bias(jnp.asarray(key_mask), jnp.asarray([write_ix]), jnp.asarray(slots))
+    params = attn.init(jax.random.PRNGKey(0), x, bias, positions)
+
+    def step(write_ix, ck, cv, ks):
+        cache = {"ck": ck, "cv": cv, "ck_scale": ks, "v_scale": jnp.asarray(vs[1])[:, :, None],
+                 "ix": jnp.int32(1), "index": write_ix}
+        return attn.apply(params, x, bias, positions, cache, jnp.asarray(key_mask))[0]
+
+    refuse = (lambda *a: None) if fused else (lambda *a: "the test asks for the XLA branch")
+    with mock.patch.object(tr, "decode_attn_unfused", refuse):
+        out = jax.jit(step)(jnp.int32(write_ix), jnp.asarray(ck), jnp.asarray(cv), jnp.asarray(ks))
+    return np.asarray(out, np.float32)
+
+
+@pytest.mark.parametrize(
+    "rep,S,write_ix",
+    [
+        (1, 640, 300),  # inside the third of five chunks
+        (1, 640, 255),  # a chunk's last slot
+        (1, 640, 256),  # the next chunk's first slot
+        (1, 640, 5),  # in the first chunk: four idle steps a row
+        (4, 640, 300),  # grouped heads: four query rows a kv head
+        (1, 128, 100),  # one chunk
+        (4, 128, 127),
+    ],
+)
+def test_fused_int8_decode_matches_the_xla_branch(rep, S, write_ix, monkeypatch):
+    """The fused decode kernel against the folded-scale XLA branch on
+    the same int8 cache (interpret mode), chunks of 128 slots: the same
+    output wherever the write index lies, with left padding and grouped
+    heads, and nothing past the write index is read (127s and NaN
+    scales there change nothing)."""
+    from trlx_tpu.ops import decode_attention
+
+    monkeypatch.setattr(decode_attention, "CELL_BYTES", 1)  # one 128-slot chunk a cell
+    assert decode_attention.decode_chunk(S, 2, 16) == 128
+    want = _int8_decode_step(rep, S, write_ix, fused=False)
+    got = _int8_decode_step(rep, S, write_ix, fused=True, garbage=True)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
+
+
+def test_fused_int8_decode_on_a_four_device_mesh():
+    """GSPMD cannot partition a Mosaic call: on a mesh the kernel runs
+    under shard_map (rows over dp x fsdp, heads over tp), one row a
+    device here, and gives what the XLA branch gives unmeshed; rows the
+    mesh does not divide keep the XLA branch, with the reason."""
+    from trlx_tpu.models.transformer import decode_attn_unfused
+    from trlx_tpu.parallel import make_mesh
+
+    mesh = make_mesh({"dp": 1, "fsdp": 4}, devices=jax.devices()[:4])
+    want = _int8_decode_step(1, 256, 200, fused=False)
+    got = _int8_decode_step(1, 256, 200, fused=True, mesh=mesh, garbage=True)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
+    cfg = TransformerConfig(vocab_size=32, hidden_size=32, n_layer=1, n_head=2, kv_cache_quant="int8")
+    assert decode_attn_unfused(cfg, mesh, 8, 256) is None
+    assert "does not divide 6 rows" in decode_attn_unfused(cfg, mesh, 6, 256)
+    assert "128-slot" in decode_attn_unfused(cfg, None, 8, 200)
+    assert "alibi" in decode_attn_unfused(cfg.replace(pos_embed="alibi"), None, 8, 256)
+
+
+def test_int8_kernel_is_no_value_of_kv_cache_quant():
+    with pytest.raises(ValueError, match='"int8"'):
+        TransformerConfig(vocab_size=8, hidden_size=8, n_layer=1, n_head=1, kv_cache_quant="int8_kernel")
+
+
+def test_chunks_streamed_is_the_bound_at_the_write_index():
+    """The `tokens_wait` span's chunk counts, at the longgen cell's
+    shapes: 895 steps from slot 128 of 1024 in chunks of 256 read 68%
+    of the cache they hold; a cache that is full from the start reads
+    all of it; a sampler that is not fused has no grid."""
+    from trlx_tpu.models.generation import chunks_streamed, fused_decode_cells
+
+    got = chunks_streamed(steps=895, cells=22 * 8, chunk=256, slots=1024, first_write=128)
+    assert got == {"cache_chunks_read": 176 * 2428, "cache_chunks_held": 176 * 895 * 4}
+    full = chunks_streamed(steps=127, cells=1, chunk=256, slots=2048, first_write=1920)
+    assert full["cache_chunks_read"] == full["cache_chunks_held"] == 127 * 8
+    cfg = TransformerConfig(vocab_size=32, hidden_size=32, n_layer=3, n_head=2,
+                            attention_impl="pallas", kv_cache_quant="int8")
+    assert fused_decode_cells(TransformerLM(cfg), 8, 0, 120, 8) == {
+        "cells": 24, "chunk": 128, "slots": 128, "first_write": 120}
+    assert fused_decode_cells(TransformerLM(cfg), 8, 0, 120, 1) is None  # no decode step
+    assert fused_decode_cells(TransformerLM(cfg.replace(attention_impl="xla")), 8, 0, 100, 8) is None
+    assert fused_decode_cells(TransformerLM(cfg.replace(kv_cache_quant=None)), 8, 0, 120, 8) is None
 
 
 def test_generate_program_and_its_stages_are_named(tiny_lm):

@@ -536,14 +536,19 @@ def test_counters_reach_the_cycle_row():
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
     try:
-        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     except Exception as e:  # no libtpu here, or another process holds it
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -563,5 +568,44 @@ def test_mosaic_compiles_the_flash_kernels_at_keys_192_values_128(one_chip, monk
                                        shape(2, 32, 1024, 128), shape(2, 1024, dt=jnp.int32)).compile()
         text = compiled.as_text()
         assert all(name in text for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"))
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+
+
+def test_mosaic_compiles_the_int8_decode_kernel_at_the_cells_shapes(topo, one_chip, monkeypatch):
+    """The fused decode kernel over the int8 cache, compiled by Mosaic at
+    the shapes of the three Pythia cells: one chip at 16 heads x 1024 and
+    2048 slots, and the four-chip cell's 16 rows x 32 heads under
+    shard_map on a described 2x2 mesh (4 rows a chip, no collective)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from trlx_tpu.ops import decode_attention as da
+    from trlx_tpu.parallel import make_mesh
+
+    monkeypatch.setattr(da, "_interpret", lambda: False)
+    mesh = make_mesh({"dp": 1, "fsdp": 4}, devices=topo.devices)
+    rows = ("dp", "fsdp")
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        for on, (L, B, H, S) in ((None, (22, 8, 16, 1024)), (None, (22, 8, 16, 2048)), (mesh, (12, 16, 32, 1024))):
+            def shape(*s, dt, spec=P()):
+                sharding = one_chip if on is None else NamedSharding(on, spec)
+                return jax.ShapeDtypeStruct(s, dt, sharding=sharding)
+
+            def step(q, ck, cv, ks, vs, mask, lx, w):
+                return da.decode_attention_on_mesh(on, q, ck, cv, ks, vs, mask, lx, w, 128 ** -0.5)
+
+            stacked = P(None, rows)
+            text = jax.jit(step).lower(
+                shape(B, H, 128, dt=jnp.bfloat16, spec=P(rows)),
+                shape(L, B, H, S, 128, dt=jnp.int8, spec=stacked),
+                shape(L, B, H, S, 128, dt=jnp.int8, spec=stacked),
+                shape(L, B, H, S, dt=jnp.float32, spec=stacked),
+                shape(B, H, 1, 128, dt=jnp.float32, spec=P(rows)),
+                shape(B, S, dt=jnp.int32, spec=P(rows)),
+                shape(dt=jnp.int32), shape(dt=jnp.int32),
+            ).compile().as_text()
+            assert "decode_attn" in text and "tpu_custom_call" in text
+            assert "all-gather" not in text and "all-reduce" not in text
     finally:
         jax.config.update("jax_enable_compilation_cache", True)

@@ -49,7 +49,7 @@ TIER1_BUDGETS = {
     "test_elastic.py": 34,
     "test_examples.py": 1,
     "test_exp_queue.py": 29,
-    "test_fault_tolerance.py": 63,
+    "test_fault_tolerance.py": 53,
     "test_flash_attention.py": 14,
     "test_fleet.py": 35,
     # PR 26: the stop of the backward pass at the hydra branch point —
@@ -66,7 +66,19 @@ TIER1_BUDGETS = {
     # (0.1), graft_lint 8->7 (6.2).
     "test_frozen_trunk.py": 33,
     "test_gen_engine.py": 34,
-    "test_generation.py": 14,
+    # PR 30: the fused int8 decode kernel against the XLA branch (seven
+    # interpret-mode cases through `Attention`, one on a four-device
+    # mesh, the host's chunk arithmetic), one flight-stream test in
+    # test_obs and one Mosaic compile in test_latent_moe: 68 s alone for
+    # this file (40 s before), 91.5 s inside the driver's 6-worker run
+    # (2026-10-02), whose files took 2,140 s against the 780 budgeted:
+    # the table's scale is in-run seconds / 2.74. Budgeted 26 (33 on
+    # that scale less the slack the other int8 tests had), obs 25->26
+    # (76.1 s = 27.7), latent_moe 42->43 (125.3 s = 45.7). Paid under
+    # the unchanged 780 ceiling with times of the same run on that
+    # scale: fault_tolerance 63->53 (90.8 s = 33.1), guardrails 103->99
+    # (196.7 s = 71.7).
+    "test_generation.py": 26,
     "test_golden.py": 3,
     # r13: graft-lint suite (pure-AST checker units + one whole-repo
     # lint + two tiny jax-free subprocesses) — measured ~5.2s serial on
@@ -78,7 +90,7 @@ TIER1_BUDGETS = {
     "test_grpo.py": 40,
     # r09: +4 preference-RL chaos learn() tests (GRPO nan/sigterm, DPO
     # nan/sigterm); whole file re-measured 99.9s serial
-    "test_guardrails.py": 103,
+    "test_guardrails.py": 99,
     # PR 28: the routed / latent-attention / four-stream family against its
     # float32 reference (logits, trainable gradients, cache decode, shares,
     # hydra cuts, int8 rollout weights, one Mosaic compile at keys 192 /
@@ -92,7 +104,7 @@ TIER1_BUDGETS = {
     # (34.7 s in the run = 17), scanned_epochs 46->31 (30.1 s = 15),
     # reference_harness 4->1 (0.4 s), curves 2->1 (0.1 s), deferred_stats
     # 2->1 (0.5 s), net 3->2 (2.8 s = 1.4), marker_audit 2->1 (0.2 s).
-    "test_latent_moe.py": 42,
+    "test_latent_moe.py": 43,
     "test_marker_audit.py": 1,
     "test_mcts_value_branch.py": 5,
     # r10: memory-doctor suite (ladder units are fake-clock-fast; the
@@ -126,7 +138,7 @@ TIER1_BUDGETS = {
     # scanned_epochs 50->46 (42.4), gen_engine 40->36 (32.6),
     # memdoctor 40->37 (32), elastic 35->34 (32.0), exp_queue 30->29
     # (28.2), models 18->17 (16.2), peft 15->14 (13.9).
-    "test_obs.py": 25,
+    "test_obs.py": 26,
     # r15: paged-attention kernel + sharded lanes + trunk-sharing suite
     # (op-level kernel parity grid, engine pallas==xla goldens incl.
     # the spec verify forward, trunk-shared pool accounting, grouped-

@@ -652,7 +652,7 @@ def test_backward_depth_gauges_once_per_built_step(faultfree_run, tmp_path):
     trainer, ckpt_dir = faultfree_run
     rows = list(iter_rows(os.path.join(ckpt_dir, "flight")))
     end = [r["kind"] for r in rows].index("run_end")
-    built = [r for r in rows[:end] if r["kind"] == "gauge"]
+    built = [r for r in rows[:end] if r["kind"] == "gauge" and "model/layers" in r]
     # learn() builds the per-step program and the fused block, once each
     assert [(r["model/layers"], r["model/backward_layers"]) for r in built] == [(2, 2)] * 2
     with open(os.path.join(ckpt_dir, "logs", "metrics.jsonl")) as f:
@@ -671,6 +671,36 @@ def test_backward_depth_gauges_once_per_built_step(faultfree_run, tmp_path):
     built = [r for r in iter_rows(os.path.join(hydra_dir, "flight"))
              if r["kind"] == "gauge"]
     assert [(r["model/layers"], r["model/backward_layers"]) for r in built] == [(2, 1)] * 2
+
+
+def test_fused_decode_gauge_and_chunk_counts_reach_the_flight_stream(faultfree_run, tmp_path):
+    """`gen/decode_attn_fused` lands once per built sampler, in the
+    flight stream and the tracker: 0 for the shared run (no int8 cache),
+    1 for a sampler over an int8 cache of whole 128-slot tiles, whose
+    `tokens_wait` span then carries the chunks its decode steps
+    streamed and held (7 steps of 8 rows in 2 layers, one chunk each)."""
+    from trlx_tpu.utils.loading import get_trainer
+
+    trainer, ckpt_dir = faultfree_run
+    rows = list(iter_rows(os.path.join(ckpt_dir, "flight")))
+    assert {r["gen/decode_attn_fused"] for r in rows if "gen/decode_attn_fused" in r} == {0}
+
+    fused_dir = str(tmp_path / "ckpts")
+    config = _tiny_ppo_config(fused_dir)
+    config.model.model_extra_configs["transformer"].update(
+        n_positions=128, kv_cache_quant="int8")
+    config.method.gen_kwargs["eos_token_id"] = -1
+    trainer = get_trainer(config.train.trainer)(config=config)
+    trainer.obs.start(step=0)
+    out = trainer.generate(np.ones((8, 120), np.int32))
+    trainer.generate(np.ones((8, 120), np.int32))  # the sampler is built once
+    trainer._pull_sampled_tokens(out, 8, {})
+    trainer.obs.end_cycle(step=0)
+    rows = list(iter_rows(os.path.join(fused_dir, "flight")))
+    assert [r["gen/decode_attn_fused"] for r in rows if r["kind"] == "gauge"] == [1]
+    (cycle,) = [r for r in rows if r["kind"] == "cycle"]
+    (counts,) = [c for name, *_, c in cycle["spans"] if name == "tokens_wait"]
+    assert counts == {"rows": 8, "tokens": 64, "cache_chunks_read": 112, "cache_chunks_held": 112}
 
 
 def test_cycle_programs_carry_their_own_names(faultfree_run):

@@ -154,9 +154,7 @@ class GenEngineConfig:
         """Concretize against a call's batch width and the model."""
         quant = self.kv_quant
         if quant is None:
-            quant = "int8" if model_cfg.kv_cache_quant in (
-                "int8", "int8_kernel"
-            ) else "none"
+            quant = "int8" if model_cfg.kv_cache_quant == "int8" else "none"
         slots = self.slots or batch
         if batch:
             slots = min(slots, batch)

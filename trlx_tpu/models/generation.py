@@ -220,6 +220,58 @@ def cast_params_for_decode(params: Dict, compute_dtype) -> Dict:
     )
 
 
+def cache_slots(cfg, n_virt: int, P: int, N: int) -> int:
+    """Slots generate() allocates a row: virtual prefix, prompt and new
+    tokens, and under `attention_impl="pallas"` rounded up to 128 —
+    Mosaic needs a 128-aligned cache length to lower the prefill's
+    chunked loads (the pad slots stay masked and decode never reaches
+    them). Gated on the prefill actually qualifying for the kernel
+    (Attention also needs 8-row-aligned queries, P % 8 == 0): when the
+    prefill will fall back to XLA anyway, the pad would just inflate
+    cache memory and every decode step's masked score width for
+    nothing — same reason the plain XLA path skips it."""
+    total = n_virt + P + N
+    if cfg.attention_impl == "pallas" and P % 8 == 0:
+        total += (-total) % 128
+    return total
+
+
+def fused_decode_cells(
+    model: TransformerLM, rows: int, n_virt: int, P: int, N: int
+) -> Optional[Dict[str, int]]:
+    """The grid the fused decode kernel walks for a sampler of this
+    shape, or None where its decode steps run something else (no int8
+    cache, a single new token, or `decode_attn_unfused`). Host
+    arithmetic: what Attention will see, without tracing it."""
+    from trlx_tpu.models.transformer import decode_attn_unfused
+    from trlx_tpu.ops.decode_attention import decode_chunk
+
+    cfg = model.cfg
+    slots = cache_slots(cfg, n_virt, P, N)
+    if cfg.kv_cache_quant != "int8" or N < 2 or decode_attn_unfused(cfg, model.mesh, rows, slots):
+        return None
+    return {
+        "cells": cfg.n_layer * rows,  # (layer, row) pairs a decode step
+        "chunk": decode_chunk(slots, cfg.n_kv_head, cfg.head_dim),
+        "slots": slots,
+        "first_write": n_virt + P,  # the slot the first decode step writes
+    }
+
+
+def chunks_streamed(steps: int, cells: int, chunk: int, slots: int, first_write: int) -> Dict[str, int]:
+    """Chunks of the int8 cache that `steps` decode steps streamed
+    (`cache_chunks_read`: step i writes slot first_write + i and reads
+    the chunks up to it) and the chunks allocated to them
+    (`cache_chunks_held`): their ratio is the share of the cache that
+    the bounded pass read."""
+    steps = max(steps, 0)
+    read = sum((first_write + i) // chunk + 1 for i in range(steps))
+    return {
+        "cache_chunks_read": cells * read,
+        "cache_chunks_held": cells * steps * (slots // chunk),
+    }
+
+
 def generate(
     model: TransformerLM,
     params: Dict,
@@ -272,21 +324,8 @@ def generate(
         n_virt = soft_prompt.shape[0]
     elif kv_prefix is not None:
         n_virt = kv_prefix["k"].shape[1]
-    # pallas only: round the cache up to 128 slots — Mosaic needs a
-    # 128-aligned cache length to lower the prefill's chunked loads (the
-    # pad slots stay masked below and decode never reaches them). Gated
-    # on the prefill actually qualifying for the kernel (Attention also
-    # needs 8-row-aligned queries, P % 8 == 0): when the prefill will
-    # fall back to XLA anyway, the pad would just inflate cache memory
-    # and every decode step's masked score width for nothing — same
-    # reason the plain XLA path skips it.
-    total = n_virt + P + N
-    pad_slots = (
-        (-total) % 128
-        if model.cfg.attention_impl == "pallas" and P % 8 == 0
-        else 0
-    )
-    total += pad_slots
+    total = cache_slots(model.cfg, n_virt, P, N)
+    pad_slots = total - (n_virt + P + N)
 
     # response slots count as attendable keys once written
     key_mask = jnp.concatenate(
@@ -370,7 +409,7 @@ def generate(
     # out of the loop with the tokens (transformer.moe_counters)
     moe_stats = out.get("moe_stats") or {}
     decode_cache = out["cache"]
-    if model.cfg.kv_cache_quant in ("int8", "int8_kernel"):
+    if model.cfg.kv_cache_quant == "int8":
         # quantize ONCE after prefill (prefill numerics/pallas path stay
         # untouched); every decode step then reads an int8 cache stream
         # — half the HBM traffic of bf16, which is what bounds decode at

@@ -95,15 +95,15 @@ class TransformerConfig:
     # score tensor never exists, so training at 8k+ tokens is where it
     # pays for itself.
     attention_impl: str = "xla"
-    # None | "int8" | "int8_kernel": generate() quantizes the KV cache
-    # after prefill so the decode loop's full-cache read rides an int8
-    # stream (half the HBM traffic of bf16 — decode at large batch×seq
-    # is bound on exactly that read). Prefill numerics are untouched;
-    # decode picks up symmetric quantization noise (bounded in
-    # tests/test_generation.py). "int8" drives the folded-scale XLA
-    # path; "int8_kernel" additionally routes aligned caches through
-    # the pallas decode kernel (slower on v5e today — see the measured
-    # note in Attention's int8 branch — kept for tuning).
+    # None | "int8": generate() quantizes the KV cache after prefill so
+    # the decode loop's full-cache read rides an int8 stream (half the
+    # HBM traffic of bf16 — decode at large batch×seq is bound on
+    # exactly that read). Prefill numerics are untouched; decode picks
+    # up symmetric quantization noise (bounded in
+    # tests/test_generation.py). A decode step over such a cache runs
+    # the fused kernel (ops/decode_attention.py) wherever
+    # `decode_attn_unfused` finds nothing against it, and the
+    # folded-scale XLA branch of Attention elsewhere.
     kv_cache_quant: Optional[str] = None
     # None | "int8": generate() rewrites block kernels to int8 +
     # per-output-channel scales for the rollout (prefill AND decode run
@@ -215,6 +215,12 @@ class TransformerConfig:
             object.__setattr__(self, "rotary_dim", self.head_dim)
         if self.routed and self.n_experts_held is None:
             object.__setattr__(self, "n_experts_held", self.n_routed_experts)
+        if self.kv_cache_quant not in (None, "int8"):
+            raise ValueError(
+                f"kv_cache_quant={self.kv_cache_quant!r}: the values are None and "
+                '"int8" ("int8" runs the fused decode kernel wherever the cache\'s '
+                "shape, the mask and the mesh allow it; no other value selects it)"
+            )
         self._check_family()
 
     def _check_family(self) -> None:
@@ -346,6 +352,38 @@ def alibi_slopes(n_head: int) -> Array:
 # ---------------------------------------------------------------------------
 # Modules (params are plain arrays; composition is functional below)
 # ---------------------------------------------------------------------------
+
+
+def decode_attn_unfused(cfg: "TransformerConfig", mesh, batch: int, slots: int) -> Optional[str]:
+    """Why a decode step (T == 1) of `batch` rows over an int8 cache of
+    `slots` slots takes Attention's folded-scale XLA branch, or None
+    where it takes the fused kernel (ops/decode_attention.py). Static:
+    Attention asks at trace time, the trainer on the host for the gauge
+    `gen/decode_attn_fused`."""
+    if cfg.attn_scale is not None or cfg.pos_embed == "alibi" or cfg.local_window is not None:
+        return "attn_scale, alibi or a local window: the kernel has 1/sqrt(D) and a key mask alone"
+    if slots % 128:
+        return f"a cache of {slots} slots is not whole 128-slot tiles"
+    if mesh is not None and mesh.size > 1:
+        if mesh.shape["pp"] > 1:
+            return "a pipelined mesh: decode runs outside the pipeline's shard_map"
+        data, tp = mesh.shape["dp"] * mesh.shape["fsdp"], mesh.shape["tp"]
+        if batch % data or cfg.n_head % tp or cfg.n_kv_head % tp:
+            return (
+                f"mesh {dict(mesh.shape)} does not divide {batch} rows over dp*fsdp={data} "
+                f"and {cfg.n_head}/{cfg.n_kv_head} heads over tp={tp}"
+            )
+    return None
+
+
+@functools.lru_cache(maxsize=None)
+def _warn_decode_unfused(why: str) -> None:
+    """One warning per distinct reason (the cache is the log-once)."""
+    from trlx_tpu.utils import logging
+
+    logging.get_logger(__name__).warning(
+        "kv_cache_quant=int8: decode attention runs the XLA branch, not the fused kernel (%s)", why
+    )
 
 
 class Norm(nn.Module):
@@ -490,10 +528,8 @@ class Attention(nn.Module):
                 )
                 cks = jax.lax.dynamic_update_slice(
                     cache["ck_scale"],
-                    ks.transpose(0, 2, 1)[:, :, None][None].astype(
-                        cache["ck_scale"].dtype
-                    ),
-                    (ix, 0, 0, 0, idx),
+                    ks.transpose(0, 2, 1)[None].astype(cache["ck_scale"].dtype),
+                    (ix, 0, 0, idx),
                 )
                 new_kv = {"ck": ck, "cv": cv, "ck_scale": cks}
                 S = ck.shape[3]
@@ -502,34 +538,39 @@ class Attention(nn.Module):
                     and cfg.pos_embed != "alibi"
                     and cfg.local_window is None
                 )
-                if (
-                    cfg.kv_cache_quant == "int8_kernel"
-                    and T == 1
-                    and plain
-                    and key_mask is not None
-                    and S % 128 == 0
-                ):
-                    # fused pallas decode kernel: int8 K/V stream
-                    # straight from the full carried buffer
-                    # (scalar-prefetched layer index), scales folded
-                    # in-kernel. Measured SLOWER than the folded-scale
-                    # XLA path below at 1.3B b8 seq2048 on v5e (0.185
-                    # vs ~0.13 ms/layer — per-cell M=1 dots underuse
-                    # the MXU), so it is opt-in until tuned; kept
-                    # because its per-cell VMEM streaming is the right
-                    # shape for longer caches (ops/decode_attention.py)
+                fused = False
+                if T == 1:
+                    unfused = (
+                        "no key mask" if key_mask is None
+                        else decode_attn_unfused(cfg, self.mesh, B, S)
+                    )
+                    if unfused:
+                        _warn_decode_unfused(unfused)
+                    fused = not unfused
+                if fused:
+                    # ONE fused pass (ops/decode_attention.py): the
+                    # layer's int8 K/V stream straight from the carried
+                    # buffers (scalar-prefetched layer index), scales,
+                    # mask, online softmax and weighted sum in VMEM, and
+                    # chunks past the write index are neither fetched
+                    # nor computed. The XLA branch below took 162 us a
+                    # layer a step at S = 1024 and 159 us at S = 2048
+                    # (b8, 16 heads of 128; ledger, PR 28: its score
+                    # fusion read at 147 GB/s and 510 GB/s)
                     from trlx_tpu.ops.decode_attention import (
-                        decode_attention_int8,
+                        decode_attention_on_mesh,
                     )
 
-                    kernel_out = decode_attention_int8(
-                        q[:, 0], ck, cv, cks, layer_vs, key_mask, ix,
-                        sm_scale=1.0 / math.sqrt(D),
-                    )[:, None]  # [B, 1, H, D]
+                    with jax.named_scope("decode_attn"):
+                        kernel_out = decode_attention_on_mesh(
+                            self.mesh, q[:, 0], ck, cv, cks, layer_vs,
+                            key_mask, ix, idx, sm_scale=1.0 / math.sqrt(D),
+                        )[:, None]  # [B, 1, H, D]
                 elif plain:
-                    # folded-scale XLA path (the production "int8"
-                    # decode): keep K/V int8 end to end — the per-slot
-                    # K scale rides the [B,H,T,S] scores (fuses into
+                    # folded-scale XLA path (what "int8" runs where the
+                    # kernel's conditions do not hold, and the reference
+                    # its tests compare with): keep K/V int8 end to end —
+                    # the per-slot K scale rides the [B,H,T,S] scores (fuses into
                     # the softmax chain), the per-channel V scale rides
                     # the [B,T,H,D] output; nothing S-sized is ever
                     # dequantized to HBM
@@ -542,7 +583,7 @@ class Attention(nn.Module):
                         )  # [B, Hkv, S, D]
                         ks_l = jax.lax.dynamic_index_in_dim(
                             cks, ix, 0, keepdims=False
-                        )  # [B, Hkv, 1, S]
+                        )[:, :, None]  # [B, Hkv, 1, S]
                         if Hkv != H:
                             rep = H // Hkv
                             k_i8 = jnp.repeat(k_i8, rep, axis=1)
@@ -569,7 +610,7 @@ class Attention(nn.Module):
                         .astype(jnp.float32)
                         * jax.lax.dynamic_index_in_dim(
                             cks, ix, 0, keepdims=False
-                        ).transpose(0, 1, 3, 2)
+                        )[..., None]
                     ).astype(cfg.dtype).transpose(0, 2, 1, 3)
                     v = (
                         jax.lax.dynamic_index_in_dim(cv, ix, 0, keepdims=False)
@@ -839,9 +880,12 @@ def quantize_kv_cache(cache: Dict) -> Dict:
 
     Layout change: the bf16 cache is [L, B, S, Hkv, D]; the quantized
     cache is [L, B, Hkv, S, D] — kv-head OUTSIDE the slot axis, so the
-    fused decode kernel's per-(batch, kv-head) grid cells read plain
-    trailing (S, D) tiles (ops/decode_attention.py). Scales: K per
-    (layer, batch, kv-head, slot) over D, stored [L, B, Hkv, 1, S]; V
+    fused decode kernel's grid cells (a row's heads over a chunk of
+    slots) read plain trailing (S, D) tiles (ops/decode_attention.py).
+    Scales: K per (layer, batch, kv-head, slot) over D, stored
+    [L, B, Hkv, S] (whole (Hkv, S) tiles: with a unit axis before S a
+    decode step's 128 new scales landed in 128 tiles, 12.7 us a layer
+    on the chip, PR 30); V
     per (layer, batch, kv-head, channel) over the slot axis, stored
     [L, B, Hkv, 1, D] and FROZEN here — decode writes saturate against
     it. The 1.25x headroom covers new tokens whose |v| drifts past the
@@ -859,7 +903,7 @@ def quantize_kv_cache(cache: Dict) -> Dict:
     ).astype(jnp.int8)
     out = dict(
         cache, k=kq, v=vq,
-        k_scale=ks[:, :, :, None].astype(jnp.float32),
+        k_scale=ks.astype(jnp.float32),
         v_scale=vs[:, :, :, None].astype(jnp.float32),
     )
     out.pop("static_index", None)  # decode loops carry arrays only

@@ -2,9 +2,10 @@
 
 Two kernel families live here behind the two decode-cache layouts:
 
-  * ``decode_attention_int8`` — the original contiguous-layout kernel
-    (stacked [L, B, Hkv, S, D] int8 caches, one (batch, kv-head) grid
-    cell streaming its S-width rows; design notes below).
+  * ``decode_attention_int8`` — the contiguous-layout kernel (stacked
+    [L, B, Hkv, S, D] int8 caches): what ``kv_cache_quant="int8"`` runs
+    at every decode step whose cache is whole 128-slot tiles
+    (``transformer.decode_attn_unfused``); design notes below.
   * ``paged_attention_pallas`` (selected through
     ``paged_attention_step(impl="pallas")``) — the paged-pool kernel:
     the slot→page table becomes the block index map, so K/V pages load
@@ -14,17 +15,25 @@ Two kernel families live here behind the two decode-cache layouts:
     decode step and the T=draft_k speculative verify forward.
 
 
-Decode at large batch×seq is bound on the full-cache read every step
-(1.61 GB int8 at 1.3B b8 seq2048). Driving that read through XLA ops
-costs three extra O(S·D) materializations per layer (measured via
-profile trace, 2026-07-31: the int8→bf16 convert un-fuses from the AV
-dot, the QK dot runs as a kLoop fusion at ~60% of the read roofline,
-and a per-token V dequant costs a 0.56 ms/step probs multiply). This
-kernel does the whole per-layer attention step in one pass: each
-(batch, kv-head) grid cell streams its int8 K/V rows into VMEM once,
-computes fp32 scores with the per-slot K scales folded in, runs an
-online softmax, and applies the per-channel V scales to the tiny
-[rep, D] output — nothing S-sized ever goes back to HBM.
+Decode at large batch×seq is bound on the cache read every step. Driven
+through XLA ops that read costs the whole ALLOCATED cache whatever has
+been written, at a rate the compiler's choice of window decides: the
+folded-scale branch of transformer.Attention took 162 us a layer a step
+over b8 x 16 heads x 1024 slots and 159 us over 2048 (ledger, PR 28:
+33.5 and 67 MB of int8; its score fusion, a VPU multiply-reduce over a
+cache XLA had re-laid as [L, S, Hkv, B, D], read at 147 and 510 GB/s).
+This kernel does a layer's attention step in one pass over the WRITTEN
+cache: a grid cell streams all heads of one row over a chunk of slots
+into VMEM, computes fp32 scores on the MXU from int8 widened to the
+compute dtype (exact) with the per-slot K scales folded in, runs an
+online softmax across the row's chunks, and applies the per-channel V
+scales to the tiny [H, D] output — nothing S-sized goes back to HBM,
+and chunks past the write index are neither fetched nor computed. On the
+chip (PR 30, same shapes): 52 us with all 1024 slots written, 20 us with
+a quarter of them, 97-100 us at 2048: 650-690 GB/s of written bytes.
+Both products push every int8 tile through the MXU as its stationary
+operand, 128 x 128 a unit in 128 cycles: about 770 GB/s of int8 over the
+four units, so MXU and HBM (819 GB/s) bound it alike.
 
 Layer indexing: the decode loop scans over layers carrying the stacked
 [L, B, Hkv, S, D] buffers; the layer index arrives as a SCALAR-PREFETCH
@@ -33,9 +42,8 @@ full carried buffer — slicing the layer out in XLA first would
 materialize a 33 MB copy per layer per step, which is the exact
 traffic the kernel exists to avoid.
 
-Scale layout (chosen so both dequants commute out of the reductions —
-see transformer.Attention's int8 branch for the measured alternative):
-  k_scale [L, B, Hkv, 1, S] fp32 — multiplies scores per key slot
+Scale layout (chosen so both dequants commute out of the reductions):
+  k_scale [L, B, Hkv, S] fp32 — multiplies scores per key slot
   v_scale [L, B, Hkv, 1, D] fp32 — multiplies the output per channel
 
 The reference has no decode-attention kernel at all: its rollout
@@ -56,7 +64,11 @@ from jax.experimental.pallas import tpu as pltpu
 from trlx_tpu.ops.common import interpret_mode as _interpret
 
 NEG_INF = -1e30
-CHUNK = 512  # fp32 score tile per in-kernel step: [rep, CHUNK]
+# int8 K (and V) bytes of one grid cell of the dense decode kernel. On the
+# chip (PR 30, b8 x 16 heads x 128, S = 1024, us a call with 201, 576 and
+# 1024 slots written): 256 slots a cell 20, 44, 52; 512 slots 32, 52, 52;
+# 128 slots 26, 44, 64 (there the grid, not the memory, sets the pace)
+CELL_BYTES = 1 << 19
 
 
 def paged_attention_step(
@@ -367,128 +379,205 @@ def paged_attention_pallas(
     )
 
 
+def decode_chunk(S: int, Hkv: int, D: int) -> int:
+    """Slots of one grid cell of :func:`decode_attention_int8`: the
+    largest multiple of 128 that divides `S`, keeps a cell's int8 K
+    block (all `Hkv` heads of a row) within CELL_BYTES and is at most
+    512 (the step in which the bound at the write index moves); never
+    under 128 (the lane width of the mask and scale blocks)."""
+    n = S // 128
+    cap = min(4, max(1, CELL_BYTES // (Hkv * D * 128)))
+    return 128 * max(d for d in range(1, n + 1) if n % d == 0 and d <= cap)
+
+
 def _decode_kernel(
-    lx_ref,  # scalar prefetch: [1] layer index (consumed by index maps)
-    q_ref,  # [1, 1, rep, D]
-    k_ref,  # [1, 1, 1, S, D] int8
-    v_ref,  # [1, 1, 1, S, D] int8
-    ks_ref,  # [1, 1, 1, 1, S] f32
-    vs_ref,  # [1, 1, 1, D] f32 (per-layer slice; no layer axis)
-    mask_ref,  # [1, 1, S] int32
-    o_ref,  # [1, 1, rep, D]
+    sp_ref,  # scalar prefetch [2]: layer index, leading steps to skip
+    q_ref,  # [1, H, D]
+    k_ref,  # [1, 1, Hkv, ck, D] int8
+    v_ref,  # [1, 1, Hkv, ck, D] int8
+    ks_ref,  # [1, 1, Hkv, ck] f32
+    vs_ref,  # [1, Hkv, 1, D] f32 (per-layer slice; no layer axis)
+    mask_ref,  # [1, 1, ck] int32
+    o_ref,  # [1, H, D]
+    o_scr,  # [H, D] f32: the row's running weighted sum
+    m_scr,  # [H, 1] f32: running max
+    l_scr,  # [H, 1] f32: running denominator
     *,
     sm_scale,
-    n_chunks,
-    ck,
+    rep,
 ):
-    rep, D = q_ref.shape[2], q_ref.shape[3]
-    q = q_ref[0, 0].astype(jnp.float32)  # [rep, D]
+    """One (batch row, S chunk) cell: all heads of the row against the
+    chunk's keys and values, folded into the row's online softmax.
 
-    def body(j, carry):
-        o_acc, m_run, l_run = carry
-        k_c = k_ref[0, 0, 0, pl.ds(j * ck, ck), :].astype(jnp.float32)
-        ks_c = ks_ref[0, 0, 0, 0, pl.ds(j * ck, ck)]  # [ck]
-        mk = mask_ref[0, 0, pl.ds(j * ck, ck)]  # [ck]
-        s = jax.lax.dot_general(
-            q, k_c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [rep, ck]
-        # per-slot K dequant + softmax scale fold into the score tile
-        s = s * (ks_c * sm_scale)[None, :]
-        s = jnp.where(mk[None, :] > 0, s, NEG_INF)
+    A product runs all H query rows against ONE kv head's tile and the
+    rows of that head's group are kept (`where` on the row index): the
+    MXU's time is the tile it loads, not the rows that stream through
+    it, so the other rows cost nothing, every product has H rows
+    whatever `rep` is, and scores and probabilities stay whole
+    [H, ck] tiles for the softmax."""
+    j = pl.program_id(1)
+    H, D = q_ref.shape[1], q_ref.shape[2]
+    Hkv, ck = k_ref.shape[2], k_ref.shape[3]
 
+    @pl.when(j == 0)
+    def _init():
+        o_scr[...] = jnp.zeros_like(o_scr)
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+
+    @pl.when(j >= sp_ref[1])  # the row's leading steps: nothing to fetch or run
+    def _chunk():
+        q = q_ref[0]  # [H, D], compute dtype
+        group = jax.lax.broadcasted_iota(jnp.int32, (H, 1), 0) // rep
+        s = jnp.zeros((H, ck), jnp.float32)
+        for h in range(Hkv):  # static unroll: one 2D product a kv head
+            s_h = jax.lax.dot_general(
+                q, k_ref[0, 0, h].astype(q.dtype), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # [H, ck]
+            # per-slot K dequant + softmax scale fold into the score tile
+            s = jnp.where(group == h, s_h * (ks_ref[0, 0, h:h + 1] * sm_scale), s)
+        s = jnp.where(mask_ref[0] > 0, s, NEG_INF)
+
+        m_run = m_scr[...]
         m_new = jnp.maximum(m_run, jnp.max(s, axis=-1, keepdims=True))
         corr = jnp.exp(m_run - m_new)
         p = jnp.exp(s - m_new)
-        l_new = l_run * corr + jnp.sum(p, axis=-1, keepdims=True)
-        v_c = v_ref[0, 0, 0, pl.ds(j * ck, ck), :].astype(jnp.float32)
-        o_new = o_acc * corr + jax.lax.dot_general(
-            p, v_c, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        return o_new, m_new, l_new
+        l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        m_scr[...] = m_new
+        p = p.astype(q.dtype)
+        pv = jnp.zeros((H, D), jnp.float32)
+        for h in range(Hkv):
+            pv_h = jax.lax.dot_general(
+                p, v_ref[0, 0, h].astype(q.dtype), (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # [H, D]
+            pv = jnp.where(group == h, pv_h, pv)
+        o_scr[...] = o_scr[...] * corr + pv
 
-    o0 = jnp.zeros((rep, D), jnp.float32)
-    m0 = jnp.full((rep, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((rep, 1), jnp.float32)
-    o, _, l = jax.lax.fori_loop(0, n_chunks, body, (o0, m0, l0))
-    # per-channel V dequant commutes out of the over-S dot: one [rep, D]
-    # multiply after normalization
-    o = (o / jnp.maximum(l, 1e-30)) * vs_ref[0, 0]
-    o_ref[0, 0] = o.astype(o_ref.dtype)
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _flush():
+        group = jax.lax.broadcasted_iota(jnp.int32, (H, 1), 0) // rep
+        vs = jnp.zeros((H, D), jnp.float32)
+        for h in range(Hkv):
+            vs = jnp.where(group == h, vs_ref[0, h], vs)
+        # per-channel V dequant commutes out of the over-S dot: one
+        # [H, D] multiply after normalization
+        o = o_scr[...] / jnp.maximum(l_scr[...], 1e-30) * vs
+        o_ref[0] = o.astype(o_ref.dtype)
 
 
 def decode_attention_int8(
     q,  # [B, H, D] (rope already applied)
     ck,  # [L, B, Hkv, S, D] int8 — full stacked cache
     cv,  # [L, B, Hkv, S, D] int8
-    k_scale,  # [L, B, Hkv, 1, S] f32
+    k_scale,  # [L, B, Hkv, S] f32
     v_scale,  # [B, Hkv, 1, D] f32 — this layer's slice (frozen scales
     #           ride the layer scan's xs, so no layer axis here)
     key_mask,  # [B, S] int32 — 1 for attendable slots (incl. this token)
     layer_ix,  # scalar int32: which layer's blocks to read
+    write_ix,  # scalar int32: the slot this step wrote; none later is read
     sm_scale: float,
 ):
     """One decode step's attention for ONE layer of the stacked cache.
 
-    Returns [B, H, D] in q.dtype. Requires S % 128 == 0 (Mosaic lane
-    granularity for the in-kernel chunk loads; generate() rounds real
-    rollout caches to 128 slots) — callers fall back to the XLA path
-    otherwise (transformer.Attention gates on the same condition).
+    Grid (B, S // chunk), chunks innermost: a cell holds all Hkv heads
+    of one row over `decode_chunk` slots, so the memory and not the
+    grid sets the pace. The layer index and the number of chunks wholly
+    past the write index are scalar-prefetched; a row's grid steps are
+    that many idle steps FIRST, then its written chunks in order: step
+    j reads chunk max(j - idle, 0). An idle step repeats the block of
+    the step after it, which the pipeline fetched during the row
+    before (no copy is issued for an unchanged block), and does
+    nothing; idle steps at a row's END would leave the next row's first
+    copy with nothing to hide behind (measured: 3 of 4 chunks then cost
+    as much as 4). Inside the last chunk, and for left padding,
+    `key_mask` decides.
+
+    Returns [B, H, D] in q.dtype. Requires S % 128 == 0 (generate()
+    rounds real rollout caches to 128 slots) — callers fall back to the
+    XLA path otherwise (transformer.Attention gates on the same
+    condition).
     """
     L, B, Hkv, S, D = ck.shape
     H = q.shape[1]
     if H % Hkv:
         raise ValueError(f"n_head={H} not a multiple of n_kv_head={Hkv}")
-    rep = H // Hkv
-    # largest power-of-two chunk <= CHUNK that divides S: callers are
-    # gated on S % 128 == 0, so this bottoms out at >= 128 (lane-aligned
-    # for the in-kernel dynamic loads) instead of rejecting e.g. S=640
-    from trlx_tpu.ops.common import pick_block
-
-    ckk = pick_block(S, CHUNK)
-    if ckk < 128:
+    if S % 128:
         raise ValueError(f"cache length {S} must be a multiple of 128")
+    chunk = decode_chunk(S, Hkv, D)
+    idle = S // chunk - 1 - jnp.clip(write_ix // chunk, 0, S // chunk - 1)
 
-    # consecutive rep query heads share a kv head (head h -> group
-    # h // rep), so [B, H, D] -> [B, Hkv, rep, D] groups them per cell
-    qr = q.reshape(B, Hkv, rep, D)
-    grid = (B, Hkv)
+    def kv_ix(b, j, sp):
+        return (sp[0], b, 0, jnp.maximum(j - sp[1], 0), 0)
 
-    kernel = functools.partial(
-        _decode_kernel, sm_scale=sm_scale, n_chunks=S // ckk, ck=ckk
-    )
-    out = pl.pallas_call(
+    kernel = functools.partial(_decode_kernel, sm_scale=sm_scale, rep=H // Hkv)
+    return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=grid,
+            grid=(B, S // chunk),
             in_specs=[
-                pl.BlockSpec((1, 1, rep, D), lambda b, h, lx: (b, h, 0, 0)),
+                pl.BlockSpec((1, H, D), lambda b, j, sp: (b, 0, 0)),
+                pl.BlockSpec((1, 1, Hkv, chunk, D), kv_ix),
+                pl.BlockSpec((1, 1, Hkv, chunk, D), kv_ix),
                 pl.BlockSpec(
-                    (1, 1, 1, S, D), lambda b, h, lx: (lx[0], b, h, 0, 0)
+                    (1, 1, Hkv, chunk),
+                    lambda b, j, sp: (sp[0], b, 0, jnp.maximum(j - sp[1], 0)),
                 ),
+                pl.BlockSpec((1, Hkv, 1, D), lambda b, j, sp: (b, 0, 0, 0)),
                 pl.BlockSpec(
-                    (1, 1, 1, S, D), lambda b, h, lx: (lx[0], b, h, 0, 0)
+                    (1, 1, chunk), lambda b, j, sp: (b, 0, jnp.maximum(j - sp[1], 0))
                 ),
-                pl.BlockSpec(
-                    (1, 1, 1, 1, S), lambda b, h, lx: (lx[0], b, h, 0, 0)
-                ),
-                pl.BlockSpec((1, 1, 1, D), lambda b, h, lx: (b, h, 0, 0)),
-                pl.BlockSpec((1, 1, S), lambda b, h, lx: (b, 0, 0)),
             ],
-            out_specs=pl.BlockSpec(
-                (1, 1, rep, D), lambda b, h, lx: (b, h, 0, 0)
-            ),
+            out_specs=pl.BlockSpec((1, H, D), lambda b, j, sp: (b, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((H, D), jnp.float32),
+                pltpu.VMEM((H, 1), jnp.float32),
+                pltpu.VMEM((H, 1), jnp.float32),
+            ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, rep, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
+        ),
         interpret=_interpret(),
         name="decode_attn",
     )(
-        jnp.reshape(layer_ix, (1,)).astype(jnp.int32),
-        qr,
+        jnp.stack([jnp.asarray(layer_ix, jnp.int32), idle.astype(jnp.int32)]),
+        q,
         ck,
         cv,
         k_scale,
         v_scale,
         key_mask.astype(jnp.int32)[:, None, :],
     )
-    return out.reshape(B, H, D)
+
+
+def decode_attention_on_mesh(
+    mesh, q, ck, cv, k_scale, v_scale, key_mask, layer_ix, write_ix, sm_scale: float
+):
+    """:func:`decode_attention_int8` under a device mesh (None = one
+    device). GSPMD cannot partition a Mosaic custom call, so the call is
+    a shard_map over the axes a decode step is embarrassingly parallel
+    in, like :func:`flash_attention_on_mesh`: batch rows over (dp, fsdp),
+    heads over tp, the layout the cache already has. No collective is
+    involved; `transformer.decode_attn_unfused` keeps shapes the mesh
+    does not divide off this path."""
+    kernel = functools.partial(decode_attention_int8, sm_scale=sm_scale)
+    if mesh is None or mesh.size == 1:
+        return kernel(q, ck, cv, k_scale, v_scale, key_mask, layer_ix, write_ix)
+    from jax.sharding import PartitionSpec as P
+
+    rows = ("dp", "fsdp")
+    stacked = P(None, rows, "tp", None, None)
+    return jax.shard_map(
+        kernel,
+        mesh=mesh,
+        in_specs=(
+            P(rows, "tp", None), stacked, stacked, P(None, rows, "tp", None),
+            P(rows, "tp", None, None), P(rows, None), P(), P(),
+        ),
+        out_specs=P(rows, "tp", None),
+        check_vma=False,
+    )(q, ck, cv, k_scale, v_scale, key_mask, layer_ix, write_ix)

@@ -45,6 +45,8 @@ from trlx_tpu.ops.common import running_moments_init, running_moments_update
 from trlx_tpu.models.generation import (
     HF_GEN_KWARGS_UNIMPLEMENTED,
     SamplerSettings,
+    chunks_streamed,
+    fused_decode_cells,
     generate,
 )
 from trlx_tpu.models.hf import load_pretrained, save_pretrained_hf
@@ -184,7 +186,10 @@ class TPUBaseTrainer(BaseRLTrainer):
             or self.mesh.shape["pp"] > 1
             or (
                 self.mesh.size > 1
-                and getattr(self._lm().cfg, "attention_impl", None) == "pallas"
+                and (
+                    getattr(self._lm().cfg, "attention_impl", None) == "pallas"
+                    or getattr(self._lm().cfg, "kv_cache_quant", None) == "int8"
+                )
             )
         ):
             self._lm().mesh = self.mesh
@@ -314,6 +319,8 @@ class TPUBaseTrainer(BaseRLTrainer):
         self._measured_forward_times = {}  # timing_split probes by batch shape
         self._seen_step_shapes = set()  # batch shapes whose step has compiled
         self._generate_fns: Dict[Tuple, Callable] = {}
+        # per built sampler: the fused decode kernel's grid, or None
+        self._decode_cells: Dict[Tuple, Optional[Dict[str, int]]] = {}
         # serving-grade rollout decode engine (ppo.gen_engine.*):
         # continuous batching + paged KV + speculative decoding behind
         # the same generate() seam; default-disabled
@@ -736,7 +743,30 @@ class TPUBaseTrainer(BaseRLTrainer):
 
             fn.__name__ = "generate"  # the XLA module is jit_generate
             self._generate_fns[key] = jax.jit(fn)
+            if not seq2seq:
+                self._note_decode_attn(key)
         return self._generate_fns[key]
+
+    def _note_decode_attn(self, key) -> None:
+        """Gauge `gen/decode_attn_fused`, once per built sampler, in the
+        flight stream and the tracker: 1 where its decode steps run the
+        fused kernel over the int8 cache (ops/decode_attention.py), 0
+        where they run anything else (Attention warns with the reason
+        when that is the XLA branch over an int8 cache). A fused
+        sampler's grid is kept for the `tokens_wait` span's counts."""
+        settings, (rows, prompt), _ = key
+        virtual = 0
+        if "prompt" in self.params:
+            virtual = self.params["prompt"]["embedding"].shape[0]
+        elif "prefix" in self.params:
+            virtual = self.params["prefix"]["k"].shape[1]
+        cells = fused_decode_cells(
+            self._lm(), rows, virtual, prompt, settings.max_new_tokens
+        )
+        self._decode_cells[key] = cells
+        gauge = {"gen/decode_attn_fused": int(cells is not None)}
+        self.obs.gauge(**gauge)
+        self._tracker_log(gauge, step=self.iter_count)
 
     def generation_logits_processor(self, params):
         """Optional logits hook for sampling, given the full param tree.
@@ -878,6 +908,9 @@ class TPUBaseTrainer(BaseRLTrainer):
                 out = jax.tree_util.tree_map(lambda x: x[:B], out)
         if moe_stats:
             out["moe_stats"] = moe_stats
+        cells = self._decode_cells.get((settings, gshape, proc_kwargs))
+        if cells:  # host numbers, like the counters: not rows
+            out["decode_cells"] = cells
         return out
 
     def generate_eval(self, input_ids, attention_mask=None, **kwargs):
@@ -1078,9 +1111,7 @@ class TPUBaseTrainer(BaseRLTrainer):
         lm_cfg = self._lm().cfg
         quant = cfg.kv_quant
         if quant is None:
-            quant = "int8" if lm_cfg.kv_cache_quant in (
-                "int8", "int8_kernel"
-            ) else "none"
+            quant = "int8" if lm_cfg.kv_cache_quant == "int8" else "none"
         slots = min(cfg.slots or cfg.max_batch, cfg.max_batch)
         MP = paged_kv.pages_per_slot(
             cfg.max_prompt_len, cfg.max_new_tokens, cfg.page_size
@@ -4149,6 +4180,11 @@ class TPUOnlineTrainer(TPUBaseTrainer):
             # free read here, and ride this span's counts into the cycle row
             for name, value in (gen_out.get("moe_stats") or {}).items():
                 counts[name] = float(value)
+            if gen_out.get("decode_cells"):
+                # the decode loop stops once every row has finished: its
+                # steps are the response columns any row still wrote
+                steps = int(packed[:rows, -n_new:].any(axis=0).sum()) - 1
+                counts.update(chunks_streamed(steps=steps, **gen_out["decode_cells"]))
         stats["time/rollout_generate"] = (
             stats.get("time/rollout_generate", 0.0) + time() - t0
         )

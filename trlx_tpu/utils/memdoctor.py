@@ -686,7 +686,7 @@ def estimate_plan(trainer) -> HBMPlan:
             # static sampler: contiguous whole-batch KV cache
             mc = _model_cfg(trainer)
             kv_quant = getattr(mc, "kv_cache_quant", None)
-            kv_size = 1 if kv_quant in ("int8", "int8_kernel") else decode_size
+            kv_size = 1 if kv_quant == "int8" else decode_size
             # numbers a cached position costs a row in one layer: 2 x heads
             # x head size, or a latent cache's rank + rotary channels
             per_position = getattr(mc, "cache_elems_per_position", None) or (
@@ -1306,7 +1306,7 @@ def analytic_plan(
             )
     else:
         kv_quant = tdict.get("kv_cache_quant")
-        kv_size = 1 if kv_quant in ("int8", "int8_kernel") else 2
+        kv_size = 1 if kv_quant == "int8" else 2
         latent = tdict.get("kv_lora_rank")
         per_position = (int(latent) + int(tdict.get("qk_rope_head_dim", 0))) if latent else 2 * Hkv * D
         plan.add("rollout", "static_kv_cache",
