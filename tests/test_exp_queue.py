@@ -479,11 +479,16 @@ def test_exp_enabled_fault_free_bit_equal_to_direct(tmp_path):
     )
 
 
-def test_clip_mode_trains_over_stale_chunk(tmp_path):
+@pytest.mark.parametrize("chunks_per_cycle", [1, 2])
+def test_clip_mode_trains_over_stale_chunk(tmp_path, chunks_per_cycle):
     """``staleness.mode: clip`` end to end: a stale_flood-corrupted
     chunk is ADMITTED with the IMPACT proximal recompute + per-token
     clipped importance weights, the ``staleness`` signal trips, the
-    weights ride the store into the fused loss, and the run completes."""
+    weights ride the store into the fused loss, and the run completes.
+    With two chunks a cycle the stale one is the FIRST of its cycle: its
+    ``exp/staleness_clipped`` must survive the cycle's aggregation over
+    the union of the chunks' keys (the fresh final chunk has no such
+    key)."""
     import trlx_tpu
 
     ckpt_dir = os.path.join(str(tmp_path), "clip")
@@ -494,8 +499,11 @@ def test_clip_mode_trains_over_stale_chunk(tmp_path):
     ).evolve(
         train=dict(
             guardrails=dict(enabled=True, loss_spike_sigma=0.0),
-            chaos=dict(seed=0, faults=[{"fault": "stale_flood", "at": 2}]),
+            chaos=dict(seed=0, faults=[
+                {"fault": "stale_flood", "at": chunks_per_cycle + 1}
+            ]),
         ),
+        method=dict(num_rollouts=8 * chunks_per_cycle),
     )
     prompts = ["hello world", "the cat", "a b", "xyz",
                "what is", "I am", "go", "ok"]
@@ -514,6 +522,14 @@ def test_clip_mode_trains_over_stale_chunk(tmp_path):
     w = np.asarray(trainer.store.history.is_weight)
     assert w.shape == np.asarray(trainer.store.history.logprobs).shape
     assert np.all(w >= 0.7 - 1e-6) and np.all(w <= 1.3 + 1e-6)
+    # the cycle's logged mean counts the clipped chunk among all of its
+    # chunks, wherever in the cycle it came
+    with open(os.path.join(ckpt_dir, "logs", "metrics.jsonl")) as f:
+        clipped = [
+            rec["exp/staleness_clipped"] for rec in map(json.loads, f)
+            if "exp/staleness_clipped" in rec
+        ]
+    assert clipped == [1.0 / chunks_per_cycle]
 
 
 def test_reject_regenerates_prefetch_chunk_without_livelock(tmp_path):

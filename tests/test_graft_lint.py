@@ -5,11 +5,14 @@ semantics, pragma parsing, baseline/diff, and the whole-repo clean run
 discipline and config<->docs sync loud structural failures, the way
 test_marker_audit.py already guards test budgets and bench honesty."""
 
+import glob
 import json
 import os
 import subprocess
 import sys
 import textwrap
+
+import pytest
 
 from trlx_tpu.analysis import (  # noqa: F401 (runner re-exported surface)
     config_docs,
@@ -637,33 +640,93 @@ def test_whole_repo_lint_is_clean():
     )
 
 
-def test_training_path_never_imports_analysis():
-    """The lint must add zero runtime import cost to trlx_tpu proper:
-    no module outside trlx_tpu/analysis/ may import it (bench.py
-    --smoke asserts the same at runtime)."""
+def _imports_under(subpaths, skip=()):
+    """(relative path, imported module) for every import statement, at
+    any depth, of the .py files under ``trlx_tpu/<subpath>`` (a
+    directory or one file), less ``skip``."""
     import ast as _ast
 
-    offenders = []
-    for dirpath, dirnames, filenames in os.walk(os.path.join(REPO, "trlx_tpu")):
-        dirnames[:] = [
-            d for d in dirnames if d not in ("__pycache__", "analysis")
-        ]
-        for fname in filenames:
-            if not fname.endswith(".py"):
+    found = []
+    pkg = os.path.join(REPO, "trlx_tpu")
+    for sub in subpaths:
+        top = os.path.join(pkg, sub)
+        paths = [top] if top.endswith(".py") else glob.glob(
+            os.path.join(top, "**", "*.py"), recursive=True
+        )
+        for path in paths:
+            rel = os.path.relpath(path, pkg)
+            if rel.startswith(tuple(skip)):
                 continue
-            path = os.path.join(dirpath, fname)
-            tree = _ast.parse(open(path).read())
-            for node in _ast.walk(tree):
-                mods = []
+            for node in _ast.walk(_ast.parse(open(path).read())):
                 if isinstance(node, _ast.Import):
-                    mods = [a.name for a in node.names]
+                    found += [(rel, a.name) for a in node.names]
                 elif isinstance(node, _ast.ImportFrom) and node.module:
-                    mods = [node.module]
-                if any(m.startswith("trlx_tpu.analysis") for m in mods):
-                    offenders.append(os.path.relpath(path, REPO))
-    assert not offenders, (
-        f"training-path modules import trlx_tpu.analysis: {offenders}"
-    )
+                    found += [(rel, node.module)]
+                    found += [
+                        (rel, f"{node.module}.{a.name}") for a in node.names
+                    ]
+    return found
+
+
+@pytest.mark.parametrize(
+    "importers, skip, forbidden",
+    [
+        # the lint must add zero runtime import cost to trlx_tpu proper
+        # (bench.py --smoke asserts the same at runtime)
+        pytest.param([""], ["analysis"], ["trlx_tpu.analysis"],
+                     id="training_path_never_imports_analysis"),
+        # trainer -> exp -> fleet, arrows one way: the transport's and
+        # the fleet's protocols take what the trainer owns as arguments;
+        # only the worker builds a trainer
+        pytest.param(["exp", "fleet"], ["fleet/worker.py"], ["trlx_tpu.trainer"],
+                     id="exp_and_fleet_never_import_the_trainer"),
+        pytest.param(["trainer/base.py"], [], ["trlx_tpu.exp", "trlx_tpu.fleet"],
+                     id="base_trainer_imports_no_transport_no_fleet"),
+        pytest.param(["trainer"], [], ["trlx_tpu.fleet.serde"],
+                     id="no_trainer_knows_the_fleet_wire_format"),
+    ],
+)
+def test_import_layering(importers, skip, forbidden):
+    offenders = [
+        (rel, mod) for rel, mod in _imports_under(importers, skip)
+        if any(mod == f or mod.startswith(f + ".") for f in forbidden)
+    ]
+    assert not offenders, f"{importers} import {forbidden}: {offenders}"
+
+
+def test_online_trainer_has_one_collection_loop():
+    """The rollout half keeps ONE collection loop and names no transport
+    verdict: the lease, staleness and dispatch protocols are exp/'s and
+    fleet/'s (stdlib ast only)."""
+    import ast as _ast
+
+    trainer_dir = os.path.join(REPO, "trlx_tpu", "trainer")
+    online = _ast.parse(open(os.path.join(trainer_dir, "online.py")).read())
+    defined = {
+        n.name for n in _ast.walk(online)
+        if isinstance(n, (_ast.FunctionDef, _ast.ClassDef))
+    }
+    gone = {"_make_experience_exp", "_exp_produce", "_fleet_produce",
+            "_fleet_ready", "_fleet_degrade"}
+    assert "TPUOnlineTrainer" in defined and not (defined & gone)
+    base = _ast.parse(open(os.path.join(trainer_dir, "base.py")).read())
+    assert "TPUOnlineTrainer" not in {
+        n.name for n in _ast.walk(base) if isinstance(n, _ast.ClassDef)
+    }
+    loops = [
+        fn.name for fn in _ast.walk(online) if isinstance(fn, _ast.FunctionDef)
+        for n in _ast.walk(fn)
+        if isinstance(n, _ast.While) and "n_collected" in _ast.dump(n.test)
+    ]
+    assert loops == ["_make_experience"], loops
+    for fname in os.listdir(trainer_dir):
+        if fname.endswith(".py"):
+            names = {
+                n.attr for n in _ast.walk(
+                    _ast.parse(open(os.path.join(trainer_dir, fname)).read())
+                ) if isinstance(n, _ast.Attribute)
+            }
+            assert not names & {"REJECT", "ADMIT_CLIP"}, fname
 
 
 def test_cli_exit_codes_and_jax_free(tmp_path):

@@ -128,18 +128,34 @@ def test_scanned_epoch_matches_looped(tmp_path):
             it += 1
     assert it == len(perms)
 
+    # the FIRST step sees bit-identical params and rows in both programs:
+    # its loss is the tight pin (the scan body and the standalone step
+    # differ by an ulp, 6e-8 relative here)
+    p_1, o_1 = _copy(trainer.params), _copy(trainer.opt_state)
+    with trainer.mesh:
+        _, _, first_loss, _ = fused(p_1, o_1, device_full, jnp.asarray(perms[:1]))
+    np.testing.assert_allclose(float(first_loss), losses[0], rtol=1e-6)
+
     p_s, o_s = _copy(trainer.params), _copy(trainer.opt_state)
     with trainer.mesh:
         p_s, o_s, mean_loss, _ = fused(p_s, o_s, device_full, jnp.asarray(perms))
 
+    # NOT the order of a float32 mean over the steps (float32 against
+    # float64 over these four losses is 5e-8): each later step starts
+    # from params the two programs rounded differently, which AdamW
+    # amplifies (see below), so the per-step losses drift apart as the
+    # steps go: 6e-8, 1e-6, 6e-5, 1e-5 relative here, 1.35e-5 on the
+    # mean. The bound is about ten times the drift read; the minibatch
+    # order is pinned exactly above (perms), the arithmetic by the
+    # first step.
     np.testing.assert_allclose(
-        float(mean_loss), float(np.mean(losses)), rtol=1e-5, atol=1e-6
+        float(mean_loss), float(np.mean(losses)), rtol=1e-4, atol=1e-6
     )
     # params: the two compiled programs (scan body vs standalone step)
     # may round differently at the last bit, and AdamW's m/sqrt(v)
     # normalization amplifies that to ~lr scale where gradients are near
     # zero — so the param check is absolute at a fraction of the total
-    # update budget, while the loss chain above pins the tight match
+    # update budget, while the first step's loss above pins the tight match
     for a, b in zip(
         jax.tree_util.tree_leaves(p_l), jax.tree_util.tree_leaves(p_s)
     ):

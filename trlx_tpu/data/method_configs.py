@@ -187,7 +187,7 @@ class GRPOConfig(MethodConfig):
     in the LOSS against the frozen reference (``kl_coef``) instead of
     riding the reward. The rollout engine — prompt stream, chunked
     generation, overlap prefetch, decode engine, experience transport,
-    rollout fleet — is the shared online core (trainer.base.
+    rollout fleet — is the shared online core (trainer.online.
     TPUOnlineTrainer): the ``overlap_rollouts`` / ``gen_engine`` /
     ``exp`` / ``fleet`` knobs below carry PPO's exact semantics
     (documented on PPOConfig)."""

@@ -25,6 +25,13 @@ chaos-proven layer instead of leaking into every trainer.
                 consume-side ``poll``/``admit``/``committed`` (dedup +
                 staleness), epoch aborts for guardrail requeue/rollback,
                 and ``state_dict``/``load_state_dict`` for resume.
+  rollout.py    the rollout loop's side: ``LeasedChunks``, the chunk
+                source an online trainer's collection loop pulls from —
+                the in-process consumer and producer over
+                ``transport.py`` (replay snapshots, heartbeats, the
+                ``exp_wait`` phase, reclaim, the staleness verdicts and
+                re-dispatch), given the trainer's functions as
+                arguments.
   net.py        the PROCESS-BOUNDARY substrate: the pluggable topic/
                 message transport (atomic-rename shared-fs, or a tcp
                 hub) that carries fleet chunk dispatch/delivery and

@@ -1,8 +1,8 @@
 """Experience transport orchestrator: leases + queue + admission gate.
 
-The object trainers actually drive (trainer/ppo.py is the first
-producer/consumer pair; ROADMAP item 1's remote rollout fleet plugs in
-behind the same API). One instance owns the delivery state machine:
+The object the rollout loop's chunk source drives (``exp/rollout.py``
+``LeasedChunks`` is the in-process producer/consumer pair; the rollout
+fleet plugs in behind it). One instance owns the delivery state machine:
 
   producer side   :meth:`begin_chunk` (lease + replay snapshot) ->
                   produce -> :meth:`heartbeat` at milestones ->

@@ -17,14 +17,21 @@ remote-producer half, on the PR 7 experience-transport substrate).
                   (bit-identical regeneration), degraded-mode verdicts
                   (below ``fleet.min_workers`` -> the ``fleet``
                   guardrail signal + in-process fallback).
+  dispatch.py     learner side, the protocol over those primitives
+                  (``ChunkDispatcher``): one leased chunk produced on
+                  the fleet — publish-if-due, readiness, selection,
+                  attempts, eviction, deadline, degrade, adoption —
+                  called by the transport's producer
+                  (``exp/rollout.py``).
   worker.py       the cross-process rollout worker (``run_worker``):
                   a learner-less PPO trainer driven by dispatch
-                  messages, sharing ``_score_and_assemble`` verbatim.
+                  messages, sharing ``_produce_chunk`` verbatim.
   serde.py        exact pytree <-> numpy wire conversions + atomic
                   message-directory commits.
 
 ``membership``/``broadcast``/``config`` are jax-free host modules;
-import ``coordinator``/``worker``/``serde`` directly where needed.
+import ``coordinator``/``dispatch``/``worker``/``serde`` directly where
+needed.
 """
 
 from trlx_tpu.fleet.broadcast import BroadcastCorrupt, WeightBroadcast

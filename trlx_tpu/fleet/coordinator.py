@@ -1,8 +1,9 @@
 """Learner-side fleet coordinator: dispatch, collect, degrade.
 
-Sits between the PPO trainer's experience-transport loop and the
-cross-process worker fleet. The trainer keeps owning the transport
-lease for every chunk; the coordinator turns "produce this chunk" into
+Sits between the experience transport's producer and the
+cross-process worker fleet (the protocol that drives these primitives
+for one chunk is ``fleet/dispatch.py``). The learner keeps owning the
+transport lease for every chunk; the coordinator turns "produce this chunk" into
 a dispatch message a registered worker executes, watches the worker's
 membership heartbeats while it runs, and hands the delivered payload
 back. A silent worker is evicted (flap-tracked, quarantined past

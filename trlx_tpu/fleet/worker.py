@@ -5,7 +5,7 @@ jitted sampler and score path, driven by dispatch messages instead of
 a training loop. Per assignment it restores the replay snapshot the
 learner attached (RNG + reward running-moments + ref stats), refreshes
 its policy weights from the versioned broadcast, generates and scores
-the chunk through the SAME ``_score_and_assemble`` the learner uses,
+the chunk through the SAME ``_produce_chunk`` the learner uses,
 and delivers the payload plus its post-production snapshot — which the
 learner adopts, so the learner's RNG/moments chain is bit-identical to
 having produced the chunk in-process.
@@ -259,21 +259,21 @@ class FleetWorker:
         batch = serde.prompt_batch_from_arrays(
             arrays, meta.get("prompt_metadata")
         )
-        stats: Dict[str, Any] = {}
-        t0 = time.time()
-        gen_out = t.generate(batch.input_ids, batch.attention_mask)
-        stats["time/rollout_generate"] = time.time() - t0
-        if t.chaos is not None and t.chaos.consult("fleet_worker_death"):
-            # chaos: the worker dies MID-CHUNK (generation done, score
-            # pending) — a hard exit, so the beat thread dies with it
-            # and the learner sees exactly what a real kill looks like
-            logger.error(
-                "chaos: fleet worker %r dying mid-chunk %s",
-                self.worker_id, chunk_id,
-            )
-            os._exit(3)
-        rollout_batch, rows_local = t._score_and_assemble(
-            batch, gen_out, stats, iter_count, Clock()
+
+        def die_mid_chunk() -> None:
+            if t.chaos is not None and t.chaos.consult("fleet_worker_death"):
+                # chaos: the worker dies MID-CHUNK (generation done,
+                # score pending) — a hard exit, so the beat thread dies
+                # with it and the learner sees exactly what a real kill
+                # looks like
+                logger.error(
+                    "chaos: fleet worker %r dying mid-chunk %s",
+                    self.worker_id, chunk_id,
+                )
+                os._exit(3)
+
+        (rollout_batch, stats, rows_local), _ = t._produce_chunk(
+            iter_count, Clock(), batch=batch, after_generate=die_mid_chunk
         )
         try:
             delivered = self.transport.put(

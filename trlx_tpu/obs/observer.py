@@ -1,7 +1,8 @@
 """RunObserver: the glue between the training loop and the flight
 recorder / span tracer / telemetry / profiler.
 
-Wiring (all in trainer/base.py, each a one-liner at an existing site):
+Wiring (all in trainer/base.py and trainer/online.py, each a one-liner
+at an existing site):
 
 - beat sites: registered as a sibling listener on the hang doctor's
   heartbeat registry (``HangWatchdog.add_listener``) — the span tracer

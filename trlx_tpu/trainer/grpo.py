@@ -5,7 +5,7 @@ The rollout ENGINE — prompt stream + cursors, chunked generate() with
 one-chunk lookahead, cross-cycle prefetch (`method.overlap_rollouts`),
 the decode engine (`method.gen_engine.*`), experience transport
 (`method.exp.*`) and rollout fleet (`method.fleet.*`) — is inherited
-verbatim from `trainer.base.TPUOnlineTrainer`; this module contributes
+verbatim from `trainer.online.TPUOnlineTrainer`; this module contributes
 only what is GRPO:
 
 - the PROMPT TILING: each chunk pulls ``chunk_size / group_size``
@@ -49,7 +49,7 @@ from trlx_tpu.parallel import data_sharding, shard_params
 from trlx_tpu.parallel import multihost as mh
 from trlx_tpu.parallel.mesh import replicated_sharding, vector_sharding
 from trlx_tpu.trainer import register_trainer
-from trlx_tpu.trainer.base import TPUOnlineTrainer
+from trlx_tpu.trainer.online import TPUOnlineTrainer
 from trlx_tpu.trainer.ppo import _masked_kl_stats
 from trlx_tpu.utils import Clock, logging
 

@@ -10,7 +10,7 @@ directly comparable.
 The rollout ENGINE — prompt stream + cursors, chunked generate() with
 one-chunk lookahead, cross-cycle prefetch, the experience transport
 (`exp/`) and rollout fleet (`fleet/`) — lives in the trainer-agnostic
-`trainer.base.TPUOnlineTrainer`; this module contributes only what is
+`trainer.online.TPUOnlineTrainer`; this module contributes only what is
 PPO: the value-headed model, the teacher-forced score/assemble seam
 (policy+ref+value forward, per-token KL penalty, reward injection), the
 adaptive KL controller, GAE + the clipped surrogate loss, and the
@@ -48,7 +48,7 @@ from trlx_tpu.parallel import data_sharding, shard_params
 from trlx_tpu.parallel import multihost as mh
 from trlx_tpu.parallel.mesh import replicated_sharding, vector_sharding
 from trlx_tpu.trainer import register_trainer
-from trlx_tpu.trainer.base import (  # noqa: F401  (_GroupChunkLoader re-export)
+from trlx_tpu.trainer.online import (  # noqa: F401  (_GroupChunkLoader re-export)
     TPUOnlineTrainer,
     _GroupChunkLoader,
 )
