@@ -13,6 +13,7 @@ from trlx_tpu.models.generation import (
     process_logits,
     top_p_mask,
 )
+import trlx_tpu.models.transformer as tr
 from trlx_tpu.models.transformer import TransformerConfig, TransformerLM
 
 
@@ -372,3 +373,118 @@ def test_int8_decode_weights_track_full_precision(tiny_lm):
     w = np.asarray(params["blocks"]["attn"]["q"]["kernel"], np.float32)
     deq = np.asarray(qkern, np.float32) * np.asarray(scale)[:, None]
     assert np.abs(deq - w).max() <= np.abs(w).max() / 127.0 + 1e-6
+
+
+# --- a decode step on a sharded mesh: weights stay, activations move ---
+
+_TOY_ROWS, _TOY_PROMPT, _TOY_NEW = 4, 120, 8
+
+
+@pytest.fixture(scope="module")
+def neox_toy():
+    """A GPT-NeoX block at toy widths (rotary on part of the head,
+    parallel residual, untied head) over an int8 cache of one whole
+    128-slot tile (the fused kernel under `shard_map` pins the rows, as
+    in the four-chip cell), its parameters, and the unsharded sampler's
+    tokens by (decode weights, sampled). Float32 compute: a random toy's
+    logits are near ties, which bf16 breaks by the order of the partial
+    sums; in float32 equal tokens say the layouts compute one function."""
+    cfg = TransformerConfig(
+        vocab_size=256, hidden_size=64, n_layer=2, n_head=4, n_positions=128,
+        intermediate_size=256, pos_embed="rotary", rotary_dim=4, activation="gelu",
+        parallel_residual=True, tie_word_embeddings=False, kv_cache_quant="int8",
+        dtype=jnp.float32,
+    )
+    params = TransformerLM(cfg).init(jax.random.PRNGKey(0))
+    ids = jax.random.randint(jax.random.PRNGKey(1), (_TOY_ROWS, _TOY_PROMPT), 0, 256)
+    mask = jnp.ones_like(ids).at[0, :3].set(0)  # one left-padded row
+    want = {
+        (quant, sampled): np.asarray(_toy_sampler(cfg, quant, sampled, None)(
+            params, ids, mask, jax.random.PRNGKey(2))["response_ids"])
+        for quant in (None, "int8") for sampled in (False, True)
+    }
+    return cfg, params, ids, mask, want
+
+
+def _toy_sampler(cfg, quant, sampled, mesh):
+    import dataclasses
+
+    from trlx_tpu.models.generation import make_generate_fn
+
+    lm = TransformerLM(dataclasses.replace(cfg, decode_weights_quant=quant))
+    lm.mesh = mesh
+    return make_generate_fn(lm, SamplerSettings(max_new_tokens=_TOY_NEW, do_sample=sampled))
+
+
+def _decode_loop_collectives(hlo: str):
+    """(kind, result dims) of every all-gather, all-to-all and
+    collective-permute that a decode step runs: the instructions whose
+    `op_name` lies under the scope `decode_step` (the partitioner names
+    a collective after the op it made it for), in the optimised text."""
+    import re
+
+    found = []
+    for line in hlo.splitlines():
+        m = re.search(r"= (.*?) (all-gather|all-to-all|collective-permute)(-start)?\(", line)
+        if m and "decode_step" in line:
+            shapes = re.findall(r"[a-z]+\d+\[([\d,]*)\]", m.group(1))
+            found += [(m.group(2), tuple(int(d) for d in s.split(",") if d)) for s in shapes]
+    return found
+
+
+@pytest.mark.parametrize("quant", [None, "int8"], ids=["unquantized", "int8"])
+@pytest.mark.parametrize("axes", [{"fsdp": 4}, {"dp": 2, "fsdp": 2}, {"fsdp": 2, "tp": 2}],
+                         ids=["fsdp4", "dp2xfsdp2", "fsdp2xtp2"])
+def test_decode_on_a_sharded_mesh_keeps_tokens_and_gathers_no_kernel(neox_toy, axes, quant):
+    """On a mesh whose fsdp axis shards the kernels a decode step
+    multiplies with the shards in place: greedy and sampled tokens are
+    the unsharded sampler's, and no collective of the decode loop has a
+    block kernel's or the head's shape (whole, or a tp shard of it)."""
+    from trlx_tpu.parallel import data_sharding, make_mesh, shard_params
+
+    cfg, params, ids, mask, want = neox_toy
+    mesh = make_mesh({"dp": 1, **axes}, devices=jax.devices()[:4])
+    assert tr.decode_weights_stationary(cfg, mesh, _TOY_ROWS)
+    sharded = shard_params(mesh, params)
+    ids, mask = (jax.device_put(a, data_sharding(mesh)) for a in (ids, mask))
+    args = (sharded, ids, mask, jax.random.PRNGKey(2))
+    for sampled in (False, True):
+        compiled = _toy_sampler(cfg, quant, sampled, mesh).lower(*args).compile()
+        got = np.asarray(compiled(*args)["response_ids"])
+        np.testing.assert_array_equal(got, want[quant, sampled])
+    tp = mesh.shape["tp"]
+    E, H, D, F, V = cfg.hidden_size, cfg.n_head, cfg.head_dim, cfg.intermediate_size, cfg.vocab_size
+    kernels = {(E, H // t, D) for t in (1, tp)} | {(H // t, D, E) for t in (1, tp)}
+    kernels |= {(E, F // t) for t in (1, tp)} | {(F // t, E) for t in (1, tp)}
+    kernels |= {(E, V // t) for t in (1, tp)}
+    moved = _decode_loop_collectives(compiled.as_text())
+    assert moved, "the decode loop's collectives were not found in the optimised text"
+    for kind, dims in moved:
+        bare = tuple(d for d in dims if d != 1)
+        assert bare not in kernels, (kind, dims)
+        # and nothing larger than a step's widest activation moves at all
+        assert int(np.prod(dims)) <= _TOY_ROWS * max(F, V), (kind, dims)
+
+
+@pytest.mark.parametrize("axes", [None, {"dp": 4}], ids=["no_mesh", "fsdp1"])
+def test_decode_on_one_chip_or_without_fsdp_emits_no_constraint(neox_toy, axes, monkeypatch):
+    """The one-chip cells' guard: with no mesh, and on a mesh whose fsdp
+    axis is 1, the sampler lowers to the text it lowers to with the
+    layouts taken out of the program; on fsdp the texts differ."""
+    from trlx_tpu.parallel import data_sharding, make_mesh, shard_params
+
+    cfg, params, ids, mask, _ = neox_toy
+
+    def lowered(axes):
+        mesh = axes and make_mesh({"dp": 1, **axes}, devices=jax.devices()[:4])
+        p, a, m = params, ids, mask
+        if mesh:
+            p = shard_params(mesh, params)
+            a, m = (jax.device_put(x, data_sharding(mesh)) for x in (ids, mask))
+        return _toy_sampler(cfg, "int8", True, mesh).lower(p, a, m, jax.random.PRNGKey(2)).as_text()
+
+    assert not tr.decode_weights_stationary(cfg, axes and make_mesh(axes, devices=jax.devices()[:4]), _TOY_ROWS)
+    with_layouts, on_fsdp = lowered(axes), lowered({"fsdp": 4})
+    monkeypatch.setattr(tr, "_decode_layouts", lambda *a: None)
+    assert lowered(axes) == with_layouts
+    assert lowered({"fsdp": 4}) != on_fsdp

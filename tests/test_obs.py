@@ -703,6 +703,36 @@ def test_fused_decode_gauge_and_chunk_counts_reach_the_flight_stream(faultfree_r
     assert counts == {"rows": 8, "tokens": 64, "cache_chunks_read": 112, "cache_chunks_held": 112}
 
 
+def test_stationary_decode_gauge_follows_the_mesh(faultfree_run, tmp_path):
+    """`gen/decode_weights_stationary` lands with `gen/decode_attn_fused`,
+    once per built sampler: 0 for the shared run (data parallel alone:
+    no axis shards a kernel), 1 on a mesh whose fsdp axis does and
+    divides the rows, 0 again for rows it does not divide."""
+    from trlx_tpu.models.generation import SamplerSettings
+    from trlx_tpu.utils.loading import get_trainer
+
+    trainer, ckpt_dir = faultfree_run
+    rows = list(iter_rows(os.path.join(ckpt_dir, "flight")))
+    assert {r["gen/decode_weights_stationary"] for r in rows if r["kind"] == "gauge"
+            and "gen/decode_attn_fused" in r} == {0}
+
+    mesh_dir = str(tmp_path / "ckpts")
+    config = _tiny_ppo_config(mesh_dir).evolve(train=dict(mesh={"dp": 2, "fsdp": 4}))
+    trainer = get_trainer(config.train.trainer)(config=config)
+    assert trainer._lm().mesh is trainer.mesh
+    settings = SamplerSettings(max_new_tokens=8)
+    trainer._get_generate_fn(settings, (8, 16))
+    trainer._get_generate_fn(settings, (8, 16))  # built once
+    trainer._get_generate_fn(settings, (12, 16))  # 12 rows over dp x fsdp = 8
+    gauges = [r for r in iter_rows(os.path.join(mesh_dir, "flight")) if r["kind"] == "gauge"]
+    assert [r["gen/decode_weights_stationary"] for r in gauges] == [1, 0]
+    assert [r["gen/decode_attn_fused"] for r in gauges] == [0, 0]
+    with open(os.path.join(mesh_dir, "logs", "metrics.jsonl")) as f:
+        logged = [json.loads(line) for line in f]
+    assert [r["gen/decode_weights_stationary"] for r in logged
+            if "gen/decode_weights_stationary" in r] == [1.0, 0.0]
+
+
 def test_cycle_programs_carry_their_own_names(faultfree_run):
     """`XLA Modules` in a trace and the compile log name a program by
     its function: generation, scoring and the train step each have one."""

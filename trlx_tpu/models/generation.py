@@ -306,7 +306,11 @@ def generate(
     params = cast_params_for_decode(params, model.cfg.dtype)
     # decode runs the sequential layer scan even when training is
     # pipelined; gather each stage's layer slice ONCE here instead of
-    # on every decode step (parallel/sharding.py:unshard_axis)
+    # on every decode step (parallel/sharding.py:unshard_axis). What
+    # `fsdp` shards is not gathered at all: a decode step multiplies
+    # with each chip's kernel shards in place and moves its activations
+    # (transformer.decode_weights_stationary); prefill keeps the
+    # per-layer gathers, paid once for the whole prompt
     from trlx_tpu.parallel.sharding import unshard_for_decode
 
     params = unshard_for_decode(params, getattr(model, "mesh", None))

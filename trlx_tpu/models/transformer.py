@@ -376,6 +376,39 @@ def decode_attn_unfused(cfg: "TransformerConfig", mesh, batch: int, slots: int) 
     return None
 
 
+def decode_weights_stationary(cfg: "TransformerConfig", mesh, batch: int) -> bool:
+    """Whether a decode step (T == 1) of `batch` rows leaves every kernel
+    where `parallel/sharding.py` put it and moves its activations instead
+    (`DecodeLayouts`): a mesh whose `fsdp` axis shards the kernels on `E`
+    and, with `dp`, divides the rows, under a dense stack (latent
+    attention, routed experts and several streams have no mesh to run on
+    yet). Elsewhere GSPMD lays the step out as it does any forward (on
+    `fsdp` that gathers each kernel whole at every step). Static, like
+    `decode_attn_unfused`: the modules ask at
+    trace time, the trainer on the host for the gauge
+    `gen/decode_weights_stationary`."""
+    if mesh is None or cfg.beyond_dense:
+        return False
+    fsdp, data = mesh.shape["fsdp"], mesh.shape["dp"] * mesh.shape["fsdp"]
+    return (
+        fsdp > 1 and mesh.shape["pp"] == 1
+        and cfg.hidden_size % fsdp == 0 and batch % data == 0
+    )
+
+
+def _decode_layouts(cfg: "TransformerConfig", mesh, cache, x: Array):
+    """The `DecodeLayouts` of this forward, or None where it is not a
+    decode step over the sampler's cache with stationary weights (no
+    constraint is emitted then: one chip's program is untouched)."""
+    if cache is None or "pk" in cache or x.shape[1] != 1:
+        return None
+    if not decode_weights_stationary(cfg, mesh, x.shape[0]):
+        return None
+    from trlx_tpu.parallel.sharding import DecodeLayouts
+
+    return DecodeLayouts(mesh)
+
+
 @functools.lru_cache(maxsize=None)
 def _warn_decode_unfused(why: str) -> None:
     """One warning per distinct reason (the cache is the log-once)."""
@@ -427,6 +460,10 @@ class Attention(nn.Module):
         cfg = self.cfg
         B, T, E = x.shape
         H, Hkv, D = cfg.n_head, cfg.n_kv_head, cfg.head_dim
+        # a decode step on a sharded mesh (x arrives split over E, Block):
+        # q, k, v are partial products reduced into the cache's rows, the
+        # output projection reads all rows and gives its own columns
+        lay = _decode_layouts(cfg, self.mesh, cache, x)
 
         dense = partial(
             QDense,
@@ -435,6 +472,7 @@ class Attention(nn.Module):
             param_dtype=cfg.param_dtype,
             kernel_init=nn.initializers.normal(0.02),
             use_bias=cfg.use_attn_bias,
+            reduce_into=lay and lay.rows,
         )
         q = dense(features=(H, D), name="q")(x)
         k = dense(features=(Hkv, D), name="k")(x)
@@ -745,6 +783,8 @@ class Attention(nn.Module):
             use_bias=out_bias,
             name="o",
         )
+        if lay:
+            return lay.split(proj(lay.whole(out))), new_kv
         return proj(out), new_kv
 
 
@@ -770,6 +810,10 @@ class QDense(nn.Module):
     param_dtype: Any = jnp.float32
     kernel_init: Any = nn.initializers.normal(0.02)
     use_bias: bool = True
+    # a decode step whose input and kernel are both split over the
+    # contracted dimension (parallel/sharding.py `DecodeLayouts`): the
+    # constraint that the float32 partial products are reduced into
+    reduce_into: Optional[Callable[[Array], Array]] = None
 
     @nn.compact
     def __call__(self, x: Array) -> Array:
@@ -787,7 +831,14 @@ class QDense(nn.Module):
             x.astype(self.dtype),
             kernel.astype(self.dtype),
             ((axes, tuple(range(len(axes)))), ((), ())),
+            preferred_element_type=None if self.reduce_into is None else jnp.float32,
         )
+        if self.reduce_into is not None:
+            # y is this chip's product over its slice of the contraction:
+            # summed across the chips in float32 and only then rounded,
+            # no less precise than the single product; scale and bias
+            # once, after the sum
+            y = self.reduce_into(y).astype(self.dtype)
         if self.has_variable("params", "kernel_scale"):
             y = y * self.get_variable("params", "kernel_scale").astype(
                 self.dtype
@@ -918,7 +969,9 @@ class MLP(nn.Module):
     bias: Optional[bool] = None  # None: cfg.use_mlp_bias
 
     @nn.compact
-    def __call__(self, x: Array) -> Array:
+    def __call__(self, x: Array, lay=None) -> Array:
+        """`lay`: the `DecodeLayouts` of a decode step on a sharded mesh
+        (x split over E), as in `Attention`; None everywhere else."""
         cfg = self.cfg
         act = _activation(cfg.activation)
         use_bias = cfg.use_mlp_bias if self.bias is None else self.bias
@@ -929,6 +982,10 @@ class MLP(nn.Module):
             param_dtype=cfg.param_dtype,
             kernel_init=nn.initializers.normal(0.02),
             use_bias=use_bias,
+            # summed into ALL rows on every chip: fc_out reads them all,
+            # and the activation over 16 rows twice costs less than a
+            # second collective
+            reduce_into=lay and lay.whole,
         )
         h = act(up(name="fc_in")(x))
         if cfg.mlp_gated if self.gated is None else self.gated:
@@ -941,7 +998,7 @@ class MLP(nn.Module):
             use_bias=use_bias,
             name="fc_out",
         )
-        return down(h)
+        return lay.split(down(h)) if lay else down(h)
 
 
 class _Kernel(nn.Module):
@@ -1319,10 +1376,22 @@ class Block(nn.Module):
         cfg = self.cfg
         attention = (LatentAttention if cfg.latent else Attention)(cfg, self.mesh, name="attn")
 
+        # a decode step on a sharded mesh: the residual stays rows by
+        # chip; what the sub-layers read is split over E once, and what
+        # they write comes back to the rows once (both branches of a
+        # parallel residual together)
+        lay = _decode_layouts(cfg, self.mesh, cache, x)
+        into = back = lambda a: a
+        if lay:
+            # the norm itself by rows: constrained only after it, the
+            # split would reach back into the norm and send its
+            # statistics across the chips too
+            into, back = (lambda a: lay.split(lay.residual(a))), lay.residual
+
         def feed_forward(h):
             if self.kind == "routed":
                 return RoutedMLP(cfg, name="moe")(h, decode=cache is not None and h.shape[1] == 1)
-            return MLP(cfg, name="mlp")(h), None
+            return MLP(cfg, name="mlp")(h, lay), None
 
         if cfg.residual_streams > 1:
             def sub_layer(name, X, fn):
@@ -1341,16 +1410,19 @@ class Block(nn.Module):
             x, (stats,) = sub_layer("hc_mlp", x, lambda u: feed_forward(Norm(cfg, name="ln_2")(u)))
             return x, new_kv, stats
 
-        h = Norm(cfg, name="ln_1")(x)
+        h = into(Norm(cfg, name="ln_1")(x))
         attn_out, new_kv = attention(h, attn_bias, positions, cache, key_mask, ring_mesh)
-        if cfg.parallel_residual:
+        if lay and cfg.parallel_residual:
+            mlp_out, stats = feed_forward(h)
+            x = back(x) + back(attn_out + mlp_out)
+        elif cfg.parallel_residual:
             x = x + attn_out
             mlp_out, stats = feed_forward(h)
             x = x + mlp_out
         else:
-            x = x + attn_out
-            mlp_out, stats = feed_forward(Norm(cfg, name="ln_2")(x))
-            x = x + mlp_out
+            x = x + back(attn_out)
+            mlp_out, stats = feed_forward(into(Norm(cfg, name="ln_2")(x)))
+            x = x + back(mlp_out)
         return x, new_kv, stats
 
 
@@ -1574,11 +1646,13 @@ class TransformerLM:
 
     @property
     def mesh(self):
-        """The device mesh, set by the trainer when the model itself must
-        know it: ring attention (`sp` carries the sequence shards),
-        pipelining (`pp` carries the layer stages), and the pallas
-        kernels on more than one device (GSPMD cannot partition a Mosaic
-        call, so Attention shard_maps it over this mesh)."""
+        """The device mesh, set by the trainer whenever it has more than
+        one device, for what the model itself must know of it: ring
+        attention (`sp` carries the sequence shards), pipelining (`pp`
+        carries the layer stages), the pallas kernels (GSPMD cannot
+        partition a Mosaic call, so Attention shard_maps it over this
+        mesh), and a decode step's layouts on `fsdp`
+        (`decode_weights_stationary`)."""
         return self._mesh
 
     @mesh.setter
@@ -2183,7 +2257,14 @@ class TransformerLM:
         hidden = self._final_hidden(params["ln_f"], h)
         # compute_logits=False: callers using chunked-from-hidden losses
         # (train.logit_chunks) skip the full [B, T, V] projection here
-        logits = self._logits(params, hidden) if compute_logits else None
+        logits = None
+        lay = compute_logits and _decode_layouts(self.cfg, self.mesh, cache, input_ids)
+        if lay:
+            # the head is split over E like q, k, v: float32 partial
+            # logits (both heads accumulate so), reduced into the rows
+            logits = lay.rows(self._logits(params, lay.split(lay.residual(hidden))))
+        elif compute_logits:
+            logits = self._logits(params, hidden)
         if n_virtual:
             hidden = hidden[:, n_virtual:]
             logits = logits[:, n_virtual:] if logits is not None else None

@@ -12,8 +12,15 @@ expressed as data layout, not module classes:
   lm_head        [E, V]        vocab over `tp` (vocab-parallel logits)
 
 Everything also shards over `fsdp` on a non-tp dim: that is ZeRO-3
-(DeepSpeed zero3.yaml parity) — XLA all-gathers params per layer inside
-the scan and reduce-scatters grads, which is exactly the ZeRO-3 schedule.
+(DeepSpeed zero3.yaml parity). Batch rows shard over the same axis
+(parallel/mesh.py `batch_pspec`), so wherever a forward carries many
+tokens (a train step, scoring, prefill) XLA all-gathers params per
+layer inside the scan and reduce-scatters grads, which is exactly the
+ZeRO-3 schedule: a gather is paid once for thousands of tokens. A decode
+step (T == 1) carries one token a row, 20,000 times fewer bytes than
+its kernels, so there the kernels stay where they are and the step's
+activations move instead (`DecodeLayouts`, below): same rules, same
+single copy of every weight.
 
 Rules match on the param path; unknown params fall back to replicated.
 A spec axis is silently dropped when the dim size is not divisible by the
@@ -28,6 +35,8 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from trlx_tpu.parallel.mesh import batch_pspec
 
 # (path regex, spec) — first match wins. Paths look like
 # "base/blocks/attn/q/kernel", "base/embed/wte", "heads/q_heads/0/fc_in/kernel".
@@ -116,6 +125,50 @@ def shard_params(mesh: Mesh, params: Dict) -> Dict:
     )
 
 
+class DecodeLayouts:
+    """Where one decode step's activations `[B, 1, N, ...]` live on a mesh
+    whose `fsdp` axis shards every kernel on its `E` dimension (the rules
+    above), so that each chip multiplies with the shard it already holds:
+
+      residual  [B, 1, E]       rows by chip, `E` whole: the cache's rows,
+                                the norms, sampling
+      split     [B, 1, E]       rows whole over `fsdp`, `E` over `fsdp`: what
+                                q, k, v, fc_in, fc_gate and the head contract
+                                (their partial products, float32, are then
+                                reduced and scattered into `rows`) and what
+                                o and fc_out produce
+      rows      [B, 1, N, ...]  rows by chip, `N` (heads, the MLP's width,
+                                the vocabulary) over `tp` as its kernel has it
+      whole     [B, 1, N, ...]  rows gathered over `fsdp`: what o and fc_out
+                                read
+
+    Each method is a `with_sharding_constraint`; GSPMD then moves the
+    131 KB of activations (all-to-all, reduce-scatter, all-gather) where
+    it gathered 2.8 GB of kernels a step before (ledger, PR 31, fsdp4).
+    `models/transformer.py` `decode_weights_stationary` says when."""
+
+    def __init__(self, mesh: Mesh):
+        self.mesh = mesh
+        self.by_chip = batch_pspec()[0]  # the batch axes: rows by chip
+
+    def _place(self, x, rows, feature) -> jax.Array:
+        # `_fit_spec` leaves `N` whole over tp where its kernel is
+        spec = _fit_spec(P(rows, None, feature), x.shape, self.mesh)
+        return jax.lax.with_sharding_constraint(x, NamedSharding(self.mesh, spec))
+
+    def residual(self, x):
+        return self._place(x, self.by_chip, None)
+
+    def split(self, x):
+        return self._place(x, "dp", "fsdp")
+
+    def rows(self, x):
+        return self._place(x, self.by_chip, "tp")
+
+    def whole(self, x):
+        return self._place(x, "dp", "tp")
+
+
 def unshard_axis(params: Dict, mesh: Mesh, axis: str = "pp") -> Dict:
     """Re-lay out a param tree with `axis` dropped from every spec
     (all-gathering each leaf's shards over that mesh axis).
@@ -155,7 +208,14 @@ def unshard_for_decode(params: Dict, mesh: Optional[Mesh], axis: str = "pp") -> 
     """The sampler-side gate for `unshard_axis`: no-op unless the mesh
     carries a real pp axis. Both samplers (models/generation.py and
     models/seq2seq.py:generate_seq2seq) share this so the decode-unshard
-    condition can't drift between them."""
+    condition can't drift between them.
+
+    Only `pp` is gathered, once a call: a stage's slice of the LAYER axis
+    is whole layers that the other stages' chips never hold, so there is
+    no product a chip could form from its own shard. `fsdp` shards every
+    layer's kernels on `E` instead, and a decode step multiplies with
+    those shards in place (`DecodeLayouts`): nothing is gathered, once or
+    at every step, and no second copy of the weights is held."""
     if mesh is None or mesh.shape.get(axis, 1) <= 1:
         return params
     return unshard_axis(params, mesh, axis)

@@ -44,7 +44,11 @@ from trlx_tpu.models.generation import (
     generate,
 )
 from trlx_tpu.models.hf import load_pretrained, save_pretrained_hf
-from trlx_tpu.models.transformer import TransformerConfig, TransformerLM
+from trlx_tpu.models.transformer import (
+    TransformerConfig,
+    TransformerLM,
+    decode_weights_stationary,
+)
 from trlx_tpu.parallel import (
     data_sharding,
     init_sharded_opt_state,
@@ -166,22 +170,14 @@ class TPUBaseTrainer(BaseRLTrainer):
         # subclass hook: builds self.model (wrapper), self.params and any
         # auxiliary trees (e.g. PPO's frozen reference branch)
         self.setup_model()
+        # the model itself must know a mesh of more than one device:
         # context parallelism (ring attention over `sp`) and pipeline
-        # parallelism (layer stack over `pp`) both run teacher-forced
-        # forwards through shard_map and need the mesh on the model; so
-        # do the pallas kernels on more than one device (GSPMD cannot
-        # partition a Mosaic call)
-        if (
-            self.mesh.shape["sp"] > 1
-            or self.mesh.shape["pp"] > 1
-            or (
-                self.mesh.size > 1
-                and (
-                    getattr(self._lm().cfg, "attention_impl", None) == "pallas"
-                    or getattr(self._lm().cfg, "kv_cache_quant", None) == "int8"
-                )
-            )
-        ):
+        # parallelism (layer stack over `pp`) run teacher-forced forwards
+        # through shard_map, so do the pallas kernels (GSPMD cannot
+        # partition a Mosaic call), and a decode step lays its
+        # activations out by the `fsdp` axis (parallel/sharding.py
+        # `DecodeLayouts`)
+        if self.mesh.size > 1:
             self._lm().mesh = self.mesh
 
         self._update_mask = self.trainable_mask()
@@ -744,12 +740,16 @@ class TPUBaseTrainer(BaseRLTrainer):
         return self._generate_fns[key]
 
     def _note_decode_attn(self, key) -> None:
-        """Gauge `gen/decode_attn_fused`, once per built sampler, in the
-        flight stream and the tracker: 1 where its decode steps run the
-        fused kernel over the int8 cache (ops/decode_attention.py), 0
-        where they run anything else (Attention warns with the reason
-        when that is the XLA branch over an int8 cache). A fused
-        sampler's grid is kept for the `tokens_wait` span's counts."""
+        """Gauges of a built sampler, once each, in the flight stream
+        and the tracker. `gen/decode_attn_fused`: 1 where its decode
+        steps run the fused kernel over the int8 cache
+        (ops/decode_attention.py), 0 where they run anything else
+        (Attention warns with the reason when that is the XLA branch
+        over an int8 cache); a fused sampler's grid is kept for the
+        `tokens_wait` span's counts. `gen/decode_weights_stationary`: 1
+        where its decode steps multiply with the kernel shards each chip
+        holds and move the activations (a mesh whose `fsdp` axis shards
+        the kernels), 0 where no axis does or GSPMD lays the step out."""
         settings, (rows, prompt), _ = key
         virtual = 0
         if "prompt" in self.params:
@@ -760,7 +760,13 @@ class TPUBaseTrainer(BaseRLTrainer):
             self._lm(), rows, virtual, prompt, settings.max_new_tokens
         )
         self._decode_cells[key] = cells
-        gauge = {"gen/decode_attn_fused": int(cells is not None)}
+        stationary = settings.max_new_tokens > 1 and decode_weights_stationary(
+            self._lm().cfg, self._lm().mesh, rows
+        )
+        gauge = {
+            "gen/decode_attn_fused": int(cells is not None),
+            "gen/decode_weights_stationary": int(stationary),
+        }
         self.obs.gauge(**gauge)
         self._tracker_log(gauge, step=self.iter_count)
 
