@@ -87,10 +87,10 @@ TIER1_BUDGETS = {
     # (99.9 measured), fault_tolerance 65->63 (62.4), gen_engine 36->34
     # (32.6), memdoctor 37->35 (32).
     "test_graft_lint.py": 7,
-    "test_grpo.py": 40,
+    "test_grpo.py": 30,
     # r09: +4 preference-RL chaos learn() tests (GRPO nan/sigterm, DPO
     # nan/sigterm); whole file re-measured 99.9s serial
-    "test_guardrails.py": 99,
+    "test_guardrails.py": 75,
     # PR 28: the routed / latent-attention / four-stream family against its
     # float32 reference (logits, trainable gradients, cache decode, shares,
     # hydra cuts, int8 rollout weights, one Mosaic compile at keys 192 /
@@ -105,6 +105,21 @@ TIER1_BUDGETS = {
     # reference_harness 4->1 (0.4 s), curves 2->1 (0.1 s), deferred_stats
     # 2->1 (0.5 s), net 3->2 (2.8 s = 1.4), marker_audit 2->1 (0.2 s).
     "test_latent_moe.py": 43,
+    # PR 33: delta-rule linear attention with a recurrent state beside a
+    # rotary-free latent cache against its float32 reference (logits,
+    # trainable gradients through the chunked form's checkpointed scan,
+    # chunked against recurrent in five gate regimes, prefill then decode
+    # through both kinds of state, padding, the 32 shares, hydra cuts over
+    # mixed segments, the freeze mask, gauges and counts): one toy stack
+    # jitted once and one toy trainer, module scope. 105 s alone on this
+    # container (test_latent_moe: 75 s alone, 124.9 s inside the 6-worker
+    # run of 2026-10-02, whose files took 2,603 s against the 780
+    # budgeted: the table's scale is in-run seconds / 3.34), so about
+    # 175 s in such a run: budgeted 45. Paid under the unchanged 780
+    # ceiling with times of that run on that scale: guardrails 99->75
+    # (216.6 s = 64.9), grpo 40->30 (65.4 s = 19.6), scanned_epochs
+    # 31->20 (34.7 s = 10.4).
+    "test_linear_attention.py": 45,
     "test_marker_audit.py": 1,
     "test_mcts_value_branch.py": 5,
     # r10: memory-doctor suite (ladder units are fake-clock-fast; the
@@ -163,7 +178,7 @@ TIER1_BUDGETS = {
     "test_remat.py": 1,
     "test_resilient.py": 1,
     "test_ring_attention.py": 8,
-    "test_scanned_epochs.py": 31,
+    "test_scanned_epochs.py": 20,
     "test_seq2seq.py": 13,
     "test_serve.py": 26,
     "test_sharding.py": 7,

@@ -167,10 +167,31 @@ class TransformerConfig:
     sinkhorn_iters: int = 20
     hc_eps: float = 1e-6
     hc_clamp: float = 30.0
+    # the MIXER of each layer, one entry a layer: "softmax" | "latent" |
+    # "delta" (gated delta-rule linear attention, `DeltaAttention`:
+    # `delta_heads` heads of `delta_head_dim`, causal depthwise short
+    # convolutions of `delta_conv` taps, a recurrent state in place of a
+    # cache). None: every layer the attention the keys above describe
+    mixer_layers: Optional[Tuple[str, ...]] = None
+    delta_heads: int = 0
+    delta_head_dim: int = 0
+    delta_conv: int = 4
 
     @property
     def latent(self) -> bool:
         return self.kv_lora_rank is not None
+
+    @property
+    def mixers(self) -> Tuple[str, ...]:
+        """The mixer of each layer of the stack."""
+        if self.mixer_layers is not None:
+            return self.mixer_layers
+        return ("latent" if self.latent else "softmax",) * self.n_layer
+
+    @property
+    def hybrid(self) -> bool:
+        """Some layer keeps a recurrent state where the others keep a cache."""
+        return "delta" in self.mixers
 
     @property
     def routed(self) -> bool:
@@ -178,17 +199,34 @@ class TransformerConfig:
 
     @property
     def beyond_dense(self) -> bool:
-        """Latent attention, routed experts or several residual streams: the
-        models that the paths below the dense decoder (pipeline, ring, paged
-        engine, adapters, loaders) do not reach and raise for."""
-        return self.latent or self.routed or self.residual_streams > 1
+        """Latent attention, delta-rule layers, routed experts or several
+        residual streams: the models that the paths below the dense decoder
+        (pipeline, ring, paged engine, adapters, loaders) do not reach and
+        raise for."""
+        return self.latent or self.hybrid or self.routed or self.residual_streams > 1
 
     @property
     def cache_elems_per_position(self) -> int:
-        """Numbers one cached position costs a row, in one layer."""
+        """Numbers one cached position costs a row, in one layer THAT
+        CACHES (`cache_layers` of them; a delta layer caches nothing, its
+        state is `state_elems_per_row` whatever the length)."""
         if self.latent:
             return self.kv_lora_rank + self.qk_rope_head_dim
         return 2 * self.n_kv_head * self.head_dim
+
+    @property
+    def cache_layers(self) -> int:
+        """Layers whose cache grows with the sequence."""
+        return self.n_layer - self.mixers.count("delta")
+
+    @property
+    def state_elems_per_row(self) -> int:
+        """Numbers the delta layers keep a row, whatever its length: a
+        [heads, d_k, d_v] state and the last `delta_conv - 1` inputs of the
+        three convolutions, in each of them."""
+        width = self.delta_heads * self.delta_head_dim
+        return self.mixers.count("delta") * (
+            width * self.delta_head_dim + (self.delta_conv - 1) * 3 * width)
 
     @property
     def attn_softmax_scale(self) -> float:
@@ -215,6 +253,8 @@ class TransformerConfig:
             object.__setattr__(self, "rotary_dim", self.head_dim)
         if self.routed and self.n_experts_held is None:
             object.__setattr__(self, "n_experts_held", self.n_routed_experts)
+        if self.mixer_layers is not None:  # a list from a config file: hashable
+            object.__setattr__(self, "mixer_layers", tuple(self.mixer_layers))
         if self.kv_cache_quant not in (None, "int8"):
             raise ValueError(
                 f"kv_cache_quant={self.kv_cache_quant!r}: the values are None and "
@@ -224,23 +264,39 @@ class TransformerConfig:
         self._check_family()
 
     def _check_family(self) -> None:
-        """What a latent, routed or multi-stream model does not reach
-        raises here, at configuration time, not as a wrong answer later."""
+        """What a latent, delta-rule, routed or multi-stream model does not
+        reach raises here, at configuration time, not as a wrong answer later."""
+        if self.mixer_layers is not None:
+            base = "latent" if self.latent else "softmax"
+            if len(self.mixer_layers) != self.n_layer or set(self.mixer_layers) - {base, "delta"}:
+                raise ValueError(
+                    f"mixer_layers names one of {base!r} (the attention the other keys "
+                    f"describe) or 'delta' for each of the {self.n_layer} layers"
+                )
         if not self.beyond_dense:
             return
         def no(what):
             raise NotImplementedError(
                 f"{what} is not implemented for a model with latent attention, "
-                "routed experts or several residual streams"
+                "delta-rule (KDA) layers, routed experts or several residual streams"
             )
-        if self.latent and self.kv_cache_quant is not None:
-            no(f"kv_cache_quant={self.kv_cache_quant!r} (an int8 latent cache)")
+        if (self.latent or self.hybrid) and self.kv_cache_quant is not None:
+            no(f"kv_cache_quant={self.kv_cache_quant!r} (an int8 latent cache, or a cache "
+               "beside the float32 state of delta-rule layers)")
         if self.attention_impl == "ring":
             no("attention_impl='ring'")
-        if self.latent and (self.pos_embed != "rotary" or self.local_window is not None
+        if self.latent and (self.pos_embed not in ("rotary", "none") or self.local_window is not None
                             or self.n_kv_head != self.n_head or self.attn_scale is not None):
-            no("latent attention without rotary positions, with windows, grouped "
+            no("latent attention with learned or alibi positions, with windows, grouped "
                "heads or a set attn_scale")
+        if self.hybrid:
+            if not (self.delta_heads > 0 and self.delta_head_dim > 0 and self.delta_conv >= 2):
+                raise ValueError("delta-rule layers need delta_heads, delta_head_dim and delta_conv >= 2")
+            if "softmax" in self.mixers:
+                no("delta-rule (KDA) layers beside softmax attention layers (their per-head "
+                   "key and value cache)")
+            if len(set(self.mixers[: self.first_k_dense])) > 1:
+                no("leading dense layers of more than one mixer")
         if self.parallel_residual or self.embed_layernorm:
             no("parallel_residual / embed_layernorm")
         if self.routed:
@@ -258,6 +314,35 @@ class TransformerConfig:
 
     def replace(self, **kw) -> "TransformerConfig":
         return dataclasses.replace(self, **kw)
+
+
+# the stacks of a parameter tree, in the order a tree without delta-rule
+# layers has always had them; a layer's kind (mixer, feed-forward) names
+# its stack, so a stack holds layers equal in both
+STACKS = ("dense_blocks", "blocks", "delta_blocks")
+
+
+@functools.lru_cache(maxsize=None)
+def layer_stacks(cfg: TransformerConfig) -> Tuple[Tuple[str, int], ...]:
+    """(stack, row in it) of each layer of the whole stack: the
+    `first_k_dense` leading layers under `dense_blocks`, above them the
+    delta-rule layers under `delta_blocks` and the rest under `blocks`.
+    A SEGMENT is a run of consecutive layers of one stack (consecutive
+    rows of it): one `lax.scan`. Everything that addresses a layer by its
+    index in the whole stack (a branch point, the freeze mask, a cache
+    row) goes through this."""
+    out, rows = [], {}
+    for i, mixer in enumerate(cfg.mixers):
+        name = "dense_blocks" if i < cfg.first_k_dense else (
+            "delta_blocks" if mixer == "delta" else "blocks")
+        out.append((name, rows.get(name, 0)))
+        rows[name] = rows.get(name, 0) + 1
+    return tuple(out)
+
+
+def stack_layers(cfg: TransformerConfig, name: str) -> Tuple[int, ...]:
+    """Indices in the whole stack of the layers `name` holds, row by row."""
+    return tuple(i for i, (stack, _) in enumerate(layer_stacks(cfg)) if stack == name)
 
 
 def _activation(name: str) -> Callable[[Array], Array]:
@@ -866,15 +951,17 @@ def quantize_decode_weights(params: Dict) -> Dict:
     quantize_kv_cache above). Embeddings and the logit projection stay
     in compute dtype (the tied wte must serve lookups).
 
-    Only kernels under `blocks` (and the leading `dense_blocks`) dense
-    modules and the stacked expert kernels are rewritten; scan
-    xs-slicing delivers per-layer int8 kernels + scales to QDense and to
-    `RoutedMLP` automatically.
+    Only kernels under the layer stacks' (`STACKS`) dense modules and the
+    stacked expert kernels are rewritten; scan xs-slicing delivers
+    per-layer int8 kernels + scales to QDense and to `RoutedMLP`
+    automatically.
     """
     # feature rank by dense-module name (kernel = (L, inputs..., feats...));
     # of a latent attention the four projections that are used as written
     # (the up-projection of the cached latent, `kv_b`, is used transposed
-    # in the decode form and stays as it is, as does the float32 router)
+    # in the decode form and stays as it is, as does the float32 router);
+    # of a delta-rule layer q, k, v and o (95% of its elements: the two
+    # low-rank pairs and beta's projection feed float32 gates and stay)
     n_feats = {"q": 2, "k": 2, "v": 2, "o": 1,
                "fc_in": 1, "fc_gate": 1, "fc_out": 1,
                "q_a": 1, "q_b": 2, "kv_a": 1}
@@ -899,7 +986,9 @@ def quantize_decode_weights(params: Dict) -> Dict:
             out["kernel_scale"] = s.astype(jnp.float32)
         return out
 
-    stacks = {k: walk(params[k]) for k in ("blocks", "dense_blocks") if k in params}
+    # (in the order the rewrite has always walked them: the lowered sampler
+    # of a model without delta-rule layers stays what it was)
+    stacks = {k: walk(params[k]) for k in ("blocks", "dense_blocks", "delta_blocks") if k in params}
     return dict(params, **stacks)
 
 
@@ -1067,10 +1156,13 @@ class LatentAttention(nn.Module):
         c_kv = _rms_norm(kv[..., :rank], kv_scale, cfg.layer_norm_epsilon).astype(cfg.dtype)
         w_ukv = _Kernel((rank, H, dn + dv), cfg.param_dtype, name="kv_b")().astype(cfg.dtype)
 
-        cos, sin = rope_frequencies(cfg, positions)
-        q_n = q[..., :dn]
-        q_r = apply_rope(q[..., dn:], cos, sin, cfg.rotary_style)
-        k_r = apply_rope(kv[..., None, rank:], cos, sin, cfg.rotary_style)[:, :, 0]  # [B, T, dr]
+        if cfg.pos_embed == "rotary":
+            cos, sin = rope_frequencies(cfg, positions)
+            q_n = q[..., :dn]
+            q_r = apply_rope(q[..., dn:], cos, sin, cfg.rotary_style)
+            k_r = apply_rope(kv[..., None, rank:], cos, sin, cfg.rotary_style)[:, :, 0]  # [B, T, dr]
+        else:  # "none": the same channels, unrotated
+            q_n, q_r, k_r = q[..., :dn], q[..., dn:], kv[..., rank:]
         scale = cfg.attn_softmax_scale
 
         new_kv = None
@@ -1129,6 +1221,275 @@ class LatentAttention(nn.Module):
                     probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
                     out = jnp.einsum("bhts,bshd->bthd", probs, v)
 
+        proj = QDense(
+            features=E, axis=(-2, -1), dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+            kernel_init=nn.initializers.normal(0.02 / math.sqrt(2 * cfg.n_layer)),
+            use_bias=False, name="o",
+        )
+        return proj(out), new_kv
+
+
+# chunk of the delta rule's chunked form, and the sub-chunk inside which
+# differences of cumulative log-gates are exponentiated one by one. Read on
+# the chip at a train step's shape (8 x 1024, 32 heads of 128, forward and
+# backward of one layer; PERF.md section 6, PR 33): 64 / 16 53.9 ms, 32 / 16
+# 41.9, 32 / 8 34.4, 16 / 16 31.4, 16 / 8 28.4: in plain XLA the in-chunk
+# products and their intermediates cost more than the scan's steps. 32 / 8
+# and not 16 / 8: a backward pass keeps one [B, H, d, d] state a chunk, and
+# at 16 the train step plans 1.0 GiB more (compiled for a described v5e)
+# for 1% of the cycle
+KDA_CHUNK, KDA_SUB = 32, 8
+
+
+def _small_product(a: Array, b: Array) -> Array:
+    """a [..., i, j] @ b [..., j, k] for blocks of a few rows, as one multiply
+    and reduce on the vector unit, exact in float32: the matrix unit would pad
+    each to its 128 x 128 tile and, at the highest precision, pass over it
+    six times."""
+    return jnp.sum(a[..., :, :, None] * b[..., None, :, :], axis=-2)
+
+
+def _unit_lower_inverse(a: Array, base: int) -> Array:
+    """(I + a)^-1 for strictly lower-triangular `a` [..., C, C], C = base x
+    a power of two: the diagonal blocks of `base` rows by forward
+    substitution, a row at a time (on the chip faster than the product of
+    the blocks' doubled powers, on the matrix unit or off it: 53.9 against
+    60.5 and 68.0 ms at chunks of 64), then pairs of blocks merged,
+    [[T11, 0], [-T22 a21 T11, T22]], until one is left. Exact in float32."""
+    C = a.shape[-1]
+
+    def diag_blocks(m, size):  # [..., C, C] -> [..., C / size, size, size]
+        n = C // size
+        m = m.reshape(m.shape[:-2] + (n, size, n, size))
+        return jnp.stack([m[..., i, :, i, :] for i in range(n)], axis=-3)
+
+    blocks = diag_blocks(a, base)
+    eye = jnp.eye(base, dtype=a.dtype)
+    rows = [jnp.broadcast_to(eye[0], blocks.shape[:-2] + (base,))]
+    for r in range(1, base):
+        solved = jnp.stack(rows, axis=-2)  # [..., r, base]
+        rows.append(eye[r] - jnp.sum(blocks[..., r, :r, None] * solved, axis=-2))
+    t = jnp.stack(rows, axis=-2)  # [..., C / base, base, base]
+    size = base
+    while size < C:
+        a21 = diag_blocks(a, 2 * size)[..., size:, :size]
+        t11, t22 = t[..., 0::2, :, :], t[..., 1::2, :, :]
+        t21 = -_small_product(_small_product(t22, a21), t11)
+        t = jnp.concatenate([jnp.concatenate([t11, jnp.zeros_like(t11)], axis=-1),
+                             jnp.concatenate([t21, t22], axis=-1)], axis=-2)
+        size *= 2
+    return t[..., 0, :, :]
+
+
+def _kda_chunk(state: Array, xs, sub: int):
+    """One chunk of `kda_chunked` for every row and head: `state`
+    [B, H, dk, dv], q, k, g [B, H, C, dk], v [B, H, C, dv], beta [B, H, C]
+    -> (state after the chunk, o [B, H, C, dv]). With G the cumulative
+    log-gate inside the chunk and Gamma = exp(G):
+
+        A[r, i] = beta_r sum_c k_r[c] k_i[c] exp(G_r[c] - G_i[c])     i < r
+        P[r, i] =        sum_c q_r[c] k_i[c] exp(G_r[c] - G_i[c])     i <= r
+        U = (I + A)^-1 (beta v  -  (beta k Gamma) S)                  the WY / UT transform
+        o = (q Gamma) S + P U
+        S' = Diag(Gamma_C) S + (k Gamma_C / Gamma)^T U
+
+    Every exponent is a difference formed first and never positive: inside
+    a sub-chunk of `sub` positions the differences G_r - G_i are taken one
+    by one; across sub-chunks both factors decay towards the reference
+    point R_a = G at the end of the sub-chunk before r's, which lies
+    between G_i and G_r."""
+    q, k, v, g, beta = xs
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    B, H, C, D = k.shape
+    n = C // sub
+    G = jnp.cumsum(g, axis=2)
+    Gs = G.reshape(B, H, n, sub, D)
+    R = jnp.concatenate([jnp.zeros_like(Gs[:, :, :1, 0]), Gs[:, :, :-1, -1]], axis=2)  # [B, H, n, D]
+    to_ref = jnp.exp(Gs - R[:, :, :, None])  # from the reference point down to r
+    ks, qs = k.reshape(B, H, n, sub, D), q.reshape(B, H, n, sub, D)
+    # keys decayed from i down to sub-chunk a's reference point (i in an earlier sub-chunk)
+    k_ref = k[:, :, None] * jnp.exp(jnp.minimum(R[:, :, :, None] - G[:, :, None], 0.0))  # [B, H, n, C, D]
+    tri = jnp.tril(jnp.ones((sub, sub), bool))
+    within = jnp.exp(jnp.where(tri[..., None], Gs[:, :, :, :, None] - Gs[:, :, :, None], -jnp.inf))
+    eye = jnp.eye(n, dtype=k.dtype)
+    earlier = (jnp.arange(C)[:, None] // sub > jnp.arange(C)[None, :] // sub)
+
+    def pairs(rows):  # rows [B, H, n, sub, D]: q or k at r -> [B, H, C, C] over (r, i), i <= r
+        across = jnp.einsum("bhard,bhaid->bhari", rows * to_ref, k_ref).reshape(B, H, C, C)
+        inside = jnp.sum(rows[:, :, :, :, None] * ks[:, :, :, None] * within, axis=-1)  # [B, H, n, sub, sub]
+        inside = jnp.einsum("bhari,ae->bharei", inside, eye).reshape(B, H, C, C)
+        return jnp.where(earlier, across, 0.0) + inside
+
+    strict = jnp.tril(jnp.ones((C, C), bool), -1)
+    t = _unit_lower_inverse(jnp.where(strict, beta[..., None] * pairs(ks), 0.0), sub)
+    gamma = jnp.exp(G)
+    w_v = jnp.einsum("bhri,bhid->bhrd", t, beta[..., None] * v)
+    w_k = jnp.einsum("bhri,bhid->bhrd", t, beta[..., None] * k * gamma)
+    u = w_v - jnp.einsum("bhrk,bhkd->bhrd", w_k, state)
+    o = jnp.einsum("bhrk,bhkd->bhrd", q * gamma, state) + jnp.einsum("bhri,bhid->bhrd", pairs(qs), u)
+    to_end = jnp.exp(G[:, :, -1:] - G)
+    state = gamma[:, :, -1, :, None] * state + jnp.einsum("bhik,bhid->bhkd", k * to_end, u)
+    return state, o
+
+
+def kda_chunked(q: Array, k: Array, v: Array, g: Array, beta: Array,
+                state: Optional[Array] = None, chunk: int = KDA_CHUNK,
+                sub: int = KDA_SUB) -> Tuple[Array, Array]:
+    """The gated delta rule over T positions in chunks:
+
+        S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + beta_t k_t v_t^T;   o_t = S_t^T q_t
+
+    q, k [B, T, H, dk], v [B, T, H, dv] (any float dtype: they enter the
+    products rounded to the compute dtype either way, and are laid out in
+    chunks as they come), g [B, T, H, dk] and beta [B, T, H] float32,
+    `state` [B, H, dk, dv] (None: zeros) -> (o [B, T, H, dv] float32, the
+    state after position T). One state is handed from chunk to chunk by a scan
+    over ceil(T / chunk) steps; inside a chunk `_kda_chunk`. T is padded
+    to whole chunks with positions that change nothing (beta 0, g 0). The
+    scan's body is checkpointed: a backward pass keeps one state a chunk
+    and recomputes the chunk's inside."""
+    B, T, H, D = k.shape
+    if state is None:
+        state = jnp.zeros((B, H, D, v.shape[-1]), jnp.float32)
+    pad = (-T) % chunk
+
+    def chunks(x):  # [B, T, H, ...] -> [T / chunk, B, H, chunk, ...]
+        x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+        x = x.reshape((B, (T + pad) // chunk, chunk) + x.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(x, 1, 0), 2, 3)
+
+    body = jax.checkpoint(functools.partial(_kda_chunk, sub=sub))
+    state, o = jax.lax.scan(body, state, tuple(chunks(x) for x in (q, k, v, g, beta)))
+    o = jnp.moveaxis(jnp.moveaxis(o, 3, 2), 0, 1)  # [B, T / chunk, chunk, H, dv]
+    return o.reshape((B, T + pad) + o.shape[3:])[:, :T], state
+
+
+def kda_step(q: Array, k: Array, v: Array, g: Array, beta: Array, state: Array) -> Tuple[Array, Array]:
+    """One position of the same rule on a carried state: q, k, g [B, H, dk],
+    v [B, H, dv], beta [B, H], `state` [B, H, dk, dv] float32 -> (o [B, H, dv],
+    the new state). Elementwise and exact in float32: the state is read and
+    written, nothing of it multiplied in a lower precision."""
+    state = state * jnp.exp(g)[..., None]
+    seen = jnp.sum(state * k[..., None], axis=-2)  # S^T k
+    state = state + (beta[..., None] * k)[..., None] * (v - seen)[..., None, :]
+    return jnp.sum(state * q[..., None], axis=-2), state
+
+
+def _conv_tap_init(key, shape, dtype=jnp.float32):
+    """U(-1 / sqrt(taps), 1 / sqrt(taps)): a depthwise convolution's usual start."""
+    bound = 1.0 / math.sqrt(shape[0])
+    return jax.random.uniform(key, shape, dtype, -bound, bound)
+
+
+def _a_log_init(key, shape, dtype=jnp.float32):
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+def _dt_bias_init(key, shape, dtype=jnp.float32):
+    """Inverse softplus of dt = exp(U(log 0.001, log 0.1))."""
+    dt = jnp.exp(jax.random.uniform(key, shape, dtype, math.log(0.001), math.log(0.1)))
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+class DeltaAttention(nn.Module):
+    """Gated delta-rule linear attention with a per-channel decay (KDA): H
+    heads of d, a recurrent state S [d, d] a head in place of a cache.
+
+        q~, k~, v = SiLU(Conv(x W_q)), SiLU(Conv(x W_k)), SiLU(Conv(x W_v))
+            Conv: causal depthwise, y_t = sum_i w[i] u_{t - (taps - 1) + i}, zeros before the start
+        q = q~ / |q~| * d^-0.5;  k = k~ / |k~|                          per head
+        g = -exp(A_log) softplus((x W_fa) W_fb + dt_bias)               log-decay per channel, float32
+        beta = sigmoid(x W_b)                                           per head, float32
+        S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + beta_t k_t v_t^T;   o_t = S_t^T q_t
+        y = (RMSNorm_head(o) * sigmoid((x W_ga) W_gb)) W_o
+
+    A position whose mask is 0 changes nothing: its convolution input is
+    zero, its beta 0 and its g 0, so a left-padded row reaches its first
+    token with S = 0 and an empty window.
+
+    Two forms, chosen by the shape of the call and by nothing else:
+    - teacher-forced and prefill (T > 1), scope `kda_chunk`: the chunked
+      form (`kda_chunked`). A prefill starts from the state and the window
+      the cache holds (zeros) and leaves the state after its last position
+      and its last `taps - 1` convolution inputs there.
+    - a decode step (T == 1 with a cache), scope `kda_step`: one step of
+      the rule on the carried state (`kda_step`).
+    The cache of a segment of such layers (`TransformerLM.init_cache`):
+    `s` [layers, B, H, d, d] float32 and `u` [layers, B, taps - 1, 3 H d];
+    a layer reads and writes its own row `ix`.
+    """
+
+    cfg: TransformerConfig
+    mesh: Any = None
+
+    @nn.compact
+    def __call__(self, x, attn_bias, positions, cache=None, key_mask=None, ring_mesh=None):
+        cfg = self.cfg
+        B, T, E = x.shape
+        H, D, taps = cfg.delta_heads, cfg.delta_head_dim, cfg.delta_conv
+        W = H * D
+        dense = partial(QDense, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                        kernel_init=nn.initializers.normal(0.02), use_bias=False)
+        if key_mask is None:
+            live = jnp.ones((B, T), jnp.float32)
+        elif cache is None:
+            live = key_mask.astype(jnp.float32)
+        else:  # the mask of the slots this call writes
+            live = jax.lax.dynamic_slice_in_dim(key_mask, cache["index"], T, axis=1).astype(jnp.float32)
+
+        u = jnp.concatenate(
+            [dense(features=(H, D), name=name)(x).reshape(B, T, W) for name in ("q", "k", "v")], axis=-1)
+        with jax.named_scope("kda_conv"):
+            u = u * live[..., None].astype(u.dtype)
+            tap = lambda name: self.param(name, _conv_tap_init, (taps, W), jnp.float32)
+            w = jnp.concatenate([tap("conv_q"), tap("conv_k"), tap("conv_v")], axis=-1)  # [taps, 3 W]
+            if cache is None:
+                before = jnp.zeros((B, taps - 1, 3 * W), u.dtype)
+            else:
+                before = jax.lax.dynamic_index_in_dim(cache["u"], cache["ix"], 0, keepdims=False).astype(u.dtype)
+            window = jnp.concatenate([before, u], axis=1)  # [B, taps - 1 + T, 3 W]
+            y = sum(w[i] * window[:, i : i + T].astype(jnp.float32) for i in range(taps))
+            q, k, v = (a.reshape(B, T, H, D) for a in jnp.split(jax.nn.silu(y), 3, axis=-1))
+            q = q * jax.lax.rsqrt(jnp.sum(q * q, axis=-1, keepdims=True) + 1e-6) * D ** -0.5
+            k = k * jax.lax.rsqrt(jnp.sum(k * k, axis=-1, keepdims=True) + 1e-6)
+        with jax.named_scope("kda_gate"):
+            a_log = self.param("A_log", _a_log_init, (H,), jnp.float32)
+            dt_bias = self.param("dt_bias", _dt_bias_init, (H, D), jnp.float32)
+            f = dense(features=(H, D), name="f_b")(dense(features=D, name="f_a")(x))
+            g = -jnp.exp(a_log)[:, None] * jax.nn.softplus(f.astype(jnp.float32) + dt_bias)
+            g = g * live[..., None, None]  # [B, T, H, D]
+            beta = jax.nn.sigmoid(dense(features=H, name="b")(x).astype(jnp.float32)) * live[..., None]
+
+        # with a cache, this layer's state comes out of the carried array and
+        # goes back into it under the scope of the form that runs, so that the
+        # scope's seconds hold every pass over the state
+        with jax.named_scope("kda_step" if cache is not None and T == 1 else "kda_chunk"):
+            state = None
+            if cache is not None:
+                # taken out before anything else touches it: read through two
+                # fusions and written back in a third, the compiler copied the
+                # whole array twice a layer a decode step (the barrier keeps
+                # the slice a value of its own)
+                state = jax.lax.optimization_barrier(
+                    jax.lax.dynamic_index_in_dim(cache["s"], cache["ix"], 0, keepdims=False))
+            if cache is not None and T == 1:
+                o, state = kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], state)
+                o = o[:, None]
+            else:
+                o, state = kda_chunked(*(x.astype(cfg.dtype) for x in (q, k, v)), g, beta, state)
+            new_kv = None
+            if cache is not None:
+                new_kv = {
+                    "s": jax.lax.dynamic_update_slice(cache["s"], state[None], (cache["ix"], 0, 0, 0, 0)),
+                    "u": jax.lax.dynamic_update_slice(
+                        cache["u"], window[None, :, T:].astype(cache["u"].dtype), (cache["ix"], 0, 0, 0)),
+                }
+
+        with jax.named_scope("kda_gate"):
+            o_scale = self.param("o_norm", nn.initializers.ones, (D,), cfg.param_dtype)
+            gate = dense(features=(H, D), name="g_b")(dense(features=D, name="g_a")(x))
+            out = (_rms_norm(o, o_scale, cfg.layer_norm_epsilon)
+                   * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(cfg.dtype)
         proj = QDense(
             features=E, axis=(-2, -1), dtype=cfg.dtype, param_dtype=cfg.param_dtype,
             kernel_init=nn.initializers.normal(0.02 / math.sqrt(2 * cfg.n_layer)),
@@ -1362,6 +1723,7 @@ class Block(nn.Module):
     cfg: TransformerConfig
     mesh: Any = None  # forwarded to Attention
     kind: str = "dense"  # "dense" | "routed"
+    mixer: str = ""  # "softmax" | "latent" | "delta"; "": the attention cfg describes
 
     @nn.compact
     def __call__(
@@ -1374,7 +1736,9 @@ class Block(nn.Module):
         ring_mesh=None,
     ) -> Tuple[Array, Optional[Dict[str, Array]], Optional[Dict[str, Array]]]:
         cfg = self.cfg
-        attention = (LatentAttention if cfg.latent else Attention)(cfg, self.mesh, name="attn")
+        mixer = self.mixer or ("latent" if cfg.latent else "softmax")
+        attention = {"softmax": Attention, "latent": LatentAttention,
+                     "delta": DeltaAttention}[mixer](cfg, self.mesh, name="attn")
 
         # a decode step on a sharded mesh: the residual stays rows by
         # chip; what the sub-layers read is split over E once, and what
@@ -1568,18 +1932,34 @@ def moe_counters(stats: Optional[Dict[str, Array]], program: str) -> Dict[str, A
     }
 
 
-def _with_router_bias(tree: Dict, bias: Array) -> Dict:
-    return dict(tree, blocks=dict(tree["blocks"], moe=dict(tree["blocks"]["moe"], router_bias=bias)))
+def router_bias(tree: Dict) -> Dict[str, Array]:
+    """The routers' selection bias by stack, {stack: [rows, published experts]}."""
+    return {name: tree[name]["moe"]["router_bias"] for name in STACKS
+            if name in tree and "moe" in tree[name]}
 
 
-def _balance_step(lm: "TransformerLM", tree: Dict, bias: Array, input_ids: Array,
-                  attention_mask: Array, rate: Array) -> Tuple[Array, Array]:
+def with_router_bias(tree: Dict, bias: Dict[str, Array]) -> Dict:
+    """`tree` with each routed stack's selection bias replaced by `bias[stack]`."""
+    return dict(tree, **{
+        name: dict(tree[name], moe=dict(tree[name]["moe"], router_bias=new))
+        for name, new in bias.items()})
+
+
+def _balance_step(lm: "TransformerLM", tree: Dict, bias: Dict[str, Array], input_ids: Array,
+                  attention_mask: Array, rate: Array) -> Tuple[Dict[str, Array], Array]:
     """One step of `balance_router_bias`: (the new bias, the fullest expert's
     choices over the mean under the old one, by layer)."""
-    out = lm(_with_router_bias(tree, bias), input_ids, attention_mask, compute_logits=False)
-    choices = out["moe_stats"]["choices"]  # [routed layers, published experts]
+    out = lm(with_router_bias(tree, bias), input_ids, attention_mask, compute_logits=False)
+    choices = out["moe_stats"]["choices"]  # [routed layers, published experts], in layer order
     mean = jnp.mean(choices, axis=-1, keepdims=True)
-    return bias + rate * jnp.sign(mean - choices), jnp.max(choices, axis=-1) / mean[:, 0]
+    step = rate * jnp.sign(mean - choices)
+    lead = lm.cfg.first_k_dense
+    new = {}
+    for name, old in bias.items():
+        rows = [layer - lead for layer in stack_layers(lm.cfg, name)]  # this stack's layers among the routed ones
+        # (one routed stack holds every routed layer, in order: no gather)
+        new[name] = old + (step if len(bias) == 1 else step[jnp.array(rows)])
+    return new, jnp.max(choices, axis=-1) / mean[:, 0]
 
 
 def balance_router_bias(lm: "TransformerLM", params: Dict, input_ids: Array,
@@ -1601,7 +1981,7 @@ def balance_router_bias(lm: "TransformerLM", params: Dict, input_ids: Array,
     fullest expert's choices over the mean before the first and after the
     last step, [2, routed layers]."""
     step = jax.jit(functools.partial(_balance_step, lm))
-    bias = params["blocks"]["moe"]["router_bias"]
+    bias = router_bias(params)
     ratios = []
     for i in range(steps):
         rate = rates[0] * (rates[1] / rates[0]) ** (i / max(steps - 1, 1))
@@ -1609,7 +1989,7 @@ def balance_router_bias(lm: "TransformerLM", params: Dict, input_ids: Array,
         ratios.append(ratio)
     # one more forward, at rate 0: what the last step left
     ratios.append(step(params, bias, input_ids, attention_mask, jnp.float32(0.0))[1])
-    return _with_router_bias(params, bias), jnp.stack([ratios[0], ratios[-1]])
+    return with_router_bias(params, bias), jnp.stack([ratios[0], ratios[-1]])
 
 
 class TransformerLM:
@@ -1636,13 +2016,20 @@ class TransformerLM:
         self.lm_head = None if cfg.tie_word_embeddings else LMHead(cfg)
 
     def _build_blocks(self) -> None:
-        """The stack is segments of a kind: `first_k_dense` leading dense
-        layers (`params["dense_blocks"]`, module `lead_block`), then the
-        rest (`params["blocks"]`, module `block`): routed where the model
-        has experts, dense otherwise. Each segment is one scan."""
+        """The stack is segments of a kind (`layer_stacks`): a run of
+        layers equal in mixer AND feed-forward. `first_k_dense` leading
+        dense layers (`params["dense_blocks"]`), then the rest, routed
+        where the model has experts, dense otherwise: the delta-rule
+        layers under `params["delta_blocks"]`, the others under
+        `params["blocks"]`. One module a stack (`blocks[name]`), one scan
+        a segment."""
         cfg = self.cfg
-        self.block = Block(cfg, self._mesh, kind="routed" if cfg.routed else "dense")
-        self.lead_block = Block(cfg, self._mesh, kind="dense") if cfg.first_k_dense else None
+        self.blocks: Dict[str, Block] = {}
+        for (name, _), mixer in zip(layer_stacks(cfg), cfg.mixers):
+            if name not in self.blocks:
+                routed = cfg.routed and name != "dense_blocks"
+                self.blocks[name] = Block(cfg, self._mesh, kind="routed" if routed else "dense", mixer=mixer)
+        self.block = self.blocks.get("blocks")  # the one stack of a dense decoder (pipelining)
 
     @property
     def mesh(self):
@@ -1712,7 +2099,7 @@ class TransformerLM:
         ):
             raise NotImplementedError(
                 "pipeline parallelism (pp > 1) is not implemented for a model with latent "
-                "attention, routed experts or several residual streams"
+                "attention, delta-rule (KDA) layers, routed experts or several residual streams"
             )
         if cache is not None:
             return 0
@@ -1850,13 +2237,9 @@ class TransformerLM:
             return jax.lax.map(init, keys) if block.kind == "routed" else jax.vmap(init)(keys)
 
         layer_keys = jax.random.split(r_block, cfg.n_layer)
-        params = {
-            "embed": embed_params,
-            "blocks": stacked(self.block, layer_keys[cfg.first_k_dense:]),
-            "ln_f": self.ln_f.init(r_head, h)["params"],
-        }
-        if cfg.first_k_dense:
-            params["dense_blocks"] = stacked(self.lead_block, layer_keys[: cfg.first_k_dense])
+        params = {"embed": embed_params, "ln_f": self.ln_f.init(r_head, h)["params"]}
+        for name, block in self.blocks.items():  # a layer's key is its index's, in whatever stack it lies
+            params[name] = stacked(block, layer_keys[jnp.array(stack_layers(cfg, name))])
         if cfg.embed_layernorm:
             params["ln_embed"] = self.ln_f.init(r_head, h)["params"]
         if self.lm_head is not None:
@@ -1864,25 +2247,6 @@ class TransformerLM:
         return params
 
     # -- forward ---------------------------------------------------------
-
-    def _scan_blocks(
-        self,
-        block_params: Dict,
-        h: Array,
-        attn_bias: Array,
-        positions: Array,
-        cache: Optional[Dict[str, Array]] = None,
-        remat: bool = False,
-        key_mask: Optional[Array] = None,
-        local_bias: Optional[Array] = None,
-        layer_offset: int = 0,
-        ring_mesh=None,
-    ) -> Tuple[Array, Optional[Dict[str, Array]]]:
-        """`_scan_segment` over layers of the main kind, without the counters."""
-        return self._scan_segment(
-            block_params, h, attn_bias, positions, cache, remat, key_mask,
-            local_bias, layer_offset, ring_mesh,
-        )[:2]
 
     def _scan_segment(
         self,
@@ -1896,24 +2260,67 @@ class TransformerLM:
         local_bias: Optional[Array] = None,
         layer_offset: int = 0,
         ring_mesh=None,
-        block: Optional[Block] = None,
+        stack: str = "blocks",
+        rows: Optional[Tuple[int, int]] = None,
     ) -> Tuple[Array, Optional[Dict[str, Array]], Optional[Dict[str, Array]]]:
         """lax.scan over the stacked layer params (and cache layers) of
-        ONE segment: layers of a kind, run by `block` (default: the main
+        ONE segment: layers of a kind, rows of `stack` (default: the main
         kind). `layer_offset` locates this slice within the full stack so
         per-layer attention kinds (gpt-neo global/local) and the layers'
-        rows of a latent cache line up. Returns (h, new_cache, stats):
-        stats the routed layers' counters folded over the segment, or None.
+        rows of a latent cache or a recurrent state line up. Returns
+        (h, new_cache, stats): stats the routed layers' counters folded
+        over the segment, or None. `rows` (first row, count): the segment is
+        those rows of `block_params`, indexed in place a layer at a time
+        (with a cache: a slice of the stack would be copied, every weight
+        of it, at every decode step).
 
         Cache path: the [L, B, S, Hkv, D] buffers are CARRIED through
         the scan; each layer's attention writes only its new
         [B, T, Hkv, D] column in place and attends against a slice of
         the updated buffer (update-carry-first — the full design
         history and measured costs are in Attention.__call__)."""
-        n = jax.tree_util.tree_leaves(block_params)[0].shape[0]
+        n = rows[1] if rows else jax.tree_util.tree_leaves(block_params)[0].shape[0]
         flags = self._layer_flags(n, layer_offset)
-        blk = self.block if block is None else block
+        blk = self.blocks[stack]
         from trlx_tpu.ops.remat import wrap_remat
+
+        def layers_of(xs):  # the scan's xs, and how its body finds a layer's parameters
+            if rows is None:
+                return dict(xs, p=block_params), lambda layer: layer["p"]
+            at = lambda layer: jax.tree_util.tree_map(
+                lambda x: jax.lax.dynamic_index_in_dim(x, layer["row"], 0, keepdims=False), block_params)
+            return dict(xs, row=rows[0] + jnp.arange(n)), at
+
+        if cache is not None and "pk" in cache and self.cfg.beyond_dense:
+            raise NotImplementedError(
+                "the paged decode engine (models/gen_engine.py) has no latent page pool, "
+                "keeps no recurrent state for delta-rule (KDA) layers and runs no routed "
+                "or multi-stream layer"
+            )
+        lead = "_lead" if stack == "dense_blocks" else ""
+        if cache is not None and blk.mixer == "delta":
+            # recurrent state and convolution inputs, this stack's rows
+            # (`kda_s` [layers, B, H, d, d] float32, `kda_u` [layers, B,
+            # taps - 1, 3 H d]): carried whole through the scan, as the
+            # latent rows below are; a layer reads and writes its own row
+            s_key, u_key = "kda_s" + lead, "kda_u" + lead
+
+            xs, params_of = layers_of({"ix": layer_stacks(self.cfg)[layer_offset][1] + jnp.arange(n)})
+
+            def delta_body(carry, layer):
+                hidden, s, u = carry
+                layer_cache = {"s": s, "u": u, "ix": layer["ix"], "index": cache["index"]}
+                out, new_kv, stats = blk.apply(
+                    {"params": params_of(layer)}, hidden, attn_bias, positions, layer_cache,
+                    key_mask, ring_mesh,
+                )
+                return (out, new_kv["s"], new_kv["u"]), stats
+
+            (h, s, u), stats = jax.lax.scan(
+                wrap_remat(delta_body, remat), (h, cache[s_key], cache[u_key]), xs)
+            new_cache = {k: v for k, v in cache.items() if k != "static_index"}
+            new_cache.update({s_key: s, u_key: u, "index": cache["index"] + positions.shape[1]})
+            return h, new_cache, _fold_layer_stats(stats)
 
         if cache is not None and "c" in cache:
             # latent cache, this segment's rows [layers, B, S, rank + rope]
@@ -1922,9 +2329,10 @@ class TransformerLM:
             # between them, twice a decode step): carried like the dense
             # one; each layer writes its positions' row in place and reads
             # its own [B, S, rank + rope] slice
-            lead = blk is self.lead_block
-            rows = "c_lead" if lead else "c"
-            row0 = layer_offset - (0 if lead else self.cfg.first_k_dense)
+            c_key = "c" + lead
+            row0 = layer_stacks(self.cfg)[layer_offset][1]
+
+            xs, params_of = layers_of({"ix": row0 + jnp.arange(n)})
 
             def latent_body(carry, layer):
                 hidden, c = carry
@@ -1932,25 +2340,18 @@ class TransformerLM:
                 if "static_index" in cache:
                     layer_cache["static_index"] = cache["static_index"]
                 out, new_kv, stats = blk.apply(
-                    {"params": layer["p"]}, hidden, attn_bias, positions, layer_cache,
+                    {"params": params_of(layer)}, hidden, attn_bias, positions, layer_cache,
                     key_mask, ring_mesh,
                 )
                 return (out, new_kv["c"]), stats
 
             (h, c), stats = jax.lax.scan(
-                wrap_remat(latent_body, remat), (h, cache[rows]),
-                {"p": block_params, "ix": row0 + jnp.arange(n)},
-            )
+                wrap_remat(latent_body, remat), (h, cache[c_key]), xs)
             new_cache = {k: v for k, v in cache.items() if k != "static_index"}
-            new_cache.update({rows: c, "index": cache["index"] + positions.shape[1]})
+            new_cache.update({c_key: c, "index": cache["index"] + positions.shape[1]})
             return h, new_cache, _fold_layer_stats(stats)
 
         if cache is not None and "pk" in cache:
-            if self.cfg.beyond_dense:
-                raise NotImplementedError(
-                    "the paged decode engine (models/gen_engine.py) has no latent page pool "
-                    "and runs no routed or multi-stream layer"
-                )
             # paged cache: the scan carries the page POOLS; the page
             # table / slot positions / validity masks are per-forward
             # constants (the engine advances them between forwards), so
@@ -2086,23 +2487,32 @@ class TransformerLM:
         cache: Optional[Dict[str, Array]] = None,
         **kw,
     ) -> Tuple[Array, Optional[Dict[str, Array]], Optional[Dict[str, Array]]]:
-        """Layers [lo, hi) of a WHOLE tree (`dense_blocks` then `blocks`),
-        segment by segment; a cache advances its index once."""
-        k = self.cfg.first_k_dense
+        """Layers [lo, hi) of the whole stack, segment by segment
+        (`layer_stacks`); a cache advances its index once. `params` is a
+        whole tree or a BRANCH (`extract_branch_params`): of each stack
+        the rows of the layers at or above a branch point, so a layer's
+        row there is its row in the whole stack less what the branch
+        left behind."""
+        plan = layer_stacks(self.cfg)
         stats = None
         index = None if cache is None else (cache.get("index"), cache.get("static_index"))
-        for name, block, start, end in (
-            ("dense_blocks", self.lead_block, lo, min(hi, k)),
-            ("blocks", self.block, max(lo, k), hi),
-        ):
-            if end <= start:
-                continue
-            base = 0 if name == "dense_blocks" else k
+        start = lo
+        while start < hi:
+            name, row = plan[start]
+            end = start + 1
+            while end < hi and plan[end][0] == name:
+                end += 1
             stack = params[name]
-            if (start - base, end - base) != (0, jax.tree_util.tree_leaves(stack)[0].shape[0]):
-                stack = jax.tree_util.tree_map(lambda x: x[start - base : end - base], stack)
+            held = jax.tree_util.tree_leaves(stack)[0].shape[0]
+            row -= len(stack_layers(self.cfg, name)) - held
+            rows = None
+            if (row, row + end - start) != (0, held):
+                if cache is not None and "pk" not in cache:
+                    rows = (row, end - start)  # a decode step copies no weight: the rows in place
+                else:
+                    stack = jax.tree_util.tree_map(lambda x: x[row : row + end - start], stack)
             h, new_cache, seg_stats = self._scan_segment(
-                stack, h, attn_bias, positions, cache, layer_offset=start, block=block, **kw)
+                stack, h, attn_bias, positions, cache, layer_offset=start, stack=name, rows=rows, **kw)
             stats = _join_stats(stats, seg_stats)
             if cache is not None:
                 cache = new_cache
@@ -2110,6 +2520,7 @@ class TransformerLM:
                     cache = dict(cache, index=index[0])
                     if index[1] is not None:
                         cache["static_index"] = index[1]
+            start = end
         return h, cache, stats
 
     def __call__(
@@ -2202,7 +2613,7 @@ class TransformerLM:
         elif cache is not None:
             # bf16 cache: [L, B, S, Hkv, D]; int8 (quantized) cache:
             # [L, B, Hkv, S, D] (layout rationale: quantize_kv_cache)
-            S = cache["c"].shape[2] if "c" in cache else cache["k"].shape[3 if "k_scale" in cache else 2]
+            S = cache["key_mask"].shape[1]  # one slot a key, whatever the layers keep of it
             q_slots = cache["index"] + jnp.arange(T)
             if positions is None:
                 positions = q_slots[None, :] * jnp.ones((B, 1), jnp.int32)
@@ -2227,7 +2638,7 @@ class TransformerLM:
         if self.cfg.beyond_dense and (prefix_embeds is not None or kv_prefix is not None):
             raise NotImplementedError(
                 "prompt and prefix adapters are not implemented for a model with latent "
-                "attention, routed experts or several residual streams"
+                "attention, delta-rule (KDA) layers, routed experts or several residual streams"
             )
         h = self._embed_h(params, input_ids, positions)
         if prefix_embeds is not None:
@@ -2446,22 +2857,23 @@ class TransformerLM:
     ) -> Dict[str, Array]:
         """Run only a top-k branch from a captured hidden state.
 
-        `branch_params` holds {"blocks": stacked top-k params, "ln_f",
+        `branch_params` holds {"blocks": stacked top-k params (with
+        delta-rule layers among them, those under "delta_blocks"), "ln_f",
         "embed", ["lm_head"]} — the frozen in-process reference model
         (parity: hydra `forward_hydra`, reference modeling_ppo.py:410-453).
         The branch is always the TOP k layers, so per-layer attention
         kinds are aligned from the end of the stack. With `attn_bias=None`
         (ring-attention capture) the padding mask rides in `key_mask`.
         """
-        k = jax.tree_util.tree_leaves(branch_params["blocks"])[0].shape[0]
+        k = sum(jax.tree_util.tree_leaves(branch_params[name])[0].shape[0]
+                for name in STACKS if name in branch_params)
         ring = None
         if attn_bias is None and key_mask is not None:
             B, T = branch_hidden.shape[:2]
             ring = self._ring_mesh(B, T, None)
-        h, _, stats = self._scan_segment(
-            branch_params["blocks"], branch_hidden, attn_bias, positions,
-            remat=remat, local_bias=local_bias,
-            layer_offset=self.cfg.n_layer - k,
+        h, _, stats = self._run_layers(
+            branch_params, branch_hidden, self.cfg.n_layer - k, self.cfg.n_layer,
+            attn_bias, positions, remat=remat, local_bias=local_bias,
             key_mask=key_mask, ring_mesh=ring,
         )
         hidden = self._final_hidden(branch_params["ln_f"], h)
@@ -2484,6 +2896,24 @@ class TransformerLM:
         cases just fall back to the XLA path."""
         cfg = self.cfg
         mask = key_mask if key_mask is not None else jnp.ones((batch, max_len), jnp.int32)
+        if cfg.hybrid:
+            # two kinds of state in one carry, each stack's in its own
+            # arrays: latent rows that grow with the sequence (`c`,
+            # `c_lead`, as below) and, for delta-rule layers, a float32
+            # state and the convolutions' last inputs, which do not
+            # (`kda_s`, `kda_u`; `_lead` for the leading dense layers)
+            cache = {"index": jnp.int32(0), "static_index": 0, "key_mask": mask}
+            H, D, width = cfg.delta_heads, cfg.delta_head_dim, 3 * cfg.delta_heads * cfg.delta_head_dim
+            for name, block in self.blocks.items():
+                layers = len(stack_layers(cfg, name))
+                lead = "_lead" if name == "dense_blocks" else ""
+                if block.mixer == "delta":
+                    cache["kda_s" + lead] = jnp.zeros((layers, batch, H, D, D), jnp.float32)
+                    cache["kda_u" + lead] = jnp.zeros((layers, batch, cfg.delta_conv - 1, width), cfg.dtype)
+                else:
+                    cache["c" + lead] = jnp.zeros(
+                        (layers, batch, max_len, cfg.cache_elems_per_position), cfg.dtype)
+            return cache
         if cfg.latent:
             # [layers, B, S, rank + rope]: the normed latent and the rotated
             # shared key of each position, not per-head keys and values; a
@@ -2508,22 +2938,27 @@ class TransformerLM:
         }
 
 
-def extract_branch_params(params: Dict, branch_at: int) -> Dict:
+def extract_branch_params(params: Dict, branch_at: int, cfg: Optional[TransformerConfig] = None) -> Dict:
     """Copy the top-(L-branch_at) layers + final norm + logit head as a
     frozen reference branch. Parity: the hydra 'frozen_head' build
     (reference modeling_ppo.py:475-499) without per-arch classes. A branch
-    is layers of the main kind: it forks at or above the leading dense
-    layers (`params["dense_blocks"]`), which it leaves behind."""
+    forks at or above the leading dense layers (`params["dense_blocks"]`),
+    which it leaves behind; of every other stack it takes the rows of the
+    layers at or above `branch_at` (`cfg` says which those are where the
+    tree has delta-rule layers; without them they are the top rows of
+    `blocks`)."""
     lead = jax.tree_util.tree_leaves(params["dense_blocks"])[0].shape[0] if "dense_blocks" in params else 0
     if branch_at < lead:
         raise NotImplementedError(
             f"a branch at layer {branch_at} would reach into the {lead} leading dense layers"
         )
-    branch = {
-        "blocks": jax.tree_util.tree_map(lambda x: x[branch_at - lead:], params["blocks"]),
-        "ln_f": params["ln_f"],
-        "embed": params["embed"],
-    }
+    if "delta_blocks" in params and cfg is None:
+        raise ValueError("a tree with delta-rule layers needs its config to place a branch point")
+    branch = {"ln_f": params["ln_f"], "embed": params["embed"]}
+    for name in ("blocks", "delta_blocks"):
+        if name in params:
+            below = branch_at - lead if cfg is None else sum(i < branch_at for i in stack_layers(cfg, name))
+            branch[name] = jax.tree_util.tree_map(lambda x: x[below:], params[name])
     if "lm_head" in params:
         branch["lm_head"] = params["lm_head"]
     return jax.lax.stop_gradient(branch)

@@ -135,9 +135,9 @@ class CausalLMWithValueHead:
         if self.value_branch_at is not None:
             # the same slice a reference branch takes (layers of the main
             # kind, above any leading dense ones), trainable
-            branch = extract_branch_params(base_params, self.value_branch_at)
+            branch = extract_branch_params(base_params, self.value_branch_at, self.cfg)
             params["v_branch"] = jax.tree_util.tree_map(
-                jnp.copy, {"blocks": branch["blocks"], "ln_f": branch["ln_f"]}
+                jnp.copy, {k: v for k, v in branch.items() if k not in ("embed", "lm_head")}
             )
         return params
 
@@ -151,10 +151,10 @@ class CausalLMWithValueHead:
         ring = None
         if out["attn_bias"] is None:  # ring-attention trunk pass
             ring = self.lm._ring_mesh(h.shape[0], h.shape[1], None)
-        h, _ = self.lm._scan_blocks(
-            params["v_branch"]["blocks"], h, out["attn_bias"], out["positions"],
+        h, _, _ = self.lm._run_layers(
+            params["v_branch"], h, self.value_branch_at, self.cfg.n_layer,
+            out["attn_bias"], out["positions"],
             local_bias=out.get("local_bias"),
-            layer_offset=self.value_branch_at,
             key_mask=out.get("key_mask"), ring_mesh=ring,
         )
         hidden = self.lm._final_hidden(params["v_branch"]["ln_f"], h)
@@ -166,7 +166,7 @@ class CausalLMWithValueHead:
         Deep-copied: the trainer donates `params` buffers every step, so
         the reference must not alias them."""
         if self.branch_at is not None:
-            branch = extract_branch_params(params["base"], self.branch_at)
+            branch = extract_branch_params(params["base"], self.branch_at, self.cfg)
         else:
             branch = jax.lax.stop_gradient(params["base"])
         return jax.tree_util.tree_map(jnp.copy, branch)

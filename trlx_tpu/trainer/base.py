@@ -42,6 +42,7 @@ from trlx_tpu.models.generation import (
     SamplerSettings,
     fused_decode_cells,
     generate,
+    state_bytes_per_step,
 )
 from trlx_tpu.models.hf import load_pretrained, save_pretrained_hf
 from trlx_tpu.models.transformer import (
@@ -494,9 +495,18 @@ class TPUBaseTrainer(BaseRLTrainer):
         routed = getattr(cfg, "routed", False)
         if (at is None or at == 0) and not routed:
             return None
-        n_layer = cfg.n_layer
-        lead = getattr(cfg, "first_k_dense", 0)
-        layer_mask = (jnp.arange(n_layer) >= (at or 0)).astype(jnp.float32)
+        from trlx_tpu.models.transformer import STACKS, TransformerConfig, stack_layers
+
+        # a stack's rows are layers of the whole stack, not always a run of
+        # them (delta-rule layers among the others): each by its own index
+        rows = (
+            {name: stack_layers(cfg, name) for name in STACKS}
+            if isinstance(cfg, TransformerConfig) else {"blocks": range(cfg.n_layer)}
+        )
+        trains = {
+            name: (jnp.asarray(layers, jnp.int32) >= (at or 0)).astype(jnp.float32)
+            for name, layers in rows.items()
+        }
         # a chip that holds a share of the experts sees the router's gradient
         # through its own experts alone, an eighth of the sum the chips of a
         # deployment would add up, and all of it pulling toward those experts:
@@ -512,10 +522,9 @@ class TPUBaseTrainer(BaseRLTrainer):
                 return np.float32(0.0)
             if "v_branch" in keys or "lora" in keys:
                 return np.float32(1.0)  # branches/adapters always train
-            if "dense_blocks" in keys:  # the leading dense layers, [0, lead)
-                return layer_mask[:lead].reshape((lead,) + (1,) * (np.ndim(leaf) - 1))
-            if "blocks" in keys:
-                return layer_mask[lead:].reshape((n_layer - lead,) + (1,) * (np.ndim(leaf) - 1))
+            for name, mask in trains.items():
+                if name in keys:
+                    return mask.reshape((-1,) + (1,) * (np.ndim(leaf) - 1))
             if "embed" in keys:  # frozen with the bottom layers, if any are
                 return np.float32(0.0 if at else 1.0)
             return np.float32(1.0)
@@ -547,7 +556,7 @@ class TPUBaseTrainer(BaseRLTrainer):
         if self.config.model.peft_config is not None and getattr(cfg, "beyond_dense", False):
             raise NotImplementedError(
                 "peft adapters are not implemented for a model with latent attention, "
-                "routed experts or several residual streams"
+                "delta-rule (KDA) layers, routed experts or several residual streams"
             )
 
         if isinstance(self.config.model.peft_config, str) and not (
@@ -913,6 +922,9 @@ class TPUBaseTrainer(BaseRLTrainer):
         cells = self._decode_cells.get((settings, gshape, proc_kwargs))
         if cells:  # host numbers, like the counters: not rows
             out["decode_cells"] = cells
+        state = state_bytes_per_step(self._lm().cfg, gshape[0])
+        if state:  # what a decode step of delta-rule layers carries, for the same span
+            out["decode_state_bytes"] = state
         return out
 
     def generate_eval(self, input_ids, attention_mask=None, **kwargs):
@@ -935,8 +947,9 @@ class TPUBaseTrainer(BaseRLTrainer):
         cfg = self.model.cfg
         if cfg.beyond_dense:
             raise NotImplementedError(
-                "ppo.gen_engine: the paged decode engine has no latent page pool and runs "
-                "no routed or multi-stream layer; use the static sampler for this model"
+                "ppo.gen_engine: the paged decode engine has no latent page pool, keeps no "
+                "recurrent state for delta-rule (KDA) layers and runs no routed or "
+                "multi-stream layer; use the static sampler for this model"
             )
         if mh.is_multihost() or mh.data_group_count(self.mesh) != 1:
             return False
@@ -1579,8 +1592,14 @@ class TPUBaseTrainer(BaseRLTrainer):
         gauges = {"model/layers": layers, "model/backward_layers": backward}
         if getattr(cfg, "beyond_dense", False):
             gauges["model/experts_held"] = cfg.n_experts_held or 0
+            # of ONE layer that caches; a row's cache is that times the
+            # layers that cache (`model/latent_layers` where some do not)
             gauges["model/cache_elems_per_position"] = cfg.cache_elems_per_position
             gauges["model/residual_streams"] = cfg.residual_streams
+            if cfg.hybrid:
+                gauges["model/delta_layers"] = cfg.n_layer - cfg.cache_layers
+                gauges["model/latent_layers"] = cfg.cache_layers
+                gauges["model/state_elems_per_row"] = cfg.state_elems_per_row
         self.obs.gauge(**gauges)
         self._tracker_log(gauges, step=self.iter_count)
 

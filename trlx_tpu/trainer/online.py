@@ -35,7 +35,7 @@ from trlx_tpu.exp import ExpConfig, ExperienceTransport
 from trlx_tpu.exp.rollout import LeasedChunks
 from trlx_tpu.fleet.config import FleetConfig
 from trlx_tpu.models.generation import chunks_streamed
-from trlx_tpu.models.transformer import balance_router_bias
+from trlx_tpu.models.transformer import balance_router_bias, router_bias, with_router_bias
 from trlx_tpu.ops.common import running_moments_init, running_moments_update
 from trlx_tpu.parallel import multihost as mh
 from trlx_tpu.parallel.mesh import replicated_sharding, vector_sharding
@@ -484,11 +484,15 @@ class TPUOnlineTrainer(TPUBaseTrainer):
             # free read here, and ride this span's counts into the cycle row
             for name, value in (gen_out.get("moe_stats") or {}).items():
                 counts[name] = float(value)
-            if gen_out.get("decode_cells"):
+            cells, state = gen_out.get("decode_cells"), gen_out.get("decode_state_bytes")
+            if cells or state:
                 # the decode loop stops once every row has finished: its
                 # steps are the response columns any row still wrote
                 steps = int(packed[:rows, -n_new:].any(axis=0).sum()) - 1
-                counts.update(chunks_streamed(steps=steps, **gen_out["decode_cells"]))
+                if cells:
+                    counts.update(chunks_streamed(steps=steps, **cells))
+                if state:  # what the delta-rule layers' state cost the loop to carry
+                    counts["state_bytes_carried"] = max(steps, 0) * state
         stats["time/rollout_generate"] = (
             stats.get("time/rollout_generate", 0.0) + time() - t0
         )
@@ -744,12 +748,12 @@ class TPUOnlineTrainer(TPUBaseTrainer):
             base, ratios = balance_router_bias(
                 self.model.lm, self.params["base"], jnp.asarray(batch.input_ids),
                 jnp.asarray(batch.attention_mask), steps)
-        bias = base["blocks"]["moe"]["router_bias"]
+        bias = router_bias(base)
 
-        def top_layers(tree):
-            old = tree["blocks"]["moe"]["router_bias"]
-            new = jax.device_put(jnp.array(bias[-old.shape[0]:]), old.sharding)
-            return dict(tree, blocks=dict(tree["blocks"], moe=dict(tree["blocks"]["moe"], router_bias=new)))
+        def top_layers(tree):  # a branch holds the top rows of each stack
+            return with_router_bias(tree, {
+                name: jax.device_put(jnp.array(bias[name][len(bias[name]) - old.shape[0]:]), old.sharding)
+                for name, old in router_bias(tree).items()})
 
         self.params = dict(self.params, base=top_layers(base))
         if "v_branch" in self.params:

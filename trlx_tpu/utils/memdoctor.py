@@ -693,11 +693,18 @@ def estimate_plan(trainer) -> HBMPlan:
                 2 * getattr(mc, "n_kv_head", _heads(trainer))
                 * getattr(mc, "head_dim", E // max(_heads(trainer), 1))
             )
-            kv_b = int(L * chunk * S * per_position * kv_size)
+            caching = getattr(mc, "cache_layers", L)
+            kv_b = int(caching * chunk * S * per_position * kv_size)
             plan.add("rollout", "static_kv_cache", kv_b,
                      f"whole-chunk cache, {per_position} numbers a position a layer"
                      + (" (latent)" if getattr(mc, "latent", False) else "")
+                     + (f", the {caching} of {L} layers that cache" if caching < L else "")
                      + f", quant {kv_quant or 'none'}")
+            if caching < L:
+                plan.add("rollout", "recurrent_state",
+                         delta_state_bytes(mc, chunk, decode_size),
+                         f"{L - caching} delta-rule layers: float32 state and convolution "
+                         "inputs a row, whatever its length")
     except Exception as exc:
         plan.add("rollout", "kv_cache", 0,
                  f"unestimated for this model family ({type(exc).__name__})")
@@ -1135,15 +1142,36 @@ def analytic_param_count(tcfg: Dict[str, Any]) -> int:
     mlp = E * I + I * E + I + E
     norms = 4 * E
     if not (tcfg.get("kv_lora_rank") or tcfg.get("n_routed_experts")
-            or int(tcfg.get("residual_streams", 1)) > 1):
+            or int(tcfg.get("residual_streams", 1)) > 1 or _delta_layers(tcfg)):
         return V * E + P * E + L * (attn + mlp + norms) + 2 * E
     return _family_param_count(tcfg)
 
 
+def _field(tcfg, name: str, default=None):
+    """A field of a model config, given as the config or as its dict."""
+    return tcfg.get(name, default) if isinstance(tcfg, dict) else getattr(tcfg, name, default)
+
+
+def _delta_layers(tcfg) -> int:
+    """Layers of a model config (or its dict) whose mixer is the delta rule."""
+    return list(_field(tcfg, "mixer_layers") or ()).count("delta")
+
+
+def delta_state_bytes(tcfg, rows: int, conv_size: int = 2) -> int:
+    """What the delta-rule layers of a model config (or its dict) keep for
+    `rows` rows whatever their length: a float32 [heads, d, d] state and
+    the last `delta_conv - 1` inputs of the three convolutions (compute
+    dtype), in each layer. 0 for a model without such layers."""
+    H, D = int(_field(tcfg, "delta_heads", 0)), int(_field(tcfg, "delta_head_dim", 0))
+    conv = (int(_field(tcfg, "delta_conv", 4)) - 1) * 3 * H * D
+    return _delta_layers(tcfg) * rows * (4 * H * D * D + conv_size * conv)
+
+
 def _family_param_count(tcfg: Dict[str, Any]) -> int:
-    """Parameters HELD HERE of a model with latent attention, routed experts
-    (this chip's share: `n_experts_held` of the `n_routed_experts` the router
-    scores) and several residual streams, from the config's numbers alone."""
+    """Parameters HELD HERE of a model with latent attention, delta-rule
+    layers, routed experts (this chip's share: `n_experts_held` of the
+    `n_routed_experts` the router scores) and several residual streams, from
+    the config's numbers alone."""
     V, E, L = int(tcfg["vocab_size"]), int(tcfg["hidden_size"]), int(tcfg["n_layer"])
     H, I = int(tcfg["n_head"]), int(tcfg.get("intermediate_size", 4 * E))
     gated = 3 if tcfg.get("mlp_gated") else 2
@@ -1158,16 +1186,25 @@ def _family_param_count(tcfg: Dict[str, Any]) -> int:
         attn = 4 * E * H * D
     n = int(tcfg.get("residual_streams", 1))
     mix = 2 * (n * E * (n * n + 2 * n) + 3 + 2 * n + n * n) if n > 1 else 0
-    dense = attn + gated * E * I + 2 * E + mix
+    mixers = list(tcfg.get("mixer_layers") or [None] * L)
+    if "delta" in mixers:
+        # q, k, v, o; two low-rank pairs through d; beta; the taps; A_log, dt_bias, the output norm
+        kh, kd = int(tcfg["delta_heads"]), int(tcfg["delta_head_dim"])
+        w = kh * kd
+        delta = (4 * E * w + 2 * (E * kd + kd * w) + E * kh
+                 + 3 * w * int(tcfg.get("delta_conv", 4)) + kh + w + kd)
+        attn = [delta if m == "delta" else attn for m in mixers]
+    else:
+        attn = [attn] * L
     published = int(tcfg.get("n_routed_experts", 0))
-    if not published:
-        return 2 * V * E + L * dense + E
-    F = int(tcfg["moe_intermediate_size"])
-    held = int(tcfg.get("n_experts_held") or published)
-    routed = (attn + 2 * E + mix + E * published + published  # router and its bias
-              + 3 * E * F * (held + int(tcfg.get("n_shared_experts", 0))))
-    lead = int(tcfg.get("first_k_dense", 0))
-    return 2 * V * E + lead * dense + (L - lead) * routed + E
+    lead = int(tcfg.get("first_k_dense", 0)) if published else L
+    feed = [gated * E * I] * lead
+    if published:
+        F = int(tcfg["moe_intermediate_size"])
+        held = int(tcfg.get("n_experts_held") or published)
+        feed += [E * published + published  # router and its bias
+                 + 3 * E * F * (held + int(tcfg.get("n_shared_experts", 0)))] * (L - lead)
+    return 2 * V * E + sum(attn) + sum(feed) + L * (2 * E + mix) + E
 
 
 def analytic_plan(
@@ -1309,10 +1346,17 @@ def analytic_plan(
         kv_size = 1 if kv_quant == "int8" else 2
         latent = tdict.get("kv_lora_rank")
         per_position = (int(latent) + int(tdict.get("qk_rope_head_dim", 0))) if latent else 2 * Hkv * D
+        caching = L - _delta_layers(tdict)
         plan.add("rollout", "static_kv_cache",
-                 int(L * chunk * S * per_position * kv_size),
+                 int(caching * chunk * S * per_position * kv_size),
                  f"whole-chunk cache, {per_position} numbers a position a layer"
-                 + (" (latent)" if latent else "") + f", quant {kv_quant or 'none'}")
+                 + (" (latent)" if latent else "")
+                 + (f", the {caching} of {L} layers that cache" if caching < L else "")
+                 + f", quant {kv_quant or 'none'}")
+        if caching < L:
+            plan.add("rollout", "recurrent_state", delta_state_bytes(tdict, chunk),
+                     f"{L - caching} delta-rule layers: float32 state and convolution "
+                     "inputs a row, whatever its length")
 
     exp = dict(getattr(config.method, "exp", None) or {})
     if exp.get("enabled"):
