@@ -8,7 +8,16 @@ backward never runs. These tests hold the two statements together:
 same trainable gradients as the full backward, exactly-zero gradients
 where the mask is 0 and nowhere else, bit-identical frozen leaves after
 a step, no transposed scan over the trunk, and the bypass
-configurations trace to the program they traced to before."""
+configurations trace to the program they traced to before.
+
+The forward through that trunk is a loop invariant of the fused block
+(`make_fused_train_steps`): it runs once before the scan over the
+optimizer steps (`_block_trunk`), every step gathers its rows of the
+output and resumes at the branch point. The second half of the file
+holds that block to the block that runs the whole forward in every
+step (parameters, optimizer state, loss and stats), counts its FLOPs,
+and holds every configuration that must keep the whole forward to the
+parent's program."""
 
 from collections import Counter
 
@@ -53,16 +62,17 @@ BYPASS_CASES = {
 }
 
 
-def build_trainer(ckpt_dir, case):
+def build_trainer(ckpt_dir, case, **train):
     from trlx_tpu.trainer.ppo import TPUPPOTrainer
 
     extra, unfrozen, remat, value_layers, peft = case
     config = default_ppo_config().evolve(
         train=dict(
-            batch_size=ROWS, total_steps=2, seq_length=P + N, epochs=1,
-            tracker=None, checkpoint_dir=str(ckpt_dir),
-            # fp32 compute: the tolerance below is a float32 one
-            compute_dtype="float32", remat_policy=remat,
+            dict(batch_size=ROWS, total_steps=2, seq_length=P + N, epochs=1,
+                 tracker=None, checkpoint_dir=str(ckpt_dir),
+                 # fp32 compute: the tolerance below is a float32 one
+                 compute_dtype="float32", remat_policy=remat),
+            **train,
         ),
         model=dict(
             model_path="random", num_layers_unfrozen=unfrozen,
@@ -79,19 +89,19 @@ def build_trainer(ckpt_dir, case):
     return TPUPPOTrainer(config, reward_fn=lambda **kw: [0.0])
 
 
-def rollout_batch(seq2seq: bool) -> PPORolloutBatch:
+def rollout_batch(seq2seq: bool, rows: int = ROWS, p: int = P, vocab: int = 250) -> PPORolloutBatch:
     """A synthetic store batch with ragged response masks."""
     rng = np.random.RandomState(0)
-    lens = np.array([4, 2, 3, 4, 1, 3, 2, 4])
+    lens = np.resize([4, 2, 3, 4, 1, 3, 2, 4], rows)
     mask = (np.arange(N)[None, :] < lens[:, None]).astype(np.float32)
     # seq2seq responses are decoder ids: start token + N sampled tokens
     n_resp = N + 1 if seq2seq else N
     return PPORolloutBatch(
-        query_tensors=jnp.asarray(rng.randint(1, 250, (ROWS, P)), jnp.int32),
-        response_tensors=jnp.asarray(rng.randint(1, 250, (ROWS, n_resp)), jnp.int32),
-        logprobs=jnp.asarray(rng.randn(ROWS, N) * 0.1, jnp.float32),
-        values=jnp.asarray(rng.randn(ROWS, N) * 0.1, jnp.float32),
-        rewards=jnp.asarray(rng.randn(ROWS, N) * 0.1, jnp.float32),
+        query_tensors=jnp.asarray(rng.randint(1, vocab, (rows, p)), jnp.int32),
+        response_tensors=jnp.asarray(rng.randint(1, vocab, (rows, n_resp)), jnp.int32),
+        logprobs=jnp.asarray(rng.randn(rows, N) * 0.1, jnp.float32),
+        values=jnp.asarray(rng.randn(rows, N) * 0.1, jnp.float32),
+        rewards=jnp.asarray(rng.randn(rows, N) * 0.1, jnp.float32),
         response_mask=jnp.asarray(mask),
     )
 
@@ -273,19 +283,226 @@ def test_no_transposed_scan_over_the_frozen_trunk(frozen):
     assert not ours - parents
 
 
-@pytest.mark.parametrize("name", list(BYPASS_CASES))
+# -- the fused block: the trunk's forward once a block ------------------------
+
+
+class whole_forward:
+    """The block that runs the whole forward in every optimizer step: what
+    a trainer that holds nothing (`trunk_layers_held()` 0) builds."""
+
+    def __init__(self, trainer):
+        self.trainer = trainer
+
+    def __enter__(self):
+        self.trainer.trunk_layers_held = lambda: 0
+
+    def __exit__(self, *exc):
+        del self.trainer.trunk_layers_held  # the instance attribute shadowing the method
+
+
+def block_perms(rows: int, batch: int, epochs: int) -> np.ndarray:
+    """[steps, batch] minibatch rows, `rows // batch` steps an epoch, a
+    fresh shuffle each epoch (what `_epoch_perms` hands the block)."""
+    per = rows // batch
+    return np.concatenate([
+        np.random.RandomState(e).permutation(rows)[: per * batch].reshape(per, batch)
+        for e in range(epochs)
+    ]).astype(np.int32)
+
+
+def run_block(trainer, batch, perms):
+    """A newly built fused block run from the trainer's own state, which
+    it leaves alone: (params, opt_state, mean loss, mean stats)."""
+    fused = trainer.make_fused_train_steps()
+    state = jax.tree_util.tree_map(jnp.copy, (trainer.params, trainer.opt_state))
+    with trainer.mesh:
+        out = fused(*state, batch, jnp.asarray(perms))
+    return jax.tree_util.tree_map(np.asarray, out)
+
+
+def block_both_ways(trainer, batch, perms):
+    """(the block as built, the block that runs the whole forward in
+    every step), from the same state on the same rows."""
+    held = run_block(trainer, batch, perms)
+    with whole_forward(trainer):
+        whole = run_block(trainer, batch, perms)
+    return held, whole
+
+
+def assert_same_block(got, want, rtol=0.0, skip=()):
+    """Parameters, optimizer state, loss and stats, leaf by leaf: bit for
+    bit, or within `rtol` of the leaf's largest entry."""
+    got, _ = jax.tree_util.tree_flatten_with_path(got)
+    want = jax.tree_util.tree_leaves(want)
+    assert len(got) == len(want)
+    for (path, g), w in zip(got, want):
+        name = jax.tree_util.keystr(path)
+        if any(key in name for key in skip):
+            continue
+        if rtol:
+            np.testing.assert_allclose(g, w, rtol=0, atol=rtol * max(np.abs(w).max(), 1e-30), err_msg=name)
+        else:
+            np.testing.assert_array_equal(g, w, err_msg=name)
+
+
+def parents_block(trainer):
+    """The parent's `fused_train_step`, as it stood before a block held
+    anything: the scan of the optimizer step over the permutations."""
+
+    def fused_train_step(params, opt_state, full_batch, perms):
+        def body(carry, perm):
+            p, o = carry
+            mb = jax.tree_util.tree_map(lambda x: x[perm], full_batch)
+            p, o, loss, stats = trainer._step_update(p, o, mb)
+            return (p, o), (loss, stats)
+
+        (params, opt_state), (losses, stats) = jax.lax.scan(body, (params, opt_state), perms)
+        mean_stats = jax.tree_util.tree_map(lambda x: jnp.mean(x, axis=0), stats)
+        return params, opt_state, jnp.mean(losses), mean_stats
+
+    return fused_train_step
+
+
+def block_jaxpr(trainer, fn, batch, perms):
+    with trainer.mesh:
+        return jax.make_jaxpr(fn)(trainer.params, trainer.opt_state, batch, jnp.asarray(perms))
+
+
+def gauges_of(trainer, build):
+    """The gauge rows a builder of train steps writes."""
+    rows, real = [], trainer.obs.gauge
+    trainer.obs.gauge = lambda **kw: rows.append(kw)
+    try:
+        build()
+    finally:
+        trainer.obs.gauge = real
+    return rows
+
+
+def test_the_block_that_holds_the_trunk_equals_the_block_that_runs_it_in_every_step(frozen):
+    """Three epochs over the batch (rows == batch: one group). The causal
+    cases hold the trunk up to the branch point (with the value branch
+    deeper both captures are held; shallower, its capture carries gradient
+    and stays in the step) and come out bit for bit: the same operations on
+    the same rows, the trunk's only moved out of the loop. T5 keeps the
+    whole forward in the block."""
+    trainer, batch = frozen["trainer"], frozen["batch"]
+    perms = block_perms(ROWS, ROWS, 3)
+    if trainer.seq2seq:
+        assert trainer.trunk_layers_held() == 0
+        assert str(block_jaxpr(trainer, trainer.make_fused_train_steps().__wrapped__, batch, perms)) == str(
+            block_jaxpr(trainer, parents_block(trainer), batch, perms))
+        return
+    model = trainer.model
+    assert trainer.trunk_layers_held() == model.branch_at == 6
+    with trainer.mesh:
+        captures, counters = jax.eval_shape(trainer.trunk_constants, trainer.params, batch)
+    held_points = [p for p in model._capture_points() if p <= model.branch_at]
+    assert len(captures) == len(held_points) and counters is None
+    assert all(c.shape == (ROWS, P + N, 16) for c in captures)
+    held, whole = block_both_ways(trainer, batch, perms)
+    assert np.isfinite(held[2]) and any(
+        (a != b).any() for a, b in zip(*map(jax.tree_util.tree_leaves, (held[0], trainer.params))))
+    assert_same_block(held, whole)
+
+
+@pytest.mark.parametrize("rows", [16, 12])
+def test_rows_beyond_the_batch_are_held_in_groups_of_the_batch(frozen, rows):
+    """`batch < rollouts`: the trunk runs over the block's rows in groups
+    of the step's batch size (`lax.map`; a ragged last group wraps round),
+    and a step gathers its rows from all of them."""
+    trainer = frozen["trainer"]
+    if trainer.seq2seq:  # T5 holds nothing, whatever the rows
+        assert trainer._block_trunk(trainer.params, rollout_batch(True, rows=rows), 2) is None
+        return
+    batch = rollout_batch(False, rows=rows)
+    held, whole = block_both_ways(trainer, batch, block_perms(rows, ROWS, 2))
+    assert_same_block(held, whole)
+
+
+def test_the_held_captures_split_with_the_microbatches(tmp_path):
+    """`num_mb > 1` over rows in two groups: each microbatch resumes from
+    its own rows of the captures."""
+    trainer = build_trainer(tmp_path, FROZEN_CASES["value_deeper-remat_full"],
+                            batch_size=16, minibatch_size=8)
+    assert (trainer.num_mb, trainer.mb_size, trainer.trunk_layers_held()) == (2, 8, 6)
+    batch = rollout_batch(False, rows=32)
+    held, whole = block_both_ways(trainer, batch, block_perms(32, 16, 2))
+    assert_same_block(held, whole)
+
+
+BLOCK_BYPASS = dict(
+    {name: (case, {}) for name, case in BYPASS_CASES.items()},
+    # the pipelined forward keeps the whole backward, and the whole forward
+    pp2=(FROZEN_CASES["hydra-remat_full"], dict(mesh={"pp": 2, "dp": 4, "fsdp": 1, "tp": 1})),
+)
+
+
+@pytest.mark.parametrize("name", list(BLOCK_BYPASS))
 def test_bypass_configurations_trace_to_the_parents_program(name, tmp_path):
     """All layers trainable, a peft adapter, an embedding LayerNorm
     (which trains, under every layer): no stop, the same jaxpr as with
-    `frozen_below=0` forced, which is the parent's."""
-    trainer = build_trainer(tmp_path, BYPASS_CASES[name])
-    assert trainer.model.frozen_below() == 0
+    `frozen_below=0` forced, which is the parent's. Those, and `pp > 1`,
+    hold nothing in the fused block either: it traces to the parent's
+    block, and both builders write `model/trunk_layers_hoisted` 0."""
+    case, train = BLOCK_BYPASS[name]
+    trainer = build_trainer(tmp_path, case, **train)
     batch = rollout_batch(trainer.seq2seq)
+    if name in BYPASS_CASES:
+        assert trainer.model.frozen_below() == 0
+        with trainer.mesh:
+            jaxpr = jax.make_jaxpr(loss_grad(trainer, batch))(trainer.params)
+            with full_backward(trainer):
+                jaxpr_full = jax.make_jaxpr(loss_grad(trainer, batch))(trainer.params)
+        assert str(jaxpr) == str(jaxpr_full)
+    assert trainer.trunk_layers_held() == 0
+    perms = block_perms(ROWS, ROWS, 2)
+    built = {}
+    rows = gauges_of(trainer, lambda: built.update(
+        fused=trainer.make_fused_train_steps(), step=trainer.make_train_step()))
+    assert [row["model/trunk_layers_hoisted"] for row in rows] == [0, 0]
+    assert str(block_jaxpr(trainer, built["fused"].__wrapped__, batch, perms)) == str(
+        block_jaxpr(trainer, parents_block(trainer), batch, perms))
+
+
+def test_the_per_step_program_keeps_the_whole_forward(frozen):
+    """`make_train_step` has nowhere to keep a trunk's output: on a frozen
+    trunk it traces to the parent's step (the trunk's scan inside it), and
+    its gauge reads 0 where the fused block's reads the trunk's layers."""
+    trainer, batch = frozen["trainer"], frozen["batch"]
+    built = {}
+    rows = gauges_of(trainer, lambda: built.update(
+        step=trainer.make_train_step(), fused=trainer.make_fused_train_steps()))
+    held = 0 if trainer.seq2seq else trainer.model.branch_at
+    assert [row["model/trunk_layers_hoisted"] for row in rows] == [0, held]
+    assert all(row["model/backward_layers"] == 2 for row in rows)
     with trainer.mesh:
-        jaxpr = jax.make_jaxpr(loss_grad(trainer, batch))(trainer.params)
-        with full_backward(trainer):
-            jaxpr_full = jax.make_jaxpr(loss_grad(trainer, batch))(trainer.params)
-    assert str(jaxpr) == str(jaxpr_full)
+        step = jax.make_jaxpr(built["step"].__wrapped__)(trainer.params, trainer.opt_state, batch)
+        parents = jax.make_jaxpr(trainer._step_update)(trainer.params, trainer.opt_state, batch)
+    assert str(step) == str(parents)
+    # the trunk's first segment (T5: the encoder) is scanned forward inside the step
+    first = trainer.model.cfg.n_layer if trainer.seq2seq else min(trainer.model._capture_points())
+    assert (first, False) in scans_of(step.jaxpr)
+
+
+def test_the_memory_plan_has_a_row_for_the_held_captures(frozen):
+    """`_extra_plan_items`: what lives across the block's scan, a device's
+    rows of every held capture in the compute dtype; T5 holds nothing."""
+    trainer = frozen["trainer"]
+    if trainer.seq2seq:
+        assert trainer.trunk_layers_held() == 0
+        return
+    rows = [item for item in trainer._extra_plan_items() if item.component == "trunk_constants"]
+    model = trainer.model
+    held = sum(p <= model.branch_at for p in model._capture_points())
+    (row,) = rows
+    assert row.phase == "train"
+    assert row.bytes == held * (ROWS // trainer.data_ways()) * (P + N) * 16 * 4
+
+
+def outer_scan(jaxpr, length):
+    (eqn,) = [e for e in jaxpr.eqns if e.primitive.name == "scan" and e.params["length"] == length]
+    return eqn.params["jaxpr"].jaxpr
 
 
 def test_gradient_flops_of_top2_of_8(tmp_path):
@@ -297,7 +514,12 @@ def test_gradient_flops_of_top2_of_8(tmp_path):
     ride the heads (the value head is 4 x width wide: 1.3 F with its
     backward) and, in the jaxpr as traced, the reference branch's
     forward (2.2 F of dead code that XLA removes): 16.8 / 32.9 = 0.51,
-    where ISSUE 26 reckoned 14 / 32 for the layers alone."""
+    where ISSUE 26 reckoned 14 / 32 for the layers alone.
+
+    The BLOCK of e epochs: one trunk forward and e times the step's rest,
+    where the block that runs the whole forward pays e times both; the
+    trunk's scan of 6 layers stands before the scan over the steps and
+    nowhere inside it."""
     wide = dict(CAUSAL, hidden_size=128, n_head=4)
     trainer = build_trainer(tmp_path, ({"transformer": wide}, 2, "full", 0, None))
     batch = rollout_batch(False)
@@ -305,5 +527,24 @@ def test_gradient_flops_of_top2_of_8(tmp_path):
         jaxpr = jax.make_jaxpr(loss_grad(trainer, batch))(trainer.params)
         with full_backward(trainer):
             jaxpr_full = jax.make_jaxpr(loss_grad(trainer, batch))(trainer.params)
+        trunk = matmul_flops(jax.make_jaxpr(trainer.trunk_constants)(trainer.params, batch).jaxpr)
     ratio = matmul_flops(jaxpr.jaxpr) / matmul_flops(jaxpr_full.jaxpr)
     assert 0.45 < ratio < 0.53, ratio
+
+    def block_flops(epochs):
+        perms = block_perms(ROWS, ROWS, epochs)
+        return block_jaxpr(trainer, trainer.make_fused_train_steps().__wrapped__, batch, perms).jaxpr
+
+    e = 3
+    held_1, held_e = block_flops(1), block_flops(e)
+    with whole_forward(trainer):
+        whole_e = block_flops(e)
+    step = matmul_flops(held_1) - trunk  # a step's rest: the top two layers, the heads, their backward
+    layer = trunk / 6
+    assert 0.9 * 38.3e6 < layer < 1.1 * 38.3e6
+    assert matmul_flops(held_e) == trunk + e * step
+    assert matmul_flops(whole_e) == e * (trunk + step)
+    assert 0.3 < trunk / (trunk + step) < 0.45  # 6 F of 6 + 2 x 3.67 + the heads and the dead branch
+    assert scans_of(held_e).count((6, False)) == 1
+    assert (6, False) not in scans_of(outer_scan(held_e, e))
+    assert (6, False) in scans_of(outer_scan(whole_e, e))

@@ -521,6 +521,56 @@ def test_a_random_routed_model_is_balanced_on_the_first_prompts_and_every_copy_t
     np.testing.assert_array_equal(np.asarray(trainer.ref_params["blocks"]["moe"]["router_bias"]), bias[-2:])
 
 
+@pytest.fixture(scope="module")
+def blocks(trainer):
+    """The fused block of two epochs over eight rows of the toy trainer (hydra top 2
+    of 1 dense + 3 routed layers: the trunk is the dense layer and the first routed
+    one), as built, the trunk's output held, and with the whole forward in every
+    step: two programs, run once from the same state."""
+    from tests.test_frozen_trunk import block_both_ways, block_perms, rollout_batch
+
+    rows = rollout_batch(False, rows=8, p=12, vocab=trainer.hf["vocab_size"])
+    held, whole = block_both_ways(trainer.trainer, rows, block_perms(8, 8, 2))
+    return SimpleNamespace(rows=rows, held=held, whole=whole, steps=2)
+
+
+def test_the_block_that_holds_the_routed_four_stream_trunk_equals_the_block_that_runs_it_in_every_step(trainer, blocks):
+    """Parameters, optimizer state, loss and stats bit for bit; the captures are held
+    in stream form. The two counters of pairs differ by design (the next test)."""
+    from tests.test_frozen_trunk import assert_same_block
+
+    t = trainer.trainer
+    assert t.trunk_layers_held() == t.model.branch_at == LAYERS - 2
+    (capture,), counters = jax.eval_shape(t.trunk_constants, t.params, blocks.rows)
+    assert capture.shape == (8, 16, trainer.hf["hc_mult"], trainer.hf["hidden_size"])
+    assert counters["load"].shape == (LAYERS - 2 - LEAD, trainer.hf["n_routed_experts"])
+    assert np.isfinite(blocks.held[2])
+    assert_same_block(blocks.held, blocks.whole, skip=("moe/assignments",))
+
+
+def test_the_train_counters_count_the_held_trunks_pairs_once_a_block(trainer, blocks):
+    """`moe/assignments_here.train` and `moe/assignments.train` stay "pairs the program
+    ran, a step's mean": times the steps of the block they read the trunk's routed
+    layer ONCE over all rows (counted by a whole forward over them) plus the steps' own
+    for the trainable layers, where the block that runs the whole forward in every
+    step reads the trunk's in every step. The fullest expert's share is a ratio and
+    reads the same."""
+    t, steps = trainer.trainer, blocks.steps
+    stats = jax.jit(lambda base, ids, mask: t.model.lm(base, ids, mask, compute_logits=False)["moe_stats"])(
+        t.params["base"], *t._policy_inputs(blocks.rows))
+    routed, trunk = LAYERS - LEAD, LAYERS - 2 - LEAD
+    assert stats["load"].shape[0] == routed
+    once = {"moe/assignments_here.train": float(np.sum(stats["load"][:trunk])),
+            "moe/assignments.train": float(stats["assignments"]) * trunk / routed}
+    assert once["moe/assignments_here.train"] > 0
+    held, whole = blocks.held[3], blocks.whole[3]
+    for name, trunks in once.items():
+        own = steps * (float(whole[name]) - trunks)  # every step of the whole block ran all eight rows
+        assert own > 0
+        np.testing.assert_allclose(float(held[name]) * steps, trunks + own, rtol=1e-6)
+    assert float(held["moe/load_max_over_mean.train"]) == float(whole["moe/load_max_over_mean.train"])
+
+
 def test_counters_reach_the_cycle_row():
     from trlx_tpu.obs.telemetry import TelemetryAggregator
 

@@ -510,6 +510,24 @@ def test_the_freeze_mask_addresses_layers_by_their_index_in_the_whole_stack(trai
         trainer._engine_eligible()
 
 
+def test_the_block_that_holds_the_delta_rule_trunk_equals_the_block_that_runs_it_in_every_step(trainer):
+    """Hydra top 2 of 5: the trunk is the leading dense KDA layer and two routed KDA
+    layers, in two stacks; the fused block holds its output across two epochs and
+    leaves parameters, optimizer state, loss and stats as the block that runs the
+    chunked form through it in every step does (the two counters of pairs count the
+    trunk's once a block: `tests/test_latent_moe.py`)."""
+    from tests.test_frozen_trunk import assert_same_block, block_both_ways, block_perms, rollout_batch
+
+    hf, trainer = trainer.hf, trainer.trainer
+    assert trainer.trunk_layers_held() == trainer.model.frozen_below() == 3
+    rows = rollout_batch(False, rows=8, p=12, vocab=hf["vocab_size"])
+    (capture,), counters = jax.eval_shape(trainer.trunk_constants, trainer.params, rows)
+    assert capture.shape == (8, 16, hf["hidden_size"]) and counters["load"].shape[0] == 2
+    held, whole = block_both_ways(trainer, rows, block_perms(8, 8, 2))
+    assert np.isfinite(held[2])
+    assert_same_block(held, whole, skip=("moe/assignments",))
+
+
 def test_gauges_and_the_state_count_reach_the_flight_stream(trainer):
     """`model/delta_layers`, `model/latent_layers`, `model/state_elems_per_row` beside
     `model/cache_elems_per_position` once per built train step; `state_bytes_carried`

@@ -46,10 +46,10 @@ TIER1_BUDGETS = {
     # 62.4s, scanned_epochs 42.4s (RAISED 40->50: it was already over),
     # generation 11.5s, seq2seq 16.6s, remat 0.3s, models 16.2s
     # (raised 15->20), peft 13.9s, trainers 7.9s
-    "test_elastic.py": 34,
+    "test_elastic.py": 26,
     "test_examples.py": 1,
     "test_exp_queue.py": 29,
-    "test_fault_tolerance.py": 53,
+    "test_fault_tolerance.py": 35,
     "test_flash_attention.py": 14,
     "test_fleet.py": 35,
     # PR 26: the stop of the backward pass at the hydra branch point —
@@ -64,7 +64,17 @@ TIER1_BUDGETS = {
     # summarize_eval 5->1 (0.0), pipelines 4->1 (0.0), deferred_stats
     # 5->2 (0.9), remat 2->1 (0.0), configs 5->4 (3.1), examples 2->1
     # (0.1), graft_lint 8->7 (6.2).
-    "test_frozen_trunk.py": 33,
+    # PR 34: the fused block that holds the trunk against the block that
+    # runs it in every step (two block compiles a test: six cases, rows in
+    # groups and ragged, microbatches), the bypasses' blocks, the per-step
+    # program, the block's FLOP count: 62 tests, 248 s alone, 321.5 s
+    # inside the 6-worker run of 399 s (2026-10-03), 96 on the table's
+    # scale (0.3 of the in-run seconds). Paid under the unchanged 780
+    # ceiling with times of the same run: guardrails 75->55 (134.5 s =
+    # 40.3), fault_tolerance 53->35 (62.7 s = 18.8), paged_kernel 48->38
+    # (86.0 s = 25.8), elastic 34->26 (50.7 s = 15.2), serve 26->19
+    # (28.4 s = 8.5).
+    "test_frozen_trunk.py": 96,
     "test_gen_engine.py": 34,
     # PR 30: the fused int8 decode kernel against the XLA branch (seven
     # interpret-mode cases through `Attention`, one on a four-device
@@ -90,7 +100,7 @@ TIER1_BUDGETS = {
     "test_grpo.py": 30,
     # r09: +4 preference-RL chaos learn() tests (GRPO nan/sigterm, DPO
     # nan/sigterm); whole file re-measured 99.9s serial
-    "test_guardrails.py": 75,
+    "test_guardrails.py": 55,
     # PR 28: the routed / latent-attention / four-stream family against its
     # float32 reference (logits, trainable gradients, cache decode, shares,
     # hydra cuts, int8 rollout weights, one Mosaic compile at keys 192 /
@@ -168,7 +178,7 @@ TIER1_BUDGETS = {
     # 17->14, ring_attention 9s -> 10->8, watchdog 11s -> 10->8,
     # sweep 23s -> 15->14, trainers 11s -> 10->9, flash_attention 24s
     # -> 15->14, generation 23s -> 15->14.
-    "test_paged_kernel.py": 48,
+    "test_paged_kernel.py": 38,
     "test_ops.py": 5,
     "test_peft.py": 14,
     "test_pipeline_parallel.py": 7,
@@ -180,7 +190,7 @@ TIER1_BUDGETS = {
     "test_ring_attention.py": 8,
     "test_scanned_epochs.py": 20,
     "test_seq2seq.py": 13,
-    "test_serve.py": 26,
+    "test_serve.py": 19,
     "test_sharding.py": 7,
     "test_summarize_eval.py": 1,
     "test_supervisor.py": 11,

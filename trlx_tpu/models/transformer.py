@@ -2755,6 +2755,84 @@ class TransformerLM:
             "key_mask": attention_mask,
         }
 
+    def _capture_context(self, input_ids: Array, attention_mask: Optional[Array]):
+        """(what `_run_layers` takes beside the layers of a teacher-forced
+        capture forward: `attn_bias`, `positions`, `key_mask`, `local_bias`,
+        `ring_mesh`; the pipeline's microbatch count): cheap functions of
+        the tokens' mask, rebuilt wherever the forward starts or resumes."""
+        B, T = input_ids.shape
+        if attention_mask is None:
+            attention_mask = jnp.ones((B, T), jnp.int32)
+        positions = jnp.maximum(jnp.cumsum(attention_mask, axis=1) - 1, 0)
+        ring = self._ring_mesh(B, T, None)
+        if ring is not None:
+            bias, local_bias = None, None
+        else:
+            bias, local_bias = self._build_bias(
+                attention_mask, jnp.arange(T), jnp.arange(T)
+            )
+        n_mb = 0 if ring is not None else self._pp_microbatches(B, None)
+        return dict(attn_bias=bias, positions=positions, key_mask=attention_mask,
+                    local_bias=local_bias, ring_mesh=ring), n_mb
+
+    def resume_point(self, points: Tuple[int, ...], frozen_below: int) -> int:
+        """The layer a capture forward resumes at when it is handed its
+        constants (`trunk_constants`): the highest capture point at or
+        below `frozen_below`. 0 where nothing is held: no such point, or
+        a mesh on which the forward is pipelined (`pp > 1`) or runs ring
+        attention (`sp > 1`), decided by the mesh alone so that the rows a
+        constant was computed in never matter."""
+        m = {} if self.mesh is None else dict(self.mesh.shape)
+        if m.get("pp", 1) > 1 or (self.cfg.attention_impl == "ring" and m.get("sp", 1) > 1):
+            return 0
+        return max((p for p in points if 0 < p <= frozen_below), default=0)
+
+    def _constant_segments(self, frozen: Dict, h: Array, points: Tuple[int, ...], upto: int, ctx: Dict):
+        """The segments between capture points that end at or below
+        `upto`, on gradient-stopped params, without remat (nothing is
+        transposed, so nothing is saved or recomputed), each output
+        gradient-stopped: (h, captures, stats, the layer reached)."""
+        captures, stats, prev = [], None, 0
+        for point in points:
+            if point > upto:
+                break
+            if point > prev:
+                h, _, seg_stats = self._run_layers(frozen, h, prev, point, remat=False, **ctx)
+                stats = _join_stats(stats, seg_stats)
+                h = jax.lax.stop_gradient(h)
+            if point < self.cfg.n_layer:
+                captures.append(h)
+            prev = point
+        return h, captures, stats, prev
+
+    def trunk_constants(
+        self,
+        params: Dict,
+        input_ids: Array,
+        attention_mask: Optional[Array],
+        points: Tuple[int, ...],
+        frozen_below: int,
+    ) -> Optional[Tuple[Tuple[Array, ...], Optional[Dict[str, Array]]]]:
+        """What `forward_with_multi_capture` computes of these rows that no
+        optimizer step changes: the embedding and every segment that ends
+        at or below `frozen_below`, on gradient-stopped params in the
+        compute dtype, without remat. Returns (captures, moe_stats): the
+        hidden state entering each capture point up to `resume_point`, in
+        stream form ([B, T, E]; [B, T, n, E] for n streams), the last of
+        them the residual state entering the first layer the resumed
+        forward runs; and the routed layers' counters over these rows (None
+        without experts). None where `resume_point` is 0. A fused block
+        computes them once over all its rows (`trainer/base.py`
+        `make_fused_train_steps`) and hands each step its rows'."""
+        upto = self.resume_point(points, frozen_below)
+        if not upto:
+            return None
+        ctx, _ = self._capture_context(input_ids, attention_mask)
+        frozen = jax.lax.stop_gradient(params)
+        h = self._to_streams(self._embed_h(frozen, input_ids, ctx["positions"]))
+        _, captures, stats, _ = self._constant_segments(frozen, h, tuple(points), upto, ctx)
+        return tuple(captures), jax.lax.stop_gradient(stats)
+
     def forward_with_multi_capture(
         self,
         params: Dict,
@@ -2764,6 +2842,7 @@ class TransformerLM:
         remat: bool = False,
         compute_logits: bool = True,
         frozen_below: int = 0,
+        trunk: Optional[Tuple[Tuple[Array, ...], Optional[Dict[str, Array]]]] = None,
     ) -> Dict[str, Array]:
         """Forward capturing the hidden state entering each layer index in
         `points` (sorted ascending). Generalizes branch capture so the
@@ -2779,26 +2858,26 @@ class TransformerLM:
         is gradient-stopped too, so the backward ends where the freeze
         mask (`make_freeze_mask`) says training does. A segment that
         straddles it, and the pipeline-parallel path (`pp > 1`), keep
-        the full backward."""
-        B, T = input_ids.shape
-        if attention_mask is None:
-            attention_mask = jnp.ones((B, T), jnp.int32)
-        positions = jnp.maximum(jnp.cumsum(attention_mask, axis=1) - 1, 0)
-        ring = self._ring_mesh(B, T, None)
-        if ring is not None:
-            bias, local_bias = None, None
-        else:
-            bias, local_bias = self._build_bias(
-                attention_mask, jnp.arange(T), jnp.arange(T)
-            )
-        n_mb = 0 if ring is not None else self._pp_microbatches(B, None)
+        the full backward.
+
+        `trunk` is what `trunk_constants` returned for these rows (with
+        its counters as the caller wants them counted in this call): the
+        constant part is then not run here. The forward rebuilds
+        positions and bias, starts from the last held capture at
+        `resume_point` and returns what it returns without `trunk`. A
+        fused block runs the trunk once that way, not once an optimizer
+        step; every other caller passes none and runs it here."""
+        ctx, n_mb = self._capture_context(input_ids, attention_mask)
+        attention_mask, positions = ctx["key_mask"], ctx["positions"]
+        bias, local_bias = ctx["attn_bias"], ctx["local_bias"]
         if n_mb:
             frozen_below = 0
         frozen = jax.lax.stop_gradient(params) if frozen_below else params
-        h = self._embed_h(frozen, input_ids, positions)
-        stats = None
+        points = tuple(points)
 
         if n_mb:
+            h = self._embed_h(frozen, input_ids, positions)
+            stats = None
             # match the sequential path: points >= n_layer are omitted
             # (never captured), not returned as zeros
             in_range = tuple(p for p in points if p < self.cfg.n_layer)
@@ -2809,21 +2888,16 @@ class TransformerLM:
             )
             captures = list(caps)
         else:
-            captures = []
-            prev = 0
-            h = self._to_streams(h)
-            for point in tuple(points) + (self.cfg.n_layer,):
+            if trunk is None:
+                h = self._to_streams(self._embed_h(frozen, input_ids, positions))
+                h, captures, stats, prev = self._constant_segments(frozen, h, points, frozen_below, ctx)
+            else:
+                captures, stats = list(trunk[0]), trunk[1]
+                h, prev = captures[-1], self.resume_point(points, frozen_below)
+            for point in points[sum(p <= prev for p in points):] + (self.cfg.n_layer,):
                 if point > prev:
-                    const = point <= frozen_below
-                    h, _, seg_stats = self._run_layers(
-                        frozen if const else params, h, prev, point, bias, positions,
-                        remat=False if const else remat,
-                        key_mask=attention_mask,
-                        local_bias=local_bias, ring_mesh=ring,
-                    )
+                    h, _, seg_stats = self._run_layers(params, h, prev, point, remat=remat, **ctx)
                     stats = _join_stats(stats, seg_stats)
-                    if const:
-                        h = jax.lax.stop_gradient(h)
                 if point < self.cfg.n_layer:
                     captures.append(h)
                 prev = point

@@ -188,19 +188,39 @@ class CausalLMWithValueHead:
         `params["v_branch"]`). 0 = the backward runs the whole trunk:
         all layers train, a peft adapter (`setup_model` leaves
         `branch_at` None), or an embedding LayerNorm, which trains
-        under every layer."""
+        under every layer. The layers under it run once an optimizer step
+        in the per-step program and once a BLOCK in the fused one
+        (`trunk_layers_held`)."""
         if self.branch_at is None or self.cfg.embed_layernorm:
             return 0
         return self.branch_at
 
+    def trunk_layers_held(self) -> int:
+        """Layers of the trunk whose output a fused block computes once
+        and holds across its optimizer steps (`trunk_constants`): the
+        frozen trunk up to the branch point, 0 where the step keeps the
+        whole forward (`frozen_below()` 0, `pp > 1`, a ring mesh)."""
+        return self.lm.resume_point(self._capture_points(), self.frozen_below())
+
+    def trunk_constants(self, params, input_ids, attention_mask):
+        """The frozen trunk's output for these rows, for `forward_train`'s
+        `trunk` (`TransformerLM.trunk_constants`); None where
+        `trunk_layers_held()` is 0."""
+        return self.lm.trunk_constants(
+            _effective_base(self, params), input_ids, attention_mask,
+            self._capture_points(), self.frozen_below(),
+        )
+
     def _multi_forward(self, params, input_ids, attention_mask, remat,
-                       compute_logits=True):
-        """Trunk pass capturing hydra and/or value-branch fork hiddens."""
+                       compute_logits=True, trunk=None):
+        """Trunk pass capturing hydra and/or value-branch fork hiddens
+        (resumed above the held captures where `trunk` gives them)."""
         base = _effective_base(self, params)
         points = self._capture_points()
         out = self.lm.forward_with_multi_capture(
             base, input_ids, attention_mask, points, remat=remat,
             compute_logits=compute_logits, frozen_below=self.frozen_below(),
+            trunk=trunk,
         )
         named = dict(zip(points, out["captures"]))
         if self.branch_at is not None:
@@ -247,13 +267,16 @@ class CausalLMWithValueHead:
         attention_mask: Optional[Array] = None,
         remat: bool = False,
         compute_logits: bool = True,
+        trunk=None,
     ) -> Dict[str, Array]:
         """One pass producing policy logits, values AND reference logits.
 
         Hydra mode shares the trunk below `branch_at` between policy and
         reference (the whole point of the reference's hydra heads —
         modeling_ppo.py:410-453 — done here with an array slice instead of
-        six per-arch branch classes).
+        six per-arch branch classes). `trunk`: these rows'
+        `trunk_constants`, where a fused block holds them; the pass then
+        resumes at the branch point.
 
         `compute_logits=False` (train.logit_chunks) skips BOTH full-vocab
         projections; `ref_hidden` is always returned so chunked losses can
@@ -271,7 +294,7 @@ class CausalLMWithValueHead:
                 )
         else:
             out = self._multi_forward(
-                params, input_ids, attention_mask, remat, compute_logits
+                params, input_ids, attention_mask, remat, compute_logits, trunk
             )
             out["values"] = self._values(params, out)
             with jax.named_scope("ref_branch"):

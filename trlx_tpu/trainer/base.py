@@ -1446,40 +1446,50 @@ class TPUBaseTrainer(BaseRLTrainer):
     # the training loop
     # ------------------------------------------------------------------
 
-    def _step_update(self, params, opt_state, batch):
+    def _grads_view(self, params):
+        """`params` as the loss reads them: differentiated through a
+        grads_dtype view, gradients come out in that dtype (e.g. bf16 =
+        half the HBM of fp32 grads); `params` stays the fp32 master the
+        optimizer updates (the 1.3B recipe,
+        configs/mesh/single_chip_1p3b.yml)."""
+        gd = self.config.train.grads_dtype
+        if not gd:
+            return params
+        return jax.tree_util.tree_map(
+            lambda x: x.astype(_DTYPES[gd])
+            if jnp.issubdtype(x.dtype, jnp.floating) else x,
+            params,
+        )
+
+    def _step_update(self, params, opt_state, batch, trunk=None):
         """Pure (jit-traceable) single optimizer step: microbatch scan ->
-        mean grads -> masked optimizer update."""
-        loss_fn = self.loss
+        mean grads -> masked optimizer update. `trunk`: what the fused
+        block holds of the frozen trunk for these rows (`_block_trunk`:
+        captures by row, the pass's counters), handed on to the loss; the
+        captures split with the microbatches."""
         num_mb, mb_size = self.num_mb, self.mb_size
         tx = self.tx
         gd = self.config.train.grads_dtype
         grads_dtype = _DTYPES[gd] if gd else None
 
-        def compute(p, b):
-            if grads_dtype is not None:
-                # differentiate through a grads_dtype view: gradients come
-                # out in that dtype (e.g. bf16 = half the HBM of fp32
-                # grads); `params` stays the fp32 master the optimizer
-                # updates (the 1.3B recipe, configs/mesh/single_chip_1p3b.yml)
-                p = jax.tree_util.tree_map(
-                    lambda x: x.astype(grads_dtype)
-                    if jnp.issubdtype(x.dtype, jnp.floating) else x,
-                    p,
-                )
-            return jax.value_and_grad(loss_fn, has_aux=True)(p, b)
+        def compute(p, b, captures=None):
+            held = () if trunk is None else ((captures, trunk[1]),)
+            return jax.value_and_grad(self.loss, has_aux=True)(self._grads_view(p), b, *held)
 
         if num_mb == 1:
-            (loss, stats), grads = compute(params, batch)
+            (loss, stats), grads = compute(params, batch, trunk and trunk[0])
         else:
             # gradient-accumulation compensation hook: batch-statistic
             # terms (PPO's advantage whitening) are precomputed over
             # the FULL minibatch here, so splitting cannot change them
             batch = self._pre_accum_batch(batch)
             mbs = jax.tree_util.tree_map(
-                lambda x: x.reshape((num_mb, mb_size) + x.shape[1:]), batch
+                lambda x: x.reshape((num_mb, mb_size) + x.shape[1:]),
+                (batch, trunk and trunk[0]),
             )
             first = jax.tree_util.tree_map(lambda x: x[0], mbs)
-            (l_shape, s_shape), g_shape = jax.eval_shape(compute, params, first)
+            (l_shape, s_shape), g_shape = jax.eval_shape(
+                lambda p, mb: compute(p, *mb), params, first)
             # low-precision per-microbatch grads still ACCUMULATE in fp32
             # (bf16 running sums lose mantissa against a growing total)
             zeros = jax.tree_util.tree_map(
@@ -1492,7 +1502,7 @@ class TPUBaseTrainer(BaseRLTrainer):
             )
 
             def body(acc, mb):
-                (l, s), g = compute(params, mb)
+                (l, s), g = compute(params, *mb)
                 return jax.tree_util.tree_map(
                     lambda a, x: a + x.astype(a.dtype), acc, (g, l, s)
                 ), None
@@ -1574,11 +1584,14 @@ class TPUBaseTrainer(BaseRLTrainer):
         doctor's split_microbatch rung is active). Default: identity."""
         return batch
 
-    def _note_backward_depth(self) -> None:
-        """Gauge pair, once per built train step, in the flight stream
-        and the tracker: `model/layers`, and `model/backward_layers`,
-        the layers the step's backward pass runs through (the wrapper's
-        `frozen_below()`: hydra PPO stops it at the branch point)."""
+    def _note_backward_depth(self, hoisted: int = 0) -> None:
+        """Gauges, once per built train step, in the flight stream and
+        the tracker: `model/layers`; `model/backward_layers`, the layers
+        the step's backward pass runs through (the wrapper's
+        `frozen_below()`: hydra PPO stops it at the branch point); and
+        `model/trunk_layers_hoisted`, the layers whose forward the built
+        program runs once a block and not once a step (`_block_trunk`; 0
+        wherever the step keeps the whole forward)."""
         cfg = self.model.cfg
         below = getattr(self.model, "frozen_below", lambda: 0)()
         if self.mesh.shape["pp"] > 1:
@@ -1589,7 +1602,8 @@ class TPUBaseTrainer(BaseRLTrainer):
         else:  # seq2seq: a frozen trunk takes the whole encoder with it
             layers = cfg.n_layer + decoder
             backward = decoder - below if below else layers
-        gauges = {"model/layers": layers, "model/backward_layers": backward}
+        gauges = {"model/layers": layers, "model/backward_layers": backward,
+                  "model/trunk_layers_hoisted": hoisted}
         if getattr(cfg, "beyond_dense", False):
             gauges["model/experts_held"] = cfg.n_experts_held or 0
             # of ONE layer that caches; a row's cache is that times the
@@ -1626,6 +1640,54 @@ class TPUBaseTrainer(BaseRLTrainer):
             out_shardings=(params_sh, opt_sh, None, None),
         )
 
+    def trunk_layers_held(self) -> int:
+        """Subclass hook, static: the layers of a frozen trunk whose
+        output the fused block computes once (`trunk_constants`) and holds
+        across its optimizer steps; 0 = every step runs the whole
+        forward."""
+        return 0
+
+    def trunk_constants(self, params, batch):
+        """Subclass hook, traced where `trunk_layers_held()` is above 0:
+        (captures, counters) of the frozen trunk over `batch`'s rows, what
+        the loss takes as `trunk`: arrays with the rows leading, and the
+        pass's counters (sums over the rows, or None)."""
+        raise NotImplementedError
+
+    def _block_trunk(self, params, full_batch, n_steps: int):
+        """The frozen trunk's forward over all rows of the block, ONCE,
+        with the parameters the block was entered with (the freeze mask
+        leaves them bit-identical from step to step): in groups of the
+        step's batch size, so it runs at the shapes the step would run it
+        at. Returns (captures [rows, ...], counters) or None. The counters
+        of the pass are spread over the block's steps (and a step's
+        microbatches), so that the mean of the steps' stats still reads
+        what the program ran, a step's mean."""
+        if not self.trunk_layers_held():
+            return None
+        bs = self.config.train.batch_size
+        rows = jax.tree_util.tree_leaves(full_batch)[0].shape[0]
+        view = self._grads_view(params)
+        if rows == bs:
+            captures, counters = self.trunk_constants(view, full_batch)
+        else:
+            groups = -(-rows // bs)
+
+            def grouped(x):
+                if groups * bs != rows:  # a ragged last group wraps round
+                    x = x[jnp.arange(groups * bs) % rows]
+                return x.reshape((groups, bs) + x.shape[1:])
+
+            captures, counters = jax.lax.map(
+                lambda group: self.trunk_constants(view, group),
+                jax.tree_util.tree_map(grouped, full_batch),
+            )
+            captures = jax.tree_util.tree_map(
+                lambda x: x.reshape((groups * bs,) + x.shape[2:]), captures)
+            counters = jax.tree_util.tree_map(lambda x: jnp.sum(x, axis=0), counters)
+        share = n_steps * self.num_mb
+        return captures, jax.tree_util.tree_map(lambda x: x / share, counters)
+
     def make_fused_train_steps(self):
         """The whole inner loop as ONE jitted call: scan the optimizer
         step over host-chosen minibatch permutations of a device-resident
@@ -1635,14 +1697,27 @@ class TPUBaseTrainer(BaseRLTrainer):
         overhead and the per-step host sync disappear. The reference
         pays this per minibatch by construction (torch eager loop).
 
+        A frozen trunk's forward is a loop invariant of that scan (the
+        same rows, the same weights, every epoch), and XLA cannot hoist it
+        by itself: the rows are gathered by `perm` inside the body and
+        the parameters are the scan's carry. Where the trainer says so
+        (`trunk_layers_held()`: hydra PPO), the trunk runs ONCE before the
+        scan over all rows (`_block_trunk`) and every step gathers its
+        rows of the output with its `perm` and resumes at the branch
+        point. The per-step program (`make_train_step`) has nowhere to
+        keep it and runs the whole forward.
+
         Signature: (params, opt_state, full_batch, perms[n_steps, bs])
         -> (params, opt_state, mean_loss, mean_stats)."""
 
         def fused_train_step(params, opt_state, full_batch, perms):
+            trunk = self._block_trunk(params, full_batch, perms.shape[0])
+
             def body(carry, perm):
                 p, o = carry
                 mb = jax.tree_util.tree_map(lambda x: x[perm], full_batch)
-                p, o, loss, stats = self._step_update(p, o, mb)
+                held = trunk and (jax.tree_util.tree_map(lambda x: x[perm], trunk[0]), trunk[1])
+                p, o, loss, stats = self._step_update(p, o, mb, held)
                 return (p, o), (loss, stats)
 
             (params, opt_state), (losses, stats) = jax.lax.scan(
@@ -1653,7 +1728,7 @@ class TPUBaseTrainer(BaseRLTrainer):
             )
             return params, opt_state, jnp.mean(losses), mean_stats
 
-        self._note_backward_depth()
+        self._note_backward_depth(hoisted=self.trunk_layers_held())
         params_sh, opt_sh = self._pinned_state_shardings()
         return jax.jit(
             fused_train_step,

@@ -185,9 +185,31 @@ class TPUPPOTrainer(TPUOnlineTrainer):
 
     # -- loss ------------------------------------------------------------
 
-    def loss(self, params, batch: PPORolloutBatch):
+    def _policy_inputs(self, batch: PPORolloutBatch):
+        """(tokens, attention_mask) of the causal policy's teacher-forced
+        pass over stored rollouts: query then response."""
+        P = batch.query_tensors.shape[1]
+        tokens = jnp.concatenate([batch.query_tensors, batch.response_tensors], axis=1)
+        attention_mask = (tokens != self.generate_settings.pad_token_id).astype(jnp.int32)
+        # response positions count even where response==pad (mask handles it)
+        attention_mask = attention_mask.at[:, P:].set(
+            jnp.maximum(attention_mask[:, P:], batch.response_mask.astype(jnp.int32))
+        )
+        return tokens, attention_mask
+
+    def trunk_layers_held(self) -> int:
+        # T5 keeps the whole forward in the block (`models/seq2seq.py` has
+        # its own `frozen_below` and no split at the constants)
+        return 0 if self.seq2seq else self.model.trunk_layers_held()
+
+    def trunk_constants(self, params, batch: PPORolloutBatch):
+        return self.model.trunk_constants(params, *self._policy_inputs(batch))
+
+    def loss(self, params, batch: PPORolloutBatch, trunk=None):
         """Recompute logprobs/values on stored rollouts, GAE on the fly,
-        clipped PPO objective (parity: reference loss :127-204)."""
+        clipped PPO objective (parity: reference loss :127-204). `trunk`:
+        the frozen trunk's output for these rows where the fused block
+        holds it (`trunk_constants`); the forward resumes above it."""
         method = self.config.method
         if batch.advantages is not None:
             # gradient-accumulation compensation (_pre_accum_batch):
@@ -241,15 +263,10 @@ class TPUPPOTrainer(TPUOnlineTrainer):
             )
         P = batch.query_tensors.shape[1]
         N = batch.response_tensors.shape[1]
-        tokens = jnp.concatenate([batch.query_tensors, batch.response_tensors], axis=1)
-        attention_mask = (tokens != pad).astype(jnp.int32)
-        # response positions count even where response==pad (mask handles it)
-        attention_mask = attention_mask.at[:, P:].set(
-            jnp.maximum(attention_mask[:, P:], batch.response_mask.astype(jnp.int32))
-        )
+        tokens, attention_mask = self._policy_inputs(batch)
         out = self.model.forward_train(
             params, self.ref_params, tokens, attention_mask, remat=remat,
-            compute_logits=chunks == 0,
+            compute_logits=chunks == 0, trunk=trunk,
         )
         if chunks:
             # only response positions need logprobs: slice hidden BEFORE
@@ -828,10 +845,24 @@ class TPUPPOTrainer(TPUOnlineTrainer):
         chunks = max(int(train.logit_chunks or 0), 0)
         logit_rows = S if chunks == 0 else -(-S // chunks)
         logits_b = int(2 * rows_dev * logit_rows * cfg.vocab_size * 4)
-        return [
+        items = [
             PlanItem("rollout", "experience_fwd", act_b + logits_b,
                      "teacher-forced policy+ref forward per chunk"),
         ]
+        held = self.trunk_layers_held() if train.fused_inner_loop else 0
+        if held:
+            # the frozen trunk's output for every row of the block, live
+            # across the scan over the optimizer steps (`_block_trunk`)
+            points = [p for p in self.model._capture_points() if p <= held]
+            rows = max(int(self.config.method.num_rollouts) // self.data_ways(), 1)
+            items.append(PlanItem(
+                "train", "trunk_constants",
+                int(len(points) * rows * S * cfg.hidden_size * cfg.residual_streams
+                    * _dtype_size(train.compute_dtype)),
+                f"the {held} frozen layers' output, held across the fused block: "
+                f"{len(points)} capture(s) of every rollout",
+            ))
+        return items
 
     # -- controller state layered on the online-core hooks ---------------
 
