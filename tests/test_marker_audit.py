@@ -49,7 +49,7 @@ TIER1_BUDGETS = {
     "test_elastic.py": 26,
     "test_examples.py": 1,
     "test_exp_queue.py": 29,
-    "test_fault_tolerance.py": 35,
+    "test_fault_tolerance.py": 24,
     "test_flash_attention.py": 14,
     "test_fleet.py": 35,
     # PR 26: the stop of the backward pass at the hydra branch point —
@@ -97,7 +97,7 @@ TIER1_BUDGETS = {
     # (99.9 measured), fault_tolerance 65->63 (62.4), gen_engine 36->34
     # (32.6), memdoctor 37->35 (32).
     "test_graft_lint.py": 7,
-    "test_grpo.py": 30,
+    "test_grpo.py": 20,
     # r09: +4 preference-RL chaos learn() tests (GRPO nan/sigterm, DPO
     # nan/sigterm); whole file re-measured 99.9s serial
     "test_guardrails.py": 55,
@@ -163,7 +163,7 @@ TIER1_BUDGETS = {
     # scanned_epochs 50->46 (42.4), gen_engine 40->36 (32.6),
     # memdoctor 40->37 (32), elastic 35->34 (32.0), exp_queue 30->29
     # (28.2), models 18->17 (16.2), peft 15->14 (13.9).
-    "test_obs.py": 26,
+    "test_obs.py": 25,
     # r15: paged-attention kernel + sharded lanes + trunk-sharing suite
     # (op-level kernel parity grid, engine pallas==xla goldens incl.
     # the spec verify forward, trunk-shared pool accounting, grouped-
@@ -188,10 +188,27 @@ TIER1_BUDGETS = {
     "test_remat.py": 1,
     "test_resilient.py": 1,
     "test_ring_attention.py": 8,
-    "test_scanned_epochs.py": 20,
+    "test_scanned_epochs.py": 13,
     "test_seq2seq.py": 13,
-    "test_serve.py": 19,
+    "test_serve.py": 12,
     "test_sharding.py": 7,
+    # PR 35: Mamba-2 state-space layers beside grouped-query attention and
+    # latent-space experts, every layer ONE sub-layer, against its float32
+    # reference (logits, trainable gradients through the chunked form's
+    # checkpointed scan, chunked against recurrent in four regimes, prefill
+    # then decode through state, tail and k/v rows, padding, the 64 shares,
+    # hydra cuts and trunk constants over one-sub-layer segments, the freeze
+    # mask, gauges and counts): one toy stack jitted once and one toy
+    # trainer, module scope. 72 s alone on this container
+    # (test_linear_attention: 105 s alone, 184.3 s inside the driver's
+    # 6-worker run of 2026-10-03 (PR 34), whose files took 2,747 s against
+    # the 780 budgeted: the table's scale is in-run seconds / 3.52), so
+    # about 126 s in such a run (122.9 s measured inside my own 6-worker
+    # run of the final tree, 410 s, 720 passed): budgeted 36. Paid under the unchanged 780
+    # ceiling with times of that run on that scale: fault_tolerance 35->24
+    # (77.0 s = 21.9), grpo 30->20 (62.9 s = 17.9), serve 19->12 (38.5 s =
+    # 10.9), scanned_epochs 20->13 (42.7 s = 12.1), obs 26->25 (66.3 s = 18.8).
+    "test_state_space.py": 36,
     "test_summarize_eval.py": 1,
     "test_supervisor.py": 11,
     "test_sweep.py": 14,

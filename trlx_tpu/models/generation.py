@@ -274,14 +274,15 @@ def chunks_streamed(steps: int, cells: int, chunk: int, slots: int, first_write:
 
 def state_bytes_per_step(cfg, rows: int) -> int:
     """Bytes of recurrent state one decode step carries for `rows` rows:
-    what the delta-rule layers keep (`memdoctor.delta_state_bytes`: a
-    float32 state and the inputs their convolutions hold), read once and
-    written once, whatever the length of the rows. 0 for a model without
-    such layers. Host arithmetic, as `chunks_streamed` is: times the steps
-    the loop ran it is the `tokens_wait` span's `state_bytes_carried`."""
-    from trlx_tpu.utils.memdoctor import delta_state_bytes
+    what the delta-rule and state-space layers keep
+    (`memdoctor.recurrent_state_bytes`: a float32 state and the inputs their
+    convolutions hold), read once and written once, whatever the length of
+    the rows. 0 for a model without such layers. Host arithmetic, as
+    `chunks_streamed` is: times the steps the loop ran it is the
+    `tokens_wait` span's `state_bytes_carried`."""
+    from trlx_tpu.utils.memdoctor import recurrent_state_bytes
 
-    return 2 * delta_state_bytes(cfg, rows, jnp.dtype(getattr(cfg, "dtype", jnp.bfloat16)).itemsize)
+    return 2 * recurrent_state_bytes(cfg, rows, jnp.dtype(getattr(cfg, "dtype", jnp.bfloat16)).itemsize)
 
 
 def generate(

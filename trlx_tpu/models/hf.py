@@ -221,14 +221,16 @@ def config_from_hf(hf_config: Any, dtype=None, param_dtype=None) -> TransformerC
             param_dtype=param_dtype,
         )
     if (getattr(hf_config, "kv_lora_rank", None) or getattr(hf_config, "n_routed_experts", None)
-            or getattr(hf_config, "linear_attn_config", None)):
+            or getattr(hf_config, "linear_attn_config", None)
+            or getattr(hf_config, "hybrid_override_pattern", None)):
         raise NotImplementedError(
             f"no loader for model_type {mt!r}: the program runs latent attention, delta-rule "
-            "(KDA) layers, routed experts and several residual streams (TransformerConfig's "
-            "kv_lora_rank, mixer_layers, n_routed_experts, residual_streams) from random "
-            "weights only; a checkpoint's tensor names would have to be mapped onto "
-            "params['dense_blocks'] / params['blocks'] / params['delta_blocks'], and no such "
-            "map is written here"
+            "(KDA) and state-space (Mamba-2) layers, layers of one sub-layer, routed experts "
+            "and several residual streams (TransformerConfig's kv_lora_rank, mixer_layers, "
+            "ffn_layers, n_routed_experts, residual_streams) from random weights only; a "
+            "checkpoint's tensor names would have to be mapped onto the stacks of "
+            "`transformer.STACKS` (params['dense_blocks'] / params['blocks'] / "
+            "params['delta_blocks'] / params['ssm_blocks'] ...), and no such map is written here"
         )
     raise ValueError(
         f"unsupported model_type {mt!r} (supported: gpt2, gptj, gpt_neo, "

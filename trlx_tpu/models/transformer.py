@@ -71,7 +71,7 @@ class TransformerConfig:
     attn_layers: Optional[Tuple[str, ...]] = None  # per-layer "global"/"local"
     norm: str = "layernorm"  # "layernorm" | "rmsnorm"
     layer_norm_epsilon: float = 1e-5
-    activation: str = "gelu_new"  # "gelu_new" | "gelu" | "silu" | "relu"
+    activation: str = "gelu_new"  # "gelu_new" | "gelu" | "silu" | "relu" | "relu2" (relu squared)
     mlp_gated: bool = False  # llama-style SwiGLU
     parallel_residual: bool = False  # gptj/neox: attn and mlp share input
     use_attn_bias: bool = True
@@ -176,6 +176,37 @@ class TransformerConfig:
     delta_heads: int = 0
     delta_head_dim: int = 0
     delta_conv: int = 4
+    # two more entries of `mixer_layers`: "ssm" (a Mamba-2 selective state
+    # space, `Mamba2Mixer`: `ssm_heads` heads of `ssm_head_dim` with a
+    # [head_dim, ssm_state] float32 state each, B and C shared by the heads
+    # of one of `ssm_groups` groups, a causal depthwise convolution of
+    # `ssm_conv` taps, the chunked form over `ssm_chunk` positions) and
+    # "none": the layer is its feed-forward alone. `ssm_dt_*` are read at
+    # initialisation only
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
+    ssm_dt_min: float = 0.001
+    ssm_dt_max: float = 0.1
+    ssm_dt_floor: float = 1e-4
+    # the FEED-FORWARD of each layer, one entry a layer: "none" (the layer
+    # is its mixer alone: ONE sub-layer a layer) or the kind the other
+    # keys give it ("dense" below `first_k_dense` and in a model without
+    # experts, "routed" elsewhere). None: every layer has one
+    ffn_layers: Optional[Tuple[str, ...]] = None
+    # routed experts that work in a latent space (set when
+    # `moe_latent_size` is): x is projected down to it once a token, the
+    # experts are that wide at both ends, and their weighted sum is
+    # projected back up; the shared expert reads x itself.
+    # `moe_gated` False: an expert is two products around the activation,
+    # no gate matrix (the shared expert too). `moe_shared_intermediate_size`:
+    # the shared expert's own width (None: moe_intermediate_size a shared expert)
+    moe_latent_size: Optional[int] = None
+    moe_gated: bool = True
+    moe_shared_intermediate_size: Optional[int] = None
 
     @property
     def latent(self) -> bool:
@@ -189,9 +220,19 @@ class TransformerConfig:
         return ("latent" if self.latent else "softmax",) * self.n_layer
 
     @property
+    def ffns(self) -> Tuple[str, ...]:
+        """The feed-forward of each layer of the stack."""
+        if self.ffn_layers is not None:
+            return self.ffn_layers
+        return tuple(self._default_ffn(i) for i in range(self.n_layer))
+
+    def _default_ffn(self, layer: int) -> str:
+        return "routed" if self.routed and layer >= self.first_k_dense else "dense"
+
+    @property
     def hybrid(self) -> bool:
         """Some layer keeps a recurrent state where the others keep a cache."""
-        return "delta" in self.mixers
+        return "delta" in self.mixers or "ssm" in self.mixers
 
     @property
     def routed(self) -> bool:
@@ -199,17 +240,19 @@ class TransformerConfig:
 
     @property
     def beyond_dense(self) -> bool:
-        """Latent attention, delta-rule layers, routed experts or several
-        residual streams: the models that the paths below the dense decoder
-        (pipeline, ring, paged engine, adapters, loaders) do not reach and
-        raise for."""
-        return self.latent or self.hybrid or self.routed or self.residual_streams > 1
+        """Latent attention, delta-rule or state-space layers, layers of one
+        sub-layer, routed experts or several residual streams: the models
+        that the paths below the dense decoder (pipeline, ring, paged
+        engine, adapters, loaders) do not reach and raise for."""
+        return (self.latent or self.hybrid or self.routed or self.residual_streams > 1
+                or self.ffn_layers is not None or "none" in self.mixers)
 
     @property
     def cache_elems_per_position(self) -> int:
         """Numbers one cached position costs a row, in one layer THAT
-        CACHES (`cache_layers` of them; a delta layer caches nothing, its
-        state is `state_elems_per_row` whatever the length)."""
+        CACHES (`cache_layers` of them; a delta-rule or state-space layer
+        caches nothing, its state is `state_elems_per_row` whatever the
+        length)."""
         if self.latent:
             return self.kv_lora_rank + self.qk_rope_head_dim
         return 2 * self.n_kv_head * self.head_dim
@@ -217,16 +260,25 @@ class TransformerConfig:
     @property
     def cache_layers(self) -> int:
         """Layers whose cache grows with the sequence."""
-        return self.n_layer - self.mixers.count("delta")
+        return sum(m in ("softmax", "latent") for m in self.mixers)
 
     @property
     def state_elems_per_row(self) -> int:
-        """Numbers the delta layers keep a row, whatever its length: a
-        [heads, d_k, d_v] state and the last `delta_conv - 1` inputs of the
-        three convolutions, in each of them."""
+        """Numbers the layers with a recurrent state keep a row, whatever
+        its length: in a delta-rule layer a [heads, d_k, d_v] state and the
+        last `delta_conv - 1` inputs of the three convolutions; in a
+        state-space layer a [heads, head_dim, ssm_state] state and the last
+        `ssm_conv - 1` inputs of its one convolution (`ssm_conv_width`)."""
         width = self.delta_heads * self.delta_head_dim
-        return self.mixers.count("delta") * (
-            width * self.delta_head_dim + (self.delta_conv - 1) * 3 * width)
+        return (
+            self.mixers.count("delta") * (width * self.delta_head_dim + (self.delta_conv - 1) * 3 * width)
+            + self.mixers.count("ssm") * (self.ssm_heads * self.ssm_head_dim * self.ssm_state
+                                          + (self.ssm_conv - 1) * self.ssm_conv_width))
+
+    @property
+    def ssm_conv_width(self) -> int:
+        """Channels of a state-space layer's convolution: x', B and C."""
+        return self.ssm_heads * self.ssm_head_dim + 2 * self.ssm_groups * self.ssm_state
 
     @property
     def attn_softmax_scale(self) -> float:
@@ -253,8 +305,9 @@ class TransformerConfig:
             object.__setattr__(self, "rotary_dim", self.head_dim)
         if self.routed and self.n_experts_held is None:
             object.__setattr__(self, "n_experts_held", self.n_routed_experts)
-        if self.mixer_layers is not None:  # a list from a config file: hashable
-            object.__setattr__(self, "mixer_layers", tuple(self.mixer_layers))
+        for name in ("mixer_layers", "ffn_layers"):  # a list from a config file: hashable
+            if getattr(self, name) is not None:
+                object.__setattr__(self, name, tuple(getattr(self, name)))
         if self.kv_cache_quant not in (None, "int8"):
             raise ValueError(
                 f"kv_cache_quant={self.kv_cache_quant!r}: the values are None and "
@@ -264,32 +317,34 @@ class TransformerConfig:
         self._check_family()
 
     def _check_family(self) -> None:
-        """What a latent, delta-rule, routed or multi-stream model does not
-        reach raises here, at configuration time, not as a wrong answer later."""
+        """What a latent, delta-rule, state-space, routed or multi-stream
+        model does not reach raises here, at configuration time, not as a
+        wrong answer later."""
         if self.mixer_layers is not None:
             base = "latent" if self.latent else "softmax"
-            if len(self.mixer_layers) != self.n_layer or set(self.mixer_layers) - {base, "delta"}:
+            if len(self.mixer_layers) != self.n_layer or set(self.mixer_layers) - {base, "delta", "ssm", "none"}:
                 raise ValueError(
                     f"mixer_layers names one of {base!r} (the attention the other keys "
-                    f"describe) or 'delta' for each of the {self.n_layer} layers"
+                    f"describe), 'delta', 'ssm' or 'none' for each of the {self.n_layer} layers"
                 )
         if not self.beyond_dense:
             return
         def no(what):
             raise NotImplementedError(
                 f"{what} is not implemented for a model with latent attention, "
-                "delta-rule (KDA) layers, routed experts or several residual streams"
+                "delta-rule (KDA) or state-space (Mamba-2) layers, layers of one sub-layer, "
+                "routed experts or several residual streams"
             )
         if (self.latent or self.hybrid) and self.kv_cache_quant is not None:
             no(f"kv_cache_quant={self.kv_cache_quant!r} (an int8 latent cache, or a cache "
-               "beside the float32 state of delta-rule layers)")
+               "beside the float32 state of delta-rule or state-space layers)")
         if self.attention_impl == "ring":
             no("attention_impl='ring'")
         if self.latent and (self.pos_embed not in ("rotary", "none") or self.local_window is not None
                             or self.n_kv_head != self.n_head or self.attn_scale is not None):
             no("latent attention with learned or alibi positions, with windows, grouped "
                "heads or a set attn_scale")
-        if self.hybrid:
+        if "delta" in self.mixers:
             if not (self.delta_heads > 0 and self.delta_head_dim > 0 and self.delta_conv >= 2):
                 raise ValueError("delta-rule layers need delta_heads, delta_head_dim and delta_conv >= 2")
             if "softmax" in self.mixers:
@@ -297,6 +352,16 @@ class TransformerConfig:
                    "key and value cache)")
             if len(set(self.mixers[: self.first_k_dense])) > 1:
                 no("leading dense layers of more than one mixer")
+        if "ssm" in self.mixers:
+            if not (self.ssm_heads > 0 and self.ssm_head_dim > 0 and self.ssm_state > 0
+                    and self.ssm_conv >= 2 and self.ssm_chunk > 0
+                    and self.ssm_groups > 0 and self.ssm_heads % self.ssm_groups == 0):
+                raise ValueError("state-space layers need ssm_heads (whole groups of them), "
+                                 "ssm_head_dim, ssm_state, ssm_chunk and ssm_conv >= 2")
+            if "delta" in self.mixers or self.first_k_dense or self.local_window is not None:
+                no("state-space (Mamba-2) layers beside delta-rule layers, leading dense "
+                   "layers or windowed attention")
+        self._check_sub_layers(no)
         if self.parallel_residual or self.embed_layernorm:
             no("parallel_residual / embed_layernorm")
         if self.routed:
@@ -312,29 +377,65 @@ class TransformerConfig:
         elif self.first_k_dense:
             raise ValueError("first_k_dense without routed experts")
 
+    def _check_sub_layers(self, no) -> None:
+        """A layer names its mixer or none and its feed-forward or none."""
+        if self.ffn_layers is None:
+            if "ssm" in self.mixers or "none" in self.mixers:
+                raise ValueError("a stack with 'ssm' or 'none' among its mixers states ffn_layers: "
+                                 "a state-space layer is its mixer alone")
+            return
+        if len(self.ffn_layers) != self.n_layer:
+            raise ValueError(f"ffn_layers names the feed-forward of each of the {self.n_layer} layers")
+        for layer, (mixer, ffn) in enumerate(zip(self.mixers, self.ffn_layers)):
+            if ffn not in ("none", self._default_ffn(layer)):
+                raise ValueError(
+                    f"ffn_layers[{layer}] is 'none' or {self._default_ffn(layer)!r}, the kind "
+                    f"the other keys give layer {layer}, not {ffn!r}")
+            if (mixer == "none" and ffn == "none") or (mixer == "ssm" and ffn != "none"):
+                raise ValueError(
+                    f"layer {layer}: mixer {mixer!r} with feed-forward {ffn!r} (a layer has a "
+                    "mixer, a feed-forward or both; a state-space layer is its mixer alone)")
+            if (mixer == "delta" and ffn == "none") or (layer < self.first_k_dense and "none" in (mixer, ffn)):
+                no("a delta-rule layer without a feed-forward, or a leading dense layer of one sub-layer")
+        if self.residual_streams > 1:
+            no("layers of one sub-layer under several residual streams")
+
     def replace(self, **kw) -> "TransformerConfig":
         return dataclasses.replace(self, **kw)
 
 
 # the stacks of a parameter tree, in the order a tree without delta-rule
 # layers has always had them; a layer's kind (mixer, feed-forward) names
-# its stack, so a stack holds layers equal in both
-STACKS = ("dense_blocks", "blocks", "delta_blocks")
+# its stack, so a stack holds layers equal in both. The last four hold
+# layers of ONE sub-layer: a state-space mixer, attention, routed experts,
+# a dense MLP
+STACKS = ("dense_blocks", "blocks", "delta_blocks", "ssm_blocks", "attn_blocks", "moe_blocks", "mlp_blocks")
+
+
+def _stack_of(cfg: TransformerConfig, layer: int) -> str:
+    mixer, ffn = cfg.mixers[layer], cfg.ffns[layer]
+    if layer < cfg.first_k_dense:
+        return "dense_blocks"
+    if ffn == "none":
+        return "ssm_blocks" if mixer == "ssm" else "attn_blocks"
+    if mixer == "none":
+        return "moe_blocks" if ffn == "routed" else "mlp_blocks"
+    return "delta_blocks" if mixer == "delta" else "blocks"
 
 
 @functools.lru_cache(maxsize=None)
 def layer_stacks(cfg: TransformerConfig) -> Tuple[Tuple[str, int], ...]:
     """(stack, row in it) of each layer of the whole stack: the
     `first_k_dense` leading layers under `dense_blocks`, above them the
-    delta-rule layers under `delta_blocks` and the rest under `blocks`.
+    delta-rule layers under `delta_blocks`, layers of one sub-layer under
+    the stack of their kind (`_stack_of`) and the rest under `blocks`.
     A SEGMENT is a run of consecutive layers of one stack (consecutive
     rows of it): one `lax.scan`. Everything that addresses a layer by its
     index in the whole stack (a branch point, the freeze mask, a cache
     row) goes through this."""
     out, rows = [], {}
-    for i, mixer in enumerate(cfg.mixers):
-        name = "dense_blocks" if i < cfg.first_k_dense else (
-            "delta_blocks" if mixer == "delta" else "blocks")
+    for i in range(cfg.n_layer):
+        name = _stack_of(cfg, i)
         out.append((name, rows.get(name, 0)))
         rows[name] = rows.get(name, 0) + 1
     return tuple(out)
@@ -351,6 +452,7 @@ def _activation(name: str) -> Callable[[Array], Array]:
         "gelu": partial(jax.nn.gelu, approximate=False),
         "silu": jax.nn.silu,
         "relu": jax.nn.relu,
+        "relu2": lambda x: jnp.square(jax.nn.relu(x)),
     }[name]
 
 
@@ -813,10 +915,11 @@ class Attention(nn.Module):
                 "T % 8 == 0, S % 128 == 0, 1/sqrt(D) scaling and a "
                 "causal+padding mask",
             )
-        if Hkv != H and not use_pallas and kernel_out is None:
-            # grouped-query on the XLA/ring paths: repeat kv heads (the
-            # pallas kernel handles GQA natively and must NOT see
-            # repeated kv — that would forfeit its grouped HBM reads)
+        if Hkv != H and ring_mesh is not None and kernel_out is None:
+            # grouped-query on the ring path: repeat kv heads (the pallas
+            # kernel handles GQA natively and must NOT see repeated kv —
+            # that would forfeit its grouped HBM reads; the XLA path below
+            # groups the queries instead)
             rep = H // Hkv
             k = jnp.repeat(k, rep, axis=2)
             v = jnp.repeat(v, rep, axis=2)
@@ -846,13 +949,28 @@ class Attention(nn.Module):
             ).transpose(0, 2, 1, 3)
         else:
             scale = cfg.attn_scale if cfg.attn_scale is not None else 1.0 / math.sqrt(D)
-            # [B, H, T, S]; accumulate scores in fp32 for stability
-            scores = jnp.einsum(
-                "bthd,bshd->bhts", q, k, preferred_element_type=jnp.float32
-            ) * scale
-            scores = scores + attn_bias
-            probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
-            out = jnp.einsum("bhts,bshd->bthd", probs, v)
+            if Hkv != H:
+                # grouped-query: the queries of a key-value head side by
+                # side against its keys, [B, Hkv, rep, T, S]. Repeating the
+                # heads instead wrote the whole cache out rep times at every
+                # decode step (two heads to 32: 1.86 s of a 14.9 s cycle,
+                # PERF.md section 6, PR 35)
+                rep = H // Hkv
+                scores = jnp.einsum(
+                    "btkrd,bskd->bkrts", q.reshape(B, T, Hkv, rep, D), k, preferred_element_type=jnp.float32
+                ) * scale
+                bias = attn_bias[:, :, None] if attn_bias.shape[1] == 1 else attn_bias.reshape(
+                    (B, Hkv, rep) + attn_bias.shape[2:])
+                probs = jax.nn.softmax(scores + bias, axis=-1).astype(cfg.dtype)
+                out = jnp.einsum("bkrts,bskd->btkrd", probs, v).reshape(B, T, H, D)
+            else:
+                # [B, H, T, S]; accumulate scores in fp32 for stability
+                scores = jnp.einsum(
+                    "bthd,bshd->bhts", q, k, preferred_element_type=jnp.float32
+                ) * scale
+                scores = scores + attn_bias
+                probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
+                out = jnp.einsum("bhts,bshd->bthd", probs, v)
 
         out_bias = (
             cfg.use_attn_out_bias
@@ -961,10 +1079,13 @@ def quantize_decode_weights(params: Dict) -> Dict:
     # (the up-projection of the cached latent, `kv_b`, is used transposed
     # in the decode form and stays as it is, as does the float32 router);
     # of a delta-rule layer q, k, v and o (95% of its elements: the two
-    # low-rank pairs and beta's projection feed float32 gates and stay)
+    # low-rank pairs and beta's projection feed float32 gates and stay);
+    # of a state-space layer both projections (its taps, A_log, D, dt_bias
+    # and norm stay); of latent-space experts both latent projections
     n_feats = {"q": 2, "k": 2, "v": 2, "o": 1,
                "fc_in": 1, "fc_gate": 1, "fc_out": 1,
-               "q_a": 1, "q_b": 2, "kv_a": 1}
+               "q_a": 1, "q_b": 2, "kv_a": 1,
+               "in_proj": 1, "out_proj": 1, "latent_in": 1, "latent_out": 1}
     # stacked expert kernels [L, held, in, out]: one scale per expert and
     # output channel
     experts = ("experts_fc_in", "experts_fc_gate", "experts_fc_out")
@@ -988,7 +1109,7 @@ def quantize_decode_weights(params: Dict) -> Dict:
 
     # (in the order the rewrite has always walked them: the lowered sampler
     # of a model without delta-rule layers stays what it was)
-    stacks = {k: walk(params[k]) for k in ("blocks", "dense_blocks", "delta_blocks") if k in params}
+    stacks = {k: walk(params[k]) for k in ("blocks", "dense_blocks") + STACKS[2:] if k in params}
     return dict(params, **stacks)
 
 
@@ -1385,9 +1506,9 @@ def _a_log_init(key, shape, dtype=jnp.float32):
     return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
 
 
-def _dt_bias_init(key, shape, dtype=jnp.float32):
-    """Inverse softplus of dt = exp(U(log 0.001, log 0.1))."""
-    dt = jnp.exp(jax.random.uniform(key, shape, dtype, math.log(0.001), math.log(0.1)))
+def _dt_bias_init(key, shape, dtype=jnp.float32, lo=0.001, hi=0.1, floor=0.0):
+    """Inverse softplus of dt = max(exp(U(log lo, log hi)), floor)."""
+    dt = jnp.maximum(jnp.exp(jax.random.uniform(key, shape, dtype, math.log(lo), math.log(hi))), floor)
     return dt + jnp.log(-jnp.expm1(-dt))
 
 
@@ -1498,6 +1619,215 @@ class DeltaAttention(nn.Module):
         return proj(out), new_kv
 
 
+def _ssm_chunk(state: Array, xs, a: Array, dtype):
+    """One chunk of `ssm_chunked` for every row and head, heads laid out by
+    group: `state` [B, G, Hg, P, N] float32, and of the chunk's C positions x
+    [B, C, G Hg P], Bm, Cm [B, C, G N], dt [B, C, G Hg] -> (state after the
+    chunk, y [B, C, G Hg P]): flat, as they lie in memory outside the scan (a
+    minor dimension of P = 64 would be half a lane tile, and every reshape
+    around it a copy of the whole sequence; a chunk's are small). With
+    l = dt * a the log-decay of a position (never positive) and S its
+    cumulative sum inside the chunk:
+
+        y_r = exp(S_r) C_r h  +  sum_{i <= r} exp(S_r - S_i) (C_r . B_i) dt_i x_i
+        h'  = exp(S_C) h  +  sum_i exp(S_C - S_i) dt_i x_i (x) B_i
+
+    Every exponent is a difference formed first and never positive. Products
+    take operands in `dtype` and accumulate in float32; decays are float32."""
+    x, Bm, Cm, dt = xs
+    f32 = jnp.float32
+    B, c = x.shape[:2]
+    G, Hg, P, N = state.shape[1:]
+    x = x.reshape(B, c, G, Hg, P)
+    Bm, Cm = Bm.reshape(B, c, G, N).astype(dtype), Cm.reshape(B, c, G, N).astype(dtype)
+    dt = dt.reshape(B, c, G, Hg)
+    cs = jnp.cumsum(dt * a, axis=1)  # [B, C, G, Hg]
+    by_head = jnp.moveaxis(cs, 1, -1)  # [B, G, Hg, C]
+    # (r, i): S_r - S_i below the diagonal, 0 on it as a constant (the two
+    # terms of S_r - S_r would each carry a gradient of order 1 that cancels
+    # only up to rounding, which drowns a small gradient of `a`), nothing above
+    below = jnp.tril(jnp.ones((c, c), bool), -1)
+    decay = jnp.exp(jnp.where(below, by_head[..., :, None] - by_head[..., None, :],
+                              jnp.where(jnp.eye(c, dtype=bool), 0.0, -jnp.inf)))
+    scores = jnp.einsum("brgn,bign->bgri", Cm, Bm, preferred_element_type=f32)
+    xdt = x * dt[..., None]
+    y = jnp.einsum("bghri,bighp->brghp", (decay * scores[:, :, None]).astype(dtype), xdt.astype(dtype),
+                   preferred_element_type=f32)
+    y = y + jnp.exp(cs)[..., None] * jnp.einsum(
+        "brgn,bghpn->brghp", Cm, state.astype(dtype), preferred_element_type=f32)
+    to_end = jnp.exp(cs[:, -1:] - cs)
+    state = jnp.exp(cs[:, -1])[..., None, None] * state + jnp.einsum(
+        "bighp,bign->bghpn", (xdt * to_end[..., None]).astype(dtype), Bm, preferred_element_type=f32)
+    return state, y.reshape(B, c, G * Hg * P).astype(dtype)
+
+
+def ssm_chunked(x: Array, Bm: Array, Cm: Array, dt: Array, a: Array, state: Optional[Array] = None,
+                chunk: int = 128, dtype: Any = jnp.float32) -> Tuple[Array, Array]:
+    """The selective state space (Mamba-2: a scalar decay a head) over T
+    positions in chunks:
+
+        h_t = exp(dt_t a) h_{t-1} + dt_t x_t (x) B_t;   y_t = h_t C_t
+
+    x [B, T, H, P], Bm, Cm [B, T, G, N] (a group serves H / G heads), dt
+    [B, T, H] float32 (0 where a position changes nothing), a [H] (negative),
+    `state` [B, H, P, N] float32 (None: zeros) -> (y [B, T, H, P] in `dtype`,
+    the state after position T). One state is handed from chunk to chunk by a
+    scan over ceil(T / chunk) steps; inside a chunk `_ssm_chunk`. T is
+    padded to whole chunks with positions that change nothing (dt 0). The
+    scan's body is checkpointed: a backward pass keeps one state a chunk
+    and recomputes the chunk's inside. The skip `D x` is the caller's."""
+    B, T, H, P = x.shape
+    G, N = Bm.shape[2:]
+    if state is None:
+        state = jnp.zeros((B, H, P, N), jnp.float32)
+    pad = (-T) % chunk
+
+    def chunks(v):  # [B, T, ...] -> [T / chunk, B, chunk, all the rest flat]
+        v = jnp.pad(v.reshape(B, T, -1), ((0, 0), (0, pad), (0, 0)))
+        return jnp.moveaxis(v.reshape(B, (T + pad) // chunk, chunk, -1), 1, 0)
+
+    body = jax.checkpoint(functools.partial(_ssm_chunk, a=a.reshape(G, H // G), dtype=dtype))
+    state, y = jax.lax.scan(
+        body, state.reshape(B, G, H // G, P, N), (chunks(x), chunks(Bm), chunks(Cm), chunks(dt)))
+    # (the barrier: what comes out is this array in `dtype`, not a float32 copy
+    # of it that the consumer's cast was fused into, twice its size)
+    y = jax.lax.optimization_barrier(jnp.moveaxis(y, 0, 1).reshape(B, T + pad, H * P))
+    return y.reshape(B, T + pad, H, P)[:, :T], state.reshape(B, H, P, N)
+
+
+def ssm_step(x: Array, Bm: Array, Cm: Array, dt: Array, a: Array, state: Array) -> Tuple[Array, Array]:
+    """One position of the same recurrence on a carried state: x [B, H, P],
+    Bm, Cm [B, G, N], dt [B, H], a [H], `state` [B, H, P, N] float32 ->
+    (y [B, H, P], the new state). Elementwise and exact in float32."""
+    rep = x.shape[1] // Bm.shape[1]
+    b, c = (jnp.repeat(v.astype(jnp.float32), rep, axis=1) for v in (Bm, Cm))  # [B, H, N]
+    decay, xdt = jnp.exp(dt * a), dt[..., None] * x  # [B, H], [B, H, P]
+    # the read-out from the state as it came, h' C = decay (h C) + dt x (B . C): the
+    # update and the read-out then pass over the same array, once between them
+    y = decay[..., None] * jnp.sum(state * c[:, :, None, :], axis=-1) + xdt * jnp.sum(b * c, axis=-1)[..., None]
+    return y, state * decay[..., None, None] + xdt[..., None] * b[:, :, None, :]
+
+
+class Mamba2Mixer(nn.Module):
+    """Mamba-2 selective state space: H heads of P with a float32 state
+    [P, N] a head in place of a cache, B and C shared by the heads of a group.
+
+        [z | xBC | dt] = x W_in                                  (H P | H P + 2 G N | H)
+        xBC = SiLU(Conv(xBC) + b_conv)     causal depthwise, zeros before the start
+        [x' | B | C] = xBC                 x' H heads of P; B, C G groups of N
+        dt = softplus(dt + dt_bias);  a = -exp(A_log)            one a head, float32
+        h_t = exp(dt_t a) h_{t-1} + dt_t x'_t (x) B_t;   y_t = h_t C_t + D x'_t
+        y = w * RMSNorm_group(y * SiLU(z))                       over a group's H P / G channels
+        out = y W_out
+
+    A position whose mask is 0 changes nothing: its convolution input is
+    zero, what the convolution gives there is zeroed and its dt is 0, so a
+    left-padded row reaches its first token with h = 0 and an empty window.
+
+    Two forms of one recurrence, chosen by the shape of the call and by
+    nothing else:
+    - teacher-forced and prefill (T > 1), scope `ssm_chunk`: the chunked
+      form (`ssm_chunked`). A prefill starts from the state and the window
+      the cache holds (zeros) and leaves the state after its last position
+      and its last `taps - 1` convolution inputs there.
+    - a decode step (T == 1 with a cache), scope `ssm_step`: one step on
+      the carried state (`ssm_step`).
+    The cache of the stack of such layers (`TransformerLM.init_cache`):
+    `s` [layers, B, H, P, N] float32 and `u` [layers, B, taps - 1, H P + 2 G N];
+    a layer reads and writes its own row `ix`.
+    """
+
+    cfg: TransformerConfig
+    mesh: Any = None
+
+    @nn.compact
+    def __call__(self, x, attn_bias, positions, cache=None, key_mask=None, ring_mesh=None):
+        cfg = self.cfg
+        B, T, E = x.shape
+        H, P, N, G, taps = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, cfg.ssm_groups, cfg.ssm_conv
+        W, C = H * P, cfg.ssm_conv_width
+        if key_mask is None:
+            live = jnp.ones((B, T), jnp.float32)
+        elif cache is None:
+            live = key_mask.astype(jnp.float32)
+        else:  # the mask of the slots this call writes
+            live = jax.lax.dynamic_slice_in_dim(key_mask, cache["index"], T, axis=1).astype(jnp.float32)
+
+        zxbcdt = QDense(features=W + C + H, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                        kernel_init=nn.initializers.normal(0.02), use_bias=False, name="in_proj")(x)
+        z, u, dt = zxbcdt[..., :W], zxbcdt[..., W : W + C], zxbcdt[..., W + C :]
+        with jax.named_scope("ssm_conv"):
+            u = u * live[..., None].astype(u.dtype)
+            w = self.param("conv_w", _conv_tap_init, (taps, C), jnp.float32)
+            # (the bias starts as a tap does: U(-1 / sqrt(taps), 1 / sqrt(taps)))
+            b = self.param("conv_b", lambda key, shape: _conv_tap_init(key, (taps,) + shape)[0], (C,))
+            if cache is None:
+                before = jnp.zeros((B, taps - 1, C), u.dtype)
+            else:
+                before = jax.lax.dynamic_index_in_dim(cache["u"], cache["ix"], 0, keepdims=False).astype(u.dtype)
+            window = jnp.concatenate([before, u], axis=1)  # [B, taps - 1 + T, C]
+            y = sum(w[i] * window[:, i : i + T].astype(jnp.float32) for i in range(taps)) + b
+            # (rounded here: the recurrence's products take operands in the
+            # compute dtype either way, and at 32 x 1024 positions the 10,240
+            # channels are 1.25 GiB in float32)
+            y = (jax.nn.silu(y) * live[..., None]).astype(cfg.dtype)
+            x_flat = y[..., :W]
+            xs = x_flat.reshape(B, T, H, P)
+            Bm, Cm = (y[..., W + i * G * N : W + (i + 1) * G * N].reshape(B, T, G, N) for i in (0, 1))
+        with jax.named_scope("ssm_gate"):
+            a = -jnp.exp(self.param("A_log", _a_log_init, (H,), jnp.float32))
+            dt_bias = self.param(
+                "dt_bias", functools.partial(_dt_bias_init, lo=cfg.ssm_dt_min, hi=cfg.ssm_dt_max,
+                                             floor=cfg.ssm_dt_floor), (H,), jnp.float32)
+            dt = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias) * live[..., None]  # [B, T, H]
+            skip = self.param("D", nn.initializers.ones, (H,), jnp.float32)
+
+        # with a cache, this layer's state comes out of the carried array and
+        # goes back into it under the scope of the form that runs. No barrier
+        # here: `ssm_step` reads the state as it came in both its uses, so the
+        # compiler updates the carried array in place and reads the row straight
+        # out of it, three passes over a row where the slice taken out behind
+        # a barrier made five (PERF.md section 6, PR 35)
+        with jax.named_scope("ssm_step" if cache is not None and T == 1 else "ssm_chunk"):
+            state = None
+            if cache is not None:
+                state = jax.lax.dynamic_index_in_dim(cache["s"], cache["ix"], 0, keepdims=False)
+            if cache is not None and T == 1:
+                o, state = ssm_step(xs[:, 0], Bm[:, 0], Cm[:, 0], dt[:, 0], a, state)
+                o = o[:, None]
+            else:
+                o, state = ssm_chunked(xs, Bm, Cm, dt, a, state, cfg.ssm_chunk, cfg.dtype)
+            new_kv = None
+            if cache is not None:
+                new_kv = {
+                    "s": jax.lax.dynamic_update_slice(cache["s"], state[None], (cache["ix"], 0, 0, 0, 0)),
+                    "u": jax.lax.dynamic_update_slice(
+                        cache["u"], window[None, :, T:].astype(cache["u"].dtype), (cache["ix"], 0, 0, 0)),
+                }
+
+        with jax.named_scope("ssm_gate"):
+            scale = self.param("norm", nn.initializers.ones, (W,), cfg.param_dtype)
+            # y = h C + D x', the gate and the grouped norm, all on [B, T, W] as it
+            # lies in memory: a head's 64 channels are no lane tile of their own and
+            # a group's [.., G, W / G] another tiling of the same numbers, so each
+            # reshape of the float32 sequence was a copy of 1 GiB at 32 x 1024
+            # positions (compiled for a described v5e). A group's mean square is a
+            # product with the groups' indicator [W, G], and comes back through it
+            gated = (o.reshape(B, T, W) + jnp.repeat(skip, P) * x_flat) * jax.nn.silu(z.astype(jnp.float32))
+            member = (jnp.arange(W)[:, None] // (W // G) == jnp.arange(G)).astype(jnp.float32)
+            exact = jax.lax.Precision.HIGHEST
+            square = jnp.einsum("btw,wg->btg", gated * gated, member, precision=exact) / (W // G)
+            inverse = jnp.einsum("btg,wg->btw", jax.lax.rsqrt(square + cfg.layer_norm_epsilon), member,
+                                 precision=exact)
+            out = (gated * inverse * scale).astype(cfg.dtype)
+        proj = QDense(
+            features=E, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+            kernel_init=nn.initializers.normal(0.02 / math.sqrt(2 * cfg.n_layer)),
+            use_bias=False, name="out_proj",
+        )
+        return proj(out), new_kv
+
+
 @jax.custom_vjp
 def _rows_by_assignment(x, order, inverse):
     """x [N, E] -> [N * k, E]: row r is the token of assignment `order[r]`
@@ -1540,6 +1870,46 @@ def _permute_bwd(inverse, g):
 _permute_rows.defvjp(_permute_fwd, _permute_bwd)
 
 
+@jax.custom_vjp
+def _rows_of_tokens(x, token, rows_of, held_of):
+    """x [N, E] -> [M, E]: row r is token `token[r]`. Its transpose as the
+    gather it is: `rows_of` [N, Kh] lists for each token the rows that may
+    be its own, `held_of` which of them are (a token has at most Kh)."""
+    return jnp.take(x, token, axis=0)
+
+
+def _rows_of_tokens_fwd(x, token, rows_of, held_of):
+    return jnp.take(x, token, axis=0), (rows_of, held_of)
+
+
+def _rows_of_tokens_bwd(res, g):
+    rows_of, held_of = res
+    own = jnp.where(held_of[..., None], jnp.take(g, rows_of, axis=0), 0)  # [N, Kh, E]
+    return own.sum(axis=1).astype(g.dtype), None, None, None
+
+
+_rows_of_tokens.defvjp(_rows_of_tokens_fwd, _rows_of_tokens_bwd)
+
+
+@jax.custom_vjp
+def _rows_to_slots(out, rows_of, slot_of):
+    """out [M, E] -> [N, Kh, E]: slot (n, p) reads row `rows_of[n, p]`. Its
+    transpose as the gather it is: row r is read by slot `slot_of[r]` (and by
+    slots that weigh it with zero, whose gradient is zero)."""
+    return jnp.take(out, rows_of, axis=0)
+
+
+def _rows_to_slots_fwd(out, rows_of, slot_of):
+    return jnp.take(out, rows_of, axis=0), slot_of
+
+
+def _rows_to_slots_bwd(slot_of, g):
+    return jnp.take(g.reshape((-1, g.shape[-1])), slot_of, axis=0), None, None
+
+
+_rows_to_slots.defvjp(_rows_to_slots_fwd, _rows_to_slots_bwd)
+
+
 class _ExpertKernel(nn.Module):
     """One stacked expert weight [held, in, out] under `<name>/kernel`, with
     the per-expert, per-output-channel scale `quantize_decode_weights` puts
@@ -1578,6 +1948,11 @@ class RoutedMLP(nn.Module):
     were read on the chip at 32 rows a step (PERF.md section 6, PR 28): the
     sorted form there makes the sampler 24% slower (a sort, three gathers
     and three grouped products of 16 rows a layer a step, launches all).
+    With `moe_latent_size` (scope `moe_latent`) the experts work in a latent
+    space: u = x W_down once a token, the experts are that wide at both
+    ends, and r W_up brings their weighted sum back (linear: the shares'
+    parts add up); the shared expert reads x itself, at its own width.
+    `moe_gated` False: an expert is W2 act(W1 u), no gate matrix.
     Returns (y, stats): `load`, the rows each held expert computed,
     `assignments`, the token-expert pairs the router made (`moe_counters`),
     and `choices`, how often each of the router's experts was chosen, held
@@ -1595,6 +1970,7 @@ class RoutedMLP(nn.Module):
         act = _activation(cfg.activation)
         xf = x.reshape(B * T, E)
         N = B * T
+        Ein = cfg.moe_latent_size or E  # what an expert reads and writes
 
         with jax.named_scope("moe_router"):
             # float32 here and in every program, so that the sampler and
@@ -1613,23 +1989,34 @@ class RoutedMLP(nn.Module):
             choices = jnp.sum(chosen[..., None] == jnp.arange(cfg.n_routed_experts), axis=(0, 1))
 
         std_out = 0.02 / math.sqrt(2 * cfg.n_layer)
-        w_in, s_in = _ExpertKernel((held, E, F), 0.02, cfg.param_dtype, name="experts_fc_in")()
-        w_gate, s_gate = _ExpertKernel((held, E, F), 0.02, cfg.param_dtype, name="experts_fc_gate")()
-        w_out, s_out = _ExpertKernel((held, F, E), std_out, cfg.param_dtype, name="experts_fc_out")()
+        w_in, s_in = _ExpertKernel((held, Ein, F), 0.02, cfg.param_dtype, name="experts_fc_in")()
+        w_gate = s_gate = None
+        if cfg.moe_gated:
+            w_gate, s_gate = _ExpertKernel((held, Ein, F), 0.02, cfg.param_dtype, name="experts_fc_gate")()
+        w_out, s_out = _ExpertKernel((held, F, Ein), std_out, cfg.param_dtype, name="experts_fc_out")()
+
+        latent = partial(QDense, dtype=cfg.dtype, param_dtype=cfg.param_dtype, use_bias=False)
+        if cfg.moe_latent_size:
+            with jax.named_scope("moe_latent"):
+                xf = latent(features=Ein, kernel_init=nn.initializers.normal(0.02), name="latent_in")(xf)
 
         with jax.named_scope("moe_experts"):
             load = jnp.sum(
                 (local[..., None] == jnp.arange(held)) & here[..., None], axis=(0, 1)
             ).astype(jnp.int32)  # rows of each held expert
+
+            def hidden(product, a):  # an expert up to its second matrix
+                h = act(product(a, w_in, s_in))
+                return h * product(a, w_gate, s_gate) if cfg.moe_gated else h
+
             if decode:
-                xb = jnp.broadcast_to(xf.astype(cfg.dtype)[None], (held, N, E))
+                xb = jnp.broadcast_to(xf.astype(cfg.dtype)[None], (held, N, Ein))
 
                 def product(a, w, scale):
                     y = jnp.einsum("enk,ekf->enf", a, w.astype(cfg.dtype))
                     return y if scale is None else y * scale[:, None, :].astype(cfg.dtype)
 
-                h = act(product(xb, w_in, s_in)) * product(xb, w_gate, s_gate)
-                out = product(h, w_out, s_out)  # [held, N, E]
+                out = product(hidden(product, xb), w_out, s_out)  # [held, N, Ein]
                 per_expert = jnp.sum(
                     jnp.where(local[..., None] == jnp.arange(held), weight[..., None], 0.0), axis=1
                 )  # [N, held]
@@ -1638,8 +2025,28 @@ class RoutedMLP(nn.Module):
                 key = jnp.where(here, local, held).reshape(N * K)  # held = elsewhere, sorts last
                 order = jnp.argsort(key, stable=True).astype(jnp.int32)  # row -> assignment
                 inverse = jnp.argsort(order).astype(jnp.int32)  # assignment -> row
-                valid = (jnp.arange(N * K) < jnp.sum(load))[:, None]
-                rows = jnp.where(valid, _rows_by_assignment(xf.astype(cfg.dtype), order, inverse), 0)
+                # a token meets at most `held` of the experts held here: where it
+                # is sent to more than that (22 of 512, 8 held), the first
+                # N * held rows hold every assignment computed here, and the rest
+                # of the N * K are never built
+                Kh = min(K, held)
+                M = N * Kh
+                valid = (jnp.arange(M) < jnp.sum(load))[:, None]
+                if Kh == K:
+                    rows = jnp.where(valid, _rows_by_assignment(xf.astype(cfg.dtype), order, inverse), 0)
+                else:
+                    # of a token's K rows the Kh first (its held ones among them), slot by slot
+                    by_token = inverse.reshape(N, K)
+                    nth = jnp.argsort(by_token, axis=1)[:, :Kh].astype(jnp.int32)  # [N, Kh] choices
+                    rows_of = jnp.take_along_axis(by_token, nth, axis=1)  # their rows
+                    held_of = rows_of < jnp.sum(load)
+                    rows_of = jnp.minimum(rows_of, M - 1)
+                    order = order[:M]
+                    token = order // K
+                    # row r is slot (token, p): p, how many of the token's rows come before it
+                    before = jnp.sum(jnp.take(rows_of, token, axis=0) < jnp.arange(M)[:, None], axis=1)
+                    slot_of = token * Kh + jnp.minimum(before, Kh - 1).astype(jnp.int32)
+                    rows = jnp.where(valid, _rows_of_tokens(xf.astype(cfg.dtype), token, rows_of, held_of), 0)
                 row_expert = jnp.take(key, order)
 
                 def product(a, w, scale):
@@ -1648,16 +2055,23 @@ class RoutedMLP(nn.Module):
                         y = y * jnp.take(scale, jnp.minimum(row_expert, held - 1), axis=0).astype(cfg.dtype)
                     return y
 
-                h = act(product(rows, w_in, s_in)) * product(rows, w_gate, s_gate)
-                out = jnp.where(valid, product(h, w_out, s_out), 0)  # [N * K, E]
-                back = _permute_rows(out, inverse, order).reshape(N, K, E)
+                out = jnp.where(valid, product(hidden(product, rows), w_out, s_out), 0)  # [M, Ein]
+                if Kh == K:
+                    back = _permute_rows(out, inverse, order).reshape(N, K, Ein)
+                else:  # (a slot that is not the token's own weighs what it reads with zero)
+                    back = _rows_to_slots(out, rows_of, slot_of)
+                    weight = jnp.take_along_axis(weight, nth, axis=1)
                 routed = jnp.einsum("nk,nkd->nd", weight.astype(cfg.dtype), back)
+
+        if cfg.moe_latent_size:
+            with jax.named_scope("moe_latent"):
+                routed = latent(features=E, kernel_init=nn.initializers.normal(std_out), name="latent_out")(routed)
 
         with jax.named_scope("moe_shared"):
             y = routed.reshape(B, T, E)
             if cfg.n_shared_experts:
-                y = y + MLP(cfg, width=F * cfg.n_shared_experts, gated=True, bias=False,
-                            name="shared")(x)
+                width = cfg.moe_shared_intermediate_size or F * cfg.n_shared_experts
+                y = y + MLP(cfg, width=width, gated=cfg.moe_gated, bias=False, name="shared")(x)
         return y, {"load": load.astype(jnp.float32), "assignments": jnp.float32(N * K),
                    "choices": choices.astype(jnp.float32)}
 
@@ -1714,16 +2128,19 @@ class StreamMix(nn.Module):
 
 class Block(nn.Module):
     """Pre-norm decoder block; sequential (gpt2/llama) or parallel
-    (gptj/neox) residual layout. `kind` says what follows attention: the
-    dense MLP or routed experts (`RoutedMLP`); with several residual
-    streams each sub-layer reads and writes the state through its own
-    mixing matrices (`StreamMix`). Returns (x, new_kv, stats), stats the
-    routed layer's counters or None."""
+    (gptj/neox) residual layout. `kind` says what follows the mixer: the
+    dense MLP, routed experts (`RoutedMLP`) or nothing; `mixer` "none" is
+    a layer that is its feed-forward alone (ONE sub-layer a layer: `ln_1`
+    belongs to the mixer, `ln_2` to the feed-forward, and a layer has the
+    norm of what it has); with several residual streams each sub-layer
+    reads and writes the state through its own mixing matrices
+    (`StreamMix`). Returns (x, new_kv, stats), stats the routed layer's
+    counters or None."""
 
     cfg: TransformerConfig
     mesh: Any = None  # forwarded to Attention
-    kind: str = "dense"  # "dense" | "routed"
-    mixer: str = ""  # "softmax" | "latent" | "delta"; "": the attention cfg describes
+    kind: str = "dense"  # "dense" | "routed" | "none"
+    mixer: str = ""  # "softmax" | "latent" | "delta" | "ssm" | "none"; "": the attention cfg describes
 
     @nn.compact
     def __call__(
@@ -1737,8 +2154,11 @@ class Block(nn.Module):
     ) -> Tuple[Array, Optional[Dict[str, Array]], Optional[Dict[str, Array]]]:
         cfg = self.cfg
         mixer = self.mixer or ("latent" if cfg.latent else "softmax")
-        attention = {"softmax": Attention, "latent": LatentAttention,
-                     "delta": DeltaAttention}[mixer](cfg, self.mesh, name="attn")
+        if mixer == "ssm":
+            attention = Mamba2Mixer(cfg, self.mesh, name="ssm")
+        elif mixer != "none":
+            attention = {"softmax": Attention, "latent": LatentAttention,
+                         "delta": DeltaAttention}[mixer](cfg, self.mesh, name="attn")
 
         # a decode step on a sharded mesh: the residual stays rows by
         # chip; what the sub-layers read is split over E once, and what
@@ -1774,8 +2194,13 @@ class Block(nn.Module):
             x, (stats,) = sub_layer("hc_mlp", x, lambda u: feed_forward(Norm(cfg, name="ln_2")(u)))
             return x, new_kv, stats
 
+        if mixer == "none":  # the feed-forward alone
+            mlp_out, stats = feed_forward(Norm(cfg, name="ln_2")(x))
+            return x + mlp_out, None, stats
         h = into(Norm(cfg, name="ln_1")(x))
         attn_out, new_kv = attention(h, attn_bias, positions, cache, key_mask, ring_mesh)
+        if self.kind == "none":  # the mixer alone
+            return x + back(attn_out), new_kv, None
         if lay and cfg.parallel_residual:
             mlp_out, stats = feed_forward(h)
             x = back(x) + back(attn_out + mlp_out)
@@ -1953,10 +2378,10 @@ def _balance_step(lm: "TransformerLM", tree: Dict, bias: Dict[str, Array], input
     choices = out["moe_stats"]["choices"]  # [routed layers, published experts], in layer order
     mean = jnp.mean(choices, axis=-1, keepdims=True)
     step = rate * jnp.sign(mean - choices)
-    lead = lm.cfg.first_k_dense
+    routed = [layer for layer, ffn in enumerate(lm.cfg.ffns) if ffn == "routed"]
     new = {}
     for name, old in bias.items():
-        rows = [layer - lead for layer in stack_layers(lm.cfg, name)]  # this stack's layers among the routed ones
+        rows = [routed.index(layer) for layer in stack_layers(lm.cfg, name)]  # this stack's layers among the routed ones
         # (one routed stack holds every routed layer, in order: no gather)
         new[name] = old + (step if len(bias) == 1 else step[jnp.array(rows)])
     return new, jnp.max(choices, axis=-1) / mean[:, 0]
@@ -2020,15 +2445,15 @@ class TransformerLM:
         layers equal in mixer AND feed-forward. `first_k_dense` leading
         dense layers (`params["dense_blocks"]`), then the rest, routed
         where the model has experts, dense otherwise: the delta-rule
-        layers under `params["delta_blocks"]`, the others under
+        layers under `params["delta_blocks"]`, layers of one sub-layer
+        under the stack of their kind, the others under
         `params["blocks"]`. One module a stack (`blocks[name]`), one scan
         a segment."""
         cfg = self.cfg
         self.blocks: Dict[str, Block] = {}
-        for (name, _), mixer in zip(layer_stacks(cfg), cfg.mixers):
+        for (name, _), mixer, ffn in zip(layer_stacks(cfg), cfg.mixers, cfg.ffns):
             if name not in self.blocks:
-                routed = cfg.routed and name != "dense_blocks"
-                self.blocks[name] = Block(cfg, self._mesh, kind="routed" if routed else "dense", mixer=mixer)
+                self.blocks[name] = Block(cfg, self._mesh, kind=ffn, mixer=mixer)
         self.block = self.blocks.get("blocks")  # the one stack of a dense decoder (pipelining)
 
     @property
@@ -2099,7 +2524,8 @@ class TransformerLM:
         ):
             raise NotImplementedError(
                 "pipeline parallelism (pp > 1) is not implemented for a model with latent "
-                "attention, delta-rule (KDA) layers, routed experts or several residual streams"
+                "attention, delta-rule (KDA) or state-space (Mamba-2) layers, routed experts or "
+                "several residual streams"
             )
         if cache is not None:
             return 0
@@ -2294,16 +2720,36 @@ class TransformerLM:
         if cache is not None and "pk" in cache and self.cfg.beyond_dense:
             raise NotImplementedError(
                 "the paged decode engine (models/gen_engine.py) has no latent page pool, "
-                "keeps no recurrent state for delta-rule (KDA) layers and runs no routed "
-                "or multi-stream layer"
+                "keeps no recurrent state for delta-rule (KDA) or state-space (Mamba-2) "
+                "layers and runs no routed or multi-stream layer"
             )
         lead = "_lead" if stack == "dense_blocks" else ""
-        if cache is not None and blk.mixer == "delta":
+        if cache is not None and blk.mixer == "none" and "pk" not in cache:
+            # layers that are a feed-forward alone keep nothing: the cache
+            # passes by, and says only that a single token is a decode step
+            xs, params_of = layers_of({})
+
+            def bare_body(hidden, layer):
+                out, _, stats = blk.apply(
+                    {"params": params_of(layer)}, hidden, attn_bias, positions,
+                    {"index": cache["index"]}, key_mask, ring_mesh,
+                )
+                return out, stats
+
+            h, stats = jax.lax.scan(wrap_remat(bare_body, remat), h, xs)
+            new_cache = {k: v for k, v in cache.items() if k != "static_index"}
+            new_cache["index"] = cache["index"] + positions.shape[1]
+            return h, new_cache, _fold_layer_stats(stats)
+
+        if cache is not None and blk.mixer in ("delta", "ssm"):
             # recurrent state and convolution inputs, this stack's rows
             # (`kda_s` [layers, B, H, d, d] float32, `kda_u` [layers, B,
-            # taps - 1, 3 H d]): carried whole through the scan, as the
-            # latent rows below are; a layer reads and writes its own row
-            s_key, u_key = "kda_s" + lead, "kda_u" + lead
+            # taps - 1, 3 H d]; of state-space layers `ssm_s` [layers, B,
+            # H, P, N] float32, `ssm_u` [layers, B, taps - 1, H P + 2 G N]):
+            # carried whole through the scan, as the latent rows below
+            # are; a layer reads and writes its own row
+            prefix = "kda" if blk.mixer == "delta" else "ssm"
+            s_key, u_key = f"{prefix}_s{lead}", f"{prefix}_u{lead}"
 
             xs, params_of = layers_of({"ix": layer_stacks(self.cfg)[layer_offset][1] + jnp.arange(n)})
 
@@ -2432,7 +2878,7 @@ class TransformerLM:
             else:
                 hidden = carry
                 layer_cache = None
-            lp = layer["p"]
+            lp = params_of(layer)
             bias = attn_bias
             if flags is not None:
                 bias = bias + layer["flag"] * local_bias
@@ -2448,11 +2894,15 @@ class TransformerLM:
 
         body = wrap_remat(body, remat)
 
-        xs: Dict[str, Any] = {"p": block_params}
+        xs: Dict[str, Any] = {}
         if cache is not None:
-            xs["ix"] = jnp.arange(n)
+            # beside a recurrent state the k/v rows are the attention
+            # layers' alone: a layer's row is its row in its own stack
+            row0 = layer_stacks(self.cfg)[layer_offset][1] if self.cfg.hybrid else 0
+            xs["ix"] = row0 + jnp.arange(n) if row0 else jnp.arange(n)
         if flags is not None:
             xs["flag"] = flags
+        xs, params_of = layers_of(xs)
         if quant:
             xs["vs"] = cache["v_scale"]
             (h, ck, cv, cks), stats = jax.lax.scan(
@@ -2467,9 +2917,10 @@ class TransformerLM:
             )
         elif cache is not None:
             (h, ck, cv), stats = jax.lax.scan(body, (h, cache["k"], cache["v"]), xs)
+            # (what else the cache holds, a recurrent state beside it, passes by)
             new_cache = dict(
+                {k: v for k, v in cache.items() if k != "static_index"},
                 k=ck, v=cv, index=cache["index"] + positions.shape[1],
-                key_mask=cache["key_mask"],
             )
         else:
             h, stats = jax.lax.scan(body, h, xs)
@@ -2638,7 +3089,8 @@ class TransformerLM:
         if self.cfg.beyond_dense and (prefix_embeds is not None or kv_prefix is not None):
             raise NotImplementedError(
                 "prompt and prefix adapters are not implemented for a model with latent "
-                "attention, delta-rule (KDA) layers, routed experts or several residual streams"
+                "attention, delta-rule (KDA) or state-space (Mamba-2) layers, routed experts or "
+                "several residual streams"
             )
         h = self._embed_h(params, input_ids, positions)
         if prefix_embeds is not None:
@@ -2972,10 +3424,12 @@ class TransformerLM:
         mask = key_mask if key_mask is not None else jnp.ones((batch, max_len), jnp.int32)
         if cfg.hybrid:
             # two kinds of state in one carry, each stack's in its own
-            # arrays: latent rows that grow with the sequence (`c`,
-            # `c_lead`, as below) and, for delta-rule layers, a float32
-            # state and the convolutions' last inputs, which do not
-            # (`kda_s`, `kda_u`; `_lead` for the leading dense layers)
+            # arrays: rows that grow with the sequence (latent `c`,
+            # `c_lead`, as below, or per-head `k` and `v` of the attention
+            # layers alone) and, for delta-rule and state-space layers, a
+            # float32 state and the convolutions' last inputs, which do
+            # not (`kda_s`, `kda_u`, `_lead` for the leading dense layers;
+            # `ssm_s`, `ssm_u`). A layer without a mixer keeps nothing
             cache = {"index": jnp.int32(0), "static_index": 0, "key_mask": mask}
             H, D, width = cfg.delta_heads, cfg.delta_head_dim, 3 * cfg.delta_heads * cfg.delta_head_dim
             for name, block in self.blocks.items():
@@ -2984,7 +3438,14 @@ class TransformerLM:
                 if block.mixer == "delta":
                     cache["kda_s" + lead] = jnp.zeros((layers, batch, H, D, D), jnp.float32)
                     cache["kda_u" + lead] = jnp.zeros((layers, batch, cfg.delta_conv - 1, width), cfg.dtype)
-                else:
+                elif block.mixer == "ssm":
+                    cache["ssm_s"] = jnp.zeros(
+                        (layers, batch, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state), jnp.float32)
+                    cache["ssm_u"] = jnp.zeros((layers, batch, cfg.ssm_conv - 1, cfg.ssm_conv_width), cfg.dtype)
+                elif block.mixer == "softmax":
+                    for key in ("k", "v"):
+                        cache[key] = jnp.zeros((layers, batch, max_len, cfg.n_kv_head, cfg.head_dim), cfg.dtype)
+                elif block.mixer == "latent":
                     cache["c" + lead] = jnp.zeros(
                         (layers, batch, max_len, cfg.cache_elems_per_position), cfg.dtype)
             return cache
@@ -3026,10 +3487,11 @@ def extract_branch_params(params: Dict, branch_at: int, cfg: Optional[Transforme
         raise NotImplementedError(
             f"a branch at layer {branch_at} would reach into the {lead} leading dense layers"
         )
-    if "delta_blocks" in params and cfg is None:
-        raise ValueError("a tree with delta-rule layers needs its config to place a branch point")
+    if cfg is None and any(name in params for name in STACKS[2:]):
+        raise ValueError("a tree with delta-rule layers or layers of one sub-layer needs its config "
+                         "to place a branch point")
     branch = {"ln_f": params["ln_f"], "embed": params["embed"]}
-    for name in ("blocks", "delta_blocks"):
+    for name in STACKS[1:]:
         if name in params:
             below = branch_at - lead if cfg is None else sum(i < branch_at for i in stack_layers(cfg, name))
             branch[name] = jax.tree_util.tree_map(lambda x: x[below:], params[name])

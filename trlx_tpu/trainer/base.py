@@ -556,7 +556,8 @@ class TPUBaseTrainer(BaseRLTrainer):
         if self.config.model.peft_config is not None and getattr(cfg, "beyond_dense", False):
             raise NotImplementedError(
                 "peft adapters are not implemented for a model with latent attention, "
-                "delta-rule (KDA) layers, routed experts or several residual streams"
+                "delta-rule (KDA) or state-space (Mamba-2) layers, routed experts or several "
+                "residual streams"
             )
 
         if isinstance(self.config.model.peft_config, str) and not (
@@ -948,8 +949,8 @@ class TPUBaseTrainer(BaseRLTrainer):
         if cfg.beyond_dense:
             raise NotImplementedError(
                 "ppo.gen_engine: the paged decode engine has no latent page pool, keeps no "
-                "recurrent state for delta-rule (KDA) layers and runs no routed or "
-                "multi-stream layer; use the static sampler for this model"
+                "recurrent state for delta-rule (KDA) or state-space (Mamba-2) layers and runs "
+                "no routed or multi-stream layer; use the static sampler for this model"
             )
         if mh.is_multihost() or mh.data_group_count(self.mesh) != 1:
             return False
@@ -1607,12 +1608,18 @@ class TPUBaseTrainer(BaseRLTrainer):
         if getattr(cfg, "beyond_dense", False):
             gauges["model/experts_held"] = cfg.n_experts_held or 0
             # of ONE layer that caches; a row's cache is that times the
-            # layers that cache (`model/latent_layers` where some do not)
+            # layers that cache (`model/latent_layers`, `model/cache_layers`
+            # where some do not)
             gauges["model/cache_elems_per_position"] = cfg.cache_elems_per_position
             gauges["model/residual_streams"] = cfg.residual_streams
-            if cfg.hybrid:
-                gauges["model/delta_layers"] = cfg.n_layer - cfg.cache_layers
+            if "delta" in cfg.mixers:
+                gauges["model/delta_layers"] = cfg.mixers.count("delta")
                 gauges["model/latent_layers"] = cfg.cache_layers
+            if "ssm" in cfg.mixers:  # a layer there is ONE sub-layer: the three kinds by count
+                gauges["model/ssm_layers"] = cfg.mixers.count("ssm")
+                gauges["model/cache_layers"] = cfg.cache_layers
+                gauges["model/routed_layers"] = cfg.ffns.count("routed")
+            if cfg.hybrid:
                 gauges["model/state_elems_per_row"] = cfg.state_elems_per_row
         self.obs.gauge(**gauges)
         self._tracker_log(gauges, step=self.iter_count)

@@ -700,11 +700,9 @@ def estimate_plan(trainer) -> HBMPlan:
                      + (" (latent)" if getattr(mc, "latent", False) else "")
                      + (f", the {caching} of {L} layers that cache" if caching < L else "")
                      + f", quant {kv_quant or 'none'}")
-            if caching < L:
-                plan.add("rollout", "recurrent_state",
-                         delta_state_bytes(mc, chunk, decode_size),
-                         f"{L - caching} delta-rule layers: float32 state and convolution "
-                         "inputs a row, whatever its length")
+            state_b = recurrent_state_bytes(mc, chunk, decode_size)
+            if state_b:
+                plan.add("rollout", "recurrent_state", state_b, _recurrent_note(mc))
     except Exception as exc:
         plan.add("rollout", "kv_cache", 0,
                  f"unestimated for this model family ({type(exc).__name__})")
@@ -1142,7 +1140,7 @@ def analytic_param_count(tcfg: Dict[str, Any]) -> int:
     mlp = E * I + I * E + I + E
     norms = 4 * E
     if not (tcfg.get("kv_lora_rank") or tcfg.get("n_routed_experts")
-            or int(tcfg.get("residual_streams", 1)) > 1 or _delta_layers(tcfg)):
+            or int(tcfg.get("residual_streams", 1)) > 1 or _mixers(tcfg)):
         return V * E + P * E + L * (attn + mlp + norms) + 2 * E
     return _family_param_count(tcfg)
 
@@ -1152,26 +1150,44 @@ def _field(tcfg, name: str, default=None):
     return tcfg.get(name, default) if isinstance(tcfg, dict) else getattr(tcfg, name, default)
 
 
-def _delta_layers(tcfg) -> int:
-    """Layers of a model config (or its dict) whose mixer is the delta rule."""
-    return list(_field(tcfg, "mixer_layers") or ()).count("delta")
+def _mixers(tcfg) -> list:
+    """The mixer of each layer of a model config (or its dict) that names them."""
+    return list(_field(tcfg, "mixer_layers") or ())
 
 
-def delta_state_bytes(tcfg, rows: int, conv_size: int = 2) -> int:
-    """What the delta-rule layers of a model config (or its dict) keep for
-    `rows` rows whatever their length: a float32 [heads, d, d] state and
-    the last `delta_conv - 1` inputs of the three convolutions (compute
-    dtype), in each layer. 0 for a model without such layers."""
+def _caching_layers(tcfg, L: int) -> int:
+    """Layers whose cache grows with the sequence: those with attention."""
+    mixers = _mixers(tcfg)
+    return L - sum(m in ("delta", "ssm", "none") for m in mixers)
+
+
+def recurrent_state_bytes(tcfg, rows: int, conv_size: int = 2) -> int:
+    """What the layers with a recurrent state of a model config (or its dict)
+    keep for `rows` rows whatever their length, a float32 state and the last
+    inputs of their convolutions (compute dtype): a delta-rule layer [heads,
+    d, d] and `delta_conv - 1` inputs of three convolutions, a state-space
+    layer [heads, head_dim, ssm_state] and `ssm_conv - 1` inputs of one (x',
+    B and C wide). 0 for a model without such layers."""
     H, D = int(_field(tcfg, "delta_heads", 0)), int(_field(tcfg, "delta_head_dim", 0))
     conv = (int(_field(tcfg, "delta_conv", 4)) - 1) * 3 * H * D
-    return _delta_layers(tcfg) * rows * (4 * H * D * D + conv_size * conv)
+    total = _mixers(tcfg).count("delta") * rows * (4 * H * D * D + conv_size * conv)
+    sh, sp, sn = (int(_field(tcfg, k, 0)) for k in ("ssm_heads", "ssm_head_dim", "ssm_state"))
+    tail = (int(_field(tcfg, "ssm_conv", 4)) - 1) * (sh * sp + 2 * int(_field(tcfg, "ssm_groups", 1)) * sn)
+    return total + _mixers(tcfg).count("ssm") * rows * (4 * sh * sp * sn + conv_size * tail)
+
+
+def _recurrent_note(tcfg) -> str:
+    kinds = [f"{_mixers(tcfg).count(m)} {name}" for m, name in (("delta", "delta-rule"), ("ssm", "state-space"))
+             if m in _mixers(tcfg)]
+    return " and ".join(kinds) + " layers: float32 state and convolution inputs a row, whatever its length"
 
 
 def _family_param_count(tcfg: Dict[str, Any]) -> int:
-    """Parameters HELD HERE of a model with latent attention, delta-rule
-    layers, routed experts (this chip's share: `n_experts_held` of the
-    `n_routed_experts` the router scores) and several residual streams, from
-    the config's numbers alone."""
+    """Parameters HELD HERE of a model with latent attention, delta-rule or
+    state-space layers, layers of one sub-layer, routed experts (this chip's
+    share: `n_experts_held` of the `n_routed_experts` the router scores; in a
+    latent space, gated or not) and several residual streams, from the
+    config's numbers alone."""
     V, E, L = int(tcfg["vocab_size"]), int(tcfg["hidden_size"]), int(tcfg["n_layer"])
     H, I = int(tcfg["n_head"]), int(tcfg.get("intermediate_size", 4 * E))
     gated = 3 if tcfg.get("mlp_gated") else 2
@@ -1182,29 +1198,42 @@ def _family_param_count(tcfg: Dict[str, Any]) -> int:
         q = E * qr + qr + qr * H * (dn + dr) if qr else E * H * (dn + dr)
         attn = q + E * (rank + dr) + rank + rank * H * (dn + dv) + H * dv * E
     else:
-        D = int(tcfg.get("head_dim", E // max(H, 1)))
-        attn = 4 * E * H * D
+        D = int(tcfg.get("head_dim") or E // max(H, 1))
+        attn = 2 * E * D * (H + int(tcfg.get("n_kv_head") or H))  # q and o; k and v
     n = int(tcfg.get("residual_streams", 1))
     mix = 2 * (n * E * (n * n + 2 * n) + 3 + 2 * n + n * n) if n > 1 else 0
     mixers = list(tcfg.get("mixer_layers") or [None] * L)
+    by_mixer = {None: attn, "softmax": attn, "latent": attn, "none": 0}
     if "delta" in mixers:
         # q, k, v, o; two low-rank pairs through d; beta; the taps; A_log, dt_bias, the output norm
         kh, kd = int(tcfg["delta_heads"]), int(tcfg["delta_head_dim"])
         w = kh * kd
-        delta = (4 * E * w + 2 * (E * kd + kd * w) + E * kh
-                 + 3 * w * int(tcfg.get("delta_conv", 4)) + kh + w + kd)
-        attn = [delta if m == "delta" else attn for m in mixers]
-    else:
-        attn = [attn] * L
+        by_mixer["delta"] = (4 * E * w + 2 * (E * kd + kd * w) + E * kh
+                             + 3 * w * int(tcfg.get("delta_conv", 4)) + kh + w + kd)
+    if "ssm" in mixers:
+        # both projections; the taps and their bias; A_log, D, dt_bias; the gated norm
+        sh, w = int(tcfg["ssm_heads"]), int(tcfg["ssm_heads"]) * int(tcfg["ssm_head_dim"])
+        conv = w + 2 * int(tcfg.get("ssm_groups", 1)) * int(tcfg["ssm_state"])
+        by_mixer["ssm"] = (E * (w + conv + sh) + w * E + conv * (int(tcfg.get("ssm_conv", 4)) + 1)
+                           + 3 * sh + w)
+    attn = [by_mixer[m] for m in mixers]
     published = int(tcfg.get("n_routed_experts", 0))
     lead = int(tcfg.get("first_k_dense", 0)) if published else L
     feed = [gated * E * I] * lead
     if published:
         F = int(tcfg["moe_intermediate_size"])
         held = int(tcfg.get("n_experts_held") or published)
+        matrices = 3 if tcfg.get("moe_gated", True) else 2
+        latent = int(tcfg.get("moe_latent_size") or 0)
+        shared = int(tcfg.get("moe_shared_intermediate_size") or F * int(tcfg.get("n_shared_experts", 0)))
         feed += [E * published + published  # router and its bias
-                 + 3 * E * F * (held + int(tcfg.get("n_shared_experts", 0)))] * (L - lead)
-    return 2 * V * E + sum(attn) + sum(feed) + L * (2 * E + mix) + E
+                 + matrices * (latent or E) * F * held + 2 * E * latent  # the held experts, the latent pair
+                 + (matrices * E * shared if tcfg.get("n_shared_experts") else 0)] * (L - lead)
+    # a layer of one sub-layer has the norm of what it has
+    ffns = list(tcfg.get("ffn_layers") or ["full"] * L)
+    feed = [0 if kind == "none" else size for kind, size in zip(ffns, feed)]
+    norms = sum((m != "none") + (f != "none") for m, f in zip(mixers, ffns)) * E
+    return 2 * V * E + sum(attn) + sum(feed) + norms + L * mix + E
 
 
 def analytic_plan(
@@ -1346,17 +1375,16 @@ def analytic_plan(
         kv_size = 1 if kv_quant == "int8" else 2
         latent = tdict.get("kv_lora_rank")
         per_position = (int(latent) + int(tdict.get("qk_rope_head_dim", 0))) if latent else 2 * Hkv * D
-        caching = L - _delta_layers(tdict)
+        caching = _caching_layers(tdict, L)
         plan.add("rollout", "static_kv_cache",
                  int(caching * chunk * S * per_position * kv_size),
                  f"whole-chunk cache, {per_position} numbers a position a layer"
                  + (" (latent)" if latent else "")
                  + (f", the {caching} of {L} layers that cache" if caching < L else "")
                  + f", quant {kv_quant or 'none'}")
-        if caching < L:
-            plan.add("rollout", "recurrent_state", delta_state_bytes(tdict, chunk),
-                     f"{L - caching} delta-rule layers: float32 state and convolution "
-                     "inputs a row, whatever its length")
+        state_b = recurrent_state_bytes(tdict, chunk)
+        if state_b:
+            plan.add("rollout", "recurrent_state", state_b, _recurrent_note(tdict))
 
     exp = dict(getattr(config.method, "exp", None) or {})
     if exp.get("enabled"):
