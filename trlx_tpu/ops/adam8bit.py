@@ -204,6 +204,16 @@ def fused_adamw_8bit_update(
     ~0.5 GB (measured). A whole-leaf zero mask skips the leaf entirely
     (no moment updates either — the reference's frozen params are
     excluded from optimizer param groups the same way).
+
+    The trainers no longer hand this function the rows a mask freezes:
+    `_step_update` cuts parameters, gradients and both `Q8` moments to the
+    trainable view first (`ops/trainable_view.py`: of a stacked `[L, ...]`
+    leaf whose mask is one run of rows, those rows and their range of
+    blocks; nothing of a leaf whose mask is all zeros, scalar or by row)
+    and writes the result back in place, so a frozen row costs no pass at
+    all and keeps the zero moments `init` gave it. What still arrives here
+    with a mask is a leaf the view could not cut: an elementwise mask,
+    rows that are not one run, a row that is not whole blocks.
     """
     count = state.count + 1
     c = count.astype(jnp.float32)

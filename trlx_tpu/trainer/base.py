@@ -50,6 +50,7 @@ from trlx_tpu.models.transformer import (
     TransformerLM,
     decode_weights_stationary,
 )
+from trlx_tpu.ops import trainable_view
 from trlx_tpu.parallel import (
     data_sharding,
     init_sharded_opt_state,
@@ -182,6 +183,9 @@ class TPUBaseTrainer(BaseRLTrainer):
             self._lm().mesh = self.mesh
 
         self._update_mask = self.trainable_mask()
+        # static, from the mask's values and the shapes alone: what of each
+        # leaf an optimizer step walks
+        self._view = trainable_view.trainable_view(self._update_mask, self.params)
         self.tx, self.schedule = self._assemble_optimizer(
             config.optimizer, config.scheduler
         )
@@ -1472,10 +1476,15 @@ class TPUBaseTrainer(BaseRLTrainer):
         tx = self.tx
         gd = self.config.train.grads_dtype
         grads_dtype = _DTYPES[gd] if gd else None
+        view = self._view
 
         def compute(p, b, captures=None):
             held = () if trunk is None else ((captures, trunk[1]),)
-            return jax.value_and_grad(self.loss, has_aux=True)(self._grads_view(p), b, *held)
+            out, grads = jax.value_and_grad(self.loss, has_aux=True)(self._grads_view(p), b, *held)
+            # a stacked leaf's gradient comes out zero-padded to `[L, ...]`:
+            # the static slice of the pad is the trained rows' gradient itself,
+            # and nothing below (accumulation, guard, norm, optimizer) sees more
+            return out, trainable_view.cut(grads, view)
 
         if num_mb == 1:
             (loss, stats), grads = compute(params, batch, trunk and trunk[0])
@@ -1536,10 +1545,17 @@ class TPUBaseTrainer(BaseRLTrainer):
                 jnp.asarray(True),
             )
         with jax.named_scope("optimizer_update"):
+            # the optimizer walks the view (`ops/trainable_view.py`): the
+            # rows the mask trains, of parameters and state alike, written
+            # back into the donated leaves in place
+            marks = trainable_view.state_view(tx, opt_state, view)
+            old_params = trainable_view.cut(params, view)
+            old_state = trainable_view.cut(opt_state, marks)
             if hasattr(tx, "fused_apply"):
-                # the freeze mask streams through the fused apply itself
-                # (O(chunk) extra memory); blending frozen values back after
-                # the apply would hold THREE fp32 param trees at peak —
+                # what is left of the freeze mask on the view (leaves that
+                # fell back to a whole walk) streams through the fused apply
+                # itself (O(chunk) extra memory); blending frozen values back
+                # after the apply would hold THREE fp32 param trees at peak —
                 # measured as the 0.5 GB that OOMed the 1.3B recipe. The
                 # NaN guard must respect the same budget, so here it zeroes
                 # the gradients BEFORE the apply instead of selecting whole
@@ -1552,11 +1568,12 @@ class TPUBaseTrainer(BaseRLTrainer):
                         lambda g: jnp.where(good, g, jnp.zeros_like(g)), grads
                     )
                 new_params, new_opt_state = tx.fused_apply(
-                    params, grads, opt_state, mask=self._update_mask
+                    old_params, grads, old_state,
+                    mask=trainable_view.view_mask(self._update_mask, view),
                 )
             else:
-                updates, new_opt_state = tx.update(grads, opt_state, params)
-                new_params = optax.apply_updates(params, updates)
+                updates, new_opt_state = tx.update(grads, old_state, old_params)
+                new_params = optax.apply_updates(old_params, updates)
                 if guard:
                     # NaN/inf guard must live INSIDE the trace: params and
                     # opt_state are donated, so by the time the host could
@@ -1564,11 +1581,13 @@ class TPUBaseTrainer(BaseRLTrainer):
                     # traced select commits the old state when the update is
                     # poisoned; the abort counter lives in the learn loop.
                     new_params = jax.tree_util.tree_map(
-                        lambda n, o: jnp.where(good, n, o), new_params, params
+                        lambda n, o: jnp.where(good, n, o), new_params, old_params
                     )
                     new_opt_state = jax.tree_util.tree_map(
-                        lambda n, o: jnp.where(good, n, o), new_opt_state, opt_state
+                        lambda n, o: jnp.where(good, n, o), new_opt_state, old_state
                     )
+            new_params = trainable_view.paste(params, new_params, view)
+            new_opt_state = trainable_view.paste(opt_state, new_opt_state, marks)
         if guard:
             # fold the skip signal into the returned loss: the host's
             # isfinite check then catches finite-loss/bad-grad skips too,
@@ -1589,10 +1608,14 @@ class TPUBaseTrainer(BaseRLTrainer):
         """Gauges, once per built train step, in the flight stream and
         the tracker: `model/layers`; `model/backward_layers`, the layers
         the step's backward pass runs through (the wrapper's
-        `frozen_below()`: hydra PPO stops it at the branch point); and
+        `frozen_below()`: hydra PPO stops it at the branch point);
         `model/trunk_layers_hoisted`, the layers whose forward the built
         program runs once a block and not once a step (`_block_trunk`; 0
-        wherever the step keeps the whole forward)."""
+        wherever the step keeps the whole forward); and
+        `optim/params_walked` beside `optim/params_trained`, the elements
+        the step streams through the optimizer and those the freeze mask
+        trains: the difference is the frozen part of the leaves the
+        trainable view could not cut."""
         cfg = self.model.cfg
         below = getattr(self.model, "frozen_below", lambda: 0)()
         if self.mesh.shape["pp"] > 1:
@@ -1603,8 +1626,10 @@ class TPUBaseTrainer(BaseRLTrainer):
         else:  # seq2seq: a frozen trunk takes the whole encoder with it
             layers = cfg.n_layer + decoder
             backward = decoder - below if below else layers
+        walked, trained = trainable_view.counts(self.params, self._update_mask, self._view)
         gauges = {"model/layers": layers, "model/backward_layers": backward,
-                  "model/trunk_layers_hoisted": hoisted}
+                  "model/trunk_layers_hoisted": hoisted,
+                  "optim/params_walked": walked, "optim/params_trained": trained}
         if getattr(cfg, "beyond_dense", False):
             gauges["model/experts_held"] = cfg.n_experts_held or 0
             # of ONE layer that caches; a row's cache is that times the
@@ -3032,7 +3057,10 @@ class TPUBaseTrainer(BaseRLTrainer):
             # fused_apply instead
             pass
         elif self._update_mask is not None:
-            tx = optax.chain(tx, _mask_updates(self._update_mask))
+            # the step runs on the trainable view, where the mask is all
+            # ones but for the leaves that fell back to a whole walk
+            tx = optax.chain(tx, _mask_updates(
+                trainable_view.view_mask(self._update_mask, self._view)))
         return tx, schedule
 
     def _rebuild_optimizer(self) -> None:
@@ -3933,7 +3961,8 @@ class TPUBaseTrainer(BaseRLTrainer):
 
 
 def _mask_updates(mask_tree) -> optax.GradientTransformation:
-    """Multiply updates elementwise by a broadcastable {0,1} mask."""
+    """Multiply updates elementwise by a broadcastable {0,1} mask (None:
+    a leaf with nothing to mask)."""
 
     def init_fn(params):
         del params
@@ -3942,7 +3971,7 @@ def _mask_updates(mask_tree) -> optax.GradientTransformation:
     def update_fn(updates, state, params=None):
         del params
         masked = jax.tree_util.tree_map(
-            lambda u, m: u * jnp.asarray(m, u.dtype), updates, mask_tree
+            lambda u, m: u if m is None else u * jnp.asarray(m, u.dtype), updates, mask_tree
         )
         return masked, state
 
