@@ -3,8 +3,10 @@
 
 Input: a checkpoint dir (reads ``<dir>/flight/``), a flight dir, or a
 single ``flight-*.jsonl`` file's directory. Output: per-run summary —
-a per-cycle table (wall, samples/s, phase breakdown, and the cycle's
-work-site spans as self time), the event overlay (guardrail
+the set-up (its spans as self time, what it compiled: built against
+read, by span and by program), a per-cycle table (wall, samples/s, phase
+breakdown, the cycle's work-site spans as self time and one line for
+every program it compiled), the event overlay (guardrail
 trips/actions, chaos injections, OOM-ladder rungs, watermark crossings,
 checkpoints/restores, supervisor records) keyed into the cycles they
 happened in, and slowest-phase attribution.
@@ -53,13 +55,68 @@ def _fmt_t(t) -> str:
         return "?"
 
 
-def _event_line(row: dict) -> str:
+def _event_lines(row: dict) -> list:
     kind = row.get("kind", "?")
+    if kind == "setup":
+        return _setup_lines(row)
     skip = {"t", "run", "kind", "cycle", "step", "pv"}
     detail = " ".join(
         f"{k}={row[k]}" for k in row if k not in skip
     )
-    return f"    {_fmt_t(row.get('t'))}  [{kind}] {detail}".rstrip()
+    return [f"    {_fmt_t(row.get('t'))}  [{kind}] {detail}".rstrip()]
+
+
+def _self_times(spans) -> str:
+    """Work-site spans as self time (a span's duration less what its
+    children cover), largest first."""
+    own = span_self_times(spans)
+    return ", ".join(
+        f"{k} {v:.3f}" for k, v in sorted(own.items(), key=lambda kv: -kv[1])
+    )
+
+
+def _setup_lines(row: dict) -> list:
+    """The `setup` row: why was this start slow, and did the cache work."""
+    c = row.get("compiles") or {}
+    head = f"    {_fmt_t(row.get('t'))}  [setup]"
+    if "since_import_s" in row:
+        head += f" import to observer {row['since_import_s']:.1f} s,"
+    if "init_s" in row:
+        head += f" trainer init {row['init_s']:.1f} s,"
+    lines = [
+        f"{head} {c.get('requests', 0)} compile requests: "
+        f"{c.get('built', 0)} built ({c.get('build_s', 0.0):.1f} s, "
+        f"{c.get('written', 0)} kept by the cache), "
+        f"{c.get('read', 0)} read ({c.get('read_s', 0.0):.1f} s); "
+        f"trace {c.get('trace_s', 0.0):.1f} s, lowering {c.get('lower_s', 0.0):.1f} s"
+    ]
+    if row.get("spans"):
+        lines.append("        spans (self s): " + _self_times(row["spans"]))
+    if row.get("by_span"):
+        lines.append("        compiled under: " + ", ".join(
+            f"{k} {v[0]:.1f} s ({v[1]} built)"
+            for k, v in sorted(row["by_span"].items(), key=lambda kv: -kv[1][0])
+        ))
+    if row.get("programs"):
+        lines.append("        largest programs: " + ", ".join(
+            f"{name} x{n} {seconds:.1f} s ({n_built} built)"
+            for name, n, seconds, n_built in row["programs"][:10]
+        ))
+    return lines
+
+
+def _compile_lines(c: dict) -> list:
+    """One line for every program a cycle compiled: which cycle
+    recompiled, what, and under which span."""
+    lines = [
+        f"        cycle {c.get('cycle', '?')}: {'built' if built else 'read'} "
+        f"{name} {t1 - t0:.2f} s" + (f" under {parent}" if parent else "")
+        for name, t0, t1, built, parent in c.get("compiles") or []
+    ]
+    if c.get("compiles_more"):
+        lines.append(f"        cycle {c.get('cycle', '?')}: and "
+                     f"{c['compiles_more']} shorter ones")
+    return lines
 
 
 def render(directory: str, last: int = 0, run: str = "") -> str:
@@ -114,7 +171,7 @@ def render(directory: str, last: int = 0, run: str = "") -> str:
         lines.append(header)
         for c, events in shown:
             for e in events:
-                lines.append(_event_line(e))
+                lines.extend(_event_lines(e))
             phases = c.get("phases") or {}
             slowest = max(phases.items(), key=lambda kv: kv[1])[0] if phases else "-"
             cells = " ".join(
@@ -126,19 +183,12 @@ def render(directory: str, last: int = 0, run: str = "") -> str:
                 f"{str(c.get('samples_per_sec', '-')):>7} {cells}  {slowest}"
             )
             if c.get("spans"):
-                # work-site spans of the cycle, as self time (a span's
-                # duration less what its children cover)
-                own = span_self_times(c["spans"])
-                lines.append(
-                    "        spans (self s): " + ", ".join(
-                        f"{k} {v:.3f}"
-                        for k, v in sorted(own.items(), key=lambda kv: -kv[1])
-                    )
-                )
+                lines.append("        spans (self s): " + _self_times(c["spans"]))
+            lines.extend(_compile_lines(c))
         if pending:  # events after the last cycle row (run_end, ...)
             lines.append("  events after the last cycle:")
             for e in pending:
-                lines.append(_event_line(e))
+                lines.extend(_event_lines(e))
         # attribution summary
         if totals:
             wall_total = sum(float(c.get("wall_s", 0.0)) for c in cycles)

@@ -163,7 +163,15 @@ TIER1_BUDGETS = {
     # scanned_epochs 50->46 (42.4), gen_engine 40->36 (32.6),
     # memdoctor 40->37 (32), elastic 35->34 (32.0), exp_queue 30->29
     # (28.2), models 18->17 (16.2), peft 15->14 (13.9).
-    "test_obs.py": 13,
+    # PR 37: the set-up spans and the compile records (thirteen more
+    # cases: fake-clock units, six that compile one tiny jitted function
+    # each, one more tiny learn() whose second cycle recompiles the
+    # sampler): 44.6 s alone on this container where the file took 37.6 s
+    # before (2026-10-04), so 13 -> 16 on the same scale. Paid under the
+    # unchanged 780 ceiling with files measured alone the same day on that
+    # scale (0.35 of the seconds alone): ops 5->4 (8.5 s = 3.0), watchdog
+    # 8->7 (18.5 s = 6.5).
+    "test_obs.py": 16,
     # r15: paged-attention kernel + sharded lanes + trunk-sharing suite
     # (op-level kernel parity grid, engine pallas==xla goldens incl.
     # the spec verify forward, trunk-shared pool accounting, grouped-
@@ -179,7 +187,7 @@ TIER1_BUDGETS = {
     # sweep 23s -> 15->14, trainers 11s -> 10->9, flash_attention 24s
     # -> 15->14, generation 23s -> 15->14.
     "test_paged_kernel.py": 26,
-    "test_ops.py": 5,
+    "test_ops.py": 4,
     "test_peft.py": 14,
     "test_pipeline_parallel.py": 7,
     "test_pipelines.py": 1,
@@ -226,7 +234,7 @@ TIER1_BUDGETS = {
     "test_trainable_view.py": 45,
     "test_trainers.py": 9,
     "test_utils.py": 5,
-    "test_watchdog.py": 8,
+    "test_watchdog.py": 7,
 }
 
 # ceiling: tier-1 runs under `timeout 870` (ROADMAP); budgets must fit

@@ -207,6 +207,267 @@ def test_observer_span_is_null_when_off_and_never_raises(tmp_path):
 
 
 # ---------------------------------------------------------------------------
+# set-up spans and compile events (ISSUE 37)
+# ---------------------------------------------------------------------------
+
+# the keys of a `cycle` row that compiled nothing, as the parent commit
+# wrote them (engine, counters and samples_per_sec come and go with the run)
+_STEADY_CYCLE_KEYS = {
+    "t", "run", "kind", "cycle", "step", "pv", "wall_s", "phases", "samples",
+    "real_tokens", "train_steps", "spans",
+}
+
+
+def _compile(tracer, name, seconds, hit=False, trace_s=0.0, lower_s=0.0):
+    """The events JAX fires for one compilation, as the listener hands
+    them to the tracer."""
+    from trlx_tpu.obs import spans as S
+
+    if trace_s:
+        tracer.on_compile_duration(S.TRACE_EVENT, trace_s, name)
+    if lower_s:
+        tracer.on_compile_duration(S.LOWER_EVENT, lower_s, f"jit({name})")
+    tracer.on_compile_event(S.REQUEST_EVENT)
+    if hit:
+        tracer.on_compile_event(S.HIT_EVENT)
+    tracer.on_compile_duration(S.BACKEND_EVENT, seconds, f"jit({name})")
+
+
+def _probe_program(tag):
+    """A jitted function nothing else in the process has compiled."""
+    import jax
+
+    def obs_probe(x):
+        return x * 2.0 + float(tag)
+
+    obs_probe.__name__ = f"obs_probe_{tag}"
+    return jax.jit(obs_probe), f"jit_obs_probe_{tag}"
+
+
+def test_spans_closed_before_the_first_cycle_reach_setup_and_not_cycle_one():
+    clock = _FakeClock(100.0)
+    t = SpanTracer(clock=clock, annotate=None)
+    with t.span("model_init", params=9):
+        clock.t = 103.0
+        with t.span("ref_init"):
+            clock.t = 104.0
+    clock.t = 105.0
+    t.start_cycle(105.0)
+    # seconds from the tracer's birth, handed over as a cycle's are
+    assert t.cycle_spans == [["ref_init", 3.0, 4.0, "model_init", {}],
+                             ["model_init", 0.0, 4.0, None, {"params": 9}]]
+    with t.span("generate"):
+        clock.t = 106.0
+    t.snapshot_cycle(107.0)
+    assert [r[0] for r in t.cycle_spans] == ["generate"]
+
+
+def test_setup_row_is_written_once_and_before_the_first_cycle_opens(tmp_path):
+    clock = _FakeClock(10.0)
+    obs = RunObserver(ObsConfig(), str(tmp_path), clock=clock)
+    with obs.span("model_init", params=5):
+        clock.t = 14.0
+        _compile(obs.tracer, "_normal", 1.5, trace_s=0.25, lower_s=0.25)
+    clock.t = 16.0
+    obs.end_init()
+    with obs.span("prompt_pipeline", prompts=8):
+        clock.t = 17.0
+    obs.start(trainer="T", step=0)
+    clock.t = 18.0
+    obs.end_cycle(step=1)
+    obs.finish()
+    # a second learn() of the same process: nothing recorded since, no row;
+    # then something is, and the row holds it alone
+    obs.start(trainer="T", step=1)
+    obs.end_cycle(step=2)
+    _compile(obs.tracer, "add", 0.5)
+    obs.start(trainer="T", step=2)
+    obs.finish()
+    rows = list(iter_rows(str(tmp_path)))
+    kinds = [r["kind"] for r in rows]
+    assert kinds[:3] == ["setup", "run_start", "cycle"] and kinds.count("setup") == 2
+    first, second = (r for r in rows if r["kind"] == "setup")
+    assert first["cycle"] == 1 and first["init_s"] == 6.0 and first["since_import_s"] > 0
+    assert first["spans"] == [["model_init", 0.0, 4.0, None, {"params": 5}],
+                              ["prompt_pipeline", 6.0, 7.0, None, {"prompts": 8}]]
+    assert first["compiles"] == {
+        "requests": 1, "built": 1, "read": 0, "written": 0, "trace_s": 0.25,
+        "lower_s": 0.25, "build_s": 1.5, "read_s": 0.0, "built_s": 2.0}
+    # the record starts where the trace did: 2 s before the event
+    assert first["programs"] == [["jit__normal", 1, 2.0, 1]]
+    assert first["by_span"] == {"model_init": [2.0, 1]}
+    assert second["programs"] == [["jit_add", 1, 0.5, 1]] and second["spans"] == []
+    assert "init_s" not in second and "since_import_s" not in second
+    # nothing happened to the run: a monitor that counts the events tail
+    # (the benchmark's `correct`) must not see the row
+    assert "setup" not in obs.events_tail()
+
+
+def test_a_program_compiled_inside_a_span_is_recorded_under_it(tmp_path):
+    obs = RunObserver(ObsConfig(), str(tmp_path))
+    program, name = _probe_program(1)
+    with obs.span("generate"):
+        program(np.ones(3, np.float32))
+    (record,) = [c for c in obs.tracer._compiles if c[0] == name]
+    _, t0, t1, built, parent = record
+    assert t1 > t0 and parent == "generate" and built in (True, False)
+    totals = obs.tracer._compile_totals
+    assert totals["built"] + totals["read"] == len(obs.tracer._compiles) >= 1
+    assert totals["trace_s"] > 0 and totals["lower_s"] > 0
+    obs.finish()
+
+
+def test_a_second_call_of_a_compiled_program_records_nothing(tmp_path):
+    obs = RunObserver(ObsConfig(), str(tmp_path))
+    program, name = _probe_program(2)
+    x = np.ones(3, np.float32)
+    program(x)
+    seen = len(obs.tracer._compiles)
+    assert [c[0] for c in obs.tracer._compiles].count(name) == 1
+    program(x)
+    assert len(obs.tracer._compiles) == seen
+    obs.finish()
+
+
+def test_a_cycle_without_compiles_writes_the_row_it_wrote_before(tmp_path):
+    obs = RunObserver(ObsConfig(), str(tmp_path), clock=_FakeClock(1.0))
+    obs.start(trainer="T", step=0)
+    _compile(obs.tracer, "generate", 2.0, hit=True)
+    obs.end_cycle(step=1, policy_version=1)
+    obs.end_cycle(step=2, policy_version=2)
+    obs.finish()
+    compiled, steady, _final = (r for r in iter_rows(str(tmp_path)) if r["kind"] == "cycle")
+    assert set(steady) == _STEADY_CYCLE_KEYS
+    assert set(compiled) == _STEADY_CYCLE_KEYS | {"compiles", "compile_totals"}
+    assert compiled["compiles"] == [["jit_generate", -2.0, 0.0, False, None]]
+    assert compiled["compile_totals"]["read"] == 1 and compiled["compile_totals"]["read_s"] == 2.0
+
+
+def test_cycle_row_keeps_the_64_longest_compiles_and_counts_the_rest(tmp_path):
+    clock = _FakeClock(0.0)
+    obs = RunObserver(ObsConfig(), str(tmp_path), clock=clock)
+    obs.start(trainer="T", step=0)
+    for i in range(70):
+        clock.t += 1.0
+        _compile(obs.tracer, f"p{i}", 0.001 * (i + 1))
+    obs.end_cycle(step=1)
+    obs.finish()
+    row = next(r for r in iter_rows(str(tmp_path)) if r["kind"] == "cycle")
+    assert len(row["compiles"]) == 64 and row["compiles_more"] == 6
+    # the six shortest went, the rest stay in time order; the totals hold all
+    assert [c[0] for c in row["compiles"]] == [f"jit_p{i}" for i in range(6, 70)]
+    assert row["compile_totals"]["built"] == 70
+    assert row["compile_totals"]["build_s"] == pytest.approx(0.001 * 70 * 71 / 2)
+
+
+def test_programs_by_name_keeps_the_totals_under_forty_names_and_others():
+    from trlx_tpu.obs.spans import OTHERS, compiles_by_span, programs_by_name
+
+    records = [[f"jit_p{i % 50}", 0.0, 0.5 + (i % 50), i % 3 == 0, "model_init" if i % 2 else None]
+               for i in range(120)]
+    rows = programs_by_name(records)
+    assert len(rows) == 41 and rows[-1][0] == OTHERS
+    assert [r[0] for r in rows[:3]] == ["jit_p49", "jit_p48", "jit_p47"]
+    assert sum(r[1] for r in rows) == 120
+    assert sum(r[2] for r in rows) == pytest.approx(sum(r[2] - r[1] for r in records))
+    assert sum(r[3] for r in rows) == sum(bool(r[3]) for r in records) == 40
+    assert programs_by_name(records[:30])[-1][0] != OTHERS  # thirty names: no such line
+    by_span = compiles_by_span(records)
+    assert set(by_span) == {"model_init", "(no span)"}
+    assert sum(v[0] for v in by_span.values()) == pytest.approx(sum(r[2] for r in rows))
+    assert sum(v[1] for v in by_span.values()) == 40
+
+
+def test_compile_events_reach_the_observer_built_last_through_one_listener(tmp_path):
+    import jax._src.monitoring as monitoring
+
+    from trlx_tpu.obs import observer as O
+
+    first = RunObserver(ObsConfig(), str(tmp_path / "a"))
+    second = RunObserver(ObsConfig(), str(tmp_path / "b"))
+    program, name = _probe_program(3)
+    program(np.ones(3, np.float32))
+    assert first.tracer._compiles == [] and not any(first.tracer._compile_totals.values())
+    assert name in [c[0] for c in second.tracer._compiles]
+    assert monitoring.get_event_duration_listeners().count(O._forward_compile_duration) == 1
+    assert monitoring.get_event_listeners().count(O._forward_compile_event) == 1
+    # the target is held weakly: a trainer that is gone takes its observer along
+    del second
+    import gc
+
+    gc.collect()
+    assert O._compile_target() is None
+    _probe_program(4)[0](np.ones(3, np.float32))  # nobody listens, nothing breaks
+    first.finish()
+
+
+def test_a_listener_that_raises_disarms_the_observer_and_the_compile_goes_through(tmp_path):
+    obs = RunObserver(ObsConfig(), str(tmp_path))
+
+    def broken(*args):
+        raise RuntimeError("from the listener")
+
+    obs.tracer.on_compile_duration = broken
+    program, _ = _probe_program(5)
+    out = program(np.ones(3, np.float32))
+    assert np.allclose(np.asarray(out), 7.0) and not obs.active
+    # disarmed: later events are dropped before they reach the tracer
+    obs.tracer.on_compile_event = broken
+    _probe_program(6)[0](np.ones(3, np.float32))
+    obs.finish()
+
+
+def test_obs_disabled_registers_no_compile_listener(tmp_path, monkeypatch):
+    import jax.monitoring
+
+    from trlx_tpu.obs import observer as O
+
+    calls = []
+    monkeypatch.setattr(O, "_forwarders_registered", False)
+    monkeypatch.setattr(O, "_compile_target", None)
+    monkeypatch.setattr(jax.monitoring, "register_event_duration_secs_listener", calls.append)
+    monkeypatch.setattr(jax.monitoring, "register_event_listener", calls.append)
+    off = RunObserver(ObsConfig.from_dict({"enabled": False}), str(tmp_path / "a"))
+    reader = RunObserver(ObsConfig(), str(tmp_path / "b"), is_writer=False)  # not process 0
+    assert calls == [] and O._compile_target is None
+    _probe_program(7)[0](np.ones(3, np.float32))
+    assert off.tracer._compiles == [] and reader.tracer._compiles == []
+    on = RunObserver(ObsConfig(), str(tmp_path / "c"))
+    assert calls == [O._forward_compile_duration, O._forward_compile_event]
+    assert O._compile_target() is on
+    on.finish()
+
+
+def test_compiles_from_several_threads_land_in_one_list_with_whole_totals():
+    import sys
+    import threading
+
+    t = SpanTracer(clock=_FakeClock(0.0), annotate=None)
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        def work(k):
+            for i in range(200):
+                _compile(t, f"w{k}", 0.5, hit=bool(i % 2), trace_s=0.25, lower_s=0.25)
+
+        threads = [threading.Thread(target=work, args=(k,)) for k in range(16)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=60)
+        assert not any(th.is_alive() for th in threads)
+    finally:
+        sys.setswitchinterval(old)
+    t.start_cycle(1.0)
+    totals = t.cycle_compile_totals
+    assert len(t.cycle_compiles) == 3200 and totals["requests"] == 3200
+    # a thread's hit marks its own compile alone: half read, half built
+    assert totals["built"] == totals["read"] == 1600
+    assert totals["built_s"] == pytest.approx(1600 * 1.0)
+    assert all(c[2] - c[1] == pytest.approx(1.0) for c in t.cycle_compiles)
+
+
+# ---------------------------------------------------------------------------
 # flight recorder: rotation + atomic append + torn-tail tolerance
 # ---------------------------------------------------------------------------
 
@@ -600,17 +861,7 @@ def test_faultfree_learn_emits_flight_stream_and_telemetry(faultfree_run):
     assert state["obs"]["run_id"] == trainer.obs.run_id
 
     # flight_report renders it
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "flight_report_obs",
-        os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            "scripts", "flight_report.py",
-        ),
-    )
-    fr = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(fr)
+    fr = _flight_report()
     rendered = fr.render(flight_dir)
     assert "slowest-phase attribution" in rendered
     assert trainer.obs.run_id in rendered
@@ -640,6 +891,107 @@ def test_learn_writes_work_site_spans_into_every_cycle_row(faultfree_run):
         logged = [json.loads(line) for line in f]
     gen_times = [r["time/rollout_generate"] for r in logged if "time/rollout_generate" in r]
     assert gen_times and all(t > 0 for t in gen_times)
+
+
+def _flight_report():
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "flight_report_obs",
+        os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            "scripts", "flight_report.py",
+        ),
+    )
+    fr = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(fr)
+    return fr
+
+
+def test_learn_writes_the_constructors_spans_into_one_setup_row(faultfree_run):
+    trainer, ckpt_dir = faultfree_run
+    rows = list(iter_rows(os.path.join(ckpt_dir, "flight")))
+    assert [r["kind"] for r in rows[:2]] == ["setup", "run_start"]
+    (setup,) = [r for r in rows if r["kind"] == "setup"]
+    spans = {}
+    for name, t0, t1, parent, counts in setup["spans"]:
+        assert t1 >= t0 >= 0.0
+        spans.setdefault(name, []).append((t0, t1, parent, counts))
+    assert set(spans) == {"model_init", "ref_init", "opt_init", "prompt_pipeline"}
+    (m0, m1, parent, counts), = spans["model_init"]
+    assert parent is None and counts == {"params": tree_param_count(trainer.params)}
+    (r0, r1, parent, counts), = spans["ref_init"]
+    assert parent == "model_init" and m0 <= r0 <= r1 <= m1
+    assert counts == {"params": tree_param_count(trainer.ref_params)}
+    assert m1 <= spans["opt_init"][0][0]
+    # the prompts, then the evaluation prompts (the first batch of them)
+    assert [c for *_, c in spans["prompt_pipeline"]] == [{"prompts": 8}] * 2
+    # the constructor ends before the pipelines are built
+    assert spans["opt_init"][0][1] <= setup["init_s"] <= spans["prompt_pipeline"][0][0]
+    assert setup["since_import_s"] > 0
+    # eager init compiles one small program an operation: all before cycle 1
+    c = setup["compiles"]
+    assert c["built"] + c["read"] == sum(n for _, n, *_ in setup["programs"]) > 10
+    assert sum(v[0] for v in setup["by_span"].values()) == pytest.approx(
+        sum(s for _, _, s, _ in setup["programs"]), abs=1e-4)
+    assert "model_init" in setup["by_span"]
+    # the sampler, the scorer and the block are built in cycle 1 and named there
+    first = next(r for r in rows if r["kind"] == "cycle")
+    named = {name: parent for name, _, _, _, parent in first["compiles"]}
+    assert named["jit_generate"] == "generate"
+    assert named["jit_ppo_experience_fwd"] == "score_dispatch"
+    assert named["jit_fused_train_step"] == "fused_block"
+    rendered = _flight_report().render(os.path.join(ckpt_dir, "flight"))
+    assert "[setup] import to observer" in rendered and "compiled under: model_init" in rendered
+    assert "largest programs: jit_" in rendered
+
+
+def test_a_recompile_is_named_in_its_cycles_row_and_in_the_report(tmp_path):
+    """A chunk with a new row count in the second cycle: that cycle's row
+    names `jit_generate` under `generate`, the cycle after it compiles
+    nothing, and the report has the operator's line."""
+    import re
+
+    from trlx_tpu.utils.loading import get_pipeline, get_trainer
+
+    ckpt_dir = str(tmp_path / "ckpts")
+    config = _tiny_ppo_config(ckpt_dir).evolve(
+        train=dict(total_steps=5, checkpoint_interval=100))
+    config.method.gen_kwargs["eos_token_id"] = -1
+    trainer = get_trainer(config.train.trainer)(
+        config=config,
+        reward_fn=lambda samples, prompts, outputs, **kw: [float(len(o)) for o in outputs])
+    prompts = ["hello world", "the cat", "a b", "xyz", "what is", "I am", "go", "ok"]
+    pipeline = get_pipeline(config.train.pipeline)
+    trainer.add_prompt_pipeline(pipeline(prompts, 16, trainer.tokenizer))
+    trainer.add_eval_pipeline(pipeline(prompts, 16, trainer.tokenizer))
+    chunks = trainer.prompt_iterator
+
+    def ragged():  # 8 rows, then 16 at a time
+        yield next(chunks)
+        while True:
+            a, b = next(chunks), next(chunks)
+            yield a.replace(
+                input_ids=np.concatenate([a.input_ids, b.input_ids]),
+                attention_mask=np.concatenate([a.attention_mask, b.attention_mask]))
+
+    trainer.prompt_iterator = ragged()
+    trainer.learn()
+    flight_dir = os.path.join(ckpt_dir, "flight")
+    first, second, third = [r for r in iter_rows(flight_dir)
+                            if r["kind"] == "cycle" and r["samples"]]
+    assert (first["samples"], second["samples"], third["samples"]) == (8, 16, 16)
+    recompiled = {name: parent for name, _, _, _, parent in second["compiles"]}
+    assert recompiled["jit_generate"] == "generate"
+    assert recompiled["jit_fused_train_step"] == "fused_block"  # two steps a block now
+    assert all(0.0 <= t0 <= t1 <= second["wall_s"] for _, t0, t1, _, _ in second["compiles"])
+    assert "compiles" not in third and "compile_totals" not in third
+    # built outside `trlx_tpu.train()`: the constructor's end was never stamped
+    setup = next(r for r in iter_rows(flight_dir) if r["kind"] == "setup")
+    assert "init_s" not in setup and "since_import_s" in setup
+    rendered = _flight_report().render(flight_dir)
+    assert re.search(r"cycle 2: (built|read) jit_generate \d+\.\d\d s under generate", rendered)
+    assert "cycle 3: " not in rendered
 
 
 def test_backward_depth_gauges_once_per_built_step(faultfree_run, tmp_path):

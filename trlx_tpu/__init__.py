@@ -4,6 +4,12 @@ and seq2seq language models, from one chip to multi-host pods via a
 single sharding-polymorphic trainer (mesh axes dp/fsdp/tp/sp).
 """
 
+import time
+
+# before the imports below, which are most of the package's own start-up
+# cost: the flight stream's `setup` row counts from here (obs/observer.py)
+IMPORTED_AT = time.monotonic()
+
 __version__ = "0.1.0"
 
 from trlx_tpu import utils  # noqa: F401

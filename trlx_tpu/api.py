@@ -81,6 +81,8 @@ def train(
         stop_sequences=stop_sequences or [],
         **config.train.trainer_kwargs,
     )
+    # the flight stream's `setup` row: the constructor ends here
+    trainer.obs.end_init()
 
     batch_size = config.train.batch_size
     max_prompt_length = config.train.seq_length - config.method.gen_kwargs.get(
@@ -101,10 +103,11 @@ def train(
         if eval_prompts is None:
             eval_prompts = prompts[:batch_size]
 
-        pipeline = get_pipeline(config.train.pipeline)(
-            prompts, max_prompt_length, trainer.tokenizer
-        )
-        trainer.add_prompt_pipeline(pipeline)
+        with trainer.obs.span("prompt_pipeline", prompts=len(prompts)):
+            pipeline = get_pipeline(config.train.pipeline)(
+                prompts, max_prompt_length, trainer.tokenizer
+            )
+            trainer.add_prompt_pipeline(pipeline)
 
     # --- offline RL ------------------------------------------------------
     elif rewards is not None:
@@ -124,10 +127,11 @@ def train(
         # (prompt, chosen, rejected) triples — the trainer validates
         trainer.make_experience(samples, None, config.train.seq_length)
 
-    eval_pipeline = get_pipeline(config.train.pipeline)(
-        eval_prompts, max_prompt_length, trainer.tokenizer
-    )
-    trainer.add_eval_pipeline(eval_pipeline)
+    with trainer.obs.span("prompt_pipeline", prompts=len(eval_prompts)):
+        eval_pipeline = get_pipeline(config.train.pipeline)(
+            eval_prompts, max_prompt_length, trainer.tokenizer
+        )
+        trainer.add_eval_pipeline(eval_pipeline)
 
     import os
 

@@ -18,7 +18,12 @@ by hand. This subsystem closes both gaps:
       around code the trainer already has: generate, tokens_wait,
       score_dispatch, block_wait, ...) ride each ``cycle`` row without
       touching the partition; phases and spans are mirrored into a
-      profiler capture as ``trlx:<name>`` annotations.
+      profiler capture as ``trlx:<name>`` annotations. The observer is
+      built before the weights, so the constructor's set-up spans
+      (model_init, opt_init, ...) and every compilation (named, timed,
+      marked read or built, from JAX's own monitoring events) are kept:
+      one ``setup`` row at the top of learn(), a ``compiles`` key in
+      each ``cycle`` row that compiled.
   FlightRecorder (obs/recorder.py)
       ONE size-rotated JSONL event stream under
       ``<checkpoint_dir>/flight/``: per-cycle phase breakdowns plus

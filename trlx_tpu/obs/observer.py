@@ -13,6 +13,11 @@ at an existing site):
   staleness/fleet/memory/stall/peer) lands in the stream the moment it
   is recorded, and perf/memory trips arm the one-shot profiler;
 - chaos injections: ``ChaosMonkey.on_fire``;
+- compiles: ONE process-wide pair of listeners on JAX's own monitoring
+  events (``jax.monitoring``), forwarding to the observer built last
+  through a weak reference; the tracer turns them into named, timed
+  records, marked read or built, which ride the ``setup`` row and each
+  ``cycle`` row that compiled;
 - everything else (cycle boundaries, samples, OOM-ladder rungs,
   watermark crossings, checkpoint commits/restores, cross-host rows)
   is an explicit ``obs.*`` call from the trainer.
@@ -29,13 +34,20 @@ import functools
 import os
 import time
 import uuid
+import weakref
 from collections import deque
 from typing import Any, Dict, Optional
 
 from trlx_tpu.obs.config import ObsConfig
 from trlx_tpu.obs.profiler import ProfilerArm
 from trlx_tpu.obs.recorder import FlightRecorder
-from trlx_tpu.obs.spans import SpanTracer
+from trlx_tpu.obs.spans import (
+    COMPILE_EVENTS,
+    SpanTracer,
+    compiles_by_span,
+    longest_compiles,
+    programs_by_name,
+)
 from trlx_tpu.obs.telemetry import TelemetryAggregator, device_provenance
 from trlx_tpu.utils import logging
 
@@ -57,6 +69,50 @@ def _no_raise(method):
             return None
 
     return wrapped
+
+
+# A trainer has no end at which to un-register a listener, and a test
+# process builds dozens: the two forwarders below are registered with
+# JAX's monitoring once a process and hand each event to the observer
+# built last, while it lives.
+_compile_target: Optional["weakref.ref[RunObserver]"] = None
+_forwarders_registered = False
+
+
+def _forward_compile_duration(event: str, seconds: float, **kwargs: Any) -> None:
+    if event in COMPILE_EVENTS and _compile_target is not None:
+        obs = _compile_target()
+        if obs is not None:
+            obs._on_compile_duration(event, seconds, str(kwargs.get("fun_name", "?")))
+
+
+def _forward_compile_event(event: str, **kwargs: Any) -> None:
+    if event in COMPILE_EVENTS and _compile_target is not None:
+        obs = _compile_target()
+        if obs is not None:
+            obs._on_compile_event(event)
+
+
+def _listen_for_compiles(obs: "RunObserver") -> None:
+    """Point the process's compile events at ``obs`` (lazy import: obs/
+    stays jax-free at module scope)."""
+    global _compile_target, _forwarders_registered
+    _compile_target = weakref.ref(obs)
+    if not _forwarders_registered:
+        import jax.monitoring
+
+        jax.monitoring.register_event_duration_secs_listener(_forward_compile_duration)
+        jax.monitoring.register_event_listener(_forward_compile_event)
+        _forwarders_registered = True
+
+
+def _rounded(totals: Dict[str, Any]) -> Dict[str, Any]:
+    return {k: round(v, 6) if isinstance(v, float) else v for k, v in totals.items()}
+
+
+def _timed_rows(rows: list) -> list:
+    """Span rows and compile records as written: times to the microsecond."""
+    return [[name, round(t0, 6), round(t1, 6), *rest] for name, t0, t1, *rest in rows]
 
 
 class RunObserver:
@@ -91,6 +147,18 @@ class RunObserver:
         self._step: Optional[int] = None
         self._policy_version: Optional[int] = None
         self._started = False
+        # the `setup` row's two figures no span can hold: the package's
+        # import to here, and here to the constructor's return (end_init)
+        import trlx_tpu
+
+        self._since_import_s = time.monotonic() - trlx_tpu.IMPORTED_AT
+        self._init_t0 = clock()
+        self._init_s: Optional[float] = None
+        if self.active:
+            try:
+                _listen_for_compiles(self)
+            except Exception as e:
+                self._disarm("compile listener", e)
 
     # -- attachment ------------------------------------------------------
 
@@ -108,7 +176,9 @@ class RunObserver:
             chaos.on_fire = self._on_chaos
         # keep beat timestamps and cycle boundaries on one timebase
         if watchdog is not None:
-            self._clock = self.tracer.clock = watchdog.clock
+            self._clock = watchdog.clock
+            self.tracer.set_clock(watchdog.clock)
+            self._init_t0 = self._clock()
 
     # -- listeners -------------------------------------------------------
 
@@ -144,6 +214,14 @@ class RunObserver:
                     self.tracer.close_span(rec)
                 except Exception as e:
                     self._disarm("span", e)
+
+    @_no_raise
+    def _on_compile_duration(self, event: str, seconds: float, fun_name: str) -> None:
+        self.tracer.on_compile_duration(event, seconds, fun_name)
+
+    @_no_raise
+    def _on_compile_event(self, event: str) -> None:
+        self.tracer.on_compile_event(event)
 
     def _disarm(self, what: str, e: Exception) -> None:
         self.active = False
@@ -200,17 +278,52 @@ class RunObserver:
     # -- run / cycle lifecycle -------------------------------------------
 
     @_no_raise
+    def end_init(self) -> None:
+        """The trainer's constructor has returned (``api.train()`` says
+        so: nothing inside the constructor can)."""
+        self._init_s = self._clock() - self._init_t0
+
+    def _write_setup(self, first: bool) -> None:
+        """One ``setup`` row from what the tracer recorded outside any
+        cycle: the set-up spans, and every compile named, timed and marked
+        read or built. Not an event of the run (nothing happened to it),
+        so like a gauge it stays out of the events tail. A later
+        ``learn()`` of the same process writes one only if something was
+        recorded since the last cycle closed."""
+        t = self.tracer
+        totals = t.cycle_compile_totals
+        if not (first or t.cycle_spans or any(totals.values())):
+            return
+        fields: Dict[str, Any] = {
+            "spans": _timed_rows(t.cycle_spans),
+            "compiles": _rounded(totals),
+            "programs": [
+                [name, n, round(seconds, 6), n_built]
+                for name, n, seconds, n_built in programs_by_name(t.cycle_compiles)
+            ],
+            "by_span": {
+                k: [round(seconds, 6), n_built]
+                for k, (seconds, n_built) in compiles_by_span(t.cycle_compiles).items()
+            },
+        }
+        if first:
+            fields["since_import_s"] = round(self._since_import_s, 6)
+            if self._init_s is not None:
+                fields["init_s"] = round(self._init_s, 6)
+        self.recorder.append("setup", **self._correlated(fields))
+
+    @_no_raise
     def start(self, **meta: Any) -> None:
-        """Arm at the top of learn(): stamps provenance, opens the
-        first cycle, and records ``run_start`` (a resumed run appends
-        to the same stream under the restored run_id)."""
+        """Arm at the top of learn(): stamps provenance, writes the
+        ``setup`` row, opens the first cycle, and records ``run_start``
+        (a resumed run appends to the same stream under the restored
+        run_id)."""
         self.telemetry.set_static(device=device_provenance(), **meta)
         self._step = meta.get("step")
-        now = self._clock()
-        if not self._started:
-            self.tracer.start_cycle(now)
-        else:
-            self.tracer.snapshot_cycle(now)  # discard inter-learn() time
+        # the time since the tracer was built, or between two learn()s,
+        # belongs to no cycle
+        self.tracer.start_cycle(self._clock())
+        self._write_setup(first=not self._started)
         self._started = True
         self.record("run_start", **{k: v for k, v in meta.items() if v is not None})
         self.profiler.begin_cycle(self.cycle)
@@ -259,11 +372,18 @@ class RunObserver:
         )
         # the cycle's work-site spans ride the same row, seconds from
         # the cycle's start: [name, t0, t1, parent, counts]
-        spans = [
-            [name, round(t0, 6), round(t1, 6), parent, counts]
-            for name, t0, t1, parent, counts in self.tracer.cycle_spans
-        ]
-        self.recorder.append("cycle", **row, spans=spans)
+        spans = _timed_rows(self.tracer.cycle_spans)
+        # and what it compiled, the key ABSENT from a cycle that compiled
+        # nothing: [fun_name, t0, t1, built, parent], the longest
+        compiled: Dict[str, Any] = {}
+        if any(self.tracer.cycle_compile_totals.values()):
+            records, more = longest_compiles(self.tracer.cycle_compiles)
+            compiled = {
+                "compiles": _timed_rows(records) or None,
+                "compiles_more": more or None,
+                "compile_totals": _rounded(self.tracer.cycle_compile_totals),
+            }
+        self.recorder.append("cycle", **row, spans=spans, **compiled)
         self.profiler.end_cycle(closing)
         if not final:
             self.profiler.begin_cycle(self.cycle)

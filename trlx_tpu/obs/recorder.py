@@ -91,7 +91,6 @@ class FlightRecorder:
         self._fd: Optional[int] = None
         self._index = 0
         self._lock = threading.Lock()
-        self.rows_written = 0
         self.rows_dropped = 0  # transient write failures (row skipped)
 
     # -- file management -------------------------------------------------
@@ -165,7 +164,6 @@ class FlightRecorder:
                 data = (json.dumps(row, default=str) + "\n").encode()
                 fd = self._ensure_open()
                 os.write(fd, data)  # one write = never interleaved
-                self.rows_written += 1
                 self._maybe_rotate()
             except Exception as e:
                 self.rows_dropped += 1

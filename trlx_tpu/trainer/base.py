@@ -169,9 +169,39 @@ class TPUBaseTrainer(BaseRLTrainer):
         self.tokenizer = load_tokenizer(config.tokenizer)
         self.rng = jax.random.PRNGKey(train.seed)
 
+        # run guardrails (divergence watchdog) + chaos harness +
+        # resilient reward I/O — all default-off / behavior-preserving
+        self.guardrails = build_monitor(train)
+        self.chaos = build_chaos(train)
+        # hang doctor: phase heartbeats + stall monitor thread (armed
+        # for the duration of learn(); default-off = free beats, no
+        # thread). Escalation on trip: guardrails `stall` record ->
+        # emergency snapshot from the host-RAM shadow -> stalled abort.
+        self.watchdog = build_watchdog(train)
+        self.watchdog.on_stall(self._on_watchdog_stall)
+        # flight recorder (train.obs.*, trlx_tpu/obs/ — DEFAULT ON):
+        # span tracer riding the watchdog's beat sites, unified JSONL
+        # event stream under <checkpoint_dir>/flight/ fed by the
+        # guardrail/chaos listeners registered here, and continuous
+        # bench-comparable telemetry committed with every checkpoint.
+        # Host-side only; never raises into the loop. Built (from the
+        # config alone, with the three islands it attaches to) BEFORE the
+        # weights, so that its spans and its compile listener see the
+        # whole constructor: set-up is most of a short run's chip time.
+        self.obs = build_observer(
+            train,
+            checkpoint_dir=train.checkpoint_dir,
+            is_writer=mh.is_main(),
+            watchdog=self.watchdog,
+            guardrails=self.guardrails,
+            chaos=self.chaos,
+        )
+
         # subclass hook: builds self.model (wrapper), self.params and any
         # auxiliary trees (e.g. PPO's frozen reference branch)
-        self.setup_model()
+        with self.obs.span("model_init") as counts:
+            self.setup_model()
+            counts["params"] = tree_param_count(getattr(self, "params", None))
         # the model itself must know a mesh of more than one device:
         # context parallelism (ring attention over `sp`) and pipeline
         # parallelism (layer stack over `pp`) run teacher-forced forwards
@@ -186,11 +216,12 @@ class TPUBaseTrainer(BaseRLTrainer):
         # static, from the mask's values and the shapes alone: what of each
         # leaf an optimizer step walks
         self._view = trainable_view.trainable_view(self._update_mask, self.params)
-        self.tx, self.schedule = self._assemble_optimizer(
-            config.optimizer, config.scheduler
-        )
-        with self.mesh:
-            self.opt_state = init_sharded_opt_state(self.mesh, self.tx, self.params)
+        with self.obs.span("opt_init"):
+            self.tx, self.schedule = self._assemble_optimizer(
+                config.optimizer, config.scheduler
+            )
+            with self.mesh:
+                self.opt_state = init_sharded_opt_state(self.mesh, self.tx, self.params)
 
         gen_kwargs = dict(config.method.gen_kwargs)
         self.generate_sweep_kwarg = None
@@ -234,16 +265,6 @@ class TPUBaseTrainer(BaseRLTrainer):
             failure_threshold=self._TRACKER_CIRCUIT_LIMIT, reset_timeout=0.0
         )
         self._rollout_abandoned = False  # preemption truncated the store
-        # run guardrails (divergence watchdog) + chaos harness +
-        # resilient reward I/O — all default-off / behavior-preserving
-        self.guardrails = build_monitor(train)
-        self.chaos = build_chaos(train)
-        # hang doctor: phase heartbeats + stall monitor thread (armed
-        # for the duration of learn(); default-off = free beats, no
-        # thread). Escalation on trip: guardrails `stall` record ->
-        # emergency snapshot from the host-RAM shadow -> stalled abort.
-        self.watchdog = build_watchdog(train)
-        self.watchdog.on_stall(self._on_watchdog_stall)
         self._warned_shadow_skip = False
         # memory doctor (train.memory.*): preflight HBM admission
         # control, runtime watermark sampling (feeding the `memory`
@@ -257,20 +278,6 @@ class TPUBaseTrainer(BaseRLTrainer):
         # peaks; otherwise everything lands under "run")
         self.memdoctor.sampler.set_phase_fn(self.watchdog.current_phase)
         self._hbm_plan = None  # preflight plan, kept for the abort report
-        # flight recorder (train.obs.*, trlx_tpu/obs/ — DEFAULT ON):
-        # span tracer riding the watchdog's beat sites, unified JSONL
-        # event stream under <checkpoint_dir>/flight/ fed by the
-        # guardrail/chaos listeners registered here, and continuous
-        # bench-comparable telemetry committed with every checkpoint.
-        # Host-side only; never raises into the loop.
-        self.obs = build_observer(
-            train,
-            checkpoint_dir=train.checkpoint_dir,
-            is_writer=mh.is_main(),
-            watchdog=self.watchdog,
-            guardrails=self.guardrails,
-            chaos=self.chaos,
-        )
         # the last cycle's async metrics must survive shutdown in any
         # order: tracker.close() drains these before backend teardown
         self.tracker.attach_pending(self._finish_rollout_stats)

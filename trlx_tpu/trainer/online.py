@@ -742,28 +742,29 @@ class TPUOnlineTrainer(TPUBaseTrainer):
         steps = getattr(cfg, "router_balance_steps", 0)
         if not (steps and getattr(cfg, "routed", False) and getattr(self, "_random_init", False)):
             return
-        rows = min(len(pipeline), self._prompt_chunk_rows())
-        batch = pipeline.collate([pipeline[i] for i in range(rows)])
-        with self.mesh:
-            base, ratios = balance_router_bias(
-                self.model.lm, self.params["base"], jnp.asarray(batch.input_ids),
-                jnp.asarray(batch.attention_mask), steps)
-        bias = router_bias(base)
+        with self.obs.span("router_balance", steps=steps):
+            rows = min(len(pipeline), self._prompt_chunk_rows())
+            batch = pipeline.collate([pipeline[i] for i in range(rows)])
+            with self.mesh:
+                base, ratios = balance_router_bias(
+                    self.model.lm, self.params["base"], jnp.asarray(batch.input_ids),
+                    jnp.asarray(batch.attention_mask), steps)
+            bias = router_bias(base)
 
-        def top_layers(tree):  # a branch holds the top rows of each stack
-            return with_router_bias(tree, {
-                name: jax.device_put(jnp.array(bias[name][len(bias[name]) - old.shape[0]:]), old.sharding)
-                for name, old in router_bias(tree).items()})
+            def top_layers(tree):  # a branch holds the top rows of each stack
+                return with_router_bias(tree, {
+                    name: jax.device_put(jnp.array(bias[name][len(bias[name]) - old.shape[0]:]), old.sharding)
+                    for name, old in router_bias(tree).items()})
 
-        self.params = dict(self.params, base=top_layers(base))
-        if "v_branch" in self.params:
-            self.params["v_branch"] = top_layers(self.params["v_branch"])
-        if getattr(self, "ref_params", None) is not None:
-            self.ref_params = top_layers(self.ref_params)
-        before, after = ([round(float(r), 2) for r in row] for row in np.asarray(ratios))
-        logger.info(
-            f"router bias balanced on {rows} prompts in {steps} steps: fullest expert over the "
-            f"mean, by layer, {before} -> {after}")
+            self.params = dict(self.params, base=top_layers(base))
+            if "v_branch" in self.params:
+                self.params["v_branch"] = top_layers(self.params["v_branch"])
+            if getattr(self, "ref_params", None) is not None:
+                self.ref_params = top_layers(self.ref_params)
+            before, after = ([round(float(r), 2) for r in row] for row in np.asarray(ratios))
+            logger.info(
+                f"router bias balanced on {rows} prompts in {steps} steps: fullest expert over the "
+                f"mean, by layer, {before} -> {after}")
 
     def _prompt_chunk_rows(self) -> int:
         """Prompts pulled from the stream per chunk (GRPO pulls

@@ -41,6 +41,7 @@ from trlx_tpu.data import PPORolloutBatch, PromptBatch
 from trlx_tpu.data.method_configs import PPOConfig
 from trlx_tpu.models.transformer import _join_stats, moe_counters
 from trlx_tpu.models.wrappers import CausalLMWithValueHead, Seq2SeqLMWithValueHead
+from trlx_tpu.obs.telemetry import tree_param_count
 from trlx_tpu.ops.common import chunked_logprobs, logprobs_of_labels
 from trlx_tpu.ops.ppo import gae_advantages_and_returns, ppo_loss
 from trlx_tpu.ops.remat import resolve_remat
@@ -173,7 +174,9 @@ class TPUPPOTrainer(TPUOnlineTrainer):
         # frozen in-process reference: the top-k branch (hydra) or a full
         # copy when everything is trainable (reference :74-77); with LoRA
         # the disabled-adapter base IS the reference (peft parity)
-        self.ref_params = shard_params(self.mesh, self.model.make_ref_params(self.params))
+        with self.obs.span("ref_init") as counts:
+            self.ref_params = shard_params(self.mesh, self.model.make_ref_params(self.params))
+            counts["params"] = tree_param_count(self.ref_params)
 
     def trainable_mask(self):
         lora_mask = self.lora_freeze_mask(self.params)
