@@ -316,7 +316,7 @@ def test_kernels_carry_fixed_names():
 
 
 def test_every_pallas_call_in_ops_has_a_name_of_its_own():
-    """All eight, the decode kernels included, without running them."""
+    """All nine, the decode kernels included, without running them."""
     import ast
     import glob
     import os
@@ -333,7 +333,7 @@ def test_every_pallas_call_in_ops_has_a_name_of_its_own():
                 assert "name" in kw, f"{path}:{node.lineno} pallas_call without name="
                 names.append(kw["name"].value)
     assert sorted(names) == sorted(set(names)) and {
-        "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "decode_attn", "paged_decode_attn",
+        "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "decode_attn", "paged_decode_attn", "state_step",
     } <= set(names)
 
 

@@ -659,3 +659,36 @@ def test_mosaic_compiles_the_int8_decode_kernel_at_the_cells_shapes(topo, one_ch
             assert "all-gather" not in text and "all-reduce" not in text
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
+
+
+def test_mosaic_compiles_the_state_step_kernel_at_the_cells_shapes(one_chip, monkeypatch):
+    """The decode step of a recurrent mixer as one kernel (`ops/state_step.py`), compiled
+    by Mosaic at the two longgen-b32 cells' shapes (delta rule: 6 layers x 32 rows x 32
+    heads of 128 x 128; state space: 5 x 32 x 128 heads of 64 x 128 in 8 groups): the
+    carry is the custom call's operand and its output, and nothing copies it."""
+    import re
+
+    from trlx_tpu.ops import state_step as ss
+
+    monkeypatch.setattr(ss, "_interpret", lambda: False)
+    shape = lambda *s, dt=jnp.float32: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    B = 32
+    cases = {
+        "delta": (ss.delta_state_step, (6, B, 32, 128, 128), (
+            shape(B, 32, 128), shape(B, 32, 128), shape(B, 32, 128), shape(B, 32, 128), shape(B, 32))),
+        "ssm": (ss.ssm_state_step, (5, B, 128, 64, 128), (
+            shape(B, 128, 64, dt=jnp.bfloat16), shape(B, 8, 128, dt=jnp.bfloat16),
+            shape(B, 8, 128, dt=jnp.bfloat16), shape(B, 128), shape(128))),
+    }
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        for kind, (step, carry, vectors) in cases.items():
+            compiled = jax.jit(step, donate_argnums=0).lower(shape(*carry), shape(dt=jnp.int32), *vectors).compile()
+            text = compiled.as_text()
+            assert "state_step" in text and "tpu_custom_call" in text
+            assert "output_to_operand_aliasing={{0}: (1, {})}" in text
+            carried = "f32[" + ",".join(map(str, carry)) + "]"
+            assert not re.findall(rf"= {re.escape(carried)}\S* (?:copy|dynamic-slice|dynamic-update-slice)\(", text)
+            assert compiled.memory_analysis().temp_size_in_bytes < 4 << 20  # no second carry, no layer's slice
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)

@@ -16,6 +16,7 @@ forward and `jax.grad`. Sequences are 80 long: three chunks of 32 of the chunked
 the last padded, as are the prefill's (72) and the form's own test's (100)."""
 
 import json
+import logging
 import os
 import zlib
 from types import SimpleNamespace
@@ -37,6 +38,7 @@ from trlx_tpu.models.transformer import (
     kda_step,
     layer_stacks,
     quantize_decode_weights,
+    state_step_unfused,
 )
 from trlx_tpu.models.wrappers import CausalLMWithValueHead
 
@@ -225,13 +227,11 @@ def test_the_chunked_form_equals_the_recurrence(gates):
         np.testing.assert_allclose(np.asarray(s), np.asarray(state * jnp.exp(g.sum(1))[..., None]), rtol=1e-5)
 
 
-@pytest.fixture(scope="module")
-def decoded(world):
+def _decoder(lm, base):
     """Prefill of all but 8 tokens (row 0 left-padded), then 8 single-token steps: the
     chunked form's final state and last three convolution inputs handed to the recurrent
-    form, the latent layer's rows beside them. `run` gives the logits of each step and
-    the cache at the end; `result` is the world's batch through it."""
-    lm = world.lm
+    form, the latent layer's rows beside them. Returns `run(ids, mask)`: the logits of
+    each step and the cache at the end."""
 
     @jax.jit
     def prefill(base, ids, mask, cache_mask):
@@ -244,14 +244,23 @@ def decoded(world):
     def run(ids, mask):
         total = ids.shape[1]
         P = total - 8
-        out = prefill(world.base, ids[:, :P], mask[:, :P], mask)
+        out = prefill(base, ids[:, :P], mask[:, :P], mask)
         got, cache = [out["logits"][:, -1]], out["cache"]
         for t in range(P, total - 1):
-            out = step(world.base, ids[:, t : t + 1], cache)
+            out = step(base, ids[:, t : t + 1], cache)
             got.append(out["logits"][:, 0])
             cache = out["cache"]
         return jnp.stack(got, axis=1), cache
 
+    return run
+
+
+@pytest.fixture(scope="module")
+def decoded(world):
+    """`run`, and `result`, the world's batch through it, on one device (no mesh): a decode
+    step of the recurrent layers takes the kernel of `ops/state_step.py`, interpreted."""
+    assert state_step_unfused(world.cfg, world.lm.mesh) is None
+    run = _decoder(world.lm, world.base)
     return SimpleNamespace(P=SEQ - 8, run=run, result=run(world.ids, world.mask))
 
 
@@ -283,6 +292,22 @@ def test_a_left_padded_row_decodes_as_the_same_row_unpadded(world, decoded):
     assert float(jnp.abs(got[:1] - want).max()) < LOGIT_TOL
     np.testing.assert_allclose(np.asarray(padded["kda_s"][:, :1]), np.asarray(cache["kda_s"]), atol=1e-5)
     np.testing.assert_allclose(np.asarray(padded["kda_u_lead"][:, :1]), np.asarray(cache["kda_u_lead"]), atol=1e-5)
+
+
+def test_decode_steps_on_a_mesh_of_two_devices_take_the_xla_branch_and_agree_with_the_kernels(world, decoded):
+    """More than one device is the static condition that sends a decode step of the
+    recurrent layers to `kda_step` on a slice of the carry (`state_step_unfused`): the same
+    prefill and steps there give the kernel's logits and states to float32 rounding."""
+    from trlx_tpu.parallel import make_mesh
+
+    lm = TransformerLM(world.cfg)
+    lm.mesh = make_mesh({"dp": 2}, devices=jax.devices()[:2])
+    assert "2 devices" in state_step_unfused(world.cfg, lm.mesh)
+    got, cache = _decoder(lm, world.base)(world.ids, world.mask)
+    want, kernels = decoded.result
+    assert float(jnp.abs(got - want).max()) < 2e-5
+    np.testing.assert_allclose(np.asarray(cache["kda_s"]), np.asarray(kernels["kda_s"]), atol=1e-5)
+    np.testing.assert_allclose(np.asarray(cache["kda_u_lead"]), np.asarray(kernels["kda_u_lead"]), atol=1e-5)
 
 
 def test_thirty_two_shares_of_eight_experts_add_up_to_the_uncut_layer():
@@ -528,10 +553,11 @@ def test_the_block_that_holds_the_delta_rule_trunk_equals_the_block_that_runs_it
     assert_same_block(held, whole, skip=("moe/assignments",))
 
 
-def test_gauges_and_the_state_count_reach_the_flight_stream(trainer):
+def test_gauges_and_the_state_count_reach_the_flight_stream(trainer, caplog):
     """`model/delta_layers`, `model/latent_layers`, `model/state_elems_per_row` beside
     `model/cache_elems_per_position` once per built train step; `state_bytes_carried`
     on the `tokens_wait` span: 3 decode steps of 8 rows through 4 KDA layers."""
+    from trlx_tpu.models.transformer import _warn_state_step_unfused
     from trlx_tpu.obs.recorder import iter_rows
 
     hf, trainer = trainer.hf, trainer.trainer
@@ -549,10 +575,29 @@ def test_gauges_and_the_state_count_reach_the_flight_stream(trainer):
     assert gauges[0]["model/cache_elems_per_position"] == hf["kv_lora_rank"] + hf["qk_rope_head_dim"]
     assert gauges[0]["model/experts_held"] == hf["num_experts"] and gauges[0]["model/backward_layers"] == 2
     trainer.obs.start(step=0)
-    out = trainer.generate(np.ones((8, 12), np.int32))
+    _warn_state_step_unfused.cache_clear()  # once a process and reason: another file's may have been first
+    logger = logging.getLogger("trlx_tpu.models.transformer")  # the library's loggers do not propagate
+    logger.addHandler(caplog.handler)
+    try:
+        out = trainer.generate(np.ones((8, 12), np.int32))
+    finally:
+        logger.removeHandler(caplog.handler)
     trainer._pull_sampled_tokens(out, 8, {})
     trainer.obs.end_cycle(step=0)
     rows = list(iter_rows(os.path.join(trainer.config.train.checkpoint_dir, "flight")))
+    # the trainer's mesh is the eight CPU devices: the recurrent layers' decode step takes
+    # the XLA branch and says why, once for all its layers; on one device it is the kernel
+    assert [r["gen/state_step_fused"] for r in rows if "gen/state_step_fused" in r] == [0]
+    assert [r.getMessage() for r in caplog.records if "recurrent state" in r.getMessage()] == [
+        "a decode step passes over the recurrent state in XLA ops, not in the fused kernel "
+        "(a mesh of 8 devices: the kernel is one chip's program)"]
+    mesh, trainer._lm().mesh = trainer._lm().mesh, None
+    try:
+        trainer.obs.gauge = lambda **kw: gauges.append(kw)
+        trainer._note_decode_attn((trainer.generate_experience_settings, (8, 12), ()))
+    finally:
+        trainer.obs.gauge, trainer._lm().mesh = real_gauge, mesh
+    assert gauges[-1]["gen/state_step_fused"] == 1 and gauges[-1]["gen/decode_attn_fused"] == 0
     (cycle,) = [r for r in rows if r["kind"] == "cycle"]
     (counts,) = [c for name, *_, c in cycle["spans"] if name == "tokens_wait"]
     assert counts["state_bytes_carried"] == 3 * 2 * 8 * 4 * (4 * H * D * D + 4 * 3 * 3 * H * D)

@@ -75,7 +75,7 @@ TIER1_BUDGETS = {
     # (86.0 s = 25.8), elastic 34->26 (50.7 s = 15.2), serve 26->19
     # (28.4 s = 8.5).
     "test_frozen_trunk.py": 96,
-    "test_gen_engine.py": 34,
+    "test_gen_engine.py": 25,
     # PR 30: the fused int8 decode kernel against the XLA branch (seven
     # interpret-mode cases through `Attention`, one on a four-device
     # mesh, the host's chunk arithmetic), one flight-stream test in
@@ -217,6 +217,16 @@ TIER1_BUDGETS = {
     # (77.0 s = 21.9), grpo 30->20 (62.9 s = 17.9), serve 19->12 (38.5 s =
     # 10.9), scanned_epochs 20->13 (42.7 s = 12.1), obs 26->25 (66.3 s = 18.8).
     "test_state_space.py": 36,
+    # PR 38: the decode step of a recurrent mixer as one kernel
+    # (`ops/state_step.py`): seven interpret-mode cases against `kda_step` /
+    # `ssm_step` and the sampler's jaxpr of both families traced twice;
+    # beside it one decode on a two-device mesh in test_linear_attention and
+    # test_state_space each and one Mosaic compile in test_latent_moe. 28 s
+    # alone, 33.9 s inside a 6-worker run of the driver's command (617 s,
+    # 2026-10-05), whose files took 3,124 s against the 780 budgeted: 8.5 on
+    # the table's scale. Paid under the unchanged 780 ceiling with a time of
+    # the same run on that scale: gen_engine 34->25 (70.1 s = 17.5).
+    "test_state_step.py": 9,
     "test_summarize_eval.py": 1,
     "test_supervisor.py": 11,
     "test_sweep.py": 14,

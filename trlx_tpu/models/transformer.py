@@ -606,6 +606,43 @@ def _warn_decode_unfused(why: str) -> None:
     )
 
 
+def state_step_unfused(cfg: "TransformerConfig", mesh) -> Optional[str]:
+    """Why a decode step (T == 1) of the delta-rule and state-space layers
+    runs `kda_step` / `ssm_step` on a slice of the carried state, the XLA
+    branch, or None where it takes the kernel that passes over the state
+    once (ops/state_step.py). Static, like `decode_attn_unfused`: the
+    mixers ask at trace time, the trainer on the host for the gauge
+    `gen/state_step_fused`."""
+    if mesh is not None and mesh.size > 1:
+        return f"a mesh of {mesh.size} devices: the kernel is one chip's program"
+    if _interpret_mode():
+        return None
+    tiles = {"delta": (cfg.delta_head_dim, cfg.delta_head_dim), "ssm": (cfg.ssm_head_dim, cfg.ssm_state)}
+    for kind in sorted(set(cfg.mixers) & set(tiles)):
+        a, b = tiles[kind]
+        if a % 8 or b % 128:
+            return f"a {kind} layer's state tile [{a}, {b}] is not whole (8, 128) float32 tiles"
+    return None
+
+
+@functools.lru_cache(maxsize=None)
+def _warn_state_step_unfused(why: str) -> None:
+    """One warning per distinct reason (the cache is the log-once)."""
+    from trlx_tpu.utils import logging
+
+    logging.get_logger(__name__).warning(
+        "a decode step passes over the recurrent state in XLA ops, not in the fused kernel (%s)", why
+    )
+
+
+def _state_step_fused(cfg: "TransformerConfig", mesh) -> bool:
+    """A mixer's question at a decode step: the kernel, or (warned once) the XLA branch."""
+    why = state_step_unfused(cfg, mesh)
+    if why:
+        _warn_state_step_unfused(why)
+    return not why
+
+
 class Norm(nn.Module):
     cfg: TransformerConfig
 
@@ -1534,7 +1571,9 @@ class DeltaAttention(nn.Module):
       the cache holds (zeros) and leaves the state after its last position
       and its last `taps - 1` convolution inputs there.
     - a decode step (T == 1 with a cache), scope `kda_step`: one step of
-      the rule on the carried state (`kda_step`).
+      the rule on the carried state, as one pass over it in place
+      (ops/state_step.py) or, where `state_step_unfused` gives a reason,
+      `kda_step` on the layer's slice.
     The cache of a segment of such layers (`TransformerLM.init_cache`):
     `s` [layers, B, H, d, d] float32 and `u` [layers, B, taps - 1, 3 H d];
     a layer reads and writes its own row `ix`.
@@ -1584,24 +1623,35 @@ class DeltaAttention(nn.Module):
         # with a cache, this layer's state comes out of the carried array and
         # goes back into it under the scope of the form that runs, so that the
         # scope's seconds hold every pass over the state
-        with jax.named_scope("kda_step" if cache is not None and T == 1 else "kda_chunk"):
-            state = None
-            if cache is not None:
-                # taken out before anything else touches it: read through two
-                # fusions and written back in a third, the compiler copied the
-                # whole array twice a layer a decode step (the barrier keeps
-                # the slice a value of its own)
-                state = jax.lax.optimization_barrier(
-                    jax.lax.dynamic_index_in_dim(cache["s"], cache["ix"], 0, keepdims=False))
-            if cache is not None and T == 1:
-                o, state = kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], state)
+        step = cache is not None and T == 1
+        with jax.named_scope("kda_step" if step else "kda_chunk"):
+            if step and _state_step_fused(cfg, self.mesh):
+                # ONE pass (ops/state_step.py): the layer's tiles stream out of
+                # the carried array, are stepped in VMEM and stored in place
+                from trlx_tpu.ops.state_step import delta_state_step
+
+                o, carried = delta_state_step(cache["s"], cache["ix"], q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
                 o = o[:, None]
             else:
-                o, state = kda_chunked(*(x.astype(cfg.dtype) for x in (q, k, v)), g, beta, state)
+                state = None
+                if cache is not None:
+                    # taken out before anything else touches it: read through two
+                    # fusions and written back in a third, the compiler copied the
+                    # whole array twice a layer a decode step (the barrier keeps
+                    # the slice a value of its own)
+                    state = jax.lax.optimization_barrier(
+                        jax.lax.dynamic_index_in_dim(cache["s"], cache["ix"], 0, keepdims=False))
+                if step:
+                    o, state = kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], state)
+                    o = o[:, None]
+                else:
+                    o, state = kda_chunked(*(x.astype(cfg.dtype) for x in (q, k, v)), g, beta, state)
+                if cache is not None:
+                    carried = jax.lax.dynamic_update_slice(cache["s"], state[None], (cache["ix"], 0, 0, 0, 0))
             new_kv = None
             if cache is not None:
                 new_kv = {
-                    "s": jax.lax.dynamic_update_slice(cache["s"], state[None], (cache["ix"], 0, 0, 0, 0)),
+                    "s": carried,
                     "u": jax.lax.dynamic_update_slice(
                         cache["u"], window[None, :, T:].astype(cache["u"].dtype), (cache["ix"], 0, 0, 0)),
                 }
@@ -1731,7 +1781,9 @@ class Mamba2Mixer(nn.Module):
       the cache holds (zeros) and leaves the state after its last position
       and its last `taps - 1` convolution inputs there.
     - a decode step (T == 1 with a cache), scope `ssm_step`: one step on
-      the carried state (`ssm_step`).
+      the carried state, as one pass over it in place (ops/state_step.py)
+      or, where `state_step_unfused` gives a reason, `ssm_step` on the
+      layer's slice.
     The cache of the stack of such layers (`TransformerLM.init_cache`):
     `s` [layers, B, H, P, N] float32 and `u` [layers, B, taps - 1, H P + 2 G N];
     a layer reads and writes its own row `ix`.
@@ -1783,24 +1835,35 @@ class Mamba2Mixer(nn.Module):
             skip = self.param("D", nn.initializers.ones, (H,), jnp.float32)
 
         # with a cache, this layer's state comes out of the carried array and
-        # goes back into it under the scope of the form that runs. No barrier
-        # here: `ssm_step` reads the state as it came in both its uses, so the
-        # compiler updates the carried array in place and reads the row straight
-        # out of it, three passes over a row where the slice taken out behind
-        # a barrier made five (PERF.md section 6, PR 35)
-        with jax.named_scope("ssm_step" if cache is not None and T == 1 else "ssm_chunk"):
-            state = None
-            if cache is not None:
-                state = jax.lax.dynamic_index_in_dim(cache["s"], cache["ix"], 0, keepdims=False)
-            if cache is not None and T == 1:
-                o, state = ssm_step(xs[:, 0], Bm[:, 0], Cm[:, 0], dt[:, 0], a, state)
+        # goes back into it under the scope of the form that runs
+        step = cache is not None and T == 1
+        with jax.named_scope("ssm_step" if step else "ssm_chunk"):
+            if step and _state_step_fused(cfg, self.mesh):
+                # ONE pass (ops/state_step.py), as in `DeltaAttention`
+                from trlx_tpu.ops.state_step import ssm_state_step
+
+                o, carried = ssm_state_step(cache["s"], cache["ix"], xs[:, 0], Bm[:, 0], Cm[:, 0], dt[:, 0], a)
                 o = o[:, None]
             else:
-                o, state = ssm_chunked(xs, Bm, Cm, dt, a, state, cfg.ssm_chunk, cfg.dtype)
+                state = None
+                if cache is not None:
+                    # no barrier here: `ssm_step` reads the state as it came in both
+                    # its uses, so the compiler updates the carried array in place and
+                    # reads the row straight out of it, three passes over a row where
+                    # the slice taken out behind a barrier made five (PERF.md section
+                    # 6, PR 35)
+                    state = jax.lax.dynamic_index_in_dim(cache["s"], cache["ix"], 0, keepdims=False)
+                if step:
+                    o, state = ssm_step(xs[:, 0], Bm[:, 0], Cm[:, 0], dt[:, 0], a, state)
+                    o = o[:, None]
+                else:
+                    o, state = ssm_chunked(xs, Bm, Cm, dt, a, state, cfg.ssm_chunk, cfg.dtype)
+                if cache is not None:
+                    carried = jax.lax.dynamic_update_slice(cache["s"], state[None], (cache["ix"], 0, 0, 0, 0))
             new_kv = None
             if cache is not None:
                 new_kv = {
-                    "s": jax.lax.dynamic_update_slice(cache["s"], state[None], (cache["ix"], 0, 0, 0, 0)),
+                    "s": carried,
                     "u": jax.lax.dynamic_update_slice(
                         cache["u"], window[None, :, T:].astype(cache["u"].dtype), (cache["ix"], 0, 0, 0)),
                 }

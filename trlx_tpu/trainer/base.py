@@ -49,6 +49,7 @@ from trlx_tpu.models.transformer import (
     TransformerConfig,
     TransformerLM,
     decode_weights_stationary,
+    state_step_unfused,
 )
 from trlx_tpu.ops import trainable_view
 from trlx_tpu.parallel import (
@@ -770,7 +771,11 @@ class TPUBaseTrainer(BaseRLTrainer):
         `tokens_wait` span's counts. `gen/decode_weights_stationary`: 1
         where its decode steps multiply with the kernel shards each chip
         holds and move the activations (a mesh whose `fsdp` axis shards
-        the kernels), 0 where no axis does or GSPMD lays the step out."""
+        the kernels), 0 where no axis does or GSPMD lays the step out.
+        `gen/state_step_fused`, of a model with delta-rule or state-space
+        layers alone: 1 where a decode step passes over their recurrent
+        state once, in the kernel of ops/state_step.py, 0 where it runs
+        the XLA branch (the mixers warn with the reason)."""
         settings, (rows, prompt), _ = key
         virtual = 0
         if "prompt" in self.params:
@@ -788,6 +793,10 @@ class TPUBaseTrainer(BaseRLTrainer):
             "gen/decode_attn_fused": int(cells is not None),
             "gen/decode_weights_stationary": int(stationary),
         }
+        if self._lm().cfg.hybrid:
+            gauge["gen/state_step_fused"] = int(
+                settings.max_new_tokens > 1 and state_step_unfused(self._lm().cfg, self._lm().mesh) is None
+            )
         self.obs.gauge(**gauge)
         self._tracker_log(gauge, step=self.iter_count)
 
