@@ -622,6 +622,32 @@ def test_mosaic_compiles_the_flash_kernels_at_keys_192_values_128(one_chip, monk
         jax.config.update("jax_enable_compilation_cache", True)
 
 
+def test_mosaic_compiles_the_flash_kernels_at_head_64_over_grouped_heads(one_chip, monkeypatch):
+    """The causal kernels at `lfm2-24b-a2b`'s attention: 32 query over 8 key-value
+    heads of 64, forward and backward at a train step's 8 x 1024, and the prefill's
+    896 queries at offset 0 into a cache of 1024 slots."""
+    from trlx_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        shape = lambda *s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+
+        def step(q, k, v, mask):
+            return jax.grad(lambda q, k, v: fa.flash_attention(q, k, v, mask, True, 0.125)
+                            .astype(jnp.float32).sum(), (0, 1, 2))(q, k, v)
+
+        text = jax.jit(step).lower(shape(8, 32, 1024, 64), shape(8, 8, 1024, 64), shape(8, 8, 1024, 64),
+                                   shape(8, 1024, dt=jnp.int32)).compile().as_text()
+        assert all(name in text for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"))
+        prefill = jax.jit(lambda q, k, v, mask: fa.flash_attention(q, k, v, mask, True, 0.125, q_offset=0))
+        text = prefill.lower(shape(32, 32, 896, 64), shape(32, 8, 1024, 64), shape(32, 8, 1024, 64),
+                             shape(32, 1024, dt=jnp.int32)).compile().as_text()
+        assert "flash_fwd" in text
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+
+
 def test_mosaic_compiles_the_int8_decode_kernel_at_the_cells_shapes(topo, one_chip, monkeypatch):
     """The fused decode kernel over the int8 cache, compiled by Mosaic at
     the shapes of the three Pythia cells: one chip at 16 heads x 1024 and

@@ -222,10 +222,11 @@ def config_from_hf(hf_config: Any, dtype=None, param_dtype=None) -> TransformerC
         )
     if (getattr(hf_config, "kv_lora_rank", None) or getattr(hf_config, "n_routed_experts", None)
             or getattr(hf_config, "linear_attn_config", None)
-            or getattr(hf_config, "hybrid_override_pattern", None)):
+            or getattr(hf_config, "hybrid_override_pattern", None)
+            or getattr(hf_config, "conv_L_cache", None)):
         raise NotImplementedError(
             f"no loader for model_type {mt!r}: the program runs latent attention, delta-rule "
-            "(KDA) and state-space (Mamba-2) layers, layers of one sub-layer, routed experts "
+            "(KDA), state-space (Mamba-2) and short-convolution layers, layers of one sub-layer, routed experts "
             "and several residual streams (TransformerConfig's kv_lora_rank, mixer_layers, "
             "ffn_layers, n_routed_experts, residual_streams) from random weights only; a "
             "checkpoint's tensor names would have to be mapped onto the stacks of "

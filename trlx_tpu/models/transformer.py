@@ -207,6 +207,13 @@ class TransformerConfig:
     moe_latent_size: Optional[int] = None
     moe_gated: bool = True
     moe_shared_intermediate_size: Optional[int] = None
+    # a further entry of `mixer_layers`: "conv" (a gated short convolution,
+    # `ShortConv`: B, C and u from one projection, B u through a causal
+    # depthwise convolution of `conv_taps` taps, times C, projected back;
+    # its decode state is a row's last `conv_taps - 1` products B u)
+    conv_taps: int = 3
+    # per-head RMSNorms of q and k over a head's channels, before rotary
+    qk_norm: bool = False
 
     @property
     def latent(self) -> bool:
@@ -231,8 +238,9 @@ class TransformerConfig:
 
     @property
     def hybrid(self) -> bool:
-        """Some layer keeps a recurrent state where the others keep a cache."""
-        return "delta" in self.mixers or "ssm" in self.mixers
+        """Some layer keeps a recurrent state (or a convolution's last
+        inputs alone) where the others keep a cache."""
+        return "delta" in self.mixers or "ssm" in self.mixers or "conv" in self.mixers
 
     @property
     def routed(self) -> bool:
@@ -268,12 +276,14 @@ class TransformerConfig:
         its length: in a delta-rule layer a [heads, d_k, d_v] state and the
         last `delta_conv - 1` inputs of the three convolutions; in a
         state-space layer a [heads, head_dim, ssm_state] state and the last
-        `ssm_conv - 1` inputs of its one convolution (`ssm_conv_width`)."""
+        `ssm_conv - 1` inputs of its one convolution (`ssm_conv_width`); in
+        a short-convolution layer the last `conv_taps - 1` inputs alone."""
         width = self.delta_heads * self.delta_head_dim
         return (
             self.mixers.count("delta") * (width * self.delta_head_dim + (self.delta_conv - 1) * 3 * width)
             + self.mixers.count("ssm") * (self.ssm_heads * self.ssm_head_dim * self.ssm_state
-                                          + (self.ssm_conv - 1) * self.ssm_conv_width))
+                                          + (self.ssm_conv - 1) * self.ssm_conv_width)
+            + self.mixers.count("conv") * (self.conv_taps - 1) * self.hidden_size)
 
     @property
     def ssm_conv_width(self) -> int:
@@ -322,28 +332,40 @@ class TransformerConfig:
         wrong answer later."""
         if self.mixer_layers is not None:
             base = "latent" if self.latent else "softmax"
-            if len(self.mixer_layers) != self.n_layer or set(self.mixer_layers) - {base, "delta", "ssm", "none"}:
+            if (len(self.mixer_layers) != self.n_layer
+                    or set(self.mixer_layers) - {base, "delta", "ssm", "conv", "none"}):
                 raise ValueError(
                     f"mixer_layers names one of {base!r} (the attention the other keys "
-                    f"describe), 'delta', 'ssm' or 'none' for each of the {self.n_layer} layers"
+                    f"describe), 'delta', 'ssm', 'conv' or 'none' for each of the {self.n_layer} layers"
                 )
+        if self.qk_norm and self.latent:
+            raise NotImplementedError("qk_norm is per-head attention's: latent attention has norms of its own")
         if not self.beyond_dense:
             return
         def no(what):
             raise NotImplementedError(
                 f"{what} is not implemented for a model with latent attention, "
-                "delta-rule (KDA) or state-space (Mamba-2) layers, layers of one sub-layer, "
-                "routed experts or several residual streams"
+                "delta-rule (KDA), state-space (Mamba-2) or short-convolution layers, layers of "
+                "one sub-layer, routed experts or several residual streams"
             )
         if (self.latent or self.hybrid) and self.kv_cache_quant is not None:
             no(f"kv_cache_quant={self.kv_cache_quant!r} (an int8 latent cache, or a cache "
-               "beside the float32 state of delta-rule or state-space layers)")
+               "beside the float32 state of delta-rule or state-space layers or the "
+               "convolution inputs of short-convolution layers)")
         if self.attention_impl == "ring":
             no("attention_impl='ring'")
         if self.latent and (self.pos_embed not in ("rotary", "none") or self.local_window is not None
                             or self.n_kv_head != self.n_head or self.attn_scale is not None):
             no("latent attention with learned or alibi positions, with windows, grouped "
                "heads or a set attn_scale")
+        if "conv" in self.mixers:
+            if self.conv_taps < 2:
+                raise ValueError("short-convolution layers need conv_taps >= 2")
+            if set(self.mixers) - {"conv", "softmax"}:
+                no("short-convolution layers beside latent attention, delta-rule or "
+                   "state-space layers, or layers without a mixer")
+            if len(set(self.mixers[: self.first_k_dense])) > 1:
+                no("leading dense layers of more than one mixer")
         if "delta" in self.mixers:
             if not (self.delta_heads > 0 and self.delta_head_dim > 0 and self.delta_conv >= 2):
                 raise ValueError("delta-rule layers need delta_heads, delta_head_dim and delta_conv >= 2")
@@ -395,8 +417,10 @@ class TransformerConfig:
                 raise ValueError(
                     f"layer {layer}: mixer {mixer!r} with feed-forward {ffn!r} (a layer has a "
                     "mixer, a feed-forward or both; a state-space layer is its mixer alone)")
-            if (mixer == "delta" and ffn == "none") or (layer < self.first_k_dense and "none" in (mixer, ffn)):
-                no("a delta-rule layer without a feed-forward, or a leading dense layer of one sub-layer")
+            if (mixer in ("delta", "conv") and ffn == "none") or (
+                    layer < self.first_k_dense and "none" in (mixer, ffn)):
+                no("a delta-rule or short-convolution layer without a feed-forward, or a leading "
+                   "dense layer of one sub-layer")
         if self.residual_streams > 1:
             no("layers of one sub-layer under several residual streams")
 
@@ -406,10 +430,11 @@ class TransformerConfig:
 
 # the stacks of a parameter tree, in the order a tree without delta-rule
 # layers has always had them; a layer's kind (mixer, feed-forward) names
-# its stack, so a stack holds layers equal in both. The last four hold
-# layers of ONE sub-layer: a state-space mixer, attention, routed experts,
-# a dense MLP
-STACKS = ("dense_blocks", "blocks", "delta_blocks", "ssm_blocks", "attn_blocks", "moe_blocks", "mlp_blocks")
+# its stack, so a stack holds layers equal in both. Four hold layers of
+# ONE sub-layer: a state-space mixer, attention, routed experts, a dense
+# MLP; the last, short-convolution layers above the leading ones
+STACKS = ("dense_blocks", "blocks", "delta_blocks", "ssm_blocks", "attn_blocks", "moe_blocks", "mlp_blocks",
+          "conv_blocks")
 
 
 def _stack_of(cfg: TransformerConfig, layer: int) -> str:
@@ -420,7 +445,7 @@ def _stack_of(cfg: TransformerConfig, layer: int) -> str:
         return "ssm_blocks" if mixer == "ssm" else "attn_blocks"
     if mixer == "none":
         return "moe_blocks" if ffn == "routed" else "mlp_blocks"
-    return "delta_blocks" if mixer == "delta" else "blocks"
+    return {"delta": "delta_blocks", "conv": "conv_blocks"}.get(mixer, "blocks")
 
 
 @functools.lru_cache(maxsize=None)
@@ -701,6 +726,11 @@ class Attention(nn.Module):
         q = dense(features=(H, D), name="q")(x)
         k = dense(features=(Hkv, D), name="k")(x)
         v = dense(features=(Hkv, D), name="v")(x)
+        if cfg.qk_norm:
+            # each head's q and k over its D channels, one learned weight each
+            q, k = (_rms_norm(a, self.param(name, nn.initializers.ones, (D,), cfg.param_dtype),
+                              cfg.layer_norm_epsilon).astype(a.dtype)
+                    for a, name in ((q, "q_norm"), (k, "k_norm")))
 
         if cfg.pos_embed == "rotary":
             cos, sin = rope_frequencies(cfg, positions)
@@ -1891,6 +1921,70 @@ class Mamba2Mixer(nn.Module):
         return proj(out), new_kv
 
 
+class ShortConv(nn.Module):
+    """Gated short convolution over d = hidden_size channels, no bias:
+
+        [B | C | u] = x W_in                          (d | d | d, in this order)
+        v = B u
+        c_t = sum_{i=0..taps-1} w_i v_{t-(taps-1)+i}   causal depthwise, zeros before the start
+        y = (C c) W_out
+
+    A position whose mask is 0 changes nothing: its input x is zeroed before
+    W_in, so B, C and u, and with them v and y, are 0 there, and a
+    left-padded row reaches its first token with an empty window.
+
+    Two forms by the shape of the call, each the whole mixer under a scope
+    of its own, both projections (and the zeroing) under `conv_proj` inside
+    it: the compiler fuses B u into the projection in and C c into the one
+    out, so the pass between them has no instructions of its own to time.
+    - teacher-forced and prefill (T > 1), scope `conv_mix`: the
+      convolution over the sequence. A prefill starts from the window the
+      cache holds (zeros) and leaves its last `taps - 1` products v there.
+    - a decode step (T == 1 with a cache), scope `conv_step`: the same on
+      the carried `taps - 1` products and the new one, and the window moved
+      on by one.
+    The cache of a segment of such layers (`TransformerLM.init_cache`): `u`
+    [layers, B, taps - 1, d] in the compute dtype; a layer reads and writes
+    its own row `ix`.
+    """
+
+    cfg: TransformerConfig
+    mesh: Any = None
+
+    @nn.compact
+    def __call__(self, x, attn_bias, positions, cache=None, key_mask=None, ring_mesh=None):
+        cfg = self.cfg
+        B, T, E = x.shape
+        taps = cfg.conv_taps
+        dense = partial(QDense, dtype=cfg.dtype, param_dtype=cfg.param_dtype, use_bias=False)
+        step = cache is not None and T == 1
+        with jax.named_scope("conv_step" if step else "conv_mix"):
+            with jax.named_scope("conv_proj"):
+                if key_mask is not None:
+                    live = key_mask if cache is None else jax.lax.dynamic_slice_in_dim(
+                        key_mask, cache["index"], T, axis=1)  # the slots this call writes
+                    x = x * live[..., None].astype(x.dtype)
+                bcu = dense(features=3 * E, kernel_init=nn.initializers.normal(0.02), name="in_proj")(x)
+            w = self.param("conv_w", _conv_tap_init, (taps, E), jnp.float32)
+            b, c, u = (bcu[..., i * E : (i + 1) * E].astype(jnp.float32) for i in range(3))
+            v = (b * u).astype(cfg.dtype)
+            if cache is None:
+                before = jnp.zeros((B, taps - 1, E), v.dtype)
+            else:
+                before = jax.lax.dynamic_index_in_dim(cache["u"], cache["ix"], 0, keepdims=False).astype(v.dtype)
+            window = jnp.concatenate([before, v], axis=1)  # [B, taps - 1 + T, d]
+            mixed = sum(w[i] * window[:, i : i + T].astype(jnp.float32) for i in range(taps))
+            out = (c * mixed).astype(cfg.dtype)
+            new_kv = None
+            if cache is not None:
+                new_kv = {"u": jax.lax.dynamic_update_slice(
+                    cache["u"], window[None, :, T:].astype(cache["u"].dtype), (cache["ix"], 0, 0, 0))}
+            with jax.named_scope("conv_proj"):
+                y = dense(features=E, kernel_init=nn.initializers.normal(0.02 / math.sqrt(2 * cfg.n_layer)),
+                          name="out_proj")(out)
+        return y, new_kv
+
+
 @jax.custom_vjp
 def _rows_by_assignment(x, order, inverse):
     """x [N, E] -> [N * k, E]: row r is the token of assignment `order[r]`
@@ -2203,7 +2297,7 @@ class Block(nn.Module):
     cfg: TransformerConfig
     mesh: Any = None  # forwarded to Attention
     kind: str = "dense"  # "dense" | "routed" | "none"
-    mixer: str = ""  # "softmax" | "latent" | "delta" | "ssm" | "none"; "": the attention cfg describes
+    mixer: str = ""  # "softmax" | "latent" | "delta" | "ssm" | "conv" | "none"; "": the attention cfg describes
 
     @nn.compact
     def __call__(
@@ -2219,6 +2313,8 @@ class Block(nn.Module):
         mixer = self.mixer or ("latent" if cfg.latent else "softmax")
         if mixer == "ssm":
             attention = Mamba2Mixer(cfg, self.mesh, name="ssm")
+        elif mixer == "conv":
+            attention = ShortConv(cfg, self.mesh, name="conv")
         elif mixer != "none":
             attention = {"softmax": Attention, "latent": LatentAttention,
                          "delta": DeltaAttention}[mixer](cfg, self.mesh, name="attn")
@@ -2804,31 +2900,34 @@ class TransformerLM:
             new_cache["index"] = cache["index"] + positions.shape[1]
             return h, new_cache, _fold_layer_stats(stats)
 
-        if cache is not None and blk.mixer in ("delta", "ssm"):
+        if cache is not None and blk.mixer in ("delta", "ssm", "conv"):
             # recurrent state and convolution inputs, this stack's rows
             # (`kda_s` [layers, B, H, d, d] float32, `kda_u` [layers, B,
             # taps - 1, 3 H d]; of state-space layers `ssm_s` [layers, B,
-            # H, P, N] float32, `ssm_u` [layers, B, taps - 1, H P + 2 G N]):
-            # carried whole through the scan, as the latent rows below
-            # are; a layer reads and writes its own row
-            prefix = "kda" if blk.mixer == "delta" else "ssm"
-            s_key, u_key = f"{prefix}_s{lead}", f"{prefix}_u{lead}"
+            # H, P, N] float32, `ssm_u` [layers, B, taps - 1, H P + 2 G N];
+            # of short-convolution layers the inputs alone, `conv_u`
+            # [layers, B, taps - 1, d]): carried whole through the scan, as
+            # the latent rows below are; a layer reads and writes its own row
+            prefix = {"delta": "kda", "ssm": "ssm", "conv": "conv"}[blk.mixer]
+            parts = ("u",) if blk.mixer == "conv" else ("s", "u")
+            keys = tuple(f"{prefix}_{part}{lead}" for part in parts)
 
             xs, params_of = layers_of({"ix": layer_stacks(self.cfg)[layer_offset][1] + jnp.arange(n)})
 
-            def delta_body(carry, layer):
-                hidden, s, u = carry
-                layer_cache = {"s": s, "u": u, "ix": layer["ix"], "index": cache["index"]}
+            def state_body(carry, layer):
+                hidden, held = carry[0], carry[1:]
+                layer_cache = dict(zip(parts, held), ix=layer["ix"], index=cache["index"])
                 out, new_kv, stats = blk.apply(
                     {"params": params_of(layer)}, hidden, attn_bias, positions, layer_cache,
                     key_mask, ring_mesh,
                 )
-                return (out, new_kv["s"], new_kv["u"]), stats
+                return (out,) + tuple(new_kv[part] for part in parts), stats
 
-            (h, s, u), stats = jax.lax.scan(
-                wrap_remat(delta_body, remat), (h, cache[s_key], cache[u_key]), xs)
+            carry, stats = jax.lax.scan(
+                wrap_remat(state_body, remat), (h,) + tuple(cache[key] for key in keys), xs)
+            h = carry[0]
             new_cache = {k: v for k, v in cache.items() if k != "static_index"}
-            new_cache.update({s_key: s, u_key: u, "index": cache["index"] + positions.shape[1]})
+            new_cache.update(zip(keys, carry[1:]), index=cache["index"] + positions.shape[1])
             return h, new_cache, _fold_layer_stats(stats)
 
         if cache is not None and "c" in cache:
@@ -3492,13 +3591,18 @@ class TransformerLM:
             # layers alone) and, for delta-rule and state-space layers, a
             # float32 state and the convolutions' last inputs, which do
             # not (`kda_s`, `kda_u`, `_lead` for the leading dense layers;
-            # `ssm_s`, `ssm_u`). A layer without a mixer keeps nothing
+            # `ssm_s`, `ssm_u`; of short-convolution layers the inputs
+            # alone, `conv_u`, `conv_u_lead`). A layer without a mixer
+            # keeps nothing
             cache = {"index": jnp.int32(0), "static_index": 0, "key_mask": mask}
             H, D, width = cfg.delta_heads, cfg.delta_head_dim, 3 * cfg.delta_heads * cfg.delta_head_dim
             for name, block in self.blocks.items():
                 layers = len(stack_layers(cfg, name))
                 lead = "_lead" if name == "dense_blocks" else ""
-                if block.mixer == "delta":
+                if block.mixer == "conv":
+                    cache["conv_u" + lead] = jnp.zeros((layers, batch, cfg.conv_taps - 1, cfg.hidden_size),
+                                                       cfg.dtype)
+                elif block.mixer == "delta":
                     cache["kda_s" + lead] = jnp.zeros((layers, batch, H, D, D), jnp.float32)
                     cache["kda_u" + lead] = jnp.zeros((layers, batch, cfg.delta_conv - 1, width), cfg.dtype)
                 elif block.mixer == "ssm":

@@ -793,7 +793,7 @@ class TPUBaseTrainer(BaseRLTrainer):
             "gen/decode_attn_fused": int(cells is not None),
             "gen/decode_weights_stationary": int(stationary),
         }
-        if self._lm().cfg.hybrid:
+        if {"delta", "ssm"} & set(self._lm().cfg.mixers):
             gauge["gen/state_step_fused"] = int(
                 settings.max_new_tokens > 1 and state_step_unfused(self._lm().cfg, self._lm().mesh) is None
             )
@@ -1656,8 +1656,10 @@ class TPUBaseTrainer(BaseRLTrainer):
             if "delta" in cfg.mixers:
                 gauges["model/delta_layers"] = cfg.mixers.count("delta")
                 gauges["model/latent_layers"] = cfg.cache_layers
-            if "ssm" in cfg.mixers:  # a layer there is ONE sub-layer: the three kinds by count
-                gauges["model/ssm_layers"] = cfg.mixers.count("ssm")
+            kinds = {f"model/{kind}_layers": cfg.mixers.count(kind)
+                     for kind in ("ssm", "conv") if kind in cfg.mixers}
+            if kinds:  # the kinds of layer by count
+                gauges.update(kinds)
                 gauges["model/cache_layers"] = cfg.cache_layers
                 gauges["model/routed_layers"] = cfg.ffns.count("routed")
             if cfg.hybrid:

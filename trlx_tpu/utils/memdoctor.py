@@ -1158,7 +1158,7 @@ def _mixers(tcfg) -> list:
 def _caching_layers(tcfg, L: int) -> int:
     """Layers whose cache grows with the sequence: those with attention."""
     mixers = _mixers(tcfg)
-    return L - sum(m in ("delta", "ssm", "none") for m in mixers)
+    return L - sum(m in ("delta", "ssm", "conv", "none") for m in mixers)
 
 
 def recurrent_state_bytes(tcfg, rows: int, conv_size: int = 2) -> int:
@@ -1167,19 +1167,24 @@ def recurrent_state_bytes(tcfg, rows: int, conv_size: int = 2) -> int:
     inputs of their convolutions (compute dtype): a delta-rule layer [heads,
     d, d] and `delta_conv - 1` inputs of three convolutions, a state-space
     layer [heads, head_dim, ssm_state] and `ssm_conv - 1` inputs of one (x',
-    B and C wide). 0 for a model without such layers."""
+    B and C wide), a short-convolution layer `conv_taps - 1` inputs of one
+    (hidden_size wide) and no state. 0 for a model without such layers."""
     H, D = int(_field(tcfg, "delta_heads", 0)), int(_field(tcfg, "delta_head_dim", 0))
     conv = (int(_field(tcfg, "delta_conv", 4)) - 1) * 3 * H * D
     total = _mixers(tcfg).count("delta") * rows * (4 * H * D * D + conv_size * conv)
     sh, sp, sn = (int(_field(tcfg, k, 0)) for k in ("ssm_heads", "ssm_head_dim", "ssm_state"))
     tail = (int(_field(tcfg, "ssm_conv", 4)) - 1) * (sh * sp + 2 * int(_field(tcfg, "ssm_groups", 1)) * sn)
-    return total + _mixers(tcfg).count("ssm") * rows * (4 * sh * sp * sn + conv_size * tail)
+    total += _mixers(tcfg).count("ssm") * rows * (4 * sh * sp * sn + conv_size * tail)
+    short = (int(_field(tcfg, "conv_taps", 3)) - 1) * int(_field(tcfg, "hidden_size", 0))
+    return total + _mixers(tcfg).count("conv") * rows * conv_size * short
 
 
 def _recurrent_note(tcfg) -> str:
-    kinds = [f"{_mixers(tcfg).count(m)} {name}" for m, name in (("delta", "delta-rule"), ("ssm", "state-space"))
+    kinds = [f"{_mixers(tcfg).count(m)} {name}"
+             for m, name in (("delta", "delta-rule"), ("ssm", "state-space"), ("conv", "short-convolution"))
              if m in _mixers(tcfg)]
-    return " and ".join(kinds) + " layers: float32 state and convolution inputs a row, whatever its length"
+    state = "float32 state and " if {"delta", "ssm"} & set(_mixers(tcfg)) else ""
+    return " and ".join(kinds) + f" layers: {state}convolution inputs a row, whatever its length"
 
 
 def _family_param_count(tcfg: Dict[str, Any]) -> int:
@@ -1200,6 +1205,7 @@ def _family_param_count(tcfg: Dict[str, Any]) -> int:
     else:
         D = int(tcfg.get("head_dim") or E // max(H, 1))
         attn = 2 * E * D * (H + int(tcfg.get("n_kv_head") or H))  # q and o; k and v
+        attn += 2 * D if tcfg.get("qk_norm") else 0  # a weight a head's channel, q's and k's
     n = int(tcfg.get("residual_streams", 1))
     mix = 2 * (n * E * (n * n + 2 * n) + 3 + 2 * n + n * n) if n > 1 else 0
     mixers = list(tcfg.get("mixer_layers") or [None] * L)
@@ -1216,6 +1222,9 @@ def _family_param_count(tcfg: Dict[str, Any]) -> int:
         conv = w + 2 * int(tcfg.get("ssm_groups", 1)) * int(tcfg["ssm_state"])
         by_mixer["ssm"] = (E * (w + conv + sh) + w * E + conv * (int(tcfg.get("ssm_conv", 4)) + 1)
                            + 3 * sh + w)
+    if "conv" in mixers:
+        # W_in (B, C and u) and W_out; the taps
+        by_mixer["conv"] = 4 * E * E + int(tcfg.get("conv_taps", 3)) * E
     attn = [by_mixer[m] for m in mixers]
     published = int(tcfg.get("n_routed_experts", 0))
     lead = int(tcfg.get("first_k_dense", 0)) if published else L
@@ -1233,7 +1242,8 @@ def _family_param_count(tcfg: Dict[str, Any]) -> int:
     ffns = list(tcfg.get("ffn_layers") or ["full"] * L)
     feed = [0 if kind == "none" else size for kind, size in zip(ffns, feed)]
     norms = sum((m != "none") + (f != "none") for m, f in zip(mixers, ffns)) * E
-    return 2 * V * E + sum(attn) + sum(feed) + norms + L * mix + E
+    heads = 1 if tcfg.get("tie_word_embeddings", True) else 2  # a tied head is the embedding
+    return heads * V * E + sum(attn) + sum(feed) + norms + L * mix + E
 
 
 def analytic_plan(
