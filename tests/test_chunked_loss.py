@@ -126,7 +126,7 @@ def test_sft_loss_from_hidden_matches():
         )
 
 
-def _make_ppo_trainer(num_layers_unfrozen=-1, **train_kw):
+def _make_ppo_trainer(num_layers_unfrozen=-1, tie_word_embeddings=True, **train_kw):
     from trlx_tpu.data.default_configs import default_ppo_config
     from trlx_tpu.utils.loading import get_trainer
 
@@ -140,7 +140,8 @@ def _make_ppo_trainer(num_layers_unfrozen=-1, **train_kw):
             num_layers_unfrozen=num_layers_unfrozen,
             model_extra_configs={
                 "transformer": dict(
-                    hidden_size=16, n_layer=2, n_head=2, n_positions=64
+                    hidden_size=16, n_layer=2, n_head=2, n_positions=64,
+                    tie_word_embeddings=tie_word_embeddings,
                 )
             },
         ),
@@ -307,3 +308,141 @@ def test_sft_grads_dtype_bf16_with_accumulation(tmp_path):
         np.isfinite(np.asarray(x, np.float32)).all()
         for x in jax.tree_util.tree_leaves(trainer.params)
     )
+
+
+# -- an untied head split over fsdp: gathered once per call -----------------
+
+_HB, _HT, _HE, _HV = 8, 37, 64, 96  # rows over fsdp 4; T not whole chunks
+
+
+def _head_mesh(axes):
+    from trlx_tpu.parallel import make_mesh
+
+    return make_mesh({"dp": 1, **axes}, devices=jax.devices()[:4])
+
+
+def _head_case(dtype, seed=0):
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    mesh = _head_mesh({"fsdp": 4})
+    rng = np.random.default_rng(seed)
+    kernel = jax.device_put(
+        jnp.asarray(rng.normal(size=(_HE, _HV)) * 0.2, dtype),
+        NamedSharding(mesh, P("fsdp", None)))
+    hidden = jax.device_put(
+        jnp.asarray(rng.normal(size=(_HB, _HT, _HE)), dtype),
+        NamedSharding(mesh, P(("dp", "fsdp"), None, None)))
+    labels = jnp.asarray(rng.integers(0, _HV, (_HB, _HT)), jnp.int32)
+    weights = jnp.asarray(rng.normal(size=(_HB, _HT)), jnp.float32)
+
+    def loss(k, h, on_mesh):
+        proj = logit_projection({"lm_head": {"kernel": k}}, mesh if on_mesh else None)
+        return (chunked_logprobs(proj, h, labels, 8) * weights).sum()
+
+    return mesh, kernel, hidden, loss
+
+
+@pytest.mark.parametrize("ring", [None, (0, 1, 3, 2)], ids=["axis_order", "ring_order"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_gathered_head_matches_the_closure_form(dtype, ring, monkeypatch):
+    """On fsdp 4 with 8 logit chunks the head gathered once gives the
+    closure form's log-probabilities and gradients for the head and the
+    hidden states: float32 to rounding, bfloat16 to its own precision
+    (the chips now sum a chunk's head gradient in float32 before they
+    reduce it, where the closure form reduced each chunk's bf16 part).
+    With the head's blocks in a ring order (how a 2x2 TPU host lists
+    them) every chip still gets its own block of the gradient."""
+    import trlx_tpu.ops.common as oc
+
+    monkeypatch.setattr(oc, "_fsdp_ring", lambda mesh: ring)
+    _, kernel, hidden, loss = _head_case(dtype)
+    closure = jax.jit(jax.value_and_grad(lambda k, h: loss(k, h, False), argnums=(0, 1)))
+    once = jax.jit(jax.value_and_grad(lambda k, h: loss(k, h, True), argnums=(0, 1)))
+    want, got = closure(kernel, hidden), once(kernel, hidden)
+    assert got[1][0].sharding.spec == kernel.sharding.spec
+    for w, g in zip(jax.tree_util.tree_leaves(want), jax.tree_util.tree_leaves(got)):
+        w, g = np.asarray(w, np.float32), np.asarray(g, np.float32)
+        if dtype == jnp.float32:
+            np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-5)
+        else:
+            assert np.linalg.norm(g - w) <= 1e-2 * np.linalg.norm(w)
+
+
+def _collectives_by_computation(hlo: str):
+    """{computation: [(op, dims)]} of every all-gather, all-reduce and
+    reduce-scatter in optimised HLO text, and the while bodies' names."""
+    import re
+
+    found, bodies, comp = {}, set(), None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+        if head:
+            comp = head.group(1)
+        bodies.update(re.findall(r"body=%?([\w.\-]+)", line))
+        m = re.search(r"= \(?([a-z]+\d*\[[\d,]*\])[^=]*? (all-gather|all-reduce|reduce-scatter)(?:-start)?\(", line)
+        if m:
+            dims = tuple(int(d) for d in re.search(r"\[([\d,]*)\]", m.group(1)).group(1).split(",") if d)
+            found.setdefault(comp, []).append((m.group(2), dims))
+    return found, bodies
+
+
+def test_gathered_head_moves_the_head_once_per_call():
+    """In the compiled value_and_grad no collective of the head's shape
+    (whole, or a chip's `E / 4` rows of it, either way round) sits in a
+    while body: one all-gather of it and one reduce-scatter of its
+    gradient, per call. The closure form all-reduces the gradient inside
+    the chunk loop (XLA:CPU hoists its gather, XLA:TPU does not)."""
+    _, kernel, hidden, loss = _head_case(jnp.float32)
+    head = {(_HE, _HV), (_HE // 4, _HV), (_HV, _HE), (_HV, _HE // 4)}
+
+    def moved(on_mesh):
+        fn = jax.jit(jax.value_and_grad(lambda k, h: loss(k, h, on_mesh), argnums=(0, 1)))
+        found, bodies = _collectives_by_computation(fn.lower(kernel, hidden).compile().as_text())
+        in_loops = [c for comp in bodies for c in found.get(comp, []) if c[1] in head]
+        return in_loops, sorted(c for cs in found.values() for c in cs if c[1] in head)
+
+    in_loops, every = moved(True)
+    assert in_loops == []
+    assert every == [("all-gather", (_HE, _HV)), ("reduce-scatter", (_HE // 4, _HV))]
+    in_loops, _ = moved(False)
+    assert "all-reduce" in {op for op, _ in in_loops}
+
+
+@pytest.mark.parametrize("axes, rows, tied, want", [
+    (None, 8, False, False),
+    ({"fsdp": 1}, 8, False, False),
+    ({"fsdp": 2, "tp": 2}, 8, False, False),
+    ({"fsdp": 2, "pp": 2}, 8, False, False),
+    ({"fsdp": 2, "sp": 2}, 8, False, False),
+    ({"fsdp": 4}, 8, True, False),
+    ({"fsdp": 4}, 6, False, False),
+    ({"fsdp": 4}, 8, False, True),
+    ({"dp": 2, "fsdp": 2}, 8, False, True),
+], ids=["no_mesh", "one_device", "tp2", "pp2", "sp2", "tied", "rows6", "fsdp4", "dp2xfsdp2"])
+def test_head_gathered_once_predicate(axes, rows, tied, want):
+    """Only an untied head on a mesh whose fsdp axis splits E, with no tp,
+    pp or sp axis and rows that divide over dp x fsdp, takes the form."""
+    from trlx_tpu.ops.common import head_gathered_once
+
+    mesh = None if axes is None else _head_mesh(axes)
+    params = {"embed": {"wte": jnp.zeros((_HV, _HE))}}
+    if not tied:
+        params["lm_head"] = {"kernel": jnp.zeros((_HE, _HV))}
+    assert head_gathered_once(logit_projection(params, mesh), rows) is want
+
+
+@pytest.mark.parametrize("axes", [{"dp": 1}, {"dp": 8}], ids=["one_device", "dp8"])
+def test_ppo_loss_off_fsdp_lowers_to_the_closure_form(axes):
+    """Wherever no fsdp axis splits the head, a PPO loss over an untied
+    head lowers to the text it lowers to with the closure projection."""
+    trainer = _make_ppo_trainer(1, tie_word_embeddings=False, logit_chunks=3, mesh=axes)
+    batch = _fake_rollout_batch(trainer)
+
+    def lowered():
+        return jax.jit(jax.value_and_grad(trainer.loss, has_aux=True)).lower(
+            trainer.params, batch).as_text()
+
+    assert "lm_head" in trainer.params["base"]
+    text = lowered()
+    trainer.model.logit_project_fn = lambda params: logit_projection(params["base"])
+    assert lowered() == text

@@ -1025,6 +1025,28 @@ def test_backward_depth_gauges_once_per_built_step(faultfree_run, tmp_path):
     assert [(r["model/layers"], r["model/backward_layers"]) for r in built] == [(2, 1)] * 2
 
 
+def test_head_gathered_once_gauge_follows_the_mesh(faultfree_run, tmp_path):
+    """`model/head_gathered_once` lands in the row of `model/layers`: 0
+    for the shared run (a tied head on a data-parallel mesh), 1 for an
+    untied head with logit chunks on a mesh whose fsdp axis splits it."""
+    from trlx_tpu.utils.loading import get_trainer
+
+    _, ckpt_dir = faultfree_run
+    rows = list(iter_rows(os.path.join(ckpt_dir, "flight")))
+    assert {r["model/head_gathered_once"] for r in rows
+            if r["kind"] == "gauge" and "model/layers" in r} == {0}
+
+    mesh_dir = str(tmp_path / "ckpts")
+    config = _tiny_ppo_config(mesh_dir).evolve(
+        train=dict(mesh={"dp": 2, "fsdp": 4}, logit_chunks=2))
+    config.model.model_extra_configs["transformer"]["tie_word_embeddings"] = False
+    trainer = get_trainer(config.train.trainer)(config=config)
+    trainer.make_fused_train_steps()
+    built = [r for r in iter_rows(os.path.join(mesh_dir, "flight"))
+             if r["kind"] == "gauge" and "model/layers" in r]
+    assert [r["model/head_gathered_once"] for r in built] == [1]
+
+
 def test_fused_decode_gauge_and_chunk_counts_reach_the_flight_stream(faultfree_run, tmp_path):
     """`gen/decode_attn_fused` lands once per built sampler, in the
     flight stream and the tracker: 0 for the shared run (no int8 cache),

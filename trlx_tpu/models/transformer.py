@@ -37,6 +37,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from trlx_tpu.ops.common import UntiedHead
 from trlx_tpu.ops.common import interpret_mode as _interpret_mode
 from trlx_tpu.ops.common import warn_pallas_fallback as _warn_pallas_fallback
 
@@ -2428,22 +2429,17 @@ class LMHead(nn.Module):
 # ---------------------------------------------------------------------------
 
 
-def logit_projection(params: Dict):
+def logit_projection(params: Dict, mesh=None):
     """hidden -> fp32 logits closure over a TransformerLM param tree
     (tied wte or untied lm_head), matching `TransformerLM._logits`
     numerics exactly (compute-dtype matmul, fp32 accumulation). Feeds
     `ops.common.chunked_logprobs` so losses can avoid materializing
-    full [B, T, V] logits."""
+    full [B, T, V] logits. An untied head comes as an
+    `ops.common.UntiedHead` with `mesh`, the mesh its kernel is laid out
+    on: `chunked_logprobs` gathers it once per call where
+    `ops.common.head_gathered_once` holds."""
     if "lm_head" in params:
-        kernel = params["lm_head"]["kernel"]
-
-        def proj(h: Array) -> Array:
-            return jnp.einsum(
-                "...e,ev->...v", h, kernel.astype(h.dtype),
-                preferred_element_type=jnp.float32,
-            )
-
-        return proj
+        return UntiedHead(params["lm_head"]["kernel"], mesh)
     wte = params["embed"]["wte"]
 
     def proj(h: Array) -> Array:

@@ -59,6 +59,16 @@ def _effective_base(wrapper, params: Dict) -> Dict:
     return params["base"]
 
 
+def _head_mesh(wrapper, params: Dict):
+    """The mesh `logit_projection` lays the head out by: the model's, but
+    None where a LoRA overlay adapts the head (the merged kernel is then
+    computed in the step, not a parameter where `parallel/sharding.py`
+    put it)."""
+    if "lm_head/kernel" in params.get("lora", {}):
+        return None
+    return wrapper.lm.mesh
+
+
 def _adapter_kwargs(params: Dict) -> Dict:
     """Prompt/prefix adapter kwargs for TransformerLM.__call__."""
     from trlx_tpu.models.peft import adapter_call_kwargs
@@ -96,7 +106,7 @@ class CausalLM:
         """hidden -> logits closure for chunked-from-hidden losses
         (`ops.common.chunked_logprobs`); resolves any LoRA overlay so the
         projection matches the forward's effective weights."""
-        return logit_projection(_effective_base(self, params))
+        return logit_projection(_effective_base(self, params), _head_mesh(self, params))
 
 
 class CausalLMWithValueHead:
@@ -257,7 +267,7 @@ class CausalLMWithValueHead:
         if self.frozen_below():
             # a tied head reads the (frozen) embedding
             base = dict(base, embed=jax.lax.stop_gradient(base["embed"]))
-        return logit_projection(base)
+        return logit_projection(base, _head_mesh(self, params))
 
     def forward_train(
         self,

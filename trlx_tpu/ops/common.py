@@ -15,12 +15,16 @@ reductions are per-shard.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
-from typing import Dict, MutableMapping, Optional, Tuple, Union
+from typing import Any, Dict, MutableMapping, Optional, Tuple, Union
 
 import flax.struct
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.experimental import mesh_utils
+from jax.sharding import PartitionSpec as P
 
 
 def masked_mean(xs: jnp.ndarray, mask: Optional[jnp.ndarray], axis=None) -> jnp.ndarray:
@@ -81,6 +85,180 @@ def logprobs_of_labels(logits: jnp.ndarray, labels: jnp.ndarray) -> jnp.ndarray:
     )
 
 
+def _chunk_seq(n_chunks: int, *xs: jnp.ndarray) -> Tuple[jnp.ndarray, ...]:
+    """Each [B, T, ...] -> [n_chunks, B, ceil(T/n_chunks), ...], the
+    sequence zero-padded to whole chunks."""
+    B, T = xs[0].shape[:2]
+    ck = -(-T // n_chunks)
+    pad = n_chunks * ck - T
+    if pad:
+        xs = [jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2)) for x in xs]
+    return tuple(jnp.swapaxes(x.reshape((B, n_chunks, ck) + x.shape[2:]), 0, 1) for x in xs)
+
+
+def _unchunk_seq(xs: jnp.ndarray, T: int) -> jnp.ndarray:
+    """`_chunk_seq`'s inverse: [n_chunks, B, ck, ...] -> [B, T, ...]."""
+    n_chunks, B, ck = xs.shape[:3]
+    return jnp.swapaxes(xs, 0, 1).reshape((B, n_chunks * ck) + xs.shape[3:])[:, :T]
+
+
+def _project(h: jnp.ndarray, kernel: jnp.ndarray) -> jnp.ndarray:
+    return jnp.einsum(
+        "...e,ev->...v", h, kernel.astype(h.dtype),
+        preferred_element_type=jnp.float32,
+    )
+
+
+@dataclasses.dataclass(frozen=True, eq=False)
+class UntiedHead:
+    """An untied head `kernel [E, V]` as a hidden -> fp32 logits
+    projection (models.transformer.logit_projection), with the mesh its
+    kernel is laid out on (None: no mesh). `chunked_logprobs` over it
+    gathers the head once per call where `head_gathered_once` holds."""
+
+    kernel: jnp.ndarray
+    mesh: Any = None
+
+    def __call__(self, h: jnp.ndarray) -> jnp.ndarray:
+        return _project(h, self.kernel)
+
+
+def head_gathered_once(project_fn, rows: int) -> bool:
+    """Whether `chunked_logprobs(project_fn, ...)` over `rows` rows runs
+    as one shard_map over the rows (`_gathered_head_logprobs`): an
+    untied head (`UntiedHead`) whose `E` the mesh's `fsdp` axis splits
+    (parallel/sharding.py: `P("fsdp", "tp")`), no `tp`, `pp` or `sp`
+    axis, and rows that divide over `dp * fsdp`. Elsewhere the chunk scan
+    closes over the kernel and GSPMD lays it out: on `fsdp` that gathers
+    the head and all-reduces its gradient once per chunk. Static, like
+    `models.transformer.decode_weights_stationary`: `chunked_logprobs`
+    asks at trace time, the trainer on the host for the gauge
+    `model/head_gathered_once`."""
+    if not isinstance(project_fn, UntiedHead) or project_fn.mesh is None:
+        return False
+    m = project_fn.mesh.shape
+    return (
+        m["fsdp"] > 1 and m["tp"] == m["pp"] == m["sp"] == 1
+        and project_fn.kernel.shape[0] % m["fsdp"] == 0
+        and rows % (m["dp"] * m["fsdp"]) == 0
+    )
+
+
+_ROWS = ("dp", "fsdp")
+
+
+def _fsdp_ring(mesh) -> Optional[Tuple[int, ...]]:
+    """The `fsdp` axis's indices in the order of a ring through its chips,
+    as `jax.experimental.mesh_utils` finds one on a TPU torus, or None
+    where that is the axis's own order (or where it finds none: CPU
+    devices have no coordinates). XLA:TPU reduce-scatters a large array
+    over a group listed out of ring order (a 2x2 host's devices 0-3 are
+    not a ring: 0-1-3-2 is) as an all-reduce and a slice, which moves
+    twice the bytes."""
+    fsdp = mesh.shape["fsdp"]
+    line = list(np.moveaxis(mesh.devices, mesh.axis_names.index("fsdp"), 0).reshape(fsdp, -1)[:, 0])
+    try:
+        ring = mesh_utils.create_device_mesh((fsdp,), devices=line)
+    except (AssertionError, NotImplementedError, ValueError):  # not a whole box of chips
+        return None
+    order = tuple(line.index(d) for d in ring)
+    return None if order == tuple(range(fsdp)) else order
+
+
+def _in_ring_order(x: jnp.ndarray, order) -> jnp.ndarray:
+    """`x`'s last axis as `len(order)` blocks, put in `order` (None: as it is)."""
+    if order is None:
+        return x
+    parts = jnp.split(x, len(order), axis=-1)
+    return jnp.concatenate([parts[i] for i in order], axis=-1)
+
+
+def _gather_head(kernel: jnp.ndarray, dtype, order) -> jnp.ndarray:
+    """A chip's `[E / fsdp, V]` shard of the head, cast, gathered whole
+    with its row blocks in ring `order`."""
+    return jax.lax.all_gather(
+        kernel.astype(dtype), "fsdp", axis=0, tiled=True,
+        axis_index_groups=None if order is None else [list(order)],
+    )
+
+
+def _logprobs_of_chunk(h, kernel, labels):
+    return logprobs_of_labels(_project(h, kernel), labels)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _gathered_head_logprobs(kernel, hidden, labels, n_chunks: int, mesh):
+    """`chunked_logprobs` where `head_gathered_once` holds: each chip
+    gathers the head once and scans its own rows chunk by chunk against
+    it. The backward (`_gathered_head_bwd`) adds the chunks' head
+    gradients into a chip-local float32 sum and reduce-scatters it once,
+    back to the kernel's layout. Head rows and hidden features both go
+    in the ring's block order (`_fsdp_ring`): the product is the same."""
+    order = _fsdp_ring(mesh)
+
+    def local(k, h, lab):
+        head = _gather_head(k, h.dtype, order)
+
+        def body(carry, xt):
+            return carry, _logprobs_of_chunk(xt[0], head, xt[1])
+
+        _, lp = jax.lax.scan(
+            body, jnp.zeros((), jnp.float32),
+            _chunk_seq(n_chunks, _in_ring_order(h, order), lab),
+        )
+        return _unchunk_seq(lp, lab.shape[1])
+
+    return jax.shard_map(
+        local, mesh=mesh,
+        in_specs=(P("fsdp", None), P(_ROWS, None, None), P(_ROWS, None)),
+        out_specs=P(_ROWS, None), check_vma=False,
+    )(kernel, hidden, labels)
+
+
+def _gathered_head_fwd(kernel, hidden, labels, n_chunks, mesh):
+    lp = _gathered_head_logprobs(kernel, hidden, labels, n_chunks, mesh)
+    return lp, (kernel, hidden, labels)
+
+
+def _gathered_head_bwd(n_chunks, mesh, res, g):
+    kernel, hidden, labels = res
+    order = _fsdp_ring(mesh)
+    back = None if order is None else tuple(np.argsort(order))
+
+    def local(k, h, lab, g):
+        head = _gather_head(k, h.dtype, order)
+
+        def body(acc, xt):
+            hc, lc, gc = xt
+            _, vjp = jax.vjp(lambda x, w: _logprobs_of_chunk(x, w, lc), hc, head)
+            dh, dw = vjp(gc)
+            return acc + dw.astype(jnp.float32), dh
+
+        acc, dh = jax.lax.scan(
+            body, jnp.zeros(head.shape, jnp.float32),
+            _chunk_seq(n_chunks, _in_ring_order(h, order), lab, g),
+        )
+        # the transpose of the one gather: each chip keeps the sum over
+        # every chip's rows of its own block of E, then over dp replicas
+        dk = jax.lax.psum_scatter(
+            acc, "fsdp", tiled=True,
+            axis_index_groups=None if order is None else [list(order)],
+        )
+        if mesh.shape["dp"] > 1:
+            dk = jax.lax.psum(dk, "dp")
+        return dk.astype(k.dtype), _in_ring_order(_unchunk_seq(dh, lab.shape[1]), back)
+
+    dk, dh = jax.shard_map(
+        local, mesh=mesh,
+        in_specs=(P("fsdp", None), P(_ROWS, None, None), P(_ROWS, None), P(_ROWS, None)),
+        out_specs=(P("fsdp", None), P(_ROWS, None, None)), check_vma=False,
+    )(kernel, hidden, labels, g)
+    return dk, dh, None
+
+
+_gathered_head_logprobs.defvjp(_gathered_head_fwd, _gathered_head_bwd)
+
+
 @jax.named_scope("logprobs")
 def chunked_logprobs(
     project_fn,
@@ -102,25 +280,27 @@ def chunked_logprobs(
     at b8/seq2048/vocab50257 fp32 that's 0.4 GB instead of 3.3 GB, the
     difference between the 1.3B recipe fitting one 16 GB chip or not.
 
+    An untied head split over `fsdp` (`head_gathered_once`) takes the
+    same chunks inside one shard_map over the rows, gathering the head
+    once per call instead of once per chunk.
+
     Returns fp32 logprobs with the shape of `labels`.
     """
-    B, T = labels.shape
-    ck = -(-T // n_chunks)
-    pad = n_chunks * ck - T
-    if pad:
-        hidden = jnp.pad(hidden, ((0, 0), (0, pad), (0, 0)))
-        labels = jnp.pad(labels, ((0, 0), (0, pad)))
-    hs = hidden.reshape(B, n_chunks, ck, hidden.shape[-1]).transpose(1, 0, 2, 3)
-    ls = labels.reshape(B, n_chunks, ck).transpose(1, 0, 2)
+    if head_gathered_once(project_fn, labels.shape[0]):
+        return _gathered_head_logprobs(
+            project_fn.kernel, hidden, labels, n_chunks, project_fn.mesh
+        )
 
     def body(carry, xt):
         h, lab = xt
         return carry, logprobs_of_labels(project_fn(h), lab)
 
     body = jax.checkpoint(body, prevent_cse=False)
-    _, lp = jax.lax.scan(body, jnp.zeros((), jnp.float32), (hs, ls))
-    lp = lp.transpose(1, 0, 2).reshape(B, n_chunks * ck)
-    return lp[:, :T]
+    _, lp = jax.lax.scan(
+        body, jnp.zeros((), jnp.float32),
+        _chunk_seq(n_chunks, hidden, labels),
+    )
+    return _unchunk_seq(lp, labels.shape[1])
 
 
 def topk_mask(xs: jnp.ndarray, k: int) -> jnp.ndarray:

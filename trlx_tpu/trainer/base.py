@@ -52,6 +52,7 @@ from trlx_tpu.models.transformer import (
     state_step_unfused,
 )
 from trlx_tpu.ops import trainable_view
+from trlx_tpu.ops.common import head_gathered_once
 from trlx_tpu.parallel import (
     data_sharding,
     init_sharded_opt_state,
@@ -1631,7 +1632,10 @@ class TPUBaseTrainer(BaseRLTrainer):
         `optim/params_walked` beside `optim/params_trained`, the elements
         the step streams through the optimizer and those the freeze mask
         trains: the difference is the frozen part of the leaves the
-        trainable view could not cut."""
+        trainable view could not cut; `model/head_gathered_once`, 1 where
+        the step's chunked log-probabilities gather the head once per call
+        and reduce its gradient once (`ops.common.head_gathered_once`: an
+        untied head on an `fsdp` mesh), 0 elsewhere."""
         cfg = self.model.cfg
         below = getattr(self.model, "frozen_below", lambda: 0)()
         if self.mesh.shape["pp"] > 1:
@@ -1645,7 +1649,8 @@ class TPUBaseTrainer(BaseRLTrainer):
         walked, trained = trainable_view.counts(self.params, self._update_mask, self._view)
         gauges = {"model/layers": layers, "model/backward_layers": backward,
                   "model/trunk_layers_hoisted": hoisted,
-                  "optim/params_walked": walked, "optim/params_trained": trained}
+                  "optim/params_walked": walked, "optim/params_trained": trained,
+                  "model/head_gathered_once": int(self._head_gathered_once())}
         if getattr(cfg, "beyond_dense", False):
             gauges["model/experts_held"] = cfg.n_experts_held or 0
             # of ONE layer that caches; a row's cache is that times the
@@ -1666,6 +1671,19 @@ class TPUBaseTrainer(BaseRLTrainer):
                 gauges["model/state_elems_per_row"] = cfg.state_elems_per_row
         self.obs.gauge(**gauges)
         self._tracker_log(gauges, step=self.iter_count)
+
+    def _head_gathered_once(self) -> bool:
+        """The loss's own question (`head_gathered_once` of the projection
+        it builds, over a microbatch's rows), asked of the parameters'
+        shapes: building the projection may merge an adapter."""
+        project_fn = getattr(self.model, "logit_project_fn", None)
+        if not self.config.train.logit_chunks or project_fn is None:
+            return False
+        answer = []
+        jax.eval_shape(
+            lambda p: answer.append(head_gathered_once(project_fn(p), self.mb_size)), self.params
+        )
+        return answer[0]
 
     def _pinned_state_shardings(self):
         # Pin output shardings to the current (input) shardings: without

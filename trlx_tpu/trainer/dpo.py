@@ -81,7 +81,7 @@ class TPUDPOTrainer(TPUBaseTrainer):
                 out["hidden_states"][:, :-1], ids[:, 1:], chunks,
             )
             ref_lp = chunked_logprobs(
-                logit_projection(ref_params),
+                logit_projection(ref_params, self.model.lm.mesh),
                 ref_out["hidden_states"][:, :-1], ids[:, 1:], chunks,
             )
         else:

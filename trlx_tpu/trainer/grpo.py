@@ -222,7 +222,7 @@ class TPUGRPOTrainer(TPUOnlineTrainer):
                     out["hidden_states"][:, :-1], tokens[:, 1:], chunks,
                 )
                 ref_logprobs_full = chunked_logprobs(
-                    logit_projection(ref_params),
+                    logit_projection(ref_params, model.lm.mesh),
                     ref_out["hidden_states"][:, :-1], tokens[:, 1:], chunks,
                 )
             else:

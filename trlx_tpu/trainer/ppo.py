@@ -424,7 +424,7 @@ class TPUPPOTrainer(TPUOnlineTrainer):
                     out["hidden_states"][:, :-1], tokens[:, 1:], chunks,
                 )
                 ref_logprobs_full = chunked_logprobs(
-                    logit_projection(ref_params),
+                    logit_projection(ref_params, model.lm.mesh),
                     out["ref_hidden"][:, :-1], tokens[:, 1:], chunks,
                 )
             else:
