@@ -450,8 +450,7 @@ def test_int8_rollout_weights_cover_the_delta_layers_written_projections(world):
     (dict(mixer_layers=("delta", "delta", "softmax", "latent", "delta")), "mixer_layers"),
     (dict(delta_heads=0), "delta_heads"),
     (dict(first_k_dense=4), "more than one mixer"),
-    (dict(kv_lora_rank=None, n_kv_head=2, mixer_layers=("delta", "softmax", "delta", "softmax", "delta")),
-     "softmax attention layers"),
+    (dict(delta_value_heads=3), "whole multiple of delta_heads"),
 ])
 def test_what_the_family_does_not_reach_raises_at_configuration_time(bad, match):
     kw = dict(ref.system_config(toy()), n_positions=64)

@@ -48,7 +48,7 @@ TIER1_BUDGETS = {
     # (raised 15->20), peft 13.9s, trainers 7.9s
     "test_elastic.py": 17,
     "test_examples.py": 1,
-    "test_exp_queue.py": 29,
+    "test_exp_queue.py": 21,
     "test_fault_tolerance.py": 24,
     "test_flash_attention.py": 14,
     "test_fleet.py": 21,
@@ -75,6 +75,19 @@ TIER1_BUDGETS = {
     # (86.0 s = 25.8), elastic 34->26 (50.7 s = 15.2), serve 26->19
     # (28.4 s = 8.5).
     "test_frozen_trunk.py": 96,
+    # PR 42: Gated DeltaNet layers beside output-gated attention at head 256
+    # and softmax-routed experts against their float32 reference (logits,
+    # the top two layers' gradients, the scalar-decay chunked form against
+    # the recurrence, prefill then decode through the state and k/v rows,
+    # attention at head 256 on both paths, the shares, the set-up balance
+    # of the gates, int8 rollout weights, configuration cases, the memory
+    # count): one toy stack jitted once, module scope. 87 s alone
+    # (2026-10-18), where thirteen files measured alone that hour read a
+    # median of 3.9 s a budgeted second: 22. Paid under the unchanged 780
+    # ceiling with the three lowest readings that hour on that scale:
+    # exp_queue 29->21 (77 s = 19.8), memdoctor 35->27 (100 s = 25.6),
+    # guardrails 45->40 (135 s = 34.5).
+    "test_gated_delta.py": 22,
     "test_gen_engine.py": 25,
     # PR 30: the fused int8 decode kernel against the XLA branch (seven
     # interpret-mode cases through `Attention`, one on a four-device
@@ -100,7 +113,7 @@ TIER1_BUDGETS = {
     "test_grpo.py": 20,
     # r09: +4 preference-RL chaos learn() tests (GRPO nan/sigterm, DPO
     # nan/sigterm); whole file re-measured 99.9s serial
-    "test_guardrails.py": 45,
+    "test_guardrails.py": 40,
     # PR 28: the routed / latent-attention / four-stream family against its
     # float32 reference (logits, trainable gradients, cache decode, shares,
     # hydra cuts, int8 rollout weights, one Mosaic compile at keys 192 /
@@ -138,7 +151,7 @@ TIER1_BUDGETS = {
     # Paid for under the unchanged ceiling by re-trimming files whose
     # r09 serial measurements left >=5s slack (fault_tolerance 62.4,
     # elastic 32.0, exp_queue 28.2, fleet 33.7, peft 13.9 measured).
-    "test_memdoctor.py": 35,
+    "test_memdoctor.py": 27,
     "test_models.py": 14,
     # trimmed r07 against serial measurements (the round-6 note asked
     # the next file to trim instead of raising the ceiling): these

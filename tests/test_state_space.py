@@ -482,7 +482,7 @@ def test_int8_rollout_weights_cover_both_projections_attention_the_latent_pair_a
     (dict(ssm_groups=3), "whole groups"),
     (dict(residual_streams=4), "one sub-layer under several residual streams"),
     (dict(mixer_layers=("ssm", "none", "delta", "softmax", "none", "ssm", "none"), delta_heads=2, delta_head_dim=16),
-     "softmax attention layers"),
+     "state-space .Mamba-2. layers beside delta-rule layers"),
 ])
 def test_what_the_family_does_not_reach_raises_at_configuration_time(bad, match):
     kw = dict(ref.system_config(toy()), n_positions=64)

@@ -215,6 +215,27 @@ class TransformerConfig:
     conv_taps: int = 3
     # per-head RMSNorms of q and k over a head's channels, before rotary
     qk_norm: bool = False
+    # every RMSNorm of the model (the layers' two, the final one, q's and
+    # k's) as RMSNorm(x) * (1 + w), w initialised to 0
+    norm_zero_centred: bool = False
+    # attention's output gated by sigmoid(x W_gate) a head and channel: W_q
+    # gives each head 2 x head_dim, its query and then its gate
+    attn_output_gate: bool = False
+    # the delta rule's form: "kda" (a per-channel decay from a low-rank pair,
+    # three convolutions, a sigmoid output gate through a low-rank pair) or
+    # "gated" (Gated DeltaNet: one joint projection to q, k, v and the output
+    # gate z, one convolution over q, k and v, a scalar decay and a beta a
+    # value head, RMSNorm(o) * SiLU(z)). `delta_value_heads` (None: as many
+    # as `delta_heads`) value heads, each reading key head h // (value heads
+    # / key heads)
+    delta_form: str = "kda"
+    delta_value_heads: Optional[int] = None
+    # the router's scores: "sigmoid" (with a selection bias added for the
+    # choice, `router_bias`) or "softmax" over all experts (no bias); the
+    # chosen weights are renormalised either way. `shared_expert_gate`: the
+    # shared expert's output times sigmoid(x w_sg)
+    router_score: str = "sigmoid"
+    shared_expert_gate: bool = False
 
     @property
     def latent(self) -> bool:
@@ -274,17 +295,23 @@ class TransformerConfig:
     @property
     def state_elems_per_row(self) -> int:
         """Numbers the layers with a recurrent state keep a row, whatever
-        its length: in a delta-rule layer a [heads, d_k, d_v] state and the
-        last `delta_conv - 1` inputs of the three convolutions; in a
+        its length: in a delta-rule layer a [value heads, d_k, d_v] state and
+        the last `delta_conv - 1` inputs of q, k and v (`delta_conv_width`); in a
         state-space layer a [heads, head_dim, ssm_state] state and the last
         `ssm_conv - 1` inputs of its one convolution (`ssm_conv_width`); in
         a short-convolution layer the last `conv_taps - 1` inputs alone."""
-        width = self.delta_heads * self.delta_head_dim
+        value_width = self.delta_value_heads * self.delta_head_dim
         return (
-            self.mixers.count("delta") * (width * self.delta_head_dim + (self.delta_conv - 1) * 3 * width)
+            self.mixers.count("delta") * (value_width * self.delta_head_dim
+                                          + (self.delta_conv - 1) * self.delta_conv_width)
             + self.mixers.count("ssm") * (self.ssm_heads * self.ssm_head_dim * self.ssm_state
                                           + (self.ssm_conv - 1) * self.ssm_conv_width)
             + self.mixers.count("conv") * (self.conv_taps - 1) * self.hidden_size)
+
+    @property
+    def delta_conv_width(self) -> int:
+        """Channels a delta-rule layer's convolutions see: q, k and v."""
+        return (2 * self.delta_heads + self.delta_value_heads) * self.delta_head_dim
 
     @property
     def ssm_conv_width(self) -> int:
@@ -316,6 +343,8 @@ class TransformerConfig:
             object.__setattr__(self, "rotary_dim", self.head_dim)
         if self.routed and self.n_experts_held is None:
             object.__setattr__(self, "n_experts_held", self.n_routed_experts)
+        if self.delta_value_heads is None:
+            object.__setattr__(self, "delta_value_heads", self.delta_heads)
         for name in ("mixer_layers", "ffn_layers"):  # a list from a config file: hashable
             if getattr(self, name) is not None:
                 object.__setattr__(self, name, tuple(getattr(self, name)))
@@ -339,8 +368,13 @@ class TransformerConfig:
                     f"mixer_layers names one of {base!r} (the attention the other keys "
                     f"describe), 'delta', 'ssm', 'conv' or 'none' for each of the {self.n_layer} layers"
                 )
-        if self.qk_norm and self.latent:
-            raise NotImplementedError("qk_norm is per-head attention's: latent attention has norms of its own")
+        if (self.qk_norm or self.attn_output_gate) and self.latent:
+            raise NotImplementedError("qk_norm and attn_output_gate are per-head attention's: latent "
+                                      "attention has norms of its own and no output gate")
+        if self.norm_zero_centred and self.norm != "rmsnorm":
+            raise ValueError("norm_zero_centred is a form of norm='rmsnorm'")
+        if self.router_score not in ("sigmoid", "softmax"):
+            raise ValueError(f"router_score={self.router_score!r}: 'sigmoid' or 'softmax'")
         if not self.beyond_dense:
             return
         def no(what):
@@ -370,9 +404,12 @@ class TransformerConfig:
         if "delta" in self.mixers:
             if not (self.delta_heads > 0 and self.delta_head_dim > 0 and self.delta_conv >= 2):
                 raise ValueError("delta-rule layers need delta_heads, delta_head_dim and delta_conv >= 2")
-            if "softmax" in self.mixers:
-                no("delta-rule (KDA) layers beside softmax attention layers (their per-head "
-                   "key and value cache)")
+            if self.delta_form not in ("kda", "gated"):
+                raise ValueError(f"delta_form={self.delta_form!r}: 'kda' or 'gated'")
+            if self.delta_value_heads % self.delta_heads or (
+                    self.delta_form == "kda" and self.delta_value_heads != self.delta_heads):
+                raise ValueError("delta_value_heads is a whole multiple of delta_heads (and, in the "
+                                 "'kda' form, equal to it)")
             if len(set(self.mixers[: self.first_k_dense])) > 1:
                 no("leading dense layers of more than one mixer")
         if "ssm" in self.mixers:
@@ -669,6 +706,16 @@ def _state_step_fused(cfg: "TransformerConfig", mesh) -> bool:
     return not why
 
 
+def _norm_scale(module: nn.Module, name: str, width: int) -> Array:
+    """What an RMSNorm's output is multiplied by: its learned weight w
+    (initialised to 1), or 1 + w where the model's norms are zero-centred
+    (w initialised to 0)."""
+    cfg = module.cfg
+    if cfg.norm_zero_centred:
+        return 1.0 + module.param(name, nn.initializers.zeros, (width,), cfg.param_dtype).astype(jnp.float32)
+    return module.param(name, nn.initializers.ones, (width,), cfg.param_dtype)
+
+
 class Norm(nn.Module):
     cfg: TransformerConfig
 
@@ -676,7 +723,7 @@ class Norm(nn.Module):
     def __call__(self, x: Array) -> Array:
         cfg = self.cfg
         x32 = x.astype(jnp.float32)
-        scale = self.param("scale", nn.initializers.ones, (cfg.hidden_size,), cfg.param_dtype)
+        scale = _norm_scale(self, "scale", cfg.hidden_size)
         if cfg.norm == "rmsnorm":
             var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
             y = x32 * jax.lax.rsqrt(var + cfg.layer_norm_epsilon) * scale
@@ -724,13 +771,16 @@ class Attention(nn.Module):
             use_bias=cfg.use_attn_bias,
             reduce_into=lay and lay.rows,
         )
-        q = dense(features=(H, D), name="q")(x)
+        gate = None
+        if cfg.attn_output_gate:  # a head's 2 D columns: its query, then its gate
+            q, gate = jnp.split(dense(features=(H, 2 * D), name="q")(x), 2, axis=-1)
+        else:
+            q = dense(features=(H, D), name="q")(x)
         k = dense(features=(Hkv, D), name="k")(x)
         v = dense(features=(Hkv, D), name="v")(x)
         if cfg.qk_norm:
             # each head's q and k over its D channels, one learned weight each
-            q, k = (_rms_norm(a, self.param(name, nn.initializers.ones, (D,), cfg.param_dtype),
-                              cfg.layer_norm_epsilon).astype(a.dtype)
+            q, k = (_rms_norm(a, _norm_scale(self, name, D), cfg.layer_norm_epsilon).astype(a.dtype)
                     for a, name in ((q, "q_norm"), (k, "k_norm")))
 
         if cfg.pos_embed == "rotary":
@@ -1040,6 +1090,9 @@ class Attention(nn.Module):
                 probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
                 out = jnp.einsum("bhts,bshd->bthd", probs, v)
 
+        if gate is not None:
+            with jax.named_scope("attn_out_gate"):
+                out = (out.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(cfg.dtype)
         out_bias = (
             cfg.use_attn_out_bias
             if cfg.use_attn_out_bias is not None
@@ -1147,13 +1200,14 @@ def quantize_decode_weights(params: Dict) -> Dict:
     # (the up-projection of the cached latent, `kv_b`, is used transposed
     # in the decode form and stays as it is, as does the float32 router);
     # of a delta-rule layer q, k, v and o (95% of its elements: the two
-    # low-rank pairs and beta's projection feed float32 gates and stay);
+    # low-rank pairs and beta's projection feed float32 gates and stay), in
+    # the gated form `qkvz` and o (`ba` feeds the float32 decay and beta);
     # of a state-space layer both projections (its taps, A_log, D, dt_bias
     # and norm stay); of latent-space experts both latent projections
     n_feats = {"q": 2, "k": 2, "v": 2, "o": 1,
                "fc_in": 1, "fc_gate": 1, "fc_out": 1,
                "q_a": 1, "q_b": 2, "kv_a": 1,
-               "in_proj": 1, "out_proj": 1, "latent_in": 1, "latent_out": 1}
+               "in_proj": 1, "out_proj": 1, "latent_in": 1, "latent_out": 1, "qkvz": 1}
     # stacked expert kernels [L, held, in, out]: one scale per expert and
     # output channel
     experts = ("experts_fc_in", "experts_fc_gate", "experts_fc_out")
@@ -1521,23 +1575,72 @@ def _kda_chunk(state: Array, xs, sub: int):
     return state, o
 
 
+# chunk of the chunked form for a SCALAR decay a head, and the block of its
+# inverse's forward substitution: with no sub-chunk reference points the
+# in-chunk products are plain [C, C] matrix products, so the chunk is
+# larger than KDA_CHUNK (fewer steps of the scan, fewer states kept for the
+# backward pass)
+GDN_CHUNK, GDN_BASE = 64, 16
+
+
+def _gdn_chunk(state: Array, xs, sub: int):
+    """One chunk of `kda_chunked` for a scalar log-decay a head: `state`
+    [B, H, dk, dv], q, k [B, H, C, dk], v [B, H, C, dv], g, beta [B, H, C]
+    -> (state after the chunk, o [B, H, C, dv]). With G the cumulative
+    log-decay inside the chunk, Gamma = exp(G) and M[r, i] = exp(G_r - G_i),
+    ONE [C, C] mask a head:
+
+        A = beta (k k^T) * M, strictly lower;  P = (q k^T) * M, lower
+        U = (I + A)^-1 (beta v  -  (beta k Gamma) S)
+        o = (q Gamma) S + P U
+        S' = Gamma_C S + (k Gamma_C / Gamma)^T U
+
+    Every exponent is a difference formed first and never positive (g <= 0);
+    the mask's entries above the diagonal are exp(-inf), 0. `sub` is the block
+    `_unit_lower_inverse` substitutes forward."""
+    q, k, v, g, beta = xs
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    C = k.shape[2]
+    G = jnp.cumsum(g, axis=2)
+    causal = jnp.tril(jnp.ones((C, C), bool))
+    mask = jnp.exp(jnp.where(causal, G[..., :, None] - G[..., None, :], -jnp.inf))  # [B, H, C, C]
+    strict = jnp.tril(jnp.ones((C, C), bool), -1)
+    kk = jnp.einsum("bhrd,bhid->bhri", k, k)
+    t = _unit_lower_inverse(jnp.where(strict, beta[..., None] * kk * mask, 0.0), sub)
+    gamma = jnp.exp(G)[..., None]  # [B, H, C, 1]
+    w_v = jnp.einsum("bhri,bhid->bhrd", t, beta[..., None] * v)
+    w_k = jnp.einsum("bhri,bhid->bhrd", t, beta[..., None] * k * gamma)
+    u = w_v - jnp.einsum("bhrk,bhkd->bhrd", w_k, state)
+    pairs = jnp.einsum("bhrd,bhid->bhri", q, k) * mask
+    o = jnp.einsum("bhrk,bhkd->bhrd", q * gamma, state) + jnp.einsum("bhri,bhid->bhrd", pairs, u)
+    to_end = jnp.exp(G[..., -1:] - G)[..., None]
+    state = gamma[:, :, -1:] * state + jnp.einsum("bhik,bhid->bhkd", k * to_end, u)
+    return state, o
+
+
 def kda_chunked(q: Array, k: Array, v: Array, g: Array, beta: Array,
-                state: Optional[Array] = None, chunk: int = KDA_CHUNK,
-                sub: int = KDA_SUB) -> Tuple[Array, Array]:
+                state: Optional[Array] = None, chunk: Optional[int] = None,
+                sub: Optional[int] = None) -> Tuple[Array, Array]:
     """The gated delta rule over T positions in chunks:
 
         S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + beta_t k_t v_t^T;   o_t = S_t^T q_t
 
     q, k [B, T, H, dk], v [B, T, H, dv] (any float dtype: they enter the
     products rounded to the compute dtype either way, and are laid out in
-    chunks as they come), g [B, T, H, dk] and beta [B, T, H] float32,
-    `state` [B, H, dk, dv] (None: zeros) -> (o [B, T, H, dv] float32, the
-    state after position T). One state is handed from chunk to chunk by a scan
-    over ceil(T / chunk) steps; inside a chunk `_kda_chunk`. T is padded
-    to whole chunks with positions that change nothing (beta 0, g 0). The
+    chunks as they come), g [B, T, H, dk] (a decay a channel) or [B, T, H]
+    (a scalar a head) and beta [B, T, H] float32, `state` [B, H, dk, dv]
+    (None: zeros) -> (o [B, T, H, dv] float32, the state after position T).
+    One state is handed from chunk to chunk by a scan over ceil(T / chunk)
+    steps; inside a chunk `_kda_chunk` (`chunk`, `sub` default to KDA_CHUNK,
+    KDA_SUB) or, for a scalar decay, `_gdn_chunk` (GDN_CHUNK, and `sub` the
+    block of its inverse's forward substitution, GDN_BASE). T is padded to
+    whole chunks with positions that change nothing (beta 0, g 0). The
     scan's body is checkpointed: a backward pass keeps one state a chunk
     and recomputes the chunk's inside."""
     B, T, H, D = k.shape
+    scalar = g.ndim == 3
+    chunk = chunk or (GDN_CHUNK if scalar else KDA_CHUNK)
+    sub = sub or (GDN_BASE if scalar else KDA_SUB)
     if state is None:
         state = jnp.zeros((B, H, D, v.shape[-1]), jnp.float32)
     pad = (-T) % chunk
@@ -1547,7 +1650,7 @@ def kda_chunked(q: Array, k: Array, v: Array, g: Array, beta: Array,
         x = x.reshape((B, (T + pad) // chunk, chunk) + x.shape[2:])
         return jnp.moveaxis(jnp.moveaxis(x, 1, 0), 2, 3)
 
-    body = jax.checkpoint(functools.partial(_kda_chunk, sub=sub))
+    body = jax.checkpoint(functools.partial(_gdn_chunk if scalar else _kda_chunk, sub=sub))
     state, o = jax.lax.scan(body, state, tuple(chunks(x) for x in (q, k, v, g, beta)))
     o = jnp.moveaxis(jnp.moveaxis(o, 3, 2), 0, 1)  # [B, T / chunk, chunk, H, dv]
     return o.reshape((B, T + pad) + o.shape[3:])[:, :T], state
@@ -1581,8 +1684,10 @@ def _dt_bias_init(key, shape, dtype=jnp.float32, lo=0.001, hi=0.1, floor=0.0):
 
 
 class DeltaAttention(nn.Module):
-    """Gated delta-rule linear attention with a per-channel decay (KDA): H
-    heads of d, a recurrent state S [d, d] a head in place of a cache.
+    """Gated delta-rule linear attention: a recurrent state S [d, d] a value
+    head in place of a cache, in one of two forms (`delta_form`).
+
+    "kda", a per-channel decay (KDA), H heads of d:
 
         q~, k~, v = SiLU(Conv(x W_q)), SiLU(Conv(x W_k)), SiLU(Conv(x W_v))
             Conv: causal depthwise, y_t = sum_i w[i] u_{t - (taps - 1) + i}, zeros before the start
@@ -1592,22 +1697,37 @@ class DeltaAttention(nn.Module):
         S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + beta_t k_t v_t^T;   o_t = S_t^T q_t
         y = (RMSNorm_head(o) * sigmoid((x W_ga) W_gb)) W_o
 
-    A position whose mask is 0 changes nothing: its convolution input is
-    zero, its beta 0 and its g 0, so a left-padded row reaches its first
-    token with S = 0 and an empty window.
+    "gated" (Gated DeltaNet), H key heads and Hv = r H value heads of d,
+    value head h reading key head h // r:
 
-    Two forms, chosen by the shape of the call and by nothing else:
-    - teacher-forced and prefill (T > 1), scope `kda_chunk`: the chunked
-      form (`kda_chunked`). A prefill starts from the state and the window
-      the cache holds (zeros) and leaves the state after its last position
-      and its last `taps - 1` convolution inputs there.
-    - a decode step (T == 1 with a cache), scope `kda_step`: one step of
-      the rule on the carried state, as one pass over it in place
-      (ops/state_step.py) or, where `state_step_unfused` gives a reason,
-      `kda_step` on the layer's slice.
+        [q | k | v | z] = x W_qkvz;  [b | a] = x W_ba
+        q~, k~, v = split(SiLU(Conv([q | k | v])))                     ONE convolution, no bias
+        q, k as above, then repeated r times a head (repeat_interleave)
+        g = -exp(A_log) softplus(a + dt_bias)                           a scalar a value head, float32
+        beta = sigmoid(b)                                               float32
+        S_t = exp(g_t) (I - beta_t k_t k_t^T) S_{t-1} + beta_t k_t v_t^T;   o_t = S_t^T q_t
+        y = (RMSNorm_head(o) * SiLU(z)) W_o
+
+    With a scalar decay Diag(exp(g)) is exp(g) I and commutes: the two rules
+    are one, the scalar broadcast over d. A position whose mask is 0 changes
+    nothing: its convolution input is zero, its beta 0 and its g 0, so a
+    left-padded row reaches its first token with S = 0 and an empty window.
+
+    Two passes over positions, chosen by the shape of the call and by nothing
+    else (scopes `kda_*` for the first form, `gdn_*` for the second):
+    - teacher-forced and prefill (T > 1), scope `kda_chunk` / `gdn_chunk`: the
+      chunked form (`kda_chunked`; for a scalar decay its `_gdn_chunk`). A
+      prefill starts from the state and the window the cache holds (zeros)
+      and leaves the state after its last position and its last `taps - 1`
+      convolution inputs there.
+    - a decode step (T == 1 with a cache), scope `kda_step` / `gdn_step`: one
+      step of the rule on the carried state, as one pass over it in place
+      (ops/state_step.py; a scalar decay reaches it broadcast over d_k: a
+      row's 32 x 128 decays beside its 2 MB state) or, where
+      `state_step_unfused` gives a reason, `kda_step` on the layer's slice.
     The cache of a segment of such layers (`TransformerLM.init_cache`):
-    `s` [layers, B, H, d, d] float32 and `u` [layers, B, taps - 1, 3 H d];
-    a layer reads and writes its own row `ix`.
+    `s` [layers, B, Hv, d, d] float32 and `u` [layers, B, taps - 1,
+    `delta_conv_width`]; a layer reads and writes its own row `ix`.
     """
 
     cfg: TransformerConfig
@@ -1617,8 +1737,10 @@ class DeltaAttention(nn.Module):
     def __call__(self, x, attn_bias, positions, cache=None, key_mask=None, ring_mesh=None):
         cfg = self.cfg
         B, T, E = x.shape
-        H, D, taps = cfg.delta_heads, cfg.delta_head_dim, cfg.delta_conv
+        H, Hv, D, taps = cfg.delta_heads, cfg.delta_value_heads, cfg.delta_head_dim, cfg.delta_conv
         W = H * D
+        gated = cfg.delta_form == "gated"
+        scope = "gdn_" if gated else "kda_"
         dense = partial(QDense, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                         kernel_init=nn.initializers.normal(0.02), use_bias=False)
         if key_mask is None:
@@ -1628,40 +1750,58 @@ class DeltaAttention(nn.Module):
         else:  # the mask of the slots this call writes
             live = jax.lax.dynamic_slice_in_dim(key_mask, cache["index"], T, axis=1).astype(jnp.float32)
 
-        u = jnp.concatenate(
-            [dense(features=(H, D), name=name)(x).reshape(B, T, W) for name in ("q", "k", "v")], axis=-1)
-        with jax.named_scope("kda_conv"):
+        if gated:
+            qkvz = dense(features=cfg.delta_conv_width + Hv * D, name="qkvz")(x)
+            u, z = qkvz[..., : cfg.delta_conv_width], qkvz[..., cfg.delta_conv_width :].reshape(B, T, Hv, D)
+        else:
+            u = jnp.concatenate(
+                [dense(features=(H, D), name=name)(x).reshape(B, T, W) for name in ("q", "k", "v")], axis=-1)
+        with jax.named_scope(scope + "conv"):
             u = u * live[..., None].astype(u.dtype)
-            tap = lambda name: self.param(name, _conv_tap_init, (taps, W), jnp.float32)
-            w = jnp.concatenate([tap("conv_q"), tap("conv_k"), tap("conv_v")], axis=-1)  # [taps, 3 W]
+            tap = lambda name, width: self.param(name, _conv_tap_init, (taps, width), jnp.float32)
+            if gated:
+                w = tap("conv", cfg.delta_conv_width)
+            else:
+                w = jnp.concatenate([tap("conv_q", W), tap("conv_k", W), tap("conv_v", W)], axis=-1)  # [taps, 3 W]
             if cache is None:
-                before = jnp.zeros((B, taps - 1, 3 * W), u.dtype)
+                before = jnp.zeros((B, taps - 1, u.shape[-1]), u.dtype)
             else:
                 before = jax.lax.dynamic_index_in_dim(cache["u"], cache["ix"], 0, keepdims=False).astype(u.dtype)
-            window = jnp.concatenate([before, u], axis=1)  # [B, taps - 1 + T, 3 W]
+            window = jnp.concatenate([before, u], axis=1)  # [B, taps - 1 + T, channels]
             y = sum(w[i] * window[:, i : i + T].astype(jnp.float32) for i in range(taps))
-            q, k, v = (a.reshape(B, T, H, D) for a in jnp.split(jax.nn.silu(y), 3, axis=-1))
+            q, k, v = (a.reshape(B, T, -1, D) for a in jnp.split(jax.nn.silu(y), [W, 2 * W], axis=-1))
             q = q * jax.lax.rsqrt(jnp.sum(q * q, axis=-1, keepdims=True) + 1e-6) * D ** -0.5
             k = k * jax.lax.rsqrt(jnp.sum(k * k, axis=-1, keepdims=True) + 1e-6)
-        with jax.named_scope("kda_gate"):
-            a_log = self.param("A_log", _a_log_init, (H,), jnp.float32)
-            dt_bias = self.param("dt_bias", _dt_bias_init, (H, D), jnp.float32)
-            f = dense(features=(H, D), name="f_b")(dense(features=D, name="f_a")(x))
-            g = -jnp.exp(a_log)[:, None] * jax.nn.softplus(f.astype(jnp.float32) + dt_bias)
-            g = g * live[..., None, None]  # [B, T, H, D]
-            beta = jax.nn.sigmoid(dense(features=H, name="b")(x).astype(jnp.float32)) * live[..., None]
+            if Hv != H:  # value head h reads key head h // (Hv / H)
+                q, k = (jnp.repeat(a, Hv // H, axis=2) for a in (q, k))
+        with jax.named_scope(scope + "gate"):
+            if gated:
+                ba = dense(features=2 * Hv, name="ba")(x).astype(jnp.float32)
+                a_log = self.param("A_log", _a_log_init, (Hv,), jnp.float32)
+                dt_bias = self.param("dt_bias", nn.initializers.ones, (Hv,), jnp.float32)
+                g = -jnp.exp(a_log) * jax.nn.softplus(ba[..., Hv:] + dt_bias) * live[..., None]  # [B, T, Hv]
+                beta = jax.nn.sigmoid(ba[..., :Hv]) * live[..., None]
+            else:
+                a_log = self.param("A_log", _a_log_init, (H,), jnp.float32)
+                dt_bias = self.param("dt_bias", _dt_bias_init, (H, D), jnp.float32)
+                f = dense(features=(H, D), name="f_b")(dense(features=D, name="f_a")(x))
+                g = -jnp.exp(a_log)[:, None] * jax.nn.softplus(f.astype(jnp.float32) + dt_bias)
+                g = g * live[..., None, None]  # [B, T, H, D]
+                beta = jax.nn.sigmoid(dense(features=H, name="b")(x).astype(jnp.float32)) * live[..., None]
 
         # with a cache, this layer's state comes out of the carried array and
         # goes back into it under the scope of the form that runs, so that the
         # scope's seconds hold every pass over the state
         step = cache is not None and T == 1
-        with jax.named_scope("kda_step" if step else "kda_chunk"):
+        # a step's decay: a scalar a head reaches the step broadcast over d_k
+        g_step = (lambda g: jnp.broadcast_to(g[:, 0, :, None], (B, Hv, D))) if gated else (lambda g: g[:, 0])
+        with jax.named_scope(scope + ("step" if step else "chunk")):
             if step and _state_step_fused(cfg, self.mesh):
                 # ONE pass (ops/state_step.py): the layer's tiles stream out of
                 # the carried array, are stepped in VMEM and stored in place
                 from trlx_tpu.ops.state_step import delta_state_step
 
-                o, carried = delta_state_step(cache["s"], cache["ix"], q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
+                o, carried = delta_state_step(cache["s"], cache["ix"], q[:, 0], k[:, 0], v[:, 0], g_step(g), beta[:, 0])
                 o = o[:, None]
             else:
                 state = None
@@ -1673,7 +1813,7 @@ class DeltaAttention(nn.Module):
                     state = jax.lax.optimization_barrier(
                         jax.lax.dynamic_index_in_dim(cache["s"], cache["ix"], 0, keepdims=False))
                 if step:
-                    o, state = kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], state)
+                    o, state = kda_step(q[:, 0], k[:, 0], v[:, 0], g_step(g), beta[:, 0], state)
                     o = o[:, None]
                 else:
                     o, state = kda_chunked(*(x.astype(cfg.dtype) for x in (q, k, v)), g, beta, state)
@@ -1687,11 +1827,13 @@ class DeltaAttention(nn.Module):
                         cache["u"], window[None, :, T:].astype(cache["u"].dtype), (cache["ix"], 0, 0, 0)),
                 }
 
-        with jax.named_scope("kda_gate"):
+        with jax.named_scope(scope + "gate"):
             o_scale = self.param("o_norm", nn.initializers.ones, (D,), cfg.param_dtype)
-            gate = dense(features=(H, D), name="g_b")(dense(features=D, name="g_a")(x))
-            out = (_rms_norm(o, o_scale, cfg.layer_norm_epsilon)
-                   * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(cfg.dtype)
+            if gated:
+                gate, act = z, jax.nn.silu
+            else:
+                gate, act = dense(features=(H, D), name="g_b")(dense(features=D, name="g_a")(x)), jax.nn.sigmoid
+            out = (_rms_norm(o, o_scale, cfg.layer_norm_epsilon) * act(gate.astype(jnp.float32))).astype(cfg.dtype)
         proj = QDense(
             features=E, axis=(-2, -1), dtype=cfg.dtype, param_dtype=cfg.param_dtype,
             kernel_init=nn.initializers.normal(0.02 / math.sqrt(2 * cfg.n_layer)),
@@ -2135,10 +2277,15 @@ class RoutedMLP(nn.Module):
             # the scorer choose from the same function of their inputs
             gate = self.param("router_gate", nn.initializers.normal(0.02),
                               (E, cfg.n_routed_experts), jnp.float32)
-            bias = self.param("router_bias", nn.initializers.zeros, (cfg.n_routed_experts,), jnp.float32)
-            s = jax.nn.sigmoid(jnp.dot(xf.astype(jnp.float32), gate.astype(jnp.float32),
-                                       precision=jax.lax.Precision.HIGHEST))
-            _, chosen = jax.lax.top_k(s + jax.lax.stop_gradient(bias), K)  # [N, K]
+            logits = jnp.dot(xf.astype(jnp.float32), gate.astype(jnp.float32),
+                             precision=jax.lax.Precision.HIGHEST)
+            if cfg.router_score == "softmax":
+                s = jax.nn.softmax(logits, axis=-1)
+                _, chosen = jax.lax.top_k(s, K)  # [N, K]
+            else:
+                bias = self.param("router_bias", nn.initializers.zeros, (cfg.n_routed_experts,), jnp.float32)
+                s = jax.nn.sigmoid(logits)
+                _, chosen = jax.lax.top_k(s + jax.lax.stop_gradient(bias), K)  # [N, K]
             picked = jnp.take_along_axis(s, chosen, axis=-1)
             weight = cfg.routed_scaling_factor * picked / jnp.sum(picked, axis=-1, keepdims=True)
             local = chosen - first
@@ -2229,9 +2376,18 @@ class RoutedMLP(nn.Module):
             y = routed.reshape(B, T, E)
             if cfg.n_shared_experts:
                 width = cfg.moe_shared_intermediate_size or F * cfg.n_shared_experts
-                y = y + MLP(cfg, width=width, gated=cfg.moe_gated, bias=False, name="shared")(x)
-        return y, {"load": load.astype(jnp.float32), "assignments": jnp.float32(N * K),
-                   "choices": choices.astype(jnp.float32)}
+                shared = MLP(cfg, width=width, gated=cfg.moe_gated, bias=False, name="shared")(x)
+                if cfg.shared_expert_gate:
+                    with jax.named_scope("moe_shared_gate"):
+                        w_sg = QDense(features=1, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                                      use_bias=False, name="shared_gate")(x)
+                        shared = (jax.nn.sigmoid(w_sg.astype(jnp.float32)) * shared).astype(cfg.dtype)
+                y = y + shared
+        stats = {"load": load.astype(jnp.float32), "assignments": jnp.float32(N * K),
+                 "choices": choices.astype(jnp.float32)}
+        if cfg.router_score == "softmax":  # the mean probability's numerator (`balance_router_gates`)
+            stats["probs"] = jnp.sum(s, axis=0)
+        return y, stats
 
 
 def sinkhorn(m: Array, iters: int, eps: float) -> Array:
@@ -2474,17 +2630,19 @@ def _fold_layer_stats(stats: Optional[Dict[str, Array]]) -> Optional[Dict[str, A
     `choices` [layers, published] stay by layer, the assignments made are summed."""
     if not stats:
         return None
-    return {"load": stats["load"], "assignments": jnp.sum(stats["assignments"]),
-            "choices": stats["choices"]}
+    return dict(stats, assignments=jnp.sum(stats["assignments"]))
 
 
 def _join_stats(a: Optional[Dict[str, Array]], b: Optional[Dict[str, Array]]):
     """Counters of two stretches of layers of one pass: loads side by side."""
     if not a or not b:
         return a or b
-    return {"load": jnp.concatenate([a["load"], b["load"]], axis=0),
-            "assignments": a["assignments"] + b["assignments"],
-            "choices": jnp.concatenate([a["choices"], b["choices"]], axis=0)}
+    out = {"load": jnp.concatenate([a["load"], b["load"]], axis=0),
+           "assignments": a["assignments"] + b["assignments"],
+           "choices": jnp.concatenate([a["choices"], b["choices"]], axis=0)}
+    if "probs" in a:  # a softmax router's (`balance_router_gates`)
+        out["probs"] = jnp.concatenate([a["probs"], b["probs"]], axis=0)
+    return out
 
 
 def _add_stats(a: Optional[Dict[str, Array]], b: Optional[Dict[str, Array]]):
@@ -2512,16 +2670,17 @@ def moe_counters(stats: Optional[Dict[str, Array]], program: str) -> Dict[str, A
     }
 
 
-def router_bias(tree: Dict) -> Dict[str, Array]:
-    """The routers' selection bias by stack, {stack: [rows, published experts]}."""
-    return {name: tree[name]["moe"]["router_bias"] for name in STACKS
-            if name in tree and "moe" in tree[name]}
+def router_bias(tree: Dict, leaf: str = "router_bias") -> Dict[str, Array]:
+    """The routers' selection bias by stack, {stack: [rows, published experts]}
+    (`leaf` "router_gate": their gates, [rows, d, published experts])."""
+    return {name: tree[name]["moe"][leaf] for name in STACKS
+            if name in tree and leaf in tree[name].get("moe", {})}
 
 
-def with_router_bias(tree: Dict, bias: Dict[str, Array]) -> Dict:
-    """`tree` with each routed stack's selection bias replaced by `bias[stack]`."""
+def with_router_bias(tree: Dict, bias: Dict[str, Array], leaf: str = "router_bias") -> Dict:
+    """`tree` with each routed stack's selection bias (or `leaf`) replaced by `bias[stack]`."""
     return dict(tree, **{
-        name: dict(tree[name], moe=dict(tree[name]["moe"], router_bias=new))
+        name: dict(tree[name], moe=dict(tree[name]["moe"], **{leaf: new}))
         for name, new in bias.items()})
 
 
@@ -2570,6 +2729,75 @@ def balance_router_bias(lm: "TransformerLM", params: Dict, input_ids: Array,
     # one more forward, at rate 0: what the last step left
     ratios.append(step(params, bias, input_ids, attention_mask, jnp.float32(0.0))[1])
     return with_router_bias(params, bias), jnp.stack([ratios[0], ratios[-1]])
+
+
+def _switch_loss(lm: "TransformerLM", tree: Dict, gates: Dict[str, Array], input_ids: Array,
+                 attention_mask: Array) -> Tuple[Array, Array]:
+    """The load-balancing loss of `balance_router_gates` summed over the
+    routed layers, E sum_e f_e P_e with the assignment shares f held
+    constant, and, by layer, the fullest expert's choices over the mean."""
+    # remat: the backward keeps a layer's input, not its experts' rows
+    out = lm(with_router_bias(tree, gates, "router_gate"), input_ids, attention_mask, remat=True,
+             compute_logits=False)
+    stats = out["moe_stats"]
+    choices = jax.lax.stop_gradient(stats["choices"])  # [routed layers, published experts]
+    share = choices / jnp.sum(choices, axis=-1, keepdims=True)
+    mean_p = stats["probs"] / input_ids.size
+    mean = jnp.mean(choices, axis=-1)
+    return choices.shape[-1] * jnp.sum(share * mean_p), jnp.max(choices, axis=-1) / mean
+
+
+def _rprop_step(lm: "TransformerLM", tree: Dict, gates: Dict[str, Array], input_ids: Array,
+                attention_mask: Array, step: Dict[str, Array], last: Dict[str, Array]):
+    """One step of `balance_router_gates`: (gates, step sizes, gradient after
+    it; the ratio by layer under the gates it started from)."""
+    (_, ratio), grad = jax.value_and_grad(
+        lambda g: _switch_loss(lm, tree, g, input_ids, attention_mask), has_aux=True)(gates)
+    same = jax.tree_util.tree_map(lambda g, h: jnp.sign(g) * jnp.sign(h), grad, last)
+    step = jax.tree_util.tree_map(
+        lambda s, agree: jnp.clip(jnp.where(agree > 0, 1.2 * s, jnp.where(agree < 0, 0.5 * s, s)),
+                                  1e-6, 1e-2), step, same)
+    grad = jax.tree_util.tree_map(lambda g, agree: jnp.where(agree < 0, 0.0, g), grad, same)  # iRprop-
+    gates = jax.tree_util.tree_map(lambda w, g, s: w - jnp.sign(g) * s, gates, grad, step)
+    return gates, step, grad, ratio
+
+
+def balance_router_gates(lm: "TransformerLM", params: Dict, input_ids: Array,
+                         attention_mask: Array, steps: int,
+                         first_step: float = 1e-4) -> Tuple[Dict, Array]:
+    """`steps` steps on the gates W_g of softmax routers (`router_score`
+    "softmax", no selection bias) of the Switch / GShard load-balancing loss
+
+        L = E sum_e f_e P_e        in every routed layer, summed
+
+    on one batch, every other weight held: f_e the share of the layer's
+    assignments that went to expert e (held constant), P_e its mean router
+    probability over the positions. A published checkpoint's gates have
+    been trained under this loss for its whole pretraining; random gates
+    send the load wherever the seed falls (a common direction of the
+    router's inputs lifts a few experts' logits at every position), and
+    how much of it this chip's share holds is the seed's draw. The steps
+    are resilient-propagation ones (Rprop, without the backtracking): each
+    element of W_g moves by its own step size against the sign of its
+    gradient, the size growing by 1.2 while the sign holds and halving
+    where it flips, from `first_step` (a two-hundredth of the gates' 0.02
+    at initialisation) within [1e-6, 1e-2]. The loss's gradient is about
+    (common direction) x (each expert's excess), so its signs act as the
+    selection-bias rule of `balance_router_bias` does, and its sizes find
+    the rate that the width and the inputs' scale call for. Returns the
+    tree with the new gates and, by layer, the fullest expert's choices over
+    the mean before the first and after the last step, [2, routed layers]."""
+    step_fn = jax.jit(functools.partial(_rprop_step, lm))
+    gates = router_bias(params, "router_gate")
+    sizes = jax.tree_util.tree_map(lambda w: jnp.full(w.shape, first_step, jnp.float32), gates)
+    last = jax.tree_util.tree_map(jnp.zeros_like, gates)
+    ratios = []
+    for _ in range(steps):
+        gates, sizes, last, ratio = step_fn(params, gates, input_ids, attention_mask, sizes, last)
+        ratios.append(ratio)
+    # one more call of the same program, its step discarded: what the last step left
+    ratios.append(step_fn(params, gates, input_ids, attention_mask, sizes, last)[-1])
+    return with_router_bias(params, gates, "router_gate"), jnp.stack([ratios[0], ratios[-1]])
 
 
 class TransformerLM:
@@ -3591,7 +3819,7 @@ class TransformerLM:
             # alone, `conv_u`, `conv_u_lead`). A layer without a mixer
             # keeps nothing
             cache = {"index": jnp.int32(0), "static_index": 0, "key_mask": mask}
-            H, D, width = cfg.delta_heads, cfg.delta_head_dim, 3 * cfg.delta_heads * cfg.delta_head_dim
+            H, D, width = cfg.delta_value_heads, cfg.delta_head_dim, cfg.delta_conv_width
             for name, block in self.blocks.items():
                 layers = len(stack_layers(cfg, name))
                 lead = "_lead" if name == "dense_blocks" else ""

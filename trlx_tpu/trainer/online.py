@@ -35,7 +35,7 @@ from trlx_tpu.exp import ExpConfig, ExperienceTransport
 from trlx_tpu.exp.rollout import LeasedChunks
 from trlx_tpu.fleet.config import FleetConfig
 from trlx_tpu.models.generation import chunks_streamed
-from trlx_tpu.models.transformer import balance_router_bias, router_bias, with_router_bias
+from trlx_tpu.models.transformer import balance_router_bias, balance_router_gates, router_bias, with_router_bias
 from trlx_tpu.ops.common import running_moments_init, running_moments_update
 from trlx_tpu.parallel import multihost as mh
 from trlx_tpu.parallel.mesh import replicated_sharding, vector_sharding
@@ -727,34 +727,38 @@ class TPUOnlineTrainer(TPUBaseTrainer):
         # the pipeline is retained so guardrail interventions (requeue /
         # rollback) can rebuild the stream and replay untrained prompts
         self._prompt_pipeline = pipeline
-        self._balance_router_bias(pipeline)
+        self._balance_routers(pipeline)
         self._build_prompt_iterator()
         self._fast_forward_prompts()
 
-    def _balance_router_bias(self, pipeline) -> None:
+    def _balance_routers(self, pipeline) -> None:
         """A routed model initialised at random, whose configuration asks
-        for it (`router_balance_steps`), has its selection bias balanced on
-        the first chunk of prompts before anything is sampled
-        (`balance_router_bias`). Every copy of the routed layers made at
-        set-up (the frozen reference, a value branch: the top layers of the
-        main segment) takes the same bias; the weights stay as they are."""
+        for it (`router_balance_steps`), has its routers balanced on the
+        first chunk of prompts before anything is sampled: a sigmoid
+        router's selection bias (`balance_router_bias`), a softmax router's
+        gates (`balance_router_gates`, which has no bias). Every copy of the
+        routed layers made at set-up (the frozen reference, a value branch:
+        the top layers of the main segment) takes the same; every other
+        weight stays as it is."""
         cfg = getattr(self.model, "cfg", None)
         steps = getattr(cfg, "router_balance_steps", 0)
         if not (steps and getattr(cfg, "routed", False) and getattr(self, "_random_init", False)):
             return
-        with self.obs.span("router_balance", steps=steps):
+        softmax = cfg.router_score == "softmax"
+        leaf, balance = ("router_gate", balance_router_gates) if softmax else ("router_bias", balance_router_bias)
+        with self.obs.span("router_balance", steps=steps) as counts:
             rows = min(len(pipeline), self._prompt_chunk_rows())
             batch = pipeline.collate([pipeline[i] for i in range(rows)])
             with self.mesh:
-                base, ratios = balance_router_bias(
+                base, ratios = balance(
                     self.model.lm, self.params["base"], jnp.asarray(batch.input_ids),
                     jnp.asarray(batch.attention_mask), steps)
-            bias = router_bias(base)
+            bias = router_bias(base, leaf)
 
             def top_layers(tree):  # a branch holds the top rows of each stack
                 return with_router_bias(tree, {
                     name: jax.device_put(jnp.array(bias[name][len(bias[name]) - old.shape[0]:]), old.sharding)
-                    for name, old in router_bias(tree).items()})
+                    for name, old in router_bias(tree, leaf).items()}, leaf)
 
             self.params = dict(self.params, base=top_layers(base))
             if "v_branch" in self.params:
@@ -762,9 +766,12 @@ class TPUOnlineTrainer(TPUBaseTrainer):
             if getattr(self, "ref_params", None) is not None:
                 self.ref_params = top_layers(self.ref_params)
             before, after = ([round(float(r), 2) for r in row] for row in np.asarray(ratios))
+            # the fullest expert over the mean in the worst layer, before and
+            # after: the set-up row's record of what the balance did
+            counts.update(load_max_over_mean_before=max(before), load_max_over_mean_after=max(after))
             logger.info(
-                f"router bias balanced on {rows} prompts in {steps} steps: fullest expert over the "
-                f"mean, by layer, {before} -> {after}")
+                f"router {'gates' if softmax else 'bias'} balanced on {rows} prompts in {steps} steps: "
+                f"fullest expert over the mean, by layer, {before} -> {after}")
 
     def _prompt_chunk_rows(self) -> int:
         """Prompts pulled from the stream per chunk (GRPO pulls

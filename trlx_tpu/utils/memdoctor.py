@@ -1165,13 +1165,14 @@ def recurrent_state_bytes(tcfg, rows: int, conv_size: int = 2) -> int:
     """What the layers with a recurrent state of a model config (or its dict)
     keep for `rows` rows whatever their length, a float32 state and the last
     inputs of their convolutions (compute dtype): a delta-rule layer [heads,
-    d, d] and `delta_conv - 1` inputs of three convolutions, a state-space
+    d, d] a value head and `delta_conv - 1` inputs of q, k and v, a state-space
     layer [heads, head_dim, ssm_state] and `ssm_conv - 1` inputs of one (x',
     B and C wide), a short-convolution layer `conv_taps - 1` inputs of one
     (hidden_size wide) and no state. 0 for a model without such layers."""
     H, D = int(_field(tcfg, "delta_heads", 0)), int(_field(tcfg, "delta_head_dim", 0))
-    conv = (int(_field(tcfg, "delta_conv", 4)) - 1) * 3 * H * D
-    total = _mixers(tcfg).count("delta") * rows * (4 * H * D * D + conv_size * conv)
+    Hv = int(_field(tcfg, "delta_value_heads") or H)  # value heads: one state each
+    conv = (int(_field(tcfg, "delta_conv", 4)) - 1) * (2 * H + Hv) * D
+    total = _mixers(tcfg).count("delta") * rows * (4 * Hv * D * D + conv_size * conv)
     sh, sp, sn = (int(_field(tcfg, k, 0)) for k in ("ssm_heads", "ssm_head_dim", "ssm_state"))
     tail = (int(_field(tcfg, "ssm_conv", 4)) - 1) * (sh * sp + 2 * int(_field(tcfg, "ssm_groups", 1)) * sn)
     total += _mixers(tcfg).count("ssm") * rows * (4 * sh * sp * sn + conv_size * tail)
@@ -1205,6 +1206,7 @@ def _family_param_count(tcfg: Dict[str, Any]) -> int:
     else:
         D = int(tcfg.get("head_dim") or E // max(H, 1))
         attn = 2 * E * D * (H + int(tcfg.get("n_kv_head") or H))  # q and o; k and v
+        attn += E * D * H if tcfg.get("attn_output_gate") else 0  # q's gate columns
         attn += 2 * D if tcfg.get("qk_norm") else 0  # a weight a head's channel, q's and k's
     n = int(tcfg.get("residual_streams", 1))
     mix = 2 * (n * E * (n * n + 2 * n) + 3 + 2 * n + n * n) if n > 1 else 0
@@ -1212,10 +1214,15 @@ def _family_param_count(tcfg: Dict[str, Any]) -> int:
     by_mixer = {None: attn, "softmax": attn, "latent": attn, "none": 0}
     if "delta" in mixers:
         # q, k, v, o; two low-rank pairs through d; beta; the taps; A_log, dt_bias, the output norm
-        kh, kd = int(tcfg["delta_heads"]), int(tcfg["delta_head_dim"])
+        kh, kd, taps = int(tcfg["delta_heads"]), int(tcfg["delta_head_dim"]), int(tcfg.get("delta_conv", 4))
         w = kh * kd
-        by_mixer["delta"] = (4 * E * w + 2 * (E * kd + kd * w) + E * kh
-                             + 3 * w * int(tcfg.get("delta_conv", 4)) + kh + w + kd)
+        if tcfg.get("delta_form") == "gated":
+            # W_qkvz, W_ba, W_o; the taps over q, k and v; A_log, dt_bias, the output norm
+            vh = int(tcfg.get("delta_value_heads") or kh)
+            wv, conv = vh * kd, 2 * w + vh * kd
+            by_mixer["delta"] = E * (conv + wv) + 2 * E * vh + wv * E + conv * taps + 2 * vh + kd
+        else:
+            by_mixer["delta"] = 4 * E * w + 2 * (E * kd + kd * w) + E * kh + 3 * w * taps + kh + w + kd
     if "ssm" in mixers:
         # both projections; the taps and their bias; A_log, D, dt_bias; the gated norm
         sh, w = int(tcfg["ssm_heads"]), int(tcfg["ssm_heads"]) * int(tcfg["ssm_head_dim"])
@@ -1235,9 +1242,11 @@ def _family_param_count(tcfg: Dict[str, Any]) -> int:
         matrices = 3 if tcfg.get("moe_gated", True) else 2
         latent = int(tcfg.get("moe_latent_size") or 0)
         shared = int(tcfg.get("moe_shared_intermediate_size") or F * int(tcfg.get("n_shared_experts", 0)))
-        feed += [E * published + published  # router and its bias
+        softmax = tcfg.get("router_score") == "softmax"
+        feed += [E * published + (0 if softmax else published)  # router and its bias
                  + matrices * (latent or E) * F * held + 2 * E * latent  # the held experts, the latent pair
-                 + (matrices * E * shared if tcfg.get("n_shared_experts") else 0)] * (L - lead)
+                 + (matrices * E * shared if tcfg.get("n_shared_experts") else 0)
+                 + (E if tcfg.get("shared_expert_gate") else 0)] * (L - lead)
     # a layer of one sub-layer has the norm of what it has
     ffns = list(tcfg.get("ffn_layers") or ["full"] * L)
     feed = [0 if kind == "none" else size for kind, size in zip(ffns, feed)]
