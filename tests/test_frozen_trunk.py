@@ -329,6 +329,11 @@ def block_both_ways(trainer, batch, perms):
     return held, whole
 
 
+# the routed counters of what a pass ran, which count the held trunk's once a block
+# (`tests/test_latent_moe.py`): pairs, and the rows and branches of the sorted form
+COUNTED_ONCE_A_BLOCK = ("moe/assignments", "moe/rows_built", "moe/capacity_exceeded")
+
+
 def assert_same_block(got, want, rtol=0.0, skip=()):
     """Parameters, optimizer state, loss and stats, leaf by leaf: bit for
     bit, or within `rtol` of the leaf's largest entry."""

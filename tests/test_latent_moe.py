@@ -286,6 +286,105 @@ def test_the_decode_form_and_the_sorted_form_choose_and_compute_alike():
     assert float(s_decode["load"].sum()) > 0
 
 
+def routed_layer(tokens=256, **over):
+    """A routed layer whose sorted form is bounded: at 2 x 128 tokens, 4 of 64
+    experts held, the capacity (twice the 64 or 128 pairs expected here,
+    rounded up to 256) is a quarter of the 1,024 rows that can be held here."""
+    hf = toy(n_routed_experts_published=64, **over)
+    cfg = TransformerConfig(**dict(ref.system_config(hf), n_positions=64, dtype=jnp.float32))
+    x = jax.random.normal(jax.random.PRNGKey(4), (2, tokens // 2, hf["hidden_size"]))
+    layer = RoutedMLP(cfg)
+    p = jax.tree_util.tree_map(lambda a: 5.0 * a, jax.jit(lambda k: layer.init(k, x)["params"])(jax.random.PRNGKey(5)))
+    return cfg, layer, p, x
+
+
+@pytest.mark.parametrize("over", [{}, {"num_experts_per_tok": 8}], ids=["k_within_held", "k_over_held"])
+@pytest.mark.parametrize("overflow, tokens", [(False, 256), (True, 256), (True, 200)],
+                         ids=["fits", "exceeded", "exceeded_past_the_end"])
+def test_the_bounded_sorted_form_equals_the_whole_form(over, overflow, tokens, monkeypatch):
+    """Outputs, `load` and the gradients with respect to x, the three expert
+    kernels and the router's gate, against the whole form of every row that can
+    be held here (k <= held, and k > held as nemotron's 22 of 512 with 8 held).
+    A selection bias that sends every token to the held experts first makes the
+    pairs held here overflow the capacity: they are built in four passes of 256
+    rows, counted (with 200 tokens the last pass reaches past the sorted order)."""
+    from trlx_tpu.models import transformer
+
+    cfg, layer, p, x = routed_layer(tokens, **over)
+    if overflow:
+        p["router_bias"] = jnp.zeros_like(p["router_bias"]).at[: cfg.n_experts_held].set(10.0)
+    cotangent = jax.random.normal(jax.random.PRNGKey(6), x.shape)
+
+    def run():
+        def loss(p, x):
+            y, stats = layer.apply({"params": p}, x)
+            return jnp.sum(y * cotangent), (y, stats)
+
+        return jax.jit(jax.value_and_grad(loss, (0, 1), has_aux=True))(p, x)
+
+    (_, (y, stats)), grads = run()
+    with monkeypatch.context() as patch:
+        patch.setattr(transformer, "_bounded_sorted", transformer._whole_sorted)
+        (_, (want_y, want_stats)), want = run()
+    held_pairs = float(stats["load"].sum())
+    capacity = 256
+    assert (held_pairs > capacity) == overflow, held_pairs
+    counters = moe_counters(stats, "scorer")
+    assert float(counters["moe/capacity_exceeded.scorer"]) == float(overflow)
+    assert float(counters["moe/rows_built.scorer"]) == (4 * capacity if overflow else capacity)
+    assert np.array_equal(np.asarray(stats["load"]), np.asarray(want_stats["load"]))
+    assert float(jnp.abs(y - want_y).max()) <= 1e-5 * max(1.0, float(jnp.abs(want_y).max()))
+    got = {"x": grads[1], "gate": grads[0]["router_gate"],
+           **{k: grads[0][k]["kernel"] for k in ("experts_fc_in", "experts_fc_gate", "experts_fc_out")}}
+    expected = {"x": want[1], "gate": want[0]["router_gate"],
+                **{k: want[0][k]["kernel"] for k in ("experts_fc_in", "experts_fc_gate", "experts_fc_out")}}
+    for name, g in got.items():
+        w = np.asarray(expected[name])
+        assert np.abs(w).max() > 0, name
+        assert np.abs(np.asarray(g) - w).max() <= 1e-5 * max(1.0, np.abs(w).max()), name
+
+
+@pytest.mark.parametrize("over", [{}, {"num_experts_per_tok": 8}], ids=["k_within_held", "k_over_held"])
+@pytest.mark.parametrize("overflow", [False, True], ids=["fits", "exceeded"])
+def test_the_bounded_sorted_form_equals_the_whole_form_with_int8_experts(over, overflow, monkeypatch):
+    """The rollout's int8 expert kernels (`quantize_decode_weights`): each row of
+    a pass reads its own expert's scales, so the bounded form's output is the
+    whole form's, in one pass and in four."""
+    from trlx_tpu.models import transformer
+
+    cfg, layer, p, x = routed_layer(**over)
+    if overflow:
+        p["router_bias"] = jnp.zeros_like(p["router_bias"]).at[: cfg.n_experts_held].set(10.0)
+    stacked = quantize_decode_weights({"blocks": jax.tree_util.tree_map(lambda a: a[None], p)})["blocks"]
+    q = jax.tree_util.tree_map(lambda a: a[0], stacked)
+    assert q["experts_fc_in"]["kernel"].dtype == jnp.int8 and "kernel_scale" in q["experts_fc_out"]
+    run = jax.jit(lambda q, x: layer.apply({"params": q}, x))
+    y, stats = run(q, x)
+    with monkeypatch.context() as patch:
+        patch.setattr(transformer, "_bounded_sorted", transformer._whole_sorted)
+        want, _ = jax.jit(lambda q, x: layer.apply({"params": q}, x))(q, x)
+    assert (float(stats["load"].sum()) > 256) == overflow
+    assert float(moe_counters(stats, "scorer")["moe/capacity_exceeded.scorer"]) == float(overflow)
+    assert float(jnp.abs(want - run(p, x)[0]).max()) > 1e-3  # the scales are read
+    assert float(jnp.abs(y - want).max()) <= 1e-5 * max(1.0, float(jnp.abs(want).max()))
+
+
+@pytest.mark.parametrize("tokens, bounded", [(64, False), (256, True)], ids=["every_row", "capacity"])
+def test_a_branch_is_lowered_only_where_the_capacity_is_below_every_row(tokens, bounded):
+    """C == N min(K, held) (64 tokens: 256 rows, the capacity's floor) lowers the
+    whole form alone, forward and backward, with no loop; at 256 tokens the
+    passes past the first are a loop, forward and backward."""
+    _, layer, p, x = routed_layer(tokens)
+
+    def loss(p, x):
+        return jnp.sum(layer.apply({"params": p}, x)[0])
+
+    for fn in (lambda p, x: layer.apply({"params": p}, x), jax.grad(loss, (0, 1))):
+        text = jax.jit(fn).lower(p, x).as_text()
+        assert ("stablehlo.while" in text) == bounded
+        assert "stablehlo.case" not in text and "stablehlo.if" not in text
+
+
 def test_sinkhorn_is_doubly_stochastic_and_its_backward_finite_at_the_clamp():
     """Logits of the size the layer makes (a projection scaled by a_res of order 0.01
     to 0.3, plus a bias that starts at 2 I): rows and columns sum to 1 within 1e-4 after
@@ -536,8 +635,9 @@ def blocks(trainer):
 
 def test_the_block_that_holds_the_routed_four_stream_trunk_equals_the_block_that_runs_it_in_every_step(trainer, blocks):
     """Parameters, optimizer state, loss and stats bit for bit; the captures are held
-    in stream form. The two counters of pairs differ by design (the next test)."""
-    from tests.test_frozen_trunk import assert_same_block
+    in stream form. The counters of pairs and of the sorted form's rows differ by
+    design (the next test)."""
+    from tests.test_frozen_trunk import COUNTED_ONCE_A_BLOCK, assert_same_block
 
     t = trainer.trainer
     assert t.trunk_layers_held() == t.model.branch_at == LAYERS - 2
@@ -545,23 +645,25 @@ def test_the_block_that_holds_the_routed_four_stream_trunk_equals_the_block_that
     assert capture.shape == (8, 16, trainer.hf["hc_mult"], trainer.hf["hidden_size"])
     assert counters["load"].shape == (LAYERS - 2 - LEAD, trainer.hf["n_routed_experts"])
     assert np.isfinite(blocks.held[2])
-    assert_same_block(blocks.held, blocks.whole, skip=("moe/assignments",))
+    assert_same_block(blocks.held, blocks.whole, skip=COUNTED_ONCE_A_BLOCK)
 
 
 def test_the_train_counters_count_the_held_trunks_pairs_once_a_block(trainer, blocks):
-    """`moe/assignments_here.train` and `moe/assignments.train` stay "pairs the program
-    ran, a step's mean": times the steps of the block they read the trunk's routed
-    layer ONCE over all rows (counted by a whole forward over them) plus the steps' own
-    for the trainable layers, where the block that runs the whole forward in every
-    step reads the trunk's in every step. The fullest expert's share is a ratio and
-    reads the same."""
+    """`moe/assignments_here.train`, `moe/assignments.train` and `moe/rows_built.train`
+    stay "pairs (rows) the program ran, a step's mean": times the steps of the block
+    they read the trunk's routed layer ONCE over all rows (counted by a whole forward
+    over them) plus the steps' own for the trainable layers, where the block that runs
+    the whole forward in every step reads the trunk's in every step. The fullest
+    expert's share is a ratio and reads the same."""
     t, steps = trainer.trainer, blocks.steps
     stats = jax.jit(lambda base, ids, mask: t.model.lm(base, ids, mask, compute_logits=False)["moe_stats"])(
         t.params["base"], *t._policy_inputs(blocks.rows))
     routed, trunk = LAYERS - LEAD, LAYERS - 2 - LEAD
     assert stats["load"].shape[0] == routed
+    assert float(stats["capacity_exceeded"]) == 0.0  # every layer built its capacity's rows alike
     once = {"moe/assignments_here.train": float(np.sum(stats["load"][:trunk])),
-            "moe/assignments.train": float(stats["assignments"]) * trunk / routed}
+            "moe/assignments.train": float(stats["assignments"]) * trunk / routed,
+            "moe/rows_built.train": float(stats["rows_built"]) * trunk / routed}
     assert once["moe/assignments_here.train"] > 0
     held, whole = blocks.held[3], blocks.whole[3]
     for name, trunks in once.items():

@@ -569,9 +569,10 @@ def test_the_block_that_holds_the_state_space_trunk_equals_the_block_that_runs_i
     """Hydra top 2 of 7: the trunk is M E M * E, five segments in three stacks; the
     fused block holds its output across two epochs and leaves parameters, optimizer
     state, loss and stats as the block that runs the chunked form through it in every
-    step does (the two counters of pairs count the trunk's once a block:
-    `tests/test_latent_moe.py`)."""
-    from tests.test_frozen_trunk import assert_same_block, block_both_ways, block_perms, rollout_batch
+    step does (the counters of pairs and of sorted rows count the trunk's once a
+    block: `tests/test_latent_moe.py`)."""
+    from tests.test_frozen_trunk import (COUNTED_ONCE_A_BLOCK, assert_same_block, block_both_ways,
+                                         block_perms, rollout_batch)
 
     hf, trainer = trainer.hf, trainer.trainer
     assert trainer.trunk_layers_held() == trainer.model.frozen_below() == 5
@@ -580,7 +581,7 @@ def test_the_block_that_holds_the_state_space_trunk_equals_the_block_that_runs_i
     assert capture.shape == (8, 16, hf["hidden_size"]) and counters["load"].shape[0] == 2
     held, whole = block_both_ways(trainer, rows, block_perms(8, 8, 2))
     assert np.isfinite(held[2])
-    assert_same_block(held, whole, skip=("moe/assignments",))
+    assert_same_block(held, whole, skip=COUNTED_ONCE_A_BLOCK)
 
 
 def test_gauges_and_the_state_count_reach_the_flight_stream(trainer, caplog):

@@ -2210,6 +2210,165 @@ def _rows_to_slots_bwd(slot_of, g):
 _rows_to_slots.defvjp(_rows_to_slots_fwd, _rows_to_slots_bwd)
 
 
+@jax.custom_vjp
+def _token_rows(x, token):
+    """x [N, E] -> [C, E]: row r is token `token[r]`. Its transpose adds each
+    row's gradient into its token's in float32, as `_rows_bwd`'s sum does."""
+    return jnp.take(x, token, axis=0)
+
+
+def _token_rows_fwd(x, token):
+    return jnp.take(x, token, axis=0), (token, x.shape[0])
+
+
+def _token_rows_bwd(res, g):
+    token, n = res
+    return jnp.zeros((n, g.shape[-1]), jnp.float32).at[token].add(g.astype(jnp.float32)).astype(g.dtype), None
+
+
+_token_rows.defvjp(_token_rows_fwd, _token_rows_bwd)
+
+
+@dataclass(frozen=True)
+class _SortedForm:
+    """The static facts of one call of `RoutedMLP`'s sorted form: K choices a
+    token, `held` experts here, `capacity` rows its bounded branch builds,
+    the experts' activation and gate, the compute dtype."""
+
+    K: int
+    held: int
+    capacity: int
+    act: str
+    gated: bool
+    dtype: Any
+
+
+def _grouped_experts(form: _SortedForm, a, row_expert, load, kernels):
+    """The held experts over rows `a` sorted by expert (`load` rows each, rows
+    past them are zero): three grouped products, `kernels` their (weight,
+    int8 scale or None) pairs."""
+    (w_in, s_in), (w_gate, s_gate), (w_out, s_out) = kernels
+
+    def product(a, w, scale):
+        y = jax.lax.ragged_dot(a, w.astype(form.dtype), load)
+        if scale is not None:
+            y = y * jnp.take(scale, jnp.minimum(row_expert, form.held - 1), axis=0).astype(form.dtype)
+        return y
+
+    h = _activation(form.act)(product(a, w_in, s_in))
+    if form.gated:
+        h = h * product(a, w_gate, s_gate)
+    return product(h, w_out, s_out)
+
+
+def _whole_sorted(form: _SortedForm, xf, weight, key, order, load, kernels):
+    """The sorted form over every row that can be held here, N * min(K, held):
+    the row of each token-expert pair in `order` (pairs held here first, by
+    expert), the grouped products, and each token's K rows weighed back."""
+    N, K, held = xf.shape[0], form.K, form.held
+    inverse = jnp.argsort(order).astype(jnp.int32)  # assignment -> row
+    # a token meets at most `held` of the experts held here: where it
+    # is sent to more than that (22 of 512, 8 held), the first
+    # N * held rows hold every assignment computed here, and the rest
+    # of the N * K are never built
+    Kh = min(K, held)
+    M = N * Kh
+    valid = (jnp.arange(M) < jnp.sum(load))[:, None]
+    if Kh == K:
+        rows = jnp.where(valid, _rows_by_assignment(xf.astype(form.dtype), order, inverse), 0)
+    else:
+        # of a token's K rows the Kh first (its held ones among them), slot by slot
+        by_token = inverse.reshape(N, K)
+        nth = jnp.argsort(by_token, axis=1)[:, :Kh].astype(jnp.int32)  # [N, Kh] choices
+        rows_of = jnp.take_along_axis(by_token, nth, axis=1)  # their rows
+        held_of = rows_of < jnp.sum(load)
+        rows_of = jnp.minimum(rows_of, M - 1)
+        order = order[:M]
+        token = order // K
+        # row r is slot (token, p): p, how many of the token's rows come before it
+        before = jnp.sum(jnp.take(rows_of, token, axis=0) < jnp.arange(M)[:, None], axis=1)
+        slot_of = token * Kh + jnp.minimum(before, Kh - 1).astype(jnp.int32)
+        rows = jnp.where(valid, _rows_of_tokens(xf.astype(form.dtype), token, rows_of, held_of), 0)
+    row_expert = jnp.take(key, order)
+    out = jnp.where(valid, _grouped_experts(form, rows, row_expert, load, kernels), 0)  # [M, Ein]
+    if Kh == K:
+        back = _permute_rows(out, inverse, order).reshape(N, K, out.shape[-1])
+    else:  # (a slot that is not the token's own weighs what it reads with zero)
+        back = _rows_to_slots(out, rows_of, slot_of)
+        weight = jnp.take_along_axis(weight, nth, axis=1)
+    return jnp.einsum("nk,nkd->nd", weight.astype(form.dtype), back)
+
+
+def _sorted_pass(form: _SortedForm, start, acc, xf, weight, key, order, load, kernels):
+    """Rows [start, start + capacity) of the sorted order (padded past its
+    end where a pass reaches it): the rows' tokens gathered, the grouped
+    products over each held expert's rows among them, each row weighed and
+    added into its token's row of `acc` (float32, rounded once by the caller,
+    as the whole form's contraction over a token's rows)."""
+    C = form.capacity
+    rows = jax.lax.dynamic_slice_in_dim(order, start, C)
+    token = rows // form.K
+    end = jnp.cumsum(load)
+    in_pass = jnp.clip(end - start, 0, C) - jnp.clip(end - load - start, 0, C)  # each held expert's rows here
+    valid = (start + jnp.arange(C) < end[-1])[:, None]
+    a = jnp.where(valid, _token_rows(xf.astype(form.dtype), token), 0)
+    out = jnp.where(valid, _grouped_experts(form, a, jnp.take(key, rows), in_pass, kernels), 0)  # [C, Ein]
+    w = jnp.take(weight.reshape(-1), rows).astype(form.dtype).astype(jnp.float32)
+    return acc.at[token].add(w[:, None] * out.astype(jnp.float32))
+
+
+def _routed_zeros(xf, kernels):  # the float32 sum of the routed rows, [N, Ein]
+    return jnp.zeros((xf.shape[0], kernels[2][0].shape[-1]), jnp.float32)
+
+
+def _passes(form: _SortedForm, load):
+    return -(-jnp.sum(load) // form.capacity)
+
+
+def _sorted_passes(form: _SortedForm, xf, weight, key, order, load, kernels):
+    """Every pair held here, `capacity` rows a pass: the first pass always,
+    and a loop over the rest where the pairs take more than one."""
+    order = jnp.pad(order, (0, form.capacity))
+
+    def add_pass(j, acc):
+        return _sorted_pass(form, j * form.capacity, acc, xf, weight, key, order, load, kernels)
+
+    acc = jax.lax.fori_loop(1, _passes(form, load), add_pass, add_pass(0, _routed_zeros(xf, kernels)))
+    return acc.astype(form.dtype)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _bounded_sorted(form: _SortedForm, xf, weight, key, order, load, kernels):
+    """`_sorted_passes`: the same function of the inputs as `_whole_sorted`,
+    in one pass where the pairs held here fit in `form.capacity` rows. The
+    backward keeps only the inputs and builds each pass's rows again, so
+    neither direction holds more than one pass's rows."""
+    return _sorted_passes(form, xf, weight, key, order, load, kernels)
+
+
+def _bounded_fwd(form, xf, weight, key, order, load, kernels):
+    return _sorted_passes(form, xf, weight, key, order, load, kernels), (xf, weight, key, order, load, kernels)
+
+
+def _bounded_bwd(form, res, g):
+    xf, weight, key, order, load, kernels = res
+    order = jnp.pad(order, (0, form.capacity))
+
+    def pull(j):  # the gradient of pass j
+        def contribution(x, w, k):
+            return _sorted_pass(form, j * form.capacity, _routed_zeros(x, k), x, w, key, order, load, k)
+
+        _, back = jax.vjp(contribution, xf, weight, kernels)
+        return back(g.astype(jnp.float32))
+
+    dx, dw, dk = jax.lax.fori_loop(
+        1, _passes(form, load), lambda j, total: jax.tree_util.tree_map(jnp.add, total, pull(j)), pull(0))
+    return dx, dw, None, None, None, dk
+
+
+_bounded_sorted.defvjp(_bounded_fwd, _bounded_bwd)
+
+
 class _ExpertKernel(nn.Module):
     """One stacked expert weight [held, in, out] under `<name>/kernel`, with
     the per-expert, per-output-channel scale `quantize_decode_weights` puts
@@ -2235,10 +2394,10 @@ class RoutedMLP(nn.Module):
 
     The router is as wide as published (`n_routed_experts`); the chip holds
     experts [first_expert_held, first_expert_held + n_experts_held) and
-    computes their part for the tokens routed to them. No capacity, no
-    dropped token; an assignment to an expert held elsewhere costs nothing
-    here and adds nothing (the chips that hold it would add it). Nothing
-    stands in for those chips or their exchange.
+    computes their part for the tokens routed to them. No dropped token;
+    an assignment to an expert held elsewhere costs nothing here and adds
+    nothing (the chips that hold it would add it). Nothing stands in for
+    those chips or their exchange.
 
     Two forms by the shape of the call: a decode step (T == 1, a handful of
     rows) runs every held expert over every row as one batched product and
@@ -2248,13 +2407,22 @@ class RoutedMLP(nn.Module):
     were read on the chip at 32 rows a step (PERF.md section 6, PR 28): the
     sorted form there makes the sampler 24% slower (a sort, three gathers
     and three grouped products of 16 rows a layer a step, launches all).
+    The sorted form builds C = min(N min(K, held), twice the pairs expected
+    here under uniform routing, rounded up to 256) rows, from the call's
+    shapes alone. That capacity only decides how many passes run, never
+    which token is computed: the pairs held here are built C rows a pass,
+    in one pass where they fit and in as many as they need where they do
+    not (`_sorted_passes`); where C is every such row there is no loop
+    (`_whole_sorted`).
     With `moe_latent_size` (scope `moe_latent`) the experts work in a latent
     space: u = x W_down once a token, the experts are that wide at both
     ends, and r W_up brings their weighted sum back (linear: the shares'
     parts add up); the shared expert reads x itself, at its own width.
     `moe_gated` False: an expert is W2 act(W1 u), no gate matrix.
     Returns (y, stats): `load`, the rows each held expert computed,
-    `assignments`, the token-expert pairs the router made (`moe_counters`),
+    `assignments`, the token-expert pairs the router made, `rows_built`,
+    the rows the sorted form built (0 in a decode step), and
+    `capacity_exceeded`, 1 where they took more than one pass (`moe_counters`),
     and `choices`, how often each of the router's experts was chosen, held
     here or not (`balance_router_bias`).
     """
@@ -2310,11 +2478,11 @@ class RoutedMLP(nn.Module):
                 (local[..., None] == jnp.arange(held)) & here[..., None], axis=(0, 1)
             ).astype(jnp.int32)  # rows of each held expert
 
-            def hidden(product, a):  # an expert up to its second matrix
-                h = act(product(a, w_in, s_in))
-                return h * product(a, w_gate, s_gate) if cfg.moe_gated else h
-
             if decode:
+                def hidden(product, a):  # an expert up to its second matrix
+                    h = act(product(a, w_in, s_in))
+                    return h * product(a, w_gate, s_gate) if cfg.moe_gated else h
+
                 xb = jnp.broadcast_to(xf.astype(cfg.dtype)[None], (held, N, Ein))
 
                 def product(a, w, scale):
@@ -2326,47 +2494,23 @@ class RoutedMLP(nn.Module):
                     jnp.where(local[..., None] == jnp.arange(held), weight[..., None], 0.0), axis=1
                 )  # [N, held]
                 routed = jnp.einsum("ne,end->nd", per_expert.astype(cfg.dtype), out)
+                built = exceeded = jnp.float32(0)
             else:
                 key = jnp.where(here, local, held).reshape(N * K)  # held = elsewhere, sorts last
                 order = jnp.argsort(key, stable=True).astype(jnp.int32)  # row -> assignment
-                inverse = jnp.argsort(order).astype(jnp.int32)  # assignment -> row
-                # a token meets at most `held` of the experts held here: where it
-                # is sent to more than that (22 of 512, 8 held), the first
-                # N * held rows hold every assignment computed here, and the rest
-                # of the N * K are never built
-                Kh = min(K, held)
-                M = N * Kh
-                valid = (jnp.arange(M) < jnp.sum(load))[:, None]
-                if Kh == K:
-                    rows = jnp.where(valid, _rows_by_assignment(xf.astype(cfg.dtype), order, inverse), 0)
+                kernels = ((w_in, s_in), (w_gate, s_gate), (w_out, s_out))
+                M = N * min(K, held)  # every row that can be held here
+                expected = -(-2 * N * K * held // cfg.n_routed_experts)
+                form = _SortedForm(K, held, min(M, -(-expected // 256) * 256), cfg.activation,
+                                   cfg.moe_gated, cfg.dtype)
+                if form.capacity == M:
+                    routed = _whole_sorted(form, xf, weight, key, order, load, kernels)
+                    built, exceeded = jnp.float32(M), jnp.float32(0)
                 else:
-                    # of a token's K rows the Kh first (its held ones among them), slot by slot
-                    by_token = inverse.reshape(N, K)
-                    nth = jnp.argsort(by_token, axis=1)[:, :Kh].astype(jnp.int32)  # [N, Kh] choices
-                    rows_of = jnp.take_along_axis(by_token, nth, axis=1)  # their rows
-                    held_of = rows_of < jnp.sum(load)
-                    rows_of = jnp.minimum(rows_of, M - 1)
-                    order = order[:M]
-                    token = order // K
-                    # row r is slot (token, p): p, how many of the token's rows come before it
-                    before = jnp.sum(jnp.take(rows_of, token, axis=0) < jnp.arange(M)[:, None], axis=1)
-                    slot_of = token * Kh + jnp.minimum(before, Kh - 1).astype(jnp.int32)
-                    rows = jnp.where(valid, _rows_of_tokens(xf.astype(cfg.dtype), token, rows_of, held_of), 0)
-                row_expert = jnp.take(key, order)
-
-                def product(a, w, scale):
-                    y = jax.lax.ragged_dot(a, w.astype(cfg.dtype), load)
-                    if scale is not None:
-                        y = y * jnp.take(scale, jnp.minimum(row_expert, held - 1), axis=0).astype(cfg.dtype)
-                    return y
-
-                out = jnp.where(valid, product(hidden(product, rows), w_out, s_out), 0)  # [M, Ein]
-                if Kh == K:
-                    back = _permute_rows(out, inverse, order).reshape(N, K, Ein)
-                else:  # (a slot that is not the token's own weighs what it reads with zero)
-                    back = _rows_to_slots(out, rows_of, slot_of)
-                    weight = jnp.take_along_axis(weight, nth, axis=1)
-                routed = jnp.einsum("nk,nkd->nd", weight.astype(cfg.dtype), back)
+                    routed = _bounded_sorted(form, xf, weight, key, order, load, kernels)
+                    passes = jnp.maximum(_passes(form, load), 1)
+                    built = (passes * form.capacity).astype(jnp.float32)
+                    exceeded = (passes > 1).astype(jnp.float32)
 
         if cfg.moe_latent_size:
             with jax.named_scope("moe_latent"):
@@ -2384,6 +2528,7 @@ class RoutedMLP(nn.Module):
                         shared = (jax.nn.sigmoid(w_sg.astype(jnp.float32)) * shared).astype(cfg.dtype)
                 y = y + shared
         stats = {"load": load.astype(jnp.float32), "assignments": jnp.float32(N * K),
+                 "rows_built": built, "capacity_exceeded": exceeded,
                  "choices": choices.astype(jnp.float32)}
         if cfg.router_score == "softmax":  # the mean probability's numerator (`balance_router_gates`)
             stats["probs"] = jnp.sum(s, axis=0)
@@ -2625,12 +2770,17 @@ def make_attention_bias(
     return jnp.where(visible, 0.0, NEG_INF)[:, None, :, :].astype(jnp.float32)
 
 
+# a routed layer's scalar counters, summed over layers, stretches and calls
+_SUMMED_STATS = ("assignments", "rows_built", "capacity_exceeded")
+
+
 def _fold_layer_stats(stats: Optional[Dict[str, Array]]) -> Optional[Dict[str, Array]]:
     """A scan's stacked per-layer counters: `load` [layers, held] and
-    `choices` [layers, published] stay by layer, the assignments made are summed."""
+    `choices` [layers, published] stay by layer, the assignments made and the
+    sorted form's rows and branches (`_SUMMED_STATS`) are summed."""
     if not stats:
         return None
-    return dict(stats, assignments=jnp.sum(stats["assignments"]))
+    return dict(stats, **{name: jnp.sum(stats[name]) for name in _SUMMED_STATS})
 
 
 def _join_stats(a: Optional[Dict[str, Array]], b: Optional[Dict[str, Array]]):
@@ -2638,7 +2788,7 @@ def _join_stats(a: Optional[Dict[str, Array]], b: Optional[Dict[str, Array]]):
     if not a or not b:
         return a or b
     out = {"load": jnp.concatenate([a["load"], b["load"]], axis=0),
-           "assignments": a["assignments"] + b["assignments"],
+           **{name: a[name] + b[name] for name in _SUMMED_STATS},
            "choices": jnp.concatenate([a["choices"], b["choices"]], axis=0)}
     if "probs" in a:  # a softmax router's (`balance_router_gates`)
         out["probs"] = jnp.concatenate([a["probs"], b["probs"]], axis=0)
@@ -2658,16 +2808,24 @@ def moe_counters(stats: Optional[Dict[str, Array]], program: str) -> Dict[str, A
     pairs computed on this chip), `moe/assignments` (pairs the routers made:
     tokens x experts per token x routed layers run) and
     `moe/load_max_over_mean` (the fullest held expert's rows over the mean
-    of the held experts, in the worst layer). Empty for a model without experts."""
+    of the held experts, in the worst layer); beside them, but for the
+    sampler (its decode steps build no sorted rows), `moe/rows_built` (rows
+    the sorted form built) and `moe/capacity_exceeded` (its calls whose
+    pairs held here took more than one pass, `RoutedMLP`). Empty for a model without
+    experts."""
     if not stats:
         return {}
     load = jax.lax.stop_gradient(stats["load"])
     ratio = jnp.max(load, axis=-1) / jnp.maximum(jnp.mean(load, axis=-1), 1e-9)
-    return {
+    out = {
         f"moe/assignments_here.{program}": jnp.sum(load),
         f"moe/assignments.{program}": jax.lax.stop_gradient(stats["assignments"]),
         f"moe/load_max_over_mean.{program}": jnp.max(ratio),
     }
+    if program != "sampler":
+        out[f"moe/rows_built.{program}"] = jax.lax.stop_gradient(stats["rows_built"])
+        out[f"moe/capacity_exceeded.{program}"] = jax.lax.stop_gradient(stats["capacity_exceeded"])
+    return out
 
 
 def router_bias(tree: Dict, leaf: str = "router_bias") -> Dict[str, Array]:
